@@ -46,6 +46,13 @@ The supervisor never parses worker output and the workers never talk to
 the supervisor — liveness flows exclusively through the coordinator
 membership, so the same supervisor drives local subprocess trees today
 and remote launchers later.
+
+One process for each chip: a chip belongs to the first process that
+touches it, so N workers spawned on one host cannot share it. The
+subprocess trees this module starts are host-only drills — their
+workers run with `JAX_PLATFORMS=cpu` in the child's environment (the
+tests pin it) — and a multi-chip host is driven by ONE process that
+holds all of its chips.
 """
 
 from __future__ import annotations

@@ -518,18 +518,18 @@ def _auc(ctx, ins, attrs):
 @register_op("flash_attention")
 def _flash_attention(ctx, ins, attrs):
     """Fused blockwise attention on [B, T, H, D] (pallas kernel,
-    parallel/flash_attention.py — 2.2x faster than XLA full-matrix
-    attention at T=4096 bf16 on chip; interpret mode on CPU). The fluid
+    parallel/flash_attention.py; interpret mode on CPU). The fluid
     surface's door to the hot kernel: the compute runs through the same
     custom-vjp flash path the transformer flagship uses."""
     from ...parallel.flash_attention import flash_attention as _flash
+    from ...parallel.kernel_utils import resolve_interpret
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     out = _flash(
         q, k, v,
         causal=bool(attrs.get("causal", False)),
         scale=attrs.get("scale") or None,
-        interpret=jax.default_backend() == "cpu",
+        interpret=resolve_interpret(None),
     )
     return {"Out": out}
 

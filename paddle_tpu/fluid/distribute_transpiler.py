@@ -90,9 +90,8 @@ class DistributeTranspiler(object):
     def get_trainer_program(self) -> Program:
         """The original program, to be run by an Executor holding a mesh
         whose 'data' axis plays the role of `trainers`."""
-        import jax
-
-        from ..parallel.mesh import get_default_mesh, make_mesh, set_default_mesh
+        from ..parallel.mesh import (data_parallel_width, get_default_mesh,
+                                     make_mesh, set_default_mesh)
 
         if not getattr(self, "_sync_mode", True):
             # fire at the point of use too — the transpile-time warning
@@ -105,14 +104,14 @@ class DistributeTranspiler(object):
             )
 
         if get_default_mesh() is None:
-            n = min(self._trainers, jax.device_count())
+            n = data_parallel_width(self._trainers)
             if n > 1:
                 set_default_mesh(make_mesh({"data": n}))
             elif self._trainers > 1:
                 warnings.warn(
-                    "transpile(trainers=%d) but only %d device(s) visible; "
-                    "running single-device with identical global-batch math"
-                    % (self._trainers, jax.device_count())
+                    "transpile(trainers=%d) on one CPU device: running "
+                    "single-device with identical global-batch math"
+                    % self._trainers
                 )
         return self._program
 

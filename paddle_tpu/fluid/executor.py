@@ -753,7 +753,7 @@ def _split_lod_feed(value):
     `.data/.lod` (our LoDTensor helper). Device-resident jax arrays
     pass through UNTOUCHED — np.asarray on them is a device->host copy
     that would defeat the device-resident fast path (_to_device_dtype)
-    and, through a remote tunnel, re-cross the wire per run call."""
+    and pay a transfer each way per run call."""
     if isinstance(value, tuple) and len(value) == 2 and not np.isscalar(value[0]):
         data, lod = value
         if not isinstance(data, jax.Array):

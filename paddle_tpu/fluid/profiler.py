@@ -428,11 +428,11 @@ _OPCODE_RE = _re.compile(r"\b([a-z][a-z0-9\-]*)\(")
 # Overlapped memory-movement / bookkeeping instructions: XLA hides them
 # behind compute (async weight-prefetch slices, aliasing bitcasts), so
 # they carry bytes but ~zero serial time — billing them serially made
-# the '[xla]' row claim 58% of the modeled step vs 22% measured on-chip
-# (BENCH_r05_builder.jsonl profiler_reconciliation). Synchronous VMEM
-# staging `copy`/`copy-done` instructions are NOT here: the on-chip
-# trace shows they DO serialize (~25% of the ResNet step at b=32);
-# `copy-start` alone stays free so the start/done pair is billed once.
+# the '[xla]' row claim far more of the modeled step than an earlier
+# round's on-chip trace gave it. Synchronous VMEM staging
+# `copy`/`copy-done` instructions are NOT here: that trace showed they
+# DO serialize; `copy-start` alone stays free so the start/done pair is
+# billed once.
 _OVERLAPPED_OPCODES = {
     "copy-start", "async-start", "async-done",
     "slice-start", "slice-done", "bitcast", "bitcast-convert",
@@ -561,9 +561,8 @@ def compiled_profile(exe, program, feed, fetch_list, runs=3,
     step_s = dev_s if dev_s is not None else e2e_s
 
     # roofline-time split: each row's share is max(HBM time, MXU time) in
-    # byte-equivalents (teq) — on-chip reconciliation against jax.profiler
-    # traces showed a bytes-only split under-weighting the compute-bound
-    # backward convs by ~3x (BENCH_r05_builder.jsonl profiler_reconciliation)
+    # byte-equivalents (teq) — a bytes-only split under-weights the
+    # compute-bound backward convs
     total_teq = sum(r["teq"] for r in rows.values()) or 1
     table = [
         {

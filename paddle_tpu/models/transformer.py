@@ -511,18 +511,10 @@ def kv_block_bytes(layers_n: int, heads: int, dh: int,
 
 def kv_storage_dtype(kv_quant: str):
     """Pool storage dtype for a kv_quant setting; None = the model
-    dtype (unquantized). Raises on fp8 when this jax build has no
-    float8_e4m3fn — a loud gate, never a silent f32 fallback."""
+    dtype (unquantized)."""
     _kv_quant_check(kv_quant)
-    if kv_quant == "int8":
-        return jnp.int8
-    if kv_quant == "fp8":
-        if not hasattr(jnp, "float8_e4m3fn"):
-            raise ValueError(
-                "kv_quant='fp8' needs jnp.float8_e4m3fn (this jax "
-                "build has none) — use 'int8' or 'none'")
-        return jnp.float8_e4m3fn
-    return None
+    return {"none": None, "int8": jnp.int8,
+            "fp8": jnp.float8_e4m3fn}[kv_quant]
 
 
 def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,

@@ -4,21 +4,23 @@ The reference keeps its data plane native (RecordIO chunks for the Go
 master, PyDataProvider2's C++ prefetch queue); this package does the same
 for the TPU framework: `recordio.cc` is compiled on first use with the
 ambient g++ into a shared library (no pybind11 in this environment — the
-C ABI + ctypes is the binding). Pure-Python fallbacks keep the API alive
-on machines without a toolchain.
+C ABI + ctypes is the binding). There is no pure-Python stand-in: on a
+machine without a C++ compiler every entry point raises RuntimeError
+saying so.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_HERE, "_build", "librecordio.so")
+_BUILD_DIR = os.path.join(_HERE, "_build")
 _SRC = os.path.join(_HERE, "recordio.cc")
-_INFER_SO = os.path.join(_HERE, "_build", "libptpu_infer.so")
 _INFER_SRC = os.path.join(_HERE, "inference.cc")
 _lock = threading.Lock()
 _lib = None
@@ -27,20 +29,44 @@ _infer_lib = None
 _infer_error = None
 
 
-def _compile(src: str, so: str) -> str:
-    os.makedirs(os.path.dirname(so), exist_ok=True)
-    tmp = so + ".tmp.so"
-    cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        src, "-o", tmp,
-    ]
-    subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(tmp, so)
+def _built(src: str, stem: str) -> str:
+    """Path of the shared library for `src`, compiling it unless this
+    exact source was already built here. The file name carries a hash
+    of the source: `_build/` is ignored by git but travels with a copy
+    of the tree, where modification times say nothing, so a library is
+    only ever loaded for the source it was built from. The compiler
+    writes to a name of this process's own and the finished file is
+    renamed into place, so builders racing for one name (test workers)
+    each publish a whole library and none loads a half-written one."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_BUILD_DIR, "%s-%s.so" % (stem, digest))
+    if os.path.exists(so):
+        return so
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    fd, tmp = tempfile.mkstemp(dir=_BUILD_DIR, prefix=stem + "-",
+                               suffix=".tmp")
+    os.close(fd)
+    try:
+        subprocess.run(
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+             src, "-o", tmp],
+            check=True, capture_output=True)
+    except FileNotFoundError:
+        raise RuntimeError(
+            "no C++ compiler: g++ is not on PATH, and %s has to be "
+            "compiled before use" % os.path.basename(src)) from None
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            "g++ failed on %s:\n%s" % (
+                os.path.basename(src),
+                e.stderr.decode(errors="replace")[-2000:])) from None
+    else:
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
     return so
-
-
-def _build() -> str:
-    return _compile(_SRC, _SO)
 
 
 def lib():
@@ -53,12 +79,8 @@ def lib():
         if _build_error is not None:
             raise RuntimeError("native build failed earlier: %s" % _build_error)
         try:
-            if not os.path.exists(_SO) or (
-                os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-            ):
-                _build()
-            L = ctypes.CDLL(_SO)
-        except Exception as e:  # keep the error for later callers
+            L = ctypes.CDLL(_built(_SRC, "librecordio"))
+        except (OSError, RuntimeError) as e:  # keep for later callers
             _build_error = e
             raise RuntimeError("cannot build/load native recordio: %s" % e)
         L.rio_writer_open.restype = ctypes.c_void_p
@@ -106,14 +128,10 @@ def infer_lib_path() -> str:
                 "native inference build failed earlier: %s" % _infer_error
             )
         try:
-            if not os.path.exists(_INFER_SO) or (
-                os.path.getmtime(_INFER_SRC) > os.path.getmtime(_INFER_SO)
-            ):
-                _compile(_INFER_SRC, _INFER_SO)
-        except Exception as e:
+            return _built(_INFER_SRC, "libptpu_infer")
+        except (OSError, RuntimeError) as e:
             _infer_error = e
             raise RuntimeError("cannot build native inference: %s" % e)
-        return _INFER_SO
 
 
 def infer_lib():

@@ -31,20 +31,12 @@ from typing import Any, Dict, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
-if hasattr(lax, "pcast"):
-    def _revary(v, axis):
-        return lax.pcast(v, axis, to="varying")
-else:  # pragma: no cover - older jax
-    def _revary(v, axis):
-        return lax.pvary(v, (axis,))
+def _revary(v, axis):
+    return lax.pcast(v, axis, to="varying")
 
 
 def build_local_sgd_fn(
@@ -102,7 +94,7 @@ def build_local_sgd_fn(
                 (jnp.arange(sync_every), per_round),
             )
             # sync point: replicas average their models (the only
-            # collective; everything above ran replica-local). pvary
+            # collective; everything above ran replica-local). pcast
             # re-tags the now-identical copies as axis-varying so the
             # scan carry type stays stable (shard_map VMA tracking)
             newp = {

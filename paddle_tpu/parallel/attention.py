@@ -28,13 +28,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 __all__ = [
     "ring_attention",
@@ -62,11 +58,7 @@ def reference_attention(q, k, v, causal: bool = False, scale=None):
 def _varying(x, axis_name):
     """Mark a scan-carry constant as device-varying over the ring axis
     (shard_map's vma type system; constants start out unvarying)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, (axis_name,))
-    return x
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def _online_softmax_update(o, l, m, s, vs):
@@ -378,10 +370,7 @@ def sequence_parallel_attention(
         # own error text recommends check_vma=False as the workaround);
         # only the pallas-bearing path drops the check — ring and
         # ulysses-reference keep the replication typing
-        try:
-            mapped = shard_map(body, check_vma=False, **kwargs)
-        except TypeError:  # older jax: no check_vma kwarg
-            mapped = shard_map(body, **kwargs)
+        mapped = shard_map(body, check_vma=False, **kwargs)
     else:
         mapped = shard_map(body, **kwargs)
     return mapped(q, k, v)

@@ -360,13 +360,12 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_k: int = 1024, interpret: bool = False):
     """Blockwise attention for [B, T, H, D] tensors (same layout as
     parallel/attention.py). Block sizes clamp to the sequence lengths
-    and halve until they divide them. Defaults from the r5 on-chip sweep
-    (T=4096 bf16, scan-differenced, compiled Mosaic): 1024x1024 runs
-    2.57x FASTER than XLA's full-matrix attention (39.5 TFLOP/s fwd);
-    r3's 512x1024 measured 2.24x, 512x512 1.68x, 1024x512 1.60x;
+    and halve until they divide them. The 1024x1024 default came out
+    of an earlier round's block sweep on a v5e whose records are gone;
+    its speed is not measured on this tree. What the compiler says:
     2048-wide q or k blocks exceed the 16 MB scoped-VMEM budget and
-    fail to compile; the old 128x128 was 3x slower (65k-step grid of
-    tiny matmuls starves the MXU)."""
+    fail to compile, and small blocks (128x128) make a grid of tens of
+    thousands of tiny matmuls."""
     B, T, H, D = q.shape
     S = k.shape[1]
     if scale is None:

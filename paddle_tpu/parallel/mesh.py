@@ -53,6 +53,23 @@ def make_mesh(
     return Mesh(arr, names)
 
 
+def data_parallel_width(requested: int) -> int:
+    """Devices a request for `requested` data-parallel trainers gets
+    (the reference's `trainer_count` / `transpile(trainers=N)`). On an
+    accelerator backend, asking for more chips than are visible is an
+    error — quietly training on fewer would report one chip's speed as
+    N's. On the CPU backend the reference's trainers were host threads,
+    which XLA:CPU already spreads one device's work over, so the
+    request clamps to the devices there are."""
+    requested, have = int(requested), jax.device_count()
+    if requested > have and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "%d data-parallel trainers requested but only %d %s "
+            "device(s) are visible" % (requested, have,
+                                       jax.default_backend()))
+    return min(requested, have)
+
+
 def make_hybrid_mesh(
     dcn_axes: Dict[str, int],
     ici_axes: Dict[str, int],

@@ -45,11 +45,13 @@ since PR 2.
 
 Quantized pools (ISSUE 14): with `k_scale`/`v_scale` [NB, H] the
 pools hold int8/fp8 codes and the kernels dequantize IN VMEM — the
-scales ride as scalar-prefetch operands (SMEM, like the tables), the
-DMA stays in the storage dtype, and each per-head f32 slice
-multiplies by its block's scalar scale before the matmuls. The same
-no-HBM-view discipline, applied to the dequantized values: they
-never exist outside VMEM.
+scales of the blocks each slot's table names ride as scalar-prefetch
+operands (SMEM, like the tables; gathered through the table to a flat
+[S, MAXB*H] row per slot, so their SMEM cost follows slots x table
+width and never the pool size), the DMA stays in the storage dtype,
+and each per-head f32 slice multiplies by its block's scalar scale
+before the matmuls. The same no-HBM-view discipline, applied to the
+dequantized values: they never exist outside VMEM.
 
 `interpret=None` resolves via kernel_utils.resolve_interpret: CPU CI
 runs the identical kernel interpreted; on TPU it compiles to Mosaic.
@@ -73,23 +75,26 @@ from jax.experimental.pallas import tpu as pltpu
 from .kernel_utils import NEG_INF, resolve_interpret
 
 __all__ = ["paged_decode_attention", "paged_verify_attention",
-           "paged_prefill_attention"]
+           "paged_prefill_attention", "check_paged_smem"]
 
 
 def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
                scale_in_q: bool, quant: bool):
-    """One (slot, table-GROUP) grid step: stream the G consecutive
-    blocks the slot's table names at this depth range, fold them into
-    the running online-softmax state for all R window rows of every
-    head.
+    """One (slot, row-tile, table-GROUP) grid step: stream the G
+    consecutive blocks the slot's table names at this depth range,
+    fold them into the running online-softmax state for the R window
+    rows of this row tile, every head.
 
-    Grid (S, ceil(MAXB/G)), groups innermost — the flash
+    Grid (S, ceil(rows/R), ceil(MAXB/G)), groups innermost — the flash
     grid-reduction pattern: init at b == 0, accumulate per group,
     finalise at the last group. `tbl_ref` [S, MAXB] / `base_ref` [S]
     are scalar-prefetch refs; the g-th K/V BlockSpec index map already
     used tbl_ref to pick physical block tbl[s, b*G + g] (clamped to 0
     when unallocated — masked below, exact no-op), so the per-head
-    score tile is [R, G*Bt].
+    score tile is [R, G*Bt]. Row tile t holds window rows
+    [t*R, (t+1)*R): its first row sits at global position
+    base + t*R, and nothing else distinguishes the tiles — each keeps
+    its own (m, l, acc) state from init to finalise.
 
     Mosaic constraints shape the body, each probed by AOT-compiling
     for a virtual v5e (the bench_offline pattern): its dot takes 2D
@@ -105,8 +110,9 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
     fewer, larger grid steps for the DMA pipeline to overlap).
 
     With `quant` (ISSUE 14) the pools hold int8/fp8 codes and two more
-    SCALAR-PREFETCH operands carry the per-(physical block, head)
-    absmax scales [NB, H] f32: after each group's blocks upcast to f32
+    SCALAR-PREFETCH operands carry the absmax scales of the blocks the
+    tables name, flat [S, MAXB*H] f32 (entry (s, d*H + h) is table
+    depth d, head h): after each group's blocks upcast to f32
     in VMEM (the same one-upcast-then-slice-f32 discipline the 16-bit
     path needs anyway), every per-head 2D slice multiplies by its
     block's scalar scale read from SMEM — dequantization happens
@@ -134,8 +140,8 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
     m_refs = refs[2 * G + 1 + H:2 * G + 1 + 2 * H]
     l_refs = refs[2 * G + 1 + 2 * H:]
     si = pl.program_id(0)
-    b = pl.program_id(1)
-    nb = pl.num_programs(1)
+    b = pl.program_id(2)
+    nb = pl.num_programs(2)
     W = G * Bt  # tokens per grid step
 
     @pl.when(b == 0)
@@ -145,7 +151,7 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
             mr[...] = jnp.full_like(mr, NEG_INF)
             lr[...] = jnp.zeros_like(lr)
 
-    base = base_ref[si]
+    base = base_ref[si] + pl.program_id(1) * R  # this tile's first row
     # whole-group skip: every row of this window sits at or below
     # base + R - 1, so a group starting past that depth is fully
     # masked — skip its matmuls entirely (masked groups are exact
@@ -155,12 +161,6 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
         q = q_ref[0].astype(jnp.float32)  # [R, H, Dh]
         ks = [r[0].astype(jnp.float32) for r in k_refs]  # G x [Bt, H, Dh]
         vs = [r[0].astype(jnp.float32) for r in v_refs]
-        if quant:
-            # the physical block each group entry streamed (the same
-            # expression its index map used; -1 clamps to 0 — its
-            # scale is garbage-but-finite, position-masked below)
-            pbs = [jnp.maximum(tbl_ref[si, b * G + g], 0)
-                   for g in range(G)]
         if scale_in_q:  # chunk family: scale folded into q pre-matmul
             q = q * scale
         # position mask: row r (global position base + r) attends
@@ -174,12 +174,14 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
             if quant:
                 # dequant per (group entry, head): 2D f32 slice times
                 # one scalar SMEM scale — layout-safe (no mid-dim
-                # vector ops on the quantized block)
+                # vector ops on the quantized block). An unallocated
+                # (-1) entry carries block 0's scale, like its payload:
+                # garbage-but-finite, position-masked below
                 k = jnp.concatenate(
-                    [ks[g][:, hh, :] * ksc_ref[pbs[g], hh]
+                    [ks[g][:, hh, :] * ksc_ref[si, (b * G + g) * H + hh]
                      for g in range(G)], axis=0)
                 v = jnp.concatenate(
-                    [vs[g][:, hh, :] * vsc_ref[pbs[g], hh]
+                    [vs[g][:, hh, :] * vsc_ref[si, (b * G + g) * H + hh]
                      for g in range(G)], axis=0)
             else:
                 k = jnp.concatenate([kk[:, hh, :] for kk in ks], axis=0)
@@ -225,6 +227,72 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
                 o_ref.dtype)
 
 
+# what one TPU core gives one program: the scalar memory the v5e's
+# compiler reports ("1.00M smem", less what a kernel's own scalars
+# take beside the prefetch operands) and the vector memory a program
+# may scope by default (16 MiB of the core's 128)
+_SMEM_BYTES = 1 << 20
+_SMEM_RESERVE = 16 << 10
+_VMEM_BYTES = 16 << 20
+
+
+def _group(Bt: int, maxb: int) -> int:
+    """Table entries per grid step: enough for the per-head score tile
+    [R, G*Bt] to fill the 128-lane dim, capped at the whole table for
+    tiny configs (the score tile then equals the array dim, which
+    Mosaic also accepts)."""
+    return max(1, min(-(-128 // Bt), maxb))
+
+
+def _row_tile(H: int, dh: int, W: int, q_itemsize: int,
+              pool_itemsize: int) -> int:
+    """Window rows per grid step: the largest power of two, at most
+    256, whose VMEM fits beside one group's K/V. Counted per (head,
+    lane) with Dh padded to whole 128-lane tiles: a group of W tokens
+    holds K and V double-buffered in the pool dtype plus their f32
+    upcasts; each window row holds the double-buffered q and out
+    blocks, the f32 accumulator, and the (m, l) columns, which pad to
+    a full lane tile each. At H=16, Dh=128, 16-bit: 4 MiB of K/V and
+    40 KiB a row, so 256 rows — which the v5e's compiler accepts where
+    it refuses 512. Never under one 8-row sublane tile; a geometry too
+    wide even for that is the compiler's to refuse."""
+    n = H * (-(-dh // 128) * 128)
+    kv = W * n * (4 * pool_itemsize + 8)
+    row = n * (4 * q_itemsize + 4) + 2 * H * 128 * 4
+    rows = max(8, (_VMEM_BYTES - kv) // row)
+    return min(256, 1 << (rows.bit_length() - 1))
+
+
+def _smem_padded(rows: int, cols: int) -> int:
+    # a 2-D 32-bit scalar-prefetch operand is laid out in (8, 128) tiles
+    return (-(-rows // 8) * 8) * (-(-cols // 128) * 128) * 4
+
+
+def check_paged_smem(slots: int, maxb: int, block_tokens: int,
+                     heads: int, quant: bool):
+    """Refuse a geometry whose prefetch operands cannot fit scalar
+    memory — at construction, with the arithmetic, instead of at the
+    first step's compile. One kernel call prefetches the block tables
+    [S, MAXB], the row bases [S] and, on a quantized pool, the two
+    flat scale rows [S, MAXB*H] — all padded to SMEM's (8, 128) word
+    tiles, MAXB first padded to a whole number of groups."""
+    G = _group(block_tokens, maxb)
+    mb = -(-maxb // G) * G
+    need = _smem_padded(slots, mb) + _smem_padded(1, slots)
+    if quant:
+        need += 2 * _smem_padded(slots, mb * heads)
+    room = _SMEM_BYTES - _SMEM_RESERVE
+    if need > room:
+        raise ValueError(
+            "fused paged attention keeps the block tables%s in scalar "
+            "memory: %d slots x %d table entries%s need %d bytes, the "
+            "core has %d (%d less %d for the kernel's own scalars) — "
+            "lower max_slots, raise kv_block_tokens or lower max_len"
+            % (" and the KV scales" if quant else "", slots, maxb,
+               " x (1 + 2 x %d heads)" % heads if quant else "",
+               need, room, _SMEM_BYTES, _SMEM_RESERVE))
+
+
 def _paged_attention(q, k_pool, v_pool, tables, base, *, scale,
                      scale_in_q, interpret, k_scale=None, v_scale=None):
     """Shared pallas_call builder: q [S, R, H, Dh] windows based at
@@ -232,21 +300,26 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, scale,
     [NB, Bt, H, Dh] -> out [S, R, H, Dh].
 
     `k_scale`/`v_scale` [NB, H] f32 (both or neither) mark a quantized
-    pool (ISSUE 14): they ride as two more scalar-prefetch operands —
-    SMEM-resident like the tables, read per (block, head) scalar in
-    the kernel body — and the blocks dequantize in VMEM after the DMA.
-    SMEM cost is 2 x NB x H x 4 bytes; at pool sizes where that
-    presses the scalar-memory budget, shrink NB (more, smaller
-    engines) before reaching for a VMEM-block scale plumbing.
+    pool (ISSUE 14). The kernel reads one scalar per (table entry,
+    head), so what rides in SMEM beside the tables is the scales of
+    the blocks the tables NAME — gathered here through the table into
+    a flat [S, MAXB*H] row per slot (an activation-sized XLA gather,
+    S x MAXB x H words, not a pool-sized view). Handing the kernel the
+    pool's own [NB, H] array instead costs NB x 128 words once SMEM
+    pads the minor dim, which a chip-sized pool (NB in the thousands)
+    cannot fit; `check_paged_smem` is the bound that remains.
 
     The window-row dim R is the kernel's sublane dim: Mosaic wants it
     in whole 8-row tiles (the flash kernel refuses blocks under 8 for
     the same reason), so 1 < R < multiple-of-8 windows pad with zero
-    rows up to the tile and slice the result. Pad rows compute masked
-    garbage nothing reads; every real row's online-softmax state is
-    row-independent, so real rows are BIT-identical to the unpadded
-    math. R == 1 (the decode shape) lowers fine as-is and stays
-    unpadded."""
+    rows up to the tile and slice the result. Windows taller than
+    `_row_tile` rows (prefill chunks) run as several row tiles on a
+    grid axis of their own, padded to a whole number of tiles — a
+    whole 512+-row chunk in one block is more VMEM than a program may
+    scope. Pad rows compute masked garbage nothing reads; every real
+    row's online-softmax state is row-independent, so real rows are
+    BIT-identical to the unpadded, untiled math. R == 1 (the decode
+    shape) lowers fine as-is and stays unpadded."""
     S, R, H, dh = q.shape
     NB, Bt = k_pool.shape[0], k_pool.shape[1]
     maxb = tables.shape[1]
@@ -255,17 +328,21 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, scale,
     quant = k_scale is not None
     if quant != (v_scale is not None):
         raise ValueError("pass both k_scale and v_scale, or neither")
-    Rp = R if R == 1 else -(-R // 8) * 8
+    G = _group(Bt, maxb)
+    Rt = _row_tile(H, dh, G * Bt, q.dtype.itemsize,
+                   k_pool.dtype.itemsize)
+    if R == 1:
+        Rt = Rp = 1
+    elif R <= Rt:
+        Rt = Rp = -(-R // 8) * 8
+    else:
+        Rp = -(-R // Rt) * Rt
     if Rp != R:
         q = jnp.concatenate(
             [q, jnp.zeros((S, Rp - R, H, dh), q.dtype)], axis=1)
-    # group size: enough table entries per grid step for the per-head
-    # score tile [Rp, G*Bt] to fill the 128-lane dim (capped at the
-    # whole table for tiny configs — the score tile then equals the
-    # array dim, which Mosaic also accepts); the table pads to a whole
-    # number of groups with -1 (unallocated) entries — clamped and
-    # position-masked like any other -1, i.e. exact no-ops
-    G = max(1, min(-(-128 // Bt), maxb))
+    # the table pads to a whole number of groups with -1 (unallocated)
+    # entries — clamped and position-masked like any other -1, i.e.
+    # exact no-ops
     pad = -maxb % G
     if pad:
         tables = jnp.concatenate(
@@ -274,11 +351,14 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, scale,
     # index maps take the scalar-prefetch refs after the grid indices:
     # (tbl, pos) unquantized, (tbl, pos, ksc, vsc) quantized — only
     # tbl is consulted, so the maps accept either arity
-    def _q_map(si, b, tbl, *pref):
-        return (si, 0, 0, 0)
+    def _q_map(si, t, b, tbl, *pref):
+        return (si, t, 0, 0)
+
+    def _o_map(si, t, b, tbl, *pref):
+        return (si, 0, t, 0)
 
     def _kv_map(g):
-        def _map(si, b, tbl, *pref):
+        def _map(si, t, b, tbl, *pref):
             # THE gather: the pipeline DMAs pool block tbl[s, b*G+g]
             # for this grid step. -1 (unallocated or group padding)
             # clamps to block 0 — its rows are excluded by the
@@ -287,23 +367,25 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, scale,
         return _map
 
     kernel = functools.partial(
-        _pa_kernel, Bt=Bt, R=Rp, G=G, scale=scale,
+        _pa_kernel, Bt=Bt, R=Rt, G=G, scale=scale,
         scale_in_q=scale_in_q, quant=quant,
     )
     prefetch = (tables, base)
     if quant:
-        prefetch = prefetch + (jnp.asarray(k_scale, jnp.float32),
-                               jnp.asarray(v_scale, jnp.float32))
+        named = jnp.clip(tables, 0, NB - 1)  # -1 reads block 0's scale
+        prefetch = prefetch + tuple(
+            jnp.asarray(sc, jnp.float32)[named].reshape(S, -1)
+            for sc in (k_scale, v_scale))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(S, (maxb + pad) // G),
-        in_specs=[pl.BlockSpec((1, Rp, H, dh), _q_map)]
+        grid=(S, Rp // Rt, (maxb + pad) // G),
+        in_specs=[pl.BlockSpec((1, Rt, H, dh), _q_map)]
         + [pl.BlockSpec((1, Bt, H, dh), _kv_map(g)) for g in range(G)]
         + [pl.BlockSpec((1, Bt, H, dh), _kv_map(g)) for g in range(G)],
-        out_specs=pl.BlockSpec((1, H, Rp, dh), _q_map),
-        scratch_shapes=[pltpu.VMEM((Rp, dh), jnp.float32)
+        out_specs=pl.BlockSpec((1, H, Rt, dh), _o_map),
+        scratch_shapes=[pltpu.VMEM((Rt, dh), jnp.float32)
                         for _ in range(H)]
-        + [pltpu.VMEM((Rp, 1), jnp.float32) for _ in range(2 * H)],
+        + [pltpu.VMEM((Rt, 1), jnp.float32) for _ in range(2 * H)],
     )
     out = pl.pallas_call(
         kernel,
@@ -362,10 +444,10 @@ def paged_prefill_attention(q, k_pool, v_pool, table_row, start,
     attending cache[0:start] plus the intra-chunk causal prefix through
     `table_row` [MAXB]. Chunk-family numerics (scale-into-q), padded
     rows past true_len compute garbage nothing reads — identical
-    semantics to `paged_prefill_chunk`'s gather form. The whole chunk
-    stays resident in VMEM (C <= max_len; at serving shapes a chunk is
-    `prefill_chunk_tokens`, well under the VMEM budget). Scales
-    dequantize a quantized pool in-kernel (ISSUE 14)."""
+    semantics to `paged_prefill_chunk`'s gather form. A chunk taller
+    than `_row_tile` rows runs as several row tiles of one call, so
+    every bucket up to max_len fits VMEM. Scales dequantize a
+    quantized pool in-kernel (ISSUE 14)."""
     C, H, dh = q.shape
     out = _paged_attention(
         q[None], k_pool, v_pool, jnp.asarray(table_row)[None],

@@ -23,13 +23,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["gpipe_pipeline", "reference_pipeline"]
 
@@ -61,9 +57,8 @@ def _pipe_shard(stage_fn, params, x, axis_name: str, n_micro: int):
     outs = jnp.zeros((n_micro, mb, D), x.dtype)
     # the carry becomes device-varying after one tick; mark the zero
     # initials as varying so scan's carry types line up
-    if hasattr(lax, "pcast"):
-        state = lax.pcast(state, (axis_name,), to="varying")
-        outs = lax.pcast(outs, (axis_name,), to="varying")
+    state = lax.pcast(state, (axis_name,), to="varying")
+    outs = lax.pcast(outs, (axis_name,), to="varying")
 
     def tick(carry, t):
         state, outs = carry

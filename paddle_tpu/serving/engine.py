@@ -2,8 +2,7 @@
 reuse by block-table aliasing, chunked prefill, and ONE compiled decode
 (or speculative-verify) step for many concurrent requests.
 
-The training path sits at the HBM roof (PERF.md r5); the unclaimed
-serving throughput is workload shape — one request per batch underfills
+Serving throughput is workload shape — one request per batch underfills
 the lanes and every new prompt length recompiles. This engine
 reproduces Orca-style iteration-level scheduling (Yu et al., OSDI '22)
 in JAX/XLA idiom: static shapes everywhere, slots instead of dynamic
@@ -423,14 +422,21 @@ class ServingEngine(object):
                 "paged_kernel must be 'fused' or 'gather' (got %r)"
                 % (pk,))
         self.paged_kernel = pk
+        tlm._kv_quant_check(kv_quant)
+        if pk == "fused":
+            # the kernels keep every slot's block table (and, on a
+            # quantized pool, the named blocks' scales) in scalar
+            # memory: a geometry that cannot fit is refused HERE, with
+            # the arithmetic, not by the first step's compile
+            from ..parallel.paged_attention import check_paged_smem
+
+            check_paged_smem(S, self.blocks_per_slot, Bt, cfg.heads,
+                             kv_quant != "none")
         # per-block KV quantization (ISSUE 14): the pool's storage
         # dtype, fixed for the engine's lifetime (baked into the cache
         # pytree AND the compiled steps). 'none' keeps the exact
         # pre-quant cache structure and traces, so the default engine
         # stays token-identical to the PR 13 tree.
-        tlm._kv_quant_check(kv_quant)
-        if kv_quant != "none":
-            tlm.kv_storage_dtype(kv_quant)  # loud fp8-support gate
         self.kv_quant = kv_quant
         # per-tensor int8 weights (ISSUE 14): quantized ONCE below;
         # dequant is the first op of every compiled step

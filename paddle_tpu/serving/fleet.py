@@ -4673,6 +4673,11 @@ def run_fleet_subprocess(argv_for, worker_ids, requests,
     queue counts} — `coordinator["done"] == len(requests)` with
     `discarded == 0` is the no-lost-request check, and lease fencing
     means each rid was acked exactly once.
+
+    A host-only drill: a chip belongs to one process at a time, so the
+    workers run on the CPU backend (`env_for` sets `JAX_PLATFORMS=cpu`
+    in each child's environment, as the tests do). Replicas on chips
+    are one process with one engine per device — `ServingFleet`.
     """
     from ..distributed.coordinator import Coordinator, CoordinatorServer
     from ..distributed.supervisor import Supervisor

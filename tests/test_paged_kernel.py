@@ -380,3 +380,72 @@ def test_fused_slot_count_sweep_token_identity():
         eng.run()
         for h, want in zip(hs, oracle):
             np.testing.assert_array_equal(_full(h), want)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+def test_prefill_taller_than_one_row_tile_matches_the_gather_form(quant):
+    """A chunk taller than the kernel's row tile runs as several row
+    tiles on a grid axis of their own (at real widths a 512+-row chunk
+    as ONE q block is more VMEM than the chip lets a program scope —
+    tests/test_tpu_aot_compile.py). Each tile must attend from its own
+    base position and keep its own softmax state, padded rows of the
+    last tile must stay out of the result, and on a quantized pool
+    every table group must read ITS blocks' scales from the flat
+    per-slot scale rows."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    H, dh, Bt, C, start = 2, 16, 16, 600, 40
+    tile = pa._row_tile(H, dh, 128, 4, 1 if quant else 4)
+    assert tile < C and C % tile  # several tiles, the last one padded
+    maxb = -(-(start + C) // Bt) + 1
+    NB = maxb + 3
+    rng = np.random.RandomState(11)
+    q = jnp.asarray(rng.randn(C, H, dh).astype(np.float32))
+    table = rng.permutation(NB)[:maxb].astype(np.int32)
+    table[-1] = -1  # the unallocated tail entry
+    table = jnp.asarray(table)
+    if quant:
+        k = jnp.asarray(rng.randint(-127, 128, (NB, Bt, H, dh)), jnp.int8)
+        v = jnp.asarray(rng.randint(-127, 128, (NB, Bt, H, dh)), jnp.int8)
+        ks = jnp.asarray(rng.rand(NB, H).astype(np.float32) / 64)
+        vs = jnp.asarray(rng.rand(NB, H).astype(np.float32) / 64)
+        view_k = T._paged_deq_view(k, ks, table[None])[0]
+        view_v = T._paged_deq_view(v, vs, table[None])[0]
+        scales = {"k_scale": ks, "v_scale": vs}
+    else:
+        k = jnp.asarray(rng.randn(NB, Bt, H, dh).astype(np.float32))
+        v = jnp.asarray(rng.randn(NB, Bt, H, dh).astype(np.float32))
+        view_k = T._paged_view(k, table[None])[0]
+        view_v = T._paged_view(v, table[None])[0]
+        scales = {}
+    got = pa.paged_prefill_attention(q, k, v, table, start,
+                                     interpret=True, **scales)
+    s = jnp.einsum("thd,shd->hts", q * dh ** -0.5, view_k)
+    seen = (jnp.arange(maxb * Bt)[None, :]
+            <= start + jnp.arange(C)[:, None])
+    p = jax.nn.softmax(jnp.where(seen[None], s, -1e30), axis=-1)
+    want = jnp.einsum("hts,shd->thd", p, view_v)
+    assert got.shape == (C, H, dh)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=_RTOL, atol=_ATOL)
+
+
+def test_fused_engine_refuses_a_geometry_past_scalar_memory():
+    """The kernels keep every slot's block table — and on a quantized
+    pool the named blocks' scales — in the core's scalar memory. A
+    geometry that cannot fit is refused when the engine is built, with
+    the arithmetic, not by the first step's compile on the chip."""
+    cfg = _cfg(dim=2048, heads=16, max_len=2048)
+    params = jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    with pytest.raises(ValueError) as ei:
+        ServingEngine(params, cfg, max_slots=64, kv_block_tokens=16,
+                      kv_quant="int8", paged_kernel="fused")
+    msg = str(ei.value)
+    assert "64 slots x 128 table entries" in msg
+    assert "2 x 16 heads" in msg and "bytes" in msg
+    # the same geometry fits without the scales, and on the XLA form
+    for kw in ({"paged_kernel": "fused"},
+               {"paged_kernel": "gather", "kv_quant": "int8"}):
+        ServingEngine(params, cfg, max_slots=64, kv_block_tokens=16,
+                      kv_pool_blocks=2, **kw)

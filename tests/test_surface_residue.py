@@ -6,6 +6,7 @@ behavioural checks where the shim computes something.
 """
 
 import numpy as np
+import pytest
 
 import paddle_tpu.fluid as fluid
 
@@ -17,6 +18,8 @@ def test_module_parity_v2_and_fluid():
     import os
 
     ref_v2 = "/root/reference/python/paddle/v2"
+    if not os.path.isdir(ref_v2):
+        pytest.skip("reference tree absent: %s" % ref_v2)
     for sub, pkg in ((".", "paddle_tpu.v2"), ("fluid", "paddle_tpu.fluid")):
         d = os.path.join(ref_v2, sub)
         for f in sorted(os.listdir(d)):

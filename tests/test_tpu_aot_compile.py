@@ -1,0 +1,281 @@
+"""Ask the chip's compiler, without the chip (ISSUE 21).
+
+The TPU compiler is installed here and compiles for a device that is
+described, not attached. Every case lowers a kernel or a compiled step
+of the main path at the widths chip_smoke.py runs on the chip — 16
+heads of 128, bf16, max_len 2048, a pool the size one chip holds — and
+compiles it for one described v5e: what Mosaic refuses (a tile that
+does not fit VMEM, prefetch operands that do not fit SMEM, a slice it
+cannot lay out) is refused HERE, where interpret-mode tests cannot see
+it. Depth is cut to two layers; a kernel's compile does not depend on
+how many layers call it. Nothing runs, so nothing here says anything
+about results or times.
+
+The topology is described inside a fixture of this file — never while
+a module is imported — because only one process may load the TPU's
+library: under pytest-xdist only the worker that is handed this file
+may touch it, and every worker must collect the same tests.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.models import transformer as tlm
+from paddle_tpu.parallel import paged_attention as pa
+from paddle_tpu.parallel.flash_attention import flash_attention
+
+# chip_smoke.py's serving widths
+H, DH, L, BT, S = 16, 128, 2048, 16, 8
+MAXB = L // BT
+# pools the size the smoke's engines allocate on a 16 GB chip: ~12 GiB
+# of 2 MiB bf16 blocks, twice as many half-size int8/fp8 blocks
+NB, NB_QUANT = 6000, 12000
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep it off here
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Steer code that asks the backend (the engine's kernel default,
+    resolve_interpret) down its accelerator branch: the host here is a
+    CPU, the compile target is not."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, *args, **kwargs):
+    """Compile `fn` (a function, or one of the engine's jitted steps)
+    for the devices its argument shapes are placed on, at the chip's
+    own matmul precision (conftest pins float32 for the numeric
+    tests); returns the compiled module's text."""
+    lower = fn.lower if hasattr(fn, "lower") else jax.jit(fn).lower
+    with jax.default_matmul_precision(None):
+        return lower(*args, **kwargs).compile().as_text()
+
+
+def _placed(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=sharding), tree)
+
+
+def _sds(one_chip):
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["fwd", "fwd_bwd"])
+def test_flash_attention_compiles(one_chip, backward):
+    q = _sds(one_chip)((2, L, H, DH), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    assert "tpu_custom_call" in _compile(fn, q, q, q)
+
+
+def _pool(sds, quant):
+    """Pool (and scale) shapes of the smoke's bf16 / quantized engine."""
+    if quant == "none":
+        return sds((NB, BT, H, DH), jnp.bfloat16), {}
+    scale = sds((NB_QUANT, H), jnp.float32)
+    return (sds((NB_QUANT, BT, H, DH), tlm.kv_storage_dtype(quant)),
+            {"k_scale": scale, "v_scale": scale})
+
+
+@pytest.mark.parametrize("quant", ["none", "int8", "fp8"])
+def test_paged_decode_attention_compiles_at_the_chips_pool(one_chip,
+                                                           quant):
+    """The quantized cases are the refusal this PR repaired: the
+    pool's [NB, H] scales as a prefetch operand need NB x 128 words of
+    scalar memory; the scales of the blocks the tables name do not
+    grow with the pool."""
+    sds = _sds(one_chip)
+    pool, scales = _pool(sds, quant)
+
+    def fn(q, k, v, tables, pos, **sc):
+        return pa.paged_decode_attention(q, k, v, tables, pos,
+                                         interpret=False, **sc)
+
+    assert "tpu_custom_call" in _compile(
+        fn, sds((S, H, DH), jnp.bfloat16), pool, pool,
+        sds((S, MAXB), jnp.int32), sds((S,), jnp.int32), **scales)
+
+
+def test_paged_verify_attention_compiles(one_chip):
+    sds = _sds(one_chip)
+    pool, _ = _pool(sds, "none")
+    assert "tpu_custom_call" in _compile(
+        lambda q, k, v, t, p: pa.paged_verify_attention(
+            q, k, v, t, p, interpret=False),
+        sds((S, 4, H, DH), jnp.bfloat16), pool, pool,
+        sds((S, MAXB), jnp.int32), sds((S,), jnp.int32))
+
+
+@pytest.mark.parametrize("chunk,quant", [
+    (8, "none"), (512, "none"), (L, "none"), (L, "int8")])
+def test_paged_prefill_attention_compiles_at_every_bucket_size(
+        one_chip, chunk, quant):
+    """512 rows and up are the other refusal this PR repaired: a whole
+    chunk as one q block overflows VMEM; row tiles do not."""
+    sds = _sds(one_chip)
+    pool, scales = _pool(sds, quant)
+
+    def fn(q, k, v, table, start, **sc):
+        return pa.paged_prefill_attention(q, k, v, table, start,
+                                          interpret=False, **sc)
+
+    assert "tpu_custom_call" in _compile(
+        fn, sds((chunk, H, DH), jnp.bfloat16), pool, pool,
+        sds((MAXB,), jnp.int32), sds((), jnp.int32), **scales)
+
+
+def _engine(one_chip, **kw):
+    """A default-options engine at the smoke's widths, depth cut to 2,
+    built on shapes alone, with the argument shapes of its compiled
+    steps placed on the described chip."""
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = tlm.TransformerConfig(vocab=32000, dim=H * DH, heads=H,
+                                layers=2, max_len=L, dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: tlm.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = ServingEngine(params, cfg, kv_pool_blocks=4, **kw)
+    assert eng.paged_kernel == "fused"
+    assert (eng.max_slots, eng.kv_block_tokens) == (S, BT)
+    cache = jax.eval_shape(lambda: tlm.init_paged_kv_cache(
+        cfg, NB, BT, kv_quant=eng.kv_quant))
+    sds = _sds(one_chip)
+    bands = (sds((S, MAXB), jnp.int32), sds((S,), jnp.int32),
+             sds((S,), jnp.int32), sds((S,), jnp.bool_),
+             sds((S,), jnp.float32), sds((S,), jnp.int32),
+             sds((S, 2), jnp.uint32))
+    return (eng, _placed(params, one_chip), _placed(cache, one_chip),
+            bands, sds)
+
+
+@pytest.mark.parametrize("window", [1, 8])
+def test_engine_decode_step_compiles(one_chip, as_on_tpu, window):
+    kw = {} if window == 1 else {"decode_window": window,
+                                 "async_dispatch": True}
+    eng, params, cache, bands, sds = _engine(one_chip, **kw)
+    if window == 1:
+        fn, extra = eng._decode_fn, ()
+    else:
+        fn = eng._window_fn
+        extra = (sds((S,), jnp.int32), sds((S,), jnp.int32))
+    # one kernel call per layer
+    assert _compile(fn, params, cache, *bands,
+                    *extra).count("tpu_custom_call") >= 2
+
+
+def test_engine_prefill_step_compiles_at_the_largest_bucket(one_chip,
+                                                            as_on_tpu):
+    eng, params, cache, _, sds = _engine(one_chip)
+    assert eng._bucket(L - 100) == L
+    assert _compile(
+        eng._chunk_fn(L), params, cache, sds((L,), jnp.int32),
+        sds((), jnp.int32), sds((MAXB,), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.float32), sds((2,), jnp.uint32),
+    ).count("tpu_custom_call") >= 2
+
+
+def test_data_parallel_step_has_an_all_reduce(topo):
+    """A fluid training step under the executor's own sharding rules
+    on a four-device mesh: the partitioner must put the gradient
+    all-reduce in."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu import parallel
+    from paddle_tpu.fluid.core.lowering import build_step_fn
+    from paddle_tpu.fluid.executor import _mesh_jit_kwargs
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[784], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="int64")
+        h = fluid.layers.fc(input=x, size=512, act="relu")
+        p = fluid.layers.fc(input=h, size=10, act="softmax")
+        loss = fluid.layers.mean(
+            x=fluid.layers.cross_entropy(input=p, label=y))
+        fluid.optimizer.Momentum(learning_rate=0.01,
+                                 momentum=0.9).minimize(loss)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+    names = sorted(v.name for v in main.list_vars() if v.persistable)
+    persist = {n: scope.get(n) for n in names if n in scope}
+    feed = {"x": np.zeros((128, 784), np.float32),
+            "y": np.zeros((128, 1), np.int32)}
+    fn, persist_out = build_step_fn(
+        main, feed_names=list(feed), fetch_names=[loss.name],
+        persist_names=names, persist_in=list(persist))
+    mesh = parallel.make_mesh({"data": 4}, devices=list(topo.devices))
+    kw = _mesh_jit_kwargs(mesh, main, feed, list(persist), persist_out,
+                          [loss.name])
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+        (persist, feed))
+    compiled = jax.jit(fn, donate_argnums=(0,), **kw).lower(
+        *shapes, jax.random.PRNGKey(0)).compile()
+    assert "all-reduce" in compiled.as_text()
+    assert len(compiled.input_shardings[0][1]["x"].device_set) == 4
+
+
+def test_smem_bound_agrees_with_the_compiler(one_chip):
+    """`check_paged_smem` is arithmetic about what the compiler will
+    accept; hold it to the compiler on both sides of the bound."""
+    sds = _sds(one_chip)
+    pool, scales = _pool(sds, "int8")
+
+    def compiles(slots):
+        try:
+            _compile(
+                lambda q, k, v, t, p, **sc: pa.paged_decode_attention(
+                    q, k, v, t, p, interpret=False, **sc),
+                sds((slots, H, DH), jnp.bfloat16), pool, pool,
+                sds((slots, MAXB), jnp.int32), sds((slots,), jnp.int32),
+                **scales)
+            return True
+        except Exception as e:  # whatever type the compiler raises
+            assert "smem" in str(e).lower(), e
+            return False
+
+    def accepted(slots):
+        try:
+            pa.check_paged_smem(slots, MAXB, BT, H, True)
+            return True
+        except ValueError:
+            return False
+
+    assert accepted(56) and compiles(56)
+    assert not accepted(64) and not compiles(64)
