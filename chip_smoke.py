@@ -257,8 +257,10 @@ def _drive_engine(params, cfg, requests, check_text, **engine_kw):
     eng.run()
     wall = time.monotonic() - t0
     rep = eng.metrics.report()
-    # host clock around each blocked compiled step, per step name: its
-    # slowest call is the one that compiled, the rest are steps
+    # host clock by row of the engine's phases (`ServingMetrics.phase`):
+    # `decode_step` / `prefill_T<bucket>` are whole decode and chunk
+    # phases, `engine.dispatch` / `engine.device_wait` / ... their
+    # parts; a row's slowest call is the one that compiled
     step_s = {
         name: {"calls": n, "with_compile": round(worst, 3),
                "min": round(best, 5),

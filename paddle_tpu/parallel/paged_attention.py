@@ -293,11 +293,16 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
                need, room, _SMEM_BYTES, _SMEM_RESERVE))
 
 
-def _paged_attention(q, k_pool, v_pool, tables, base, *, scale,
+def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
                      scale_in_q, interpret, k_scale=None, v_scale=None):
     """Shared pallas_call builder: q [S, R, H, Dh] windows based at
     `base` [S] over per-slot tables [S, MAXB] into the pools
     [NB, Bt, H, Dh] -> out [S, R, H, Dh].
+
+    `name` is the calling kernel's own (`paged_decode_attention`, ...):
+    it names the custom call and rides in its `kernel_metadata`, so a
+    device trace tells the three apart by name and not by the shape of
+    what they return.
 
     `k_scale`/`v_scale` [NB, H] f32 (both or neither) mark a quantized
     pool (ISSUE 14). The kernel reads one scalar per (table entry,
@@ -392,6 +397,8 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, Rp, dh), q.dtype),
         interpret=resolve_interpret(interpret),
+        name=name,
+        metadata={"kernel": name},
     )(*prefetch, q, *([k_pool] * G), *([v_pool] * G))
     # the kernel emits head-major [S, H, Rp, Dh] (leading-dim writes
     # only); this transpose is ordinary XLA on the activation-sized
@@ -415,6 +422,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     S, H, dh = q.shape
     out = _paged_attention(
         q[:, None], k_pool, v_pool, tables, pos,
+        name="paged_decode_attention",
         scale=1.0 / math.sqrt(dh), scale_in_q=False,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale,
     )
@@ -432,6 +440,7 @@ def paged_verify_attention(q, k_pool, v_pool, tables, pos,
     dh = q.shape[-1]
     return _paged_attention(
         q, k_pool, v_pool, tables, pos,
+        name="paged_verify_attention",
         scale=1.0 / math.sqrt(dh), scale_in_q=True,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale,
     )
@@ -452,6 +461,7 @@ def paged_prefill_attention(q, k_pool, v_pool, table_row, start,
     out = _paged_attention(
         q[None], k_pool, v_pool, jnp.asarray(table_row)[None],
         jnp.asarray(start, jnp.int32).reshape(1),
+        name="paged_prefill_attention",
         scale=1.0 / math.sqrt(dh), scale_in_q=True,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale,
     )

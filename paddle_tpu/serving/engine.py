@@ -915,6 +915,15 @@ class ServingEngine(object):
             self.metrics.band_uploads += 1
         return self._dev[name]
 
+    def _bands(self, *names):
+        """The device copies of several side-bands, in order; the
+        dirty ones among them upload first, under ONE span (a steady
+        decode loop uploads nothing and opens none)."""
+        if self._dirty.intersection(names):
+            with self.metrics.phase("engine.upload"):
+                return [self._band(n) for n in names]
+        return [self._dev[n] for n in names]
+
     def _mark_dirty(self, *names):
         self._dirty.update(names or _BANDS)
 
@@ -1610,11 +1619,6 @@ class ServingEngine(object):
         if n_blocks < 1:
             return
         store = self._kv_store
-        # chain keys for the store records: one fold per publish call,
-        # shared with the trie summary and the router (fold_key) — the
-        # store is keyed by the SAME chain identity the trie uses
-        keys = (chain_keys(h.full_prompt[:n_blocks * Bt], Bt)
-                if store is not None else None)
 
         def _take(d):
             bid = int(self._tables[s, d])
@@ -1646,7 +1650,14 @@ class ServingEngine(object):
                 self.metrics.store_spilled_blocks += 1
             return bid
 
-        pc.publish(h.full_prompt, n_blocks, _take)
+        with self.metrics.phase("engine.publish"):
+            # chain keys for the store records: one fold per publish
+            # call, shared with the trie summary and the router
+            # (fold_key) — the store is keyed by the SAME chain
+            # identity the trie uses
+            keys = (chain_keys(h.full_prompt[:n_blocks * Bt], Bt)
+                    if store is not None else None)
+            pc.publish(h.full_prompt, n_blocks, _take)
 
     def _run_chunk(self, s: int) -> bool:  # band-verb: resume
         """Advance slot s's prefill by one chunk; on the final chunk,
@@ -1659,64 +1670,74 @@ class ServingEngine(object):
         c = T0 - cursor
         if self.prefill_chunk_tokens is not None:
             c = min(c, self.prefill_chunk_tokens)
-        self._ensure_blocks(s, cursor, cursor + c)
         Cb = self._bucket(c)
-        padded = np.zeros(Cb, np.int32)
-        padded[:c] = h.full_prompt[cursor:cursor + c]
-        fn = self._chunk_fn(Cb)
-        t0 = time.monotonic()
-        self._cache, first, trap_d, scale_d = fn(
-            self._params, self._cache, jnp.asarray(padded),
-            jnp.int32(cursor), jnp.asarray(self._tables[s]),
-            jnp.int32(c), jnp.float32(h.temperature), st["key"],
-            **self._adapter_args(jnp.int32(int(self._aidx[s]))),
-        )
-        st["cursor"] = cursor + c
-        self.metrics.prefill_chunks += 1
-        self.metrics.prefill_tokens_computed += c
-        self.metrics.kv_blocks_in_use = self._alloc.blocks_in_use
-        if st["cursor"] < T0:
-            # mid-prompt chunk: dispatch only, nothing to read back —
-            # the batched decode below overlaps with it
-            self.metrics.span("prefill_T%d" % Cb, time.monotonic() - t0)
-            return False
-        first = int(np.asarray(first))  # blocks: first token is real
-        if self.integrity_traps:
-            # the trap rides the same readback sync (mid-prompt chunks
-            # stay dispatch-only: a mid-chunk NaN propagates through
-            # the cache into THIS final chunk's logits)
-            self._check_integrity(trap_d, np.asarray(scale_d),
-                                  "prefill chunk", slots=[s])
-        now = time.monotonic()
-        h.ttft_s = now - h.submit_t
-        self.metrics.ttft_s.append(h.ttft_s)
-        self.metrics.span("prefill_T%d" % Cb, now - t0)
-        self.metrics.observe_device_interval(t0, now)
-        self.metrics.prefills += 1
-        self._publish(s, h)
-        del self._prefill_state[s]
+        m = self.metrics
+        with m.phase("engine.prefill_chunk", row="prefill_T%d" % Cb,
+                     rid=h.rid, bucket=Cb, tokens=c):
+            with m.phase("engine.alloc_blocks"):
+                self._ensure_blocks(s, cursor, cursor + c)
+            padded = np.zeros(Cb, np.int32)
+            padded[:c] = h.full_prompt[cursor:cursor + c]
+            fn = self._chunk_fn(Cb)
+            with m.phase("engine.upload"):
+                args = (jnp.asarray(padded), jnp.int32(cursor),
+                        jnp.asarray(self._tables[s]), jnp.int32(c),
+                        jnp.float32(h.temperature))
+                adapter = self._adapter_args(jnp.int32(int(self._aidx[s])))
+            with m.phase("engine.dispatch") as disp:
+                self._cache, first, trap_d, scale_d = fn(
+                    self._params, self._cache, *args, st["key"], **adapter)
+            st["cursor"] = cursor + c
+            m.prefill_chunks += 1
+            m.prefill_tokens_computed += c
+            m.kv_blocks_in_use = self._alloc.blocks_in_use
+            if st["cursor"] < T0:
+                # mid-prompt chunk: dispatch only, nothing to read back
+                # — the batched decode below overlaps with it
+                return False
+            with m.phase("engine.device_wait") as wait:
+                first = int(np.asarray(first))  # blocks: the token is real
+            if self.integrity_traps:
+                # the trap rides the same readback sync (mid-prompt
+                # chunks stay dispatch-only: a mid-chunk NaN propagates
+                # through the cache into THIS final chunk's logits)
+                with m.phase("engine.integrity"):
+                    self._check_integrity(trap_d, np.asarray(scale_d),
+                                          "prefill chunk", slots=[s])
+            h.ttft_s = wait.t1 - h.submit_t
+            m.ttft_s.append(h.ttft_s)
+            m.observe_device_interval(disp.t0, wait.t1)
+            m.prefills += 1
+            self._publish(s, h)
+            with m.phase("engine.emit"):
+                del self._prefill_state[s]
 
-        self._tok[s] = first
-        self._pos[s] = T0
-        self._alive[s] = True
-        self._temps[s] = h.temperature
-        # a resumed request continues the ORIGINAL fold_in schedule:
-        # its next sampled token is overall index resume_len
-        self._counts[s] = h.resume_len
-        self._base_keys[s] = np.asarray(jax.random.PRNGKey(h.seed))
-        # device-side EOS judgment for the decode window (-1 = none);
-        # the _mark_dirty() below re-uploads it with everything else
-        self._eos[s] = -1 if h.eos_id is None else int(h.eos_id)
-        if self.spec_draft_len is not None:
-            # seed the drafting index from the context once (O(T0));
-            # _emit keeps it current per token from here on
-            ctx = [int(t) for t in h.full_prompt]
-            bmap = {}
-            for i in range(len(ctx) - 1):
-                bmap[(ctx[i], ctx[i + 1])] = i + 2
-            self._spec_ctx[s] = {"ctx": ctx, "map": bmap, "from": None}
-        self._mark_dirty()  # all bands: slot s changed everywhere
-        self._emit(s, first)  # may retire immediately (max_new==1 / eos)
+                self._tok[s] = first
+                self._pos[s] = T0
+                self._alive[s] = True
+                self._temps[s] = h.temperature
+                # a resumed request continues the ORIGINAL fold_in
+                # schedule: its next sampled token is overall index
+                # resume_len
+                self._counts[s] = h.resume_len
+                self._base_keys[s] = np.asarray(jax.random.PRNGKey(h.seed))
+                # device-side EOS judgment for the decode window (-1 =
+                # none); the _mark_dirty() below re-uploads it with
+                # everything else
+                self._eos[s] = -1 if h.eos_id is None else int(h.eos_id)
+                if self.spec_draft_len is not None:
+                    # seed the drafting index from the context once
+                    # (O(T0)); _emit keeps it current per token from
+                    # here on
+                    ctx = [int(t) for t in h.full_prompt]
+                    bmap = {}
+                    for i in range(len(ctx) - 1):
+                        bmap[(ctx[i], ctx[i + 1])] = i + 2
+                    self._spec_ctx[s] = {"ctx": ctx, "map": bmap,
+                                         "from": None}
+                self._mark_dirty()  # all bands: slot s changed everywhere
+                # may retire immediately (max_new==1 / eos)
+                self._emit(s, first)
         return True
 
     def _drop_slot(self, s: int, reason: str):
@@ -1845,17 +1866,21 @@ class ServingEngine(object):
                 _fi.default_injector()
                 if os.environ.get(_fi.ENV_VAR) else _fi.FaultInjector("")
             )
-        t0 = time.monotonic()
+        m = self.metrics
+        m.steps += 1
+        m.step_phases.clear()
         try:
-            if inj.active:
-                inj.tick()
-                if inj.take_flip():
-                    # flip@ drill (ISSUE 15): silent KV corruption —
-                    # finite garbage into one resident block, invisible
-                    # to the numeric traps, caught only by the
-                    # fingerprint spot-check at aliased re-open
-                    self._flip_resident_block()
-            out = self._step_inner()
+            with m.phase("engine.step", step=m.steps) as whole:
+                if inj.active:
+                    inj.tick()
+                    if inj.take_flip():
+                        # flip@ drill (ISSUE 15): silent KV corruption
+                        # — finite garbage into one resident block,
+                        # invisible to the numeric traps, caught only
+                        # by the fingerprint spot-check at aliased
+                        # re-open
+                        self._flip_resident_block()
+                out = self._step_inner()
         except Exception as exc:
             self.abort(exc)
             raise
@@ -1868,17 +1893,22 @@ class ServingEngine(object):
         # emitted count — a low-occupancy window still does K
         # iterations of device work, and dividing by fewer emitted
         # tokens would make an idle replica read slow (false demotion).
-        self.metrics.observe_step(time.monotonic() - t0,
-                                  tokens=self.decode_window)
+        m.observe_step(whole.t1 - whole.t0, tokens=self.decode_window)
         return out
 
     def _step_inner(self) -> bool:
-        progressed = self._expire_sweep()
+        progressed = False
+        if self._deadlines:
+            with self.metrics.phase("engine.expire"):
+                progressed = self._expire_sweep()
         while self._queue:
             s = self._free_slot()
             if s is None:
                 break
-            if not self._admit(self._queue[0], s):
+            h = self._queue[0]
+            with self.metrics.phase("engine.admit", rid=h.rid):
+                admitted = self._admit(h, s)
+            if not admitted:
                 break  # block-starved: FCFS head waits, so do followers
             self._queue.popleft()
             progressed = True
@@ -1902,7 +1932,8 @@ class ServingEngine(object):
         elif not self._alive.any():
             return progressed
         elif self.spec_draft_len is not None:
-            self._spec_step()
+            with self.metrics.phase("engine.decode", row="spec_verify"):
+                self._spec_step()
         else:
             self._decode_once()
 
@@ -1921,45 +1952,48 @@ class ServingEngine(object):
         """The plain (non-speculative) batched decode: one token per
         live slot, bands advanced on device so a steady loop uploads
         nothing (tables change only at a block-boundary append)."""
-        live = np.nonzero(self._alive)[0]
-        for s in live:
-            p = int(self._pos[s])
-            self._ensure_blocks(s, p, p + 1)
-        t0 = time.monotonic()
-        self._cache, nxt_d, pos_d, counts_d, trap_d, scale_d = \
-            self._decode_fn(
-                self._params, self._cache, self._band("tables"),
-                self._band("tok"), self._band("pos"),
-                self._band("alive"), self._band("temps"),
-                self._band("counts"), self._band("base_keys"),
-                **self._adapter_args(self._band("aidx")),
-            )
-        nxt = np.asarray(nxt_d)  # blocks; tokens are real
-        if self.integrity_traps:
-            # a tripped slot becomes an integrity event INSTEAD of an
-            # emitted token: checked before the emit loop below, so no
-            # token from a poisoned step reaches a handle
-            self._check_integrity(trap_d, np.asarray(scale_d), "decode")
-        # the decode step advanced tok/pos/counts on device; adopt its
-        # outputs so an admission-free step re-uploads nothing. (Dead
-        # rows: device tok holds this step's don't-care sample, host
-        # keeps the stale final token — both are masked and parked, and
-        # an admission re-dirties every band anyway.)
-        self._dev["tok"], self._dev["pos"], self._dev["counts"] = (
-            nxt_d, pos_d, counts_d)
-        self._dirty.difference_update(("tok", "pos", "counts"))
-        t1 = time.monotonic()
-        self.metrics.span("decode_step", t1 - t0)
-        self.metrics.observe_device_interval(t0, t1)
-        self.metrics.decode_steps += 1
-        self.metrics.occupancy.append(
-            float(self._alive.sum()) / self.max_slots
-        )
+        m = self.metrics
+        with m.phase("engine.decode", row="decode_step"):
+            live = np.nonzero(self._alive)[0]
+            with m.phase("engine.alloc_blocks"):
+                for s in live:
+                    p = int(self._pos[s])
+                    self._ensure_blocks(s, p, p + 1)
+            *bands, aidx = self._bands(
+                "tables", "tok", "pos", "alive", "temps", "counts",
+                "base_keys", "aidx")
+            adapter = self._adapter_args(aidx)
+            with m.phase("engine.dispatch") as disp:
+                self._cache, nxt_d, pos_d, counts_d, trap_d, scale_d = \
+                    self._decode_fn(self._params, self._cache, *bands,
+                                    **adapter)
+            with m.phase("engine.device_wait") as wait:
+                nxt = np.asarray(nxt_d)  # blocks; tokens are real
+            if self.integrity_traps:
+                # a tripped slot becomes an integrity event INSTEAD of
+                # an emitted token: checked before the emit loop below,
+                # so no token from a poisoned step reaches a handle
+                with m.phase("engine.integrity"):
+                    self._check_integrity(trap_d, np.asarray(scale_d),
+                                          "decode")
+            # the decode step advanced tok/pos/counts on device; adopt
+            # its outputs so an admission-free step re-uploads nothing.
+            # (Dead rows: device tok holds this step's don't-care
+            # sample, host keeps the stale final token — both are
+            # masked and parked, and an admission re-dirties every band
+            # anyway.)
+            self._dev["tok"], self._dev["pos"], self._dev["counts"] = (
+                nxt_d, pos_d, counts_d)
+            self._dirty.difference_update(("tok", "pos", "counts"))
+            m.observe_device_interval(disp.t0, wait.t1)
+            m.decode_steps += 1
+            m.occupancy.append(float(self._alive.sum()) / self.max_slots)
 
-        self._pos[live] += 1  # the token just cached sat at pos
-        for s in live:
-            self._tok[s] = nxt[s]
-            self._emit(s, nxt[s])
+            with m.phase("engine.emit"):
+                self._pos[live] += 1  # the token just cached sat at pos
+                for s in live:
+                    self._tok[s] = nxt[s]
+                    self._emit(s, nxt[s])
 
     # ------------------------------------------------------------------
     # megabatch decode window (ISSUE 19)
@@ -1983,27 +2017,30 @@ class ServingEngine(object):
         rec, self._inflight = self._inflight, None
         if rec is None and not self._alive.any():
             return False
-        if rec is not None:
-            chained = None
-            if self.async_dispatch and self._alive.any() \
-                    and self._can_chain():
-                # enqueue window N+1 off window N's device outputs
-                # BEFORE syncing N: the emit/schedule work below runs
-                # under N+1's device compute (the whole point)
-                chained = self._dispatch_window(prev=rec)
-            self._sync_window(rec)
-            self._inflight = chained
-            if chained is None and self.async_dispatch \
-                    and self._alive.any():
-                # chain broken by a host event: host truth is current
-                # again post-sync — refill the pipeline this step
-                self._inflight = self._dispatch_window()
-            return True
-        w = self._dispatch_window()
-        if self.async_dispatch:
-            self._inflight = w  # one-step-behind emission: sync next step
-        else:
-            self._sync_window(w)
+        with self.metrics.phase("engine.decode", row="decode_step"):
+            if rec is not None:
+                chained = None
+                if self.async_dispatch and self._alive.any() \
+                        and self._can_chain():
+                    # enqueue window N+1 off window N's device outputs
+                    # BEFORE syncing N: the emit/schedule work below
+                    # runs under N+1's device compute (the whole point)
+                    chained = self._dispatch_window(prev=rec)
+                self._sync_window(rec)
+                self._inflight = chained
+                if chained is None and self.async_dispatch \
+                        and self._alive.any():
+                    # chain broken by a host event: host truth is
+                    # current again post-sync — refill the pipeline
+                    # this step
+                    self._inflight = self._dispatch_window()
+                return True
+            w = self._dispatch_window()
+            if self.async_dispatch:
+                # one-step-behind emission: sync next step
+                self._inflight = w
+            else:
+                self._sync_window(w)
         return True
 
     def _dispatch_window(self, prev=None):
@@ -2014,32 +2051,36 @@ class ServingEngine(object):
         K = self.decode_window
         live = np.nonzero(self._alive)[0]
         horizon = 2 * K if prev is not None else K
-        for s in live:
-            p = int(self._pos[s])
-            # positions < limits-1 are the only ones ever written (the
-            # budget rule parks a slot after its write at limits-2)
-            self._ensure_blocks(
-                s, p, min(p + horizon, int(self._limits[s]) - 1))
-        t0 = time.monotonic()
+        m = self.metrics
+        with m.phase("engine.alloc_blocks"):
+            for s in live:
+                p = int(self._pos[s])
+                # positions < limits-1 are the only ones ever written
+                # (the budget rule parks a slot after its write at
+                # limits-2)
+                self._ensure_blocks(
+                    s, p, min(p + horizon, int(self._limits[s]) - 1))
+        rest = ("tables", "temps", "base_keys", "limits", "eos", "aidx")
         if prev is None:
-            tok_d, pos_d = self._band("tok"), self._band("pos")
-            alive_d, counts_d = self._band("alive"), self._band("counts")
+            tok_d, pos_d, alive_d, counts_d, *rest_d = self._bands(
+                "tok", "pos", "alive", "counts", *rest)
         else:
             tok_d, pos_d, alive_d, counts_d = prev["bands"]
-        out = self._window_fn(
-            self._params, self._cache, self._band("tables"), tok_d,
-            pos_d, alive_d, self._band("temps"), counts_d,
-            self._band("base_keys"), self._band("limits"),
-            self._band("eos"),
-            **self._adapter_args(self._band("aidx")),
-        )
+            rest_d = self._bands(*rest)
+        tables_d, temps_d, keys_d, limits_d, eos_d, aidx_d = rest_d
+        adapter = self._adapter_args(aidx_d)
+        with m.phase("engine.dispatch") as disp:
+            out = self._window_fn(
+                self._params, self._cache, tables_d, tok_d, pos_d,
+                alive_d, temps_d, counts_d, keys_d, limits_d, eos_d,
+                **adapter)
         self._cache = out[0]
         self.metrics.decode_steps += 1
         self.metrics.occupancy.append(
             float(self._alive.sum()) / self.max_slots
         )
         return {"bands": out[1:5], "toks": out[5], "traps": out[6],
-                "scales": out[7], "t0": t0,
+                "scales": out[7], "t0": disp.t0,
                 "slots": [(int(s), self._slot_req[int(s)])
                           for s in live]}
 
@@ -2055,27 +2096,32 @@ class ServingEngine(object):
         tentpole rule); all-parked rows are skipped so the spike EWMA
         never ingests masked zeros."""
         K = self.decode_window
-        toks = np.asarray(rec["toks"])  # [K, S] — THE sync point
-        t1 = time.monotonic()
-        self.metrics.span("decode_step", t1 - rec["t0"])
-        self.metrics.observe_device_interval(rec["t0"], t1)
+        m = self.metrics
+        with m.phase("engine.device_wait") as wait:
+            toks = np.asarray(rec["toks"])  # [K, S] — THE sync point
+        m.observe_device_interval(rec["t0"], wait.t1)
         if self.integrity_traps:
-            traps_w = np.asarray(rec["traps"])
-            scales_w = np.asarray(rec["scales"])
-        for j in range(K):
-            row = toks[j]
-            if self.integrity_traps and (row >= 0).any():
-                self._check_integrity(traps_w[j], scales_w[j],
-                                      "decode window")
-            for s, h in rec["slots"]:
-                if self._slot_req[s] is not h or not self._alive[s]:
-                    continue  # expired/cancelled/re-tenanted: discard
-                t = int(row[s])
-                if t < 0:
-                    continue  # parked lane
-                self._pos[s] += 1  # the token just synced sat at pos
-                self._tok[s] = t
-                self._emit(s, t)
+            # the window's K rows of trap flags and magnitudes, read
+            # once; each row is judged in the loop below, before its
+            # own tokens emit
+            with m.phase("engine.integrity"):
+                traps_w = np.asarray(rec["traps"])
+                scales_w = np.asarray(rec["scales"])
+        with m.phase("engine.emit"):
+            for j in range(K):
+                row = toks[j]
+                if self.integrity_traps and (row >= 0).any():
+                    self._check_integrity(traps_w[j], scales_w[j],
+                                          "decode window")
+                for s, h in rec["slots"]:
+                    if self._slot_req[s] is not h or not self._alive[s]:
+                        continue  # expired/cancelled/re-tenanted: discard
+                    t = int(row[s])
+                    if t < 0:
+                        continue  # parked lane
+                    self._pos[s] += 1  # the token just synced sat at pos
+                    self._tok[s] = t
+                    self._emit(s, t)
         # adopt the window's outputs as device truth (steady loop
         # re-uploads nothing) — but only when the host mirrors agree:
         # a host-side divergence (fault drills shifting emitted
@@ -2119,54 +2165,62 @@ class ServingEngine(object):
         step (the documented spec trade: ~3 small h2d per multi-token
         step instead of zero per single-token step)."""
         K = self.spec_draft_len
+        metrics, phase = self.metrics, self.metrics.phase
         live = np.nonzero(self._alive)[0]
         window = np.zeros((self.max_slots, K), np.int32)
+        with phase("engine.alloc_blocks"):
+            for s in live:
+                lo = int(self._pos[s])
+                self._ensure_blocks(
+                    s, lo, min(lo + K, int(self._limits[s])))
         for s in live:
-            lo = int(self._pos[s])
-            self._ensure_blocks(s, lo, min(lo + K, int(self._limits[s])))
             window[s] = self._draft_window(s)
-        t0 = time.monotonic()
-        self._cache, cand_d, trap_d, scale_d = self._verify_fn(
-            self._params, self._cache, self._band("tables"),
-            jnp.asarray(window), self._band("pos"), self._band("alive"),
-            self._band("limits"), self._band("temps"),
-            self._band("counts"), self._band("base_keys"),
-            **self._adapter_args(self._band("aidx")),
-        )
-        cand = np.asarray(cand_d)  # blocks; candidates are real
+        tables_d, pos_d, alive_d, limits_d, temps_d, counts_d, keys_d, \
+            aidx_d = self._bands("tables", "pos", "alive", "limits",
+                                 "temps", "counts", "base_keys", "aidx")
+        with phase("engine.upload"):
+            window_d = jnp.asarray(window)
+        adapter = self._adapter_args(aidx_d)
+        with phase("engine.dispatch") as disp:
+            self._cache, cand_d, trap_d, scale_d = self._verify_fn(
+                self._params, self._cache, tables_d, window_d, pos_d,
+                alive_d, limits_d, temps_d, counts_d, keys_d, **adapter)
+        with phase("engine.device_wait") as wait:
+            cand = np.asarray(cand_d)  # blocks; candidates are real
         if self.integrity_traps:
-            self._check_integrity(trap_d, np.asarray(scale_d),
-                                  "spec verify")
-        t1 = time.monotonic()
-        self.metrics.span("spec_verify", t1 - t0)
-        self.metrics.observe_device_interval(t0, t1)
-        self.metrics.decode_steps += 1
-        self.metrics.occupancy.append(
-            float(self._alive.sum()) / self.max_slots
-        )
-        for s in live:
-            h = self._slot_req[s]
-            m = 0  # accepted drafts: longest window prefix the model agrees with
-            while m < K - 1 and window[s, m + 1] == cand[s, m]:
-                m += 1
-            budget_left = h.max_new_tokens - len(h.tokens)
-            n = min(m + 1, budget_left)
-            self.metrics.spec_windows += 1
-            # count only drafts actually PROPOSED (-1 rows are empty
-            # lanes, not rejections) AND within the request's remaining
-            # budget (a final window's over-budget lanes can never be
-            # accepted): accept_rate stays an honest measure of draft
-            # quality
-            lanes = window[s, 1:max(1, budget_left)]
-            self.metrics.spec_drafted += int((lanes >= 0).sum())
-            adv = 0
-            for j in range(n):
-                adv += 1
-                self._tok[s] = cand[s, j]
-                if self._emit(s, cand[s, j]):
-                    break  # EOS/budget: later accepted drafts discarded
-            self._pos[s] += adv  # one cache write per emitted token
-            self.metrics.spec_accepted += max(0, adv - 1)
+            with phase("engine.integrity"):
+                self._check_integrity(trap_d, np.asarray(scale_d),
+                                      "spec verify")
+        metrics.observe_device_interval(disp.t0, wait.t1)
+        metrics.decode_steps += 1
+        metrics.occupancy.append(
+            float(self._alive.sum()) / self.max_slots)
+        with phase("engine.emit"):
+            for s in live:
+                h = self._slot_req[s]
+                # accepted drafts: longest window prefix the model
+                # agrees with
+                m = 0
+                while m < K - 1 and window[s, m + 1] == cand[s, m]:
+                    m += 1
+                budget_left = h.max_new_tokens - len(h.tokens)
+                n = min(m + 1, budget_left)
+                self.metrics.spec_windows += 1
+                # count only drafts actually PROPOSED (-1 rows are empty
+                # lanes, not rejections) AND within the request's remaining
+                # budget (a final window's over-budget lanes can never be
+                # accepted): accept_rate stays an honest measure of draft
+                # quality
+                lanes = window[s, 1:max(1, budget_left)]
+                self.metrics.spec_drafted += int((lanes >= 0).sum())
+                adv = 0
+                for j in range(n):
+                    adv += 1
+                    self._tok[s] = cand[s, j]
+                    if self._emit(s, cand[s, j]):
+                        break  # EOS/budget: later accepted drafts discarded
+                self._pos[s] += adv  # one cache write per emitted token
+                self.metrics.spec_accepted += max(0, adv - 1)
         # acceptance is a host decision: these bands re-upload next step
         self._mark_dirty("tok", "pos", "counts")
 

@@ -13,10 +13,16 @@ backend; tokens/s is only meaningful on-chip.
 
 from __future__ import annotations
 
+import collections
+import logging
 import time
 from typing import Dict
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["ServingMetrics"]
+
+_LOG = logging.getLogger(__name__)
 
 
 # A long-lived engine records one value per decode step / per request
@@ -26,6 +32,29 @@ __all__ = ["ServingMetrics"]
 # (shared with data.DataMetrics); the underscore alias is the
 # backward-compatible name.
 from ..utils.stat import RunningStat as _RunningStat
+
+
+class _Phase(object):
+    """One open span of `ServingMetrics.phase`: a profiler annotation
+    around a host-clock pair. `t0`/`t1` stay readable after the block
+    (the engine's device-busy union reads them)."""
+
+    __slots__ = ("_metrics", "_ann", "name", "row", "t0", "t1")
+
+    def __init__(self, metrics, name, row, attrs):
+        self._metrics, self.name, self.row = metrics, name, row
+        self._ann = TraceAnnotation(name, **attrs)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.monotonic()
+        self._ann.__exit__(*exc)
+        self._metrics._fold(self)
+        return False
 
 
 class ServingMetrics(object):
@@ -127,10 +156,19 @@ class ServingMetrics(object):
         # bench's headline column.
         self.device_busy_s = 0.0
         self._busy_last_end = 0.0
+        # PR 25 — the scheduler's own phases (`phase()` below):
+        # seconds by phase name since the engine last cleared it (at
+        # the top of every step()), the count of step() calls, and the
+        # last few steps slower than SLOW_STEP_S with their phase
+        # seconds — what a stall in an untraced run leaves behind
+        self.steps = 0
+        self.step_phases: Dict[str, float] = {}
+        self.slow_steps = collections.deque(maxlen=8)
         self._t0 = None
         self._t1 = None
 
     STEP_EWMA_ALPHA = 0.5  # fast decay: ~3 healthy steps erase a spike
+    SLOW_STEP_S = 0.5  # a step() slower than this is kept and logged
 
     def observe_step(self, seconds: float, tokens: int = 1):
         """Fold one engine-step wall time into the step-latency EWMA,
@@ -141,6 +179,15 @@ class ServingMetrics(object):
         (the static window size), so K=1 keeps the original per-step
         semantics exactly."""
         a = self.STEP_EWMA_ALPHA
+        if seconds > self.SLOW_STEP_S:
+            # which phase held the step: time of `engine.step` that no
+            # phase beneath it accounts for is the scheduler's own
+            # Python (or a stall of the whole process)
+            rec = {"step": self.steps, "seconds": round(seconds, 6),
+                   "phases": {k: round(v, 6)
+                              for k, v in self.step_phases.items()}}
+            self.slow_steps.append(rec)
+            _LOG.warning("slow engine step: %r", rec)
         seconds = seconds / max(1, int(tokens))
         if self.step_ewma_s == 0.0:
             self.step_ewma_s = seconds
@@ -163,12 +210,29 @@ class ServingMetrics(object):
         (== once per compile signature), never per execution."""
         self.trace_counts[name] = self.trace_counts.get(name, 0) + 1
 
-    def span(self, name: str, seconds: float):
-        self.ops.record(name, seconds)
-        now = time.monotonic()
+    def phase(self, name: str, row: str = None, **attrs):
+        """Context manager around one phase of the scheduler's work —
+        the engine's one timing mechanism, with two sinks. Under a
+        running `jax.profiler` trace the span lies on the host plane
+        of the same `.xplane.pb` as the device's operations, on their
+        clock (`attrs` — a request id, a bucket — ride as the
+        annotation's arguments: the NAME stays one of a dozen fixed
+        `engine.<phase>` strings). With no trace running it costs two
+        clock reads and folds, O(1), into the `ops` row `name` (and
+        `row`, the row's older name where it had one) and into
+        `step_phases`."""
+        return _Phase(self, name, row, attrs)
+
+    def _fold(self, ph: _Phase):
+        seconds = ph.t1 - ph.t0
+        self.ops.record(ph.name, seconds)
+        if ph.row is not None:
+            self.ops.record(ph.row, seconds)
+        self.step_phases[ph.name] = \
+            self.step_phases.get(ph.name, 0.0) + seconds
         if self._t0 is None:
-            self._t0 = now - seconds
-        self._t1 = now
+            self._t0 = ph.t0
+        self._t1 = ph.t1
 
     # -- derived --------------------------------------------------------
     @property
@@ -242,6 +306,8 @@ class ServingMetrics(object):
             "store_spilled_blocks": self.store_spilled_blocks,
             "store_warm_blocks": self.store_warm_blocks,
             "store_quarantined": self.store_quarantined,
+            "steps": self.steps,
+            "slow_steps": list(self.slow_steps),
         }
         if self.prefix_cache is not None:
             rep["prefix_cache"] = self.prefix_cache.stats()

@@ -17,6 +17,8 @@ library: under pytest-xdist only the worker that is handed this file
 may touch it, and every worker must collect the same tests.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -158,6 +160,30 @@ def test_paged_prefill_attention_compiles_at_every_bucket_size(
     assert "tpu_custom_call" in _compile(
         fn, sds((chunk, H, DH), jnp.bfloat16), pool, pool,
         sds((MAXB,), jnp.int32), sds((), jnp.int32), **scales)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "verify", "prefill"])
+def test_paged_kernels_carry_their_names(one_chip, kernel):
+    """The custom call is named after its kernel and carries the name
+    in `kernel_metadata` (ISSUE 25): what a device trace's event text
+    holds, so a reduction can find the kernel by name and not by the
+    shape of what it returns."""
+    sds = _sds(one_chip)
+    pool, _ = _pool(sds, "none")
+    name = "paged_%s_attention" % kernel
+    fn = getattr(pa, name)
+    if kernel == "prefill":
+        args = (sds((8, H, DH), jnp.bfloat16), pool, pool,
+                sds((MAXB,), jnp.int32), sds((), jnp.int32))
+    else:
+        rows = () if kernel == "decode" else (4,)
+        args = (sds((S,) + rows + (H, DH), jnp.bfloat16), pool, pool,
+                sds((S, MAXB), jnp.int32), sds((S,), jnp.int32))
+    text = _compile(lambda *a: fn(*a, interpret=False), *args)
+    call = [line for line in text.split("\n")
+            if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(call) == 1 and call[0].lstrip().startswith("%" + name)
+    assert re.search(r'kernel_metadata=\{\s*"kernel":"%s"\s*\}' % name, text)
 
 
 def _engine(one_chip, **kw):
