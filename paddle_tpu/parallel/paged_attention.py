@@ -9,16 +9,35 @@ transient contiguous per-slot view [S, MAXB*Bt, H, Dh] PER LAYER
 (`_paged_view` — PERF.md's "known trade until a fused paged kernel
 lands"): HBM write + read of the whole gathered context every step,
 which is exactly the traffic a decode step is bounded by. These kernels
-delete that view: a (slots, table-groups) grid walks each slot's block
-table with the table and positions as SCALAR-PREFETCH operands
+delete that view: the grid walks each slot's block table with the
+table and positions as SCALAR-PREFETCH operands
 (PrefetchScalarGridSpec), so the pipeline DMAs each group's G K/V
 blocks [Bt, H, Dh] straight from the pool buffer into VMEM (G blocks
-per step so the per-head score tile spans G*Bt >= 128 lanes — the
-reference pages_per_compute_block idea) — the "gather" is the index
-map, and no HBM-resident contiguous view ever exists. Blockwise
-online softmax (running (max, sum, acc), the flash_attention.py
-discipline) keeps VMEM at one group of blocks plus per-head [R, Dh]
-accumulators, regardless of context length.
+per step so the score tile spans G*Bt >= 128 tokens — the reference
+pages_per_compute_block idea) — the "gather" is the index map, and no
+HBM-resident contiguous view ever exists. Blockwise online softmax
+(running (max, sum, acc), the flash_attention.py discipline) keeps
+VMEM at one group of blocks plus the accumulators, regardless of
+context length.
+
+Two kernel bodies, chosen by the shape of the call and nothing else
+(ISSUE 26):
+
+  * R >= 8 window rows (verify windows, prefill chunks) and every
+    call on a quantized pool — `_pa_kernel`: a (slots, row-tiles,
+    table-groups) grid, heads as a static loop, one `[R, W]` score
+    tile a head. With R rows on the MXU's left the per-head product
+    is the right shape.
+  * R == 1 (the decode step's one query a slot) — `_pa_decode_kernel`:
+    all heads at once in the pool's native `[Bt, H, Dh]` tile, over a
+    flat work list (`_decode_worklist`) that holds a step only for a
+    table group a live context names, DMAs only the blocks it names
+    and gives a parked slot one empty step. On a v5e at 32 slots x 16
+    heads x 128, bf16, 16-token blocks the head-loop body took 0.76 ms
+    a call at 465-token contexts (0.46 ms at contexts of ONE token:
+    512 grid steps whatever the contexts; a parked slot 58 us against
+    a live one's 24), this body 0.21 ms — 75 % of what the HBM allows
+    (PERF.md section 5, PR 26).
 
 Masking mirrors the gather primitives exactly: row r of a window based
 at `base` attends positions <= base + r, so unwritten depths — and the
@@ -51,7 +70,9 @@ operands (SMEM, like the tables; gathered through the table to a flat
 width and never the pool size), the DMA stays in the storage dtype,
 and each per-head f32 slice multiplies by its block's scalar scale
 before the matmuls. The same no-HBM-view discipline, applied to the
-dequantized values: they never exist outside VMEM.
+dequantized values: they never exist outside VMEM. A quantized decode
+call (R == 1) stays on `_pa_kernel`: its scales are scalars of the
+head loop; no benchmark cell runs one yet (ROADMAP B.II.2).
 
 `interpret=None` resolves via kernel_utils.resolve_interpret: CPU CI
 runs the identical kernel interpreted; on TPU it compiles to Mosaic.
@@ -97,17 +118,29 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
     its own (m, l, acc) state from init to finalise.
 
     Mosaic constraints shape the body, each probed by AOT-compiling
-    for a virtual v5e (the bench_offline pattern): its dot takes 2D
+    for a virtual v5e when the body was written (PRs 13 and 21; NOT
+    re-probed on this JAX, 0.9.0 — the body compiles as it stands and
+    tests/test_tpu_aot_compile.py holds it to that): its dot takes 2D
     operands only (no batch dims), so heads run as a static in-kernel
     loop; 16-bit mid-dim VMEM extracts don't lower, so blocks upcast
-    to f32 once and every head slices f32 (the f32 MXU path halves
-    peak matmul rate vs bf16, which these HBM-bandwidth-bound steps
-    never see — the DMA stays in the pool dtype); per-head softmax
-    state must be WHOLE refs, never slices of a shared scratch (see
-    the comment below); and G groups blocks until G*Bt >= 128 so the
+    to f32 once and every head slices f32; per-head softmax state
+    must be WHOLE refs, never slices of a shared scratch (see the
+    comment below); and G groups blocks until G*Bt >= 128 so the
     score tile spans full 128-lane tiles (the reference
     pages_per_compute_block idea, jax paged_attention_kernel — also
-    fewer, larger grid steps for the DMA pipeline to overlap).
+    fewer, larger grid steps for the DMA pipeline to overlap). The
+    per-head slices and the f32 products are NOT free: with ONE row
+    (the decode shape) a live grid step measured 2.4-2.6 us against
+    1.3 us of DMA, and a dead one 0.9 us (PERF.md section 5, PR 26)
+    — why R == 1 on an unquantized pool has `_pa_decode_kernel`; with
+    R >= 8 rows the products amortise and this body stays.
+    Re-probed for the decode body on JAX 0.9.0, compiling for a
+    described v5e: merging the leading dims of a `[Bt, H, Dh]` block
+    lowers for bf16 at H = 16, 12, 8 and f32 at H = 16, 4, Bt from 8
+    to 128; a 16-bit `[H, Dh] -> [H, 1, Dh]` shape cast does NOT
+    lower at Dh = 64 (the f32 one does, so the decode body casts
+    after it); a grid bound that is data lowers beside scalar
+    prefetch.
 
     With `quant` (ISSUE 14) the pools hold int8/fp8 codes and two more
     SCALAR-PREFETCH operands carry the absmax scales of the blocks the
@@ -227,6 +260,103 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
                 o_ref.dtype)
 
 
+def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, q_ref, *refs,
+                      Bt: int, G: int, span: int, scale: float):
+    """One step of the single-token decode call's flat work list: fold
+    the G blocks of table group `wgrp[i]` of slot `wslot[i]` into that
+    slot's online-softmax state, ALL heads at once, K and V left in
+    the `[Bt, H, Dh]` tile the DMA delivered.
+
+    With one query row a head, a per-head product puts ONE row on the
+    MXU's left and pays for it with a sublane gather of that head's
+    rows out of every block (`_pa_kernel`'s shape, right for R >= 8
+    rows). Here a group's K is the 2-D `[W*H, Dh]` it already is in
+    VMEM (merging the leading dims of a `[Bt, H, Dh]` tile moves
+    nothing when H fills whole sublane tiles) and ONE product
+    `q [H, Dh] . K^T -> [H, W*H]` scores every head against every
+    (token, head') row in the pool's own dtype with f32 accumulation;
+    column (t, h') of row h is kept only where h' == h — the head
+    match folded into the position mask, so the other fifteen
+    sixteenths are exact zeros in P and `P [H, W*H] . V [W*H, Dh]`
+    sums, per head, only its own rows. H times the model's FLOPs on
+    an MXU that is otherwise idle; no head loop, no slice, no
+    concatenate of slices, no f32 copy of a block. The state is one
+    `(m, l) [H, 1]` and one `acc [H, Dh]` scratch.
+
+    The grid is the work list `_decode_worklist` built: only steps a
+    live context names (and one empty step for a parked slot, which
+    writes zeros), so `wgrp[i] * W <= pos` holds at every step of a
+    live slot and every row has an attended column — the NEG_INF
+    guards stay for the decode family's exactness contract (a masked
+    column contributes EXACTLY 0), not because a step can be empty.
+
+    Against a 16-bit V, P goes as two 16-bit halves (hi + lo, stacked
+    on the rows of ONE product, so V is loaded into the MXU once): P
+    keeps ~16 bits of mantissa instead of 8, for 1 % of the call."""
+    k_refs, v_refs = refs[:G], refs[G:2 * G]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * G:]
+    H, dh = q_ref.shape
+    W = G * Bt
+    i = pl.program_id(0)
+    si = wslot_ref[i]
+    b = wgrp_ref[i]
+    pos = pos_ref[si]
+    live = pos < span  # a parked row sits at or past the table's span
+
+    @pl.when(b == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(live)
+    def _accumulate():
+        k = jnp.concatenate([r[...].reshape(Bt * H, dh) for r in k_refs],
+                            axis=0)  # [W*H, Dh], rows (token, head)
+        v = jnp.concatenate([r[...].reshape(Bt * H, dh) for r in v_refs],
+                            axis=0)
+        s = jax.lax.dot_general(
+            q_ref[...].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [H, W*H]; decode family: scale after the product
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, W * H), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (H, W * H), 0)
+        # column c is (token c // H, head c % H): row h keeps its own
+        # head's columns at depths <= pos, i.e. c < (pos - b*W + 1)*H;
+        # everything else — other heads, unwritten depths, whatever a
+        # re-named or clamped block holds — contributes exactly 0
+        head = (col & (H - 1)) if H & (H - 1) == 0 else jax.lax.rem(col, H)
+        masked = (head != row) | (col >= (pos - b * W + 1) * H)
+        s = jnp.where(masked, NEG_INF, s)
+
+        m_prev = m_ref[...]  # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(s <= NEG_INF, 0.0, p)
+        alpha = jnp.exp(m_prev - m_new)
+        alpha = jnp.where(m_prev <= NEG_INF, 0.0, alpha)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if v.dtype.itemsize == 2:
+            hi = p.astype(v.dtype)
+            lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+            pv = jax.lax.dot_general(
+                jnp.concatenate([hi, lo], axis=0), v,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [2H, Dh]
+            pv = pv[:H] + pv[H:]
+        else:
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [H, Dh]
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
+
+    @pl.when(b == jnp.where(live, pos // W, 0))  # the slot's last step
+    def _finalise():
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = out[:, None, :].astype(o_ref.dtype)
+
+
 # what one TPU core gives one program: the scalar memory the v5e's
 # compiler reports ("1.00M smem", less what a kernel's own scalars
 # take beside the prefetch operands) and the vector memory a program
@@ -275,12 +405,20 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
     first step's compile. One kernel call prefetches the block tables
     [S, MAXB], the row bases [S] and, on a quantized pool, the two
     flat scale rows [S, MAXB*H] — all padded to SMEM's (8, 128) word
-    tiles, MAXB first padded to a whole number of groups."""
+    tiles (a 1-D operand counted as one such row of tiles, which is
+    on the safe side), MAXB first padded to a whole number of groups."""
     G = _group(block_tokens, maxb)
     mb = -(-maxb // G) * G
     need = _smem_padded(slots, mb) + _smem_padded(1, slots)
     if quant:
         need += 2 * _smem_padded(slots, mb * heads)
+    else:
+        # the decode call prefetches its work list in the tables'
+        # place: the block of every (operand, step) [G, S*NG] and two
+        # [S*NG] rows — more than the tables only where G < 8
+        steps = slots * mb // G
+        need = max(need, _smem_padded(G, steps) + _smem_padded(1, slots)
+                   + 2 * _smem_padded(1, steps))
     room = _SMEM_BYTES - _SMEM_RESERVE
     if need > room:
         raise ValueError(
@@ -291,6 +429,96 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
             % (" and the KV scales" if quant else "", slots, maxb,
                " x (1 + 2 x %d heads)" % heads if quant else "",
                need, room, _SMEM_BYTES, _SMEM_RESERVE))
+
+
+def _decode_worklist(tables, pos, Bt: int, G: int, span: int):
+    """The decode call's grid, as data: one entry for every table
+    group a live context names, slot after slot, and one empty entry
+    for a parked slot (`pos >= span`; its step computes nothing and
+    writes zeros). -> (blk [G, S*NG], wslot [S*NG], wgrp [S*NG], n):
+    entry i < n works on group `wgrp[i]` of slot `wslot[i]`, and its
+    g-th K/V operand holds pool block `blk[g, i]`.
+
+    `blk` is what keeps the DMA to the blocks the contexts name: where
+    entry i's group names no block for operand g (the tail of a
+    context's last group, a parked slot), it RE-NAMES the block that
+    operand named last, and the pipeline issues no copy for a block
+    index that did not change. Nothing is read from a re-named block
+    (the position mask). All of it is integer compares and reductions
+    on the tables and positions, the widest a fused [G, S*NG, S*NG]
+    masked max, and the same for every layer of a step, so the
+    compiled step keeps ONE copy: ~65 us of an 8 ms decode step at 32
+    slots x 16 groups on the v5e (PERF.md section 5, PR 26)."""
+    S, mb = tables.shape
+    NG = mb // G
+    W = G * Bt
+    live = pos < span
+    ng = jnp.where(live, pos // W + 1, 1)  # [S] steps each slot takes
+    ends = jnp.cumsum(ng)
+    i = jnp.arange(S * NG, dtype=jnp.int32)
+    # plain compares and reductions, nothing materialised: a search
+    # (`searchsorted`) or a scan (`cummax`) is a loop of tiny programs
+    # on the TPU, ~80 us a call where this is a few fusions
+    past = ends[None, :] <= i[:, None]  # [S*NG, S] slots wholly before i
+    wslot = jnp.minimum(jnp.sum(past, axis=1, dtype=jnp.int32), S - 1)
+    wgrp = jnp.clip(
+        i - jnp.sum(jnp.where(past, ng[None, :], 0), axis=1), 0, NG - 1)
+    depth = wgrp[None, :] * G + jnp.arange(G, dtype=jnp.int32)[:, None]
+    named = (live[wslot] & (i < ends[-1]))[None, :] \
+        & (depth * Bt <= pos[wslot][None, :])
+    # held[g, i]: the latest entry <= i at which operand g was named
+    held = jnp.max(jnp.where(
+        named[:, None, :] & (i[None, None, :] <= i[None, :, None]),
+        i[None, None, :], -1), axis=2)
+    entry = jnp.maximum(tables[wslot[None, :], depth], 0)  # -1 -> block 0
+    # an operand not named yet holds whatever entry 0 gives it: one
+    # block copied once and never read
+    blk = jnp.take_along_axis(entry, jnp.maximum(held, 0), axis=1)
+    return blk, wslot, wgrp, ends[-1]
+
+
+def _paged_decode(q, k_pool, v_pool, tables, pos, *, G, span, name, scale,
+                  interpret):
+    """The R == 1 call: `_pa_decode_kernel` over `_decode_worklist`'s
+    grid, whose LENGTH is data too (a dynamic grid bound: the steps the
+    live contexts name, not slots x table groups). q [S, 1, H, Dh] ->
+    out [S, H, 1, Dh], the shape `_pa_kernel` returns for R == 1 (and
+    the one the benchmark's `paged_attn_roofline` finds the kernel by);
+    the squeezed block dims hand the kernel q and K/V as dense
+    [H, Dh] / [Bt, H, Dh] tiles."""
+    S, _, H, dh = q.shape
+    Bt = k_pool.shape[1]
+    blk, wslot, wgrp, n = _decode_worklist(tables, pos, Bt, G, span)
+
+    def _slot_map(i, blk, pos, wslot, wgrp):
+        return (wslot[i], 0, 0, 0)
+
+    def _kv_map(g):
+        def _map(i, blk, pos, wslot, wgrp):
+            return (blk[g, i], 0, 0, 0)
+        return _map
+
+    kernel = functools.partial(
+        _pa_decode_kernel, Bt=Bt, G=G, span=span, scale=scale)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n,),
+        in_specs=[pl.BlockSpec((None, None, H, dh), _slot_map)]
+        + [pl.BlockSpec((None, Bt, H, dh), _kv_map(g)) for g in range(G)]
+        + [pl.BlockSpec((None, Bt, H, dh), _kv_map(g)) for g in range(G)],
+        out_specs=pl.BlockSpec((None, H, 1, dh), _slot_map),
+        scratch_shapes=[pltpu.VMEM((H, dh), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, 1, dh), q.dtype),
+        interpret=resolve_interpret(interpret),
+        name=name,
+        metadata={"kernel": name},
+    )(blk, pos, wslot, wgrp, q, *([k_pool] * G), *([v_pool] * G))
 
 
 def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
@@ -324,7 +552,8 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
     scope. Pad rows compute masked garbage nothing reads; every real
     row's online-softmax state is row-independent, so real rows are
     BIT-identical to the unpadded, untiled math. R == 1 (the decode
-    shape) lowers fine as-is and stays unpadded."""
+    shape) stays unpadded: on an unquantized pool it leaves here for
+    `_paged_decode`, sharing only the operand preparation above it."""
     S, R, H, dh = q.shape
     NB, Bt = k_pool.shape[0], k_pool.shape[1]
     maxb = tables.shape[1]
@@ -352,6 +581,13 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
     if pad:
         tables = jnp.concatenate(
             [tables, jnp.full((S, pad), -1, jnp.int32)], axis=1)
+    if R == 1 and not quant:
+        # the single-token decode shape has a body and a grid of its
+        # own; a quantized pool's scales ride `_pa_kernel`'s head loop
+        out = _paged_decode(q, k_pool, v_pool, tables, base, G=G,
+                            span=maxb * Bt, name=name, scale=scale,
+                            interpret=interpret)
+        return out.transpose(0, 2, 1, 3)
 
     # index maps take the scalar-prefetch refs after the grid indices:
     # (tbl, pos) unquantized, (tbl, pos, ksc, vsc) quantized — only
@@ -415,10 +651,11 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     [S, MAXB] into pools [NB, Bt, H, Dh] -> out [S, H, Dh]. Mirrors
     `_cached_attention` over `_paged_view` (divide-after-matmul
     scaling, depths > pos excluded) without ever materialising the
-    view. A parked row (pos >= MAXB*Bt) attends everything its table
-    clamps to — garbage out, exactly like the gather path, and nothing
-    reads it. `k_scale`/`v_scale` [NB, H] dequantize an int8/fp8 pool
-    inside the kernel (ISSUE 14)."""
+    view. A parked row (pos >= MAXB*Bt) costs one empty grid step and
+    returns zeros (on a quantized pool, `_pa_kernel`'s garbage, like
+    the gather path's) — nothing reads it either way.
+    `k_scale`/`v_scale` [NB, H] dequantize an int8/fp8 pool inside the
+    kernel (ISSUE 14)."""
     S, H, dh = q.shape
     out = _paged_attention(
         q[:, None], k_pool, v_pool, tables, pos,

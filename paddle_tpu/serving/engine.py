@@ -1948,6 +1948,20 @@ class ServingEngine(object):
         self.metrics.kv_blocks_in_use = self._alloc.blocks_in_use
         return True
 
+    def _count_decode_step(self):
+        """One decode step (a K-token window, a verify step) was
+        dispatched: slot occupancy, and how much of the block tables
+        the live contexts name against all of it (S x MAXB entries) —
+        `decode_blocks_live / decode_blocks_walked` is the share of a
+        whole-table walk that finds a block, i.e. what the decode
+        kernel's work list keeps of the old (slots, groups) grid."""
+        m, alive = self.metrics, self._alive
+        m.decode_steps += 1
+        m.occupancy.append(float(alive.sum()) / self.max_slots)
+        m.decode_blocks_live += int(
+            (self._pos[alive] // self.kv_block_tokens + 1).sum())
+        m.decode_blocks_walked += self.max_slots * self.blocks_per_slot
+
     def _decode_once(self):  # band-verb: sync
         """The plain (non-speculative) batched decode: one token per
         live slot, bands advanced on device so a steady loop uploads
@@ -1986,8 +2000,7 @@ class ServingEngine(object):
                 nxt_d, pos_d, counts_d)
             self._dirty.difference_update(("tok", "pos", "counts"))
             m.observe_device_interval(disp.t0, wait.t1)
-            m.decode_steps += 1
-            m.occupancy.append(float(self._alive.sum()) / self.max_slots)
+            self._count_decode_step()
 
             with m.phase("engine.emit"):
                 self._pos[live] += 1  # the token just cached sat at pos
@@ -2075,10 +2088,7 @@ class ServingEngine(object):
                 alive_d, temps_d, counts_d, keys_d, limits_d, eos_d,
                 **adapter)
         self._cache = out[0]
-        self.metrics.decode_steps += 1
-        self.metrics.occupancy.append(
-            float(self._alive.sum()) / self.max_slots
-        )
+        self._count_decode_step()
         return {"bands": out[1:5], "toks": out[5], "traps": out[6],
                 "scales": out[7], "t0": disp.t0,
                 "slots": [(int(s), self._slot_req[int(s)])
@@ -2192,9 +2202,7 @@ class ServingEngine(object):
                 self._check_integrity(trap_d, np.asarray(scale_d),
                                       "spec verify")
         metrics.observe_device_interval(disp.t0, wait.t1)
-        metrics.decode_steps += 1
-        metrics.occupancy.append(
-            float(self._alive.sum()) / self.max_slots)
+        self._count_decode_step()
         with phase("engine.emit"):
             for s in live:
                 h = self._slot_req[s]

@@ -92,22 +92,95 @@ def _assert_caches_equal(ca, cb, exact=True):
                 np.testing.assert_allclose(a, b, rtol=_RTOL, atol=_ATOL)
 
 
-def test_fused_vs_gather_logits_decode():
-    cfg, params = _mk(0)
-    NB, Bt = 10, 8
-    tables = jnp.asarray([[0, 1, -1, -1], [2, 3, 4, -1],
-                          [5, -1, -1, -1]], jnp.int32)
-    pos = jnp.asarray([9, 20, 3], jnp.int32)
-    tok = jnp.asarray([7, 11, 42], jnp.int32)
-    lg, cg = T.paged_decode_step(params, tok, pos, tables,
-                                 _rand_pool(cfg, NB, Bt), cfg,
-                                 kernel="gather")
-    lf, cf = T.paged_decode_step(params, tok, pos, tables,
-                                 _rand_pool(cfg, NB, Bt), cfg,
-                                 kernel="fused")
-    np.testing.assert_allclose(np.asarray(lf), np.asarray(lg),
+# a geometry whose decode call walks SEVERAL table groups: 8-token
+# blocks group 16 to a grid step (W = 128 tokens), 48 table entries
+# make three groups (a 384-token span)
+_BT, _MAXB = 8, 48
+_W, _SPAN = 128, _BT * _MAXB
+# positions on every edge the work list has: a context of one token,
+# one token short of a group, exactly one, one more, and the span's
+# last two
+_EDGES = [0, _W - 2, _W - 1, _W, _SPAN - 2, _SPAN - 1, 200, 77]
+
+
+def _edge_batch(S, parked=0, seed=0):
+    """-> (pos [S], tables [S, _MAXB], NB): S rows cycling through
+    `_EDGES`, the last `parked` of them parked at the span's end with
+    an all -1 table row (what the engine's decode step hands a dead
+    slot); a live row names exactly the blocks its context needs."""
+    rng = np.random.RandomState(seed)
+    pos = [_EDGES[i % len(_EDGES)] for i in range(S - parked)]
+    need = [p // _BT + 1 for p in pos]
+    NB = sum(need) + 3
+    order = rng.permutation(NB)
+    tables = np.full((S, _MAXB), -1, np.int32)
+    at = 0
+    for i, n in enumerate(need):
+        tables[i, :n] = order[at:at + n]
+        at += n
+    return (jnp.asarray(pos + [_SPAN] * parked, jnp.int32),
+            jnp.asarray(tables), NB)
+
+
+def _quant_pool(cfg, NB, Bt, kv_quant, seed=0):
+    """`_rand_pool` for a quantized cache: random codes in the storage
+    dtype and positive per-(block, head) scales."""
+    rng = np.random.RandomState(seed)
+    dh = cfg.dim // cfg.heads
+    st = T.kv_storage_dtype(kv_quant)
+
+    def codes():
+        c = rng.randint(-127, 128, (NB, Bt, cfg.heads, dh))
+        if kv_quant == "fp8":
+            c = c / 32.0
+        return jnp.asarray(c, jnp.float32).astype(st)
+
+    def scales():
+        return jnp.asarray(
+            (rng.rand(NB, cfg.heads) + 0.5).astype(np.float32) / 64)
+
+    return [{"k": codes(), "v": codes(),
+             "k_scale": scales(), "v_scale": scales()}
+            for _ in range(cfg.layers)]
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8", "fp8"])
+@pytest.mark.parametrize("S", [3, 1, 8, 32])
+def test_fused_vs_gather_logits_decode(S, kv_quant):
+    """S == 3 is the one-group geometry the suite began with. The
+    others run the decode call's own body and work list (an unquantized
+    pool) or the head-loop body (a quantized one) over three table
+    groups, contexts on every edge of a group and of the span, and —
+    from 8 rows up — two parked rows, whose garbage the gather form
+    returns too and nothing reads: only LIVE rows are compared."""
+    if S == 3:
+        cfg, params = _mk(0)
+        NB, Bt, live = 10, 8, 3
+        tables = jnp.asarray([[0, 1, -1, -1], [2, 3, 4, -1],
+                              [5, -1, -1, -1]], jnp.int32)
+        pos = jnp.asarray([9, 20, 3], jnp.int32)
+    else:
+        cfg, params = _mk(0, max_len=_SPAN)
+        Bt, live = _BT, S - (2 if S >= 8 else 0)
+        pos, tables, NB = _edge_batch(S, parked=S - live)
+    tok = jnp.asarray(np.random.RandomState(5).randint(0, 50, S),
+                      jnp.int32)
+
+    def pool():
+        if kv_quant == "none":
+            return _rand_pool(cfg, NB, Bt)
+        return _quant_pool(cfg, NB, Bt, kv_quant)
+
+    lg, cg = T.paged_decode_step(params, tok, pos, tables, pool(), cfg,
+                                 kernel="gather", kv_quant=kv_quant)
+    lf, cf = T.paged_decode_step(params, tok, pos, tables, pool(), cfg,
+                                 kernel="fused", kv_quant=kv_quant)
+    assert np.isfinite(np.asarray(lf)).all()  # parked rows: zeros in
+    np.testing.assert_allclose(np.asarray(lf)[:live],
+                               np.asarray(lg)[:live],
                                rtol=_RTOL, atol=_ATOL)
-    _assert_caches_equal(cf, cg, exact=False)
+    if kv_quant == "none":
+        _assert_caches_equal(cf, cg, exact=False)
 
 
 def test_fused_vs_gather_logits_verify():
@@ -164,20 +237,36 @@ def _toy_adapters(cfg, seed=7, P=2, rank=2):
     }
 
 
+@pytest.mark.parametrize("groups", [1, 3])
 @pytest.mark.parametrize("kernel", ["gather", "fused"])
-def test_garbage_row_invariant_bit_identical_with_adapters(kernel):
+def test_garbage_row_invariant_bit_identical_with_adapters(kernel, groups):
     """ISSUE 13 satellite: a slot's `-1` table entries must change
     NOTHING — bit-identical logits and cache vs a fully-allocated table
     at the same positions, adapters active, on BOTH kernel settings.
     Until now this invariant lived only in `_paged_view`'s docstring;
     the fused kernel must honor it too (its -1 clamp streams block 0's
-    garbage, which the position mask must erase EXACTLY)."""
-    cfg, params = _mk(3)
-    NB, Bt = 12, 8
-    # depths in use: slot0 -> 2 blocks (pos 9), slot1 -> 1 block (pos 5)
-    partial = jnp.asarray([[0, 1, -1, -1], [2, -1, -1, -1]], jnp.int32)
-    full = jnp.asarray([[0, 1, 8, 9], [2, 10, 11, 7]], jnp.int32)
-    pos = jnp.asarray([9, 5], jnp.int32)
+    garbage, which the position mask must erase EXACTLY). With three
+    table groups the decode call's work list never visits the groups
+    past a context, whatever their entries name, and re-names a block
+    for the unnamed tail of the last one it does visit."""
+    if groups == 1:
+        cfg, params = _mk(3)
+        NB, Bt = 12, 8
+        # depths in use: slot0 -> 2 blocks (pos 9), slot1 -> 1 (pos 5)
+        partial = jnp.asarray([[0, 1, -1, -1], [2, -1, -1, -1]], jnp.int32)
+        full = jnp.asarray([[0, 1, 8, 9], [2, 10, 11, 7]], jnp.int32)
+        pos = jnp.asarray([9, 5], jnp.int32)
+    else:
+        cfg, params = _mk(3, max_len=_SPAN)
+        Bt = _BT
+        pos, partial, used = _edge_batch(2, seed=3)
+        pos = pos.at[0].set(_W + 5)  # ends inside the second group
+        partial = partial.at[0, 0:_W // _BT + 1].set(
+            jnp.arange(used, used + _W // _BT + 1))
+        NB = used + _W // _BT + 1 + 2 * _MAXB
+        spare = jnp.arange(NB - 2 * _MAXB, NB, dtype=jnp.int32)
+        full = jnp.where(partial >= 0, partial,
+                         spare.reshape(2, _MAXB))
     tok = jnp.asarray([13, 21], jnp.int32)
     adapters = _toy_adapters(cfg)
     aidx = jnp.asarray([1, 0], jnp.int32)  # live adapter + zero adapter
@@ -193,6 +282,56 @@ def test_garbage_row_invariant_bit_identical_with_adapters(kernel):
     # the write landed in the same physical block either way; the
     # untouched pool blocks are bit-equal by construction
     _assert_caches_equal(ca, cb)
+
+
+@pytest.mark.parametrize("pool", ["f32", "bf16"])
+@pytest.mark.parametrize("S", [1, 8, 32])
+def test_parked_rows_cost_the_live_rows_nothing(S, pool):
+    """The decode call on a batch with parked rows (`pos` at the
+    table's span, what the engine hands a dead slot) among the live
+    ones: every LIVE row is bit-identical to the same rows called
+    without them, and a parked row's output is zeros — its work-list
+    entry computes nothing, whatever its table row names. The live
+    rows are held to plain softmax attention through the table at the
+    pinned tolerance; on a 16-bit pool that bar is what P's hi + lo
+    split is for (P rounded once to bf16 misses it by 30x)."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    H, dh = 4, 8
+    dt = jnp.float32 if pool == "f32" else jnp.bfloat16
+    pos, tables, NB = _edge_batch(S, seed=S)
+    rng = np.random.RandomState(S)
+    k = jnp.asarray(rng.randn(NB, _BT, H, dh), dt)
+    v = jnp.asarray(rng.randn(NB, _BT, H, dh), dt)
+    # a query the pool's dtype holds exactly, so the kernel's cast of q
+    # to it loses nothing the reference keeps
+    q = jnp.asarray(rng.randn(S, H, dh), dt).astype(jnp.float32)
+    alone = pa.paged_decode_attention(q, k, v, tables, pos,
+                                      interpret=True)
+    view_k = T._paged_view(k.astype(jnp.float32), tables)
+    view_v = T._paged_view(v.astype(jnp.float32), tables)
+    sc = jnp.einsum("shd,sthd->sht", q, view_k) / np.sqrt(dh)
+    seen = jnp.arange(_SPAN)[None, None, :] <= pos[:, None, None]
+    want = jnp.einsum("sht,sthd->shd",
+                      jax.nn.softmax(jnp.where(seen, sc, -1e30), axis=-1),
+                      view_v)
+    np.testing.assert_allclose(np.asarray(alone), np.asarray(want),
+                               rtol=_RTOL, atol=_ATOL)
+    # parked rows first, in the middle and last; one names real blocks
+    at = sorted({0, S // 2, S})
+    qp, pp, tp = np.asarray(q), np.asarray(pos), np.asarray(tables)
+    for n, i in enumerate(at):
+        row = tp[0] if n == 0 else np.full(_MAXB, -1, np.int32)
+        qp = np.insert(qp, i + n, rng.randn(H, dh), axis=0)
+        pp = np.insert(pp, i + n, _SPAN)
+        tp = np.insert(tp, i + n, row, axis=0)
+    mixed = np.asarray(pa.paged_decode_attention(
+        jnp.asarray(qp, jnp.float32), k, v, jnp.asarray(tp),
+        jnp.asarray(pp), interpret=True))
+    parked = pp >= _SPAN
+    assert parked.sum() == len(at)
+    np.testing.assert_array_equal(mixed[~parked], np.asarray(alone))
+    np.testing.assert_array_equal(mixed[parked], 0.0)
 
 
 def test_fused_engine_identity_aliased_and_cow_paths():

@@ -186,6 +186,43 @@ def test_paged_kernels_carry_their_names(one_chip, kernel):
     assert re.search(r'kernel_metadata=\{\s*"kernel":"%s"\s*\}' % name, text)
 
 
+def test_decode_kernel_at_the_cells_geometry_is_what_the_benchmark_reads(
+        one_chip):
+    """The benchmark's `paged_attn_roofline` finds the decode kernel in
+    a device trace by the text of its instruction (`op_match` of
+    benchmarks/chip/layer_metrics/paged_attn_roofline.json: today the
+    SHAPE of the custom call's result). Compile the decode call at the
+    serving cells' geometry (32 slots, 3,500 blocks of 16 tokens) and
+    hold the compiled text to that pattern, read from the file — a
+    kernel change that moves the result's shape fails here, not as
+    `output_malformed` on the chip. The file is read, never edited."""
+    import json
+    import pathlib
+
+    spec = json.loads((pathlib.Path(__file__).resolve().parent.parent
+                       / "benchmarks" / "chip" / "layer_metrics"
+                       / "paged_attn_roofline.json").read_text())
+    rx = re.compile(spec["args"]["op_match"])
+    sds = _sds(one_chip)
+    slots, blocks = 32, 3500
+    pool = sds((blocks, BT, H, DH), jnp.bfloat16)
+    text = _compile(
+        lambda q, k, v, t, p: pa.paged_decode_attention(
+            q, k, v, t, p, interpret=False),
+        sds((slots, H, DH), jnp.bfloat16), pool, pool,
+        sds((slots, MAXB), jnp.int32), sds((slots,), jnp.int32))
+    found = list(rx.finditer(text))
+    assert len(found) == 1  # the kernel, and nothing else of the call
+    # the matched instruction is the kernel's own: named after it,
+    # a TPU custom call, the name in its metadata
+    line = text[text.rindex("\n", 0, found[0].start()) + 1:]
+    line = line[:line.index("metadata={op_name=")]
+    assert line.lstrip().startswith("%paged_decode_attention")
+    assert 'custom_call_target="tpu_custom_call"' in line
+    assert re.search(r'kernel_metadata=\{\s*"kernel":'
+                     r'"paged_decode_attention"\s*\}', line)
+
+
 def _engine(one_chip, **kw):
     """A default-options engine at the smoke's widths, depth cut to 2,
     built on shapes alone, with the argument shapes of its compiled
