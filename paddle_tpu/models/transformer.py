@@ -51,6 +51,7 @@ class TransformerConfig:
         self.moe_experts = moe_experts
         self.moe_every = moe_every
         self.moe_capacity_factor = moe_capacity_factor
+        self.serving = SERVING  # what ServingEngine asks this family for
 
     def is_moe_block(self, i: int) -> bool:
         return self.moe_experts > 0 and (i % self.moe_every
@@ -1299,3 +1300,23 @@ def beam_search_generate(params, prompt, cfg: TransformerConfig,
 
 
 __all__ += ["beam_search_generate"]
+
+
+class _Serving(object):
+    """What ServingEngine asks a model family for (ISSUE 27): its
+    cache, the bodies of its two compiled steps, and the engine options
+    it cannot honour. The GPT block honours them all; `models/sambay.py`
+    fills the same seam for the hybrid family."""
+    name = "gpt"
+    hybrid = False
+    refused = ()
+    decode_step = staticmethod(paged_decode_step)
+    prefill_chunk = staticmethod(paged_prefill_chunk)
+
+    @staticmethod
+    def init_cache(cfg, num_blocks, block_tokens, slots, kv_quant="none"):
+        return init_paged_kv_cache(cfg, num_blocks, block_tokens,
+                                   kv_quant=kv_quant)
+
+
+SERVING = _Serving()

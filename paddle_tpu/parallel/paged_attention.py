@@ -96,7 +96,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .kernel_utils import NEG_INF, resolve_interpret
 
 __all__ = ["paged_decode_attention", "paged_verify_attention",
-           "paged_prefill_attention", "check_paged_smem"]
+           "paged_prefill_attention", "check_paged_smem", "paged_kv_write"]
 
 
 def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
@@ -260,8 +260,9 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
                 o_ref.dtype)
 
 
-def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, q_ref, *refs,
-                      Bt: int, G: int, span: int, scale: float):
+def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, *refs,
+                      Bt: int, G: int, span: int, scale: float,
+                      rep: int = 1, windowed: bool = False):
     """One step of the single-token decode call's flat work list: fold
     the G blocks of table group `wgrp[i]` of slot `wslot[i]` into that
     slot's online-softmax state, ALL heads at once, K and V left in
@@ -292,18 +293,34 @@ def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, q_ref, *refs,
 
     Against a 16-bit V, P goes as two 16-bit halves (hi + lo, stacked
     on the rows of ONE product, so V is loaded into the MXU once): P
-    keeps ~16 bits of mantissa instead of 8, for 1 % of the call."""
+    keeps ~16 bits of mantissa instead of 8, for 1 % of the call.
+
+    Two things the hybrid family brings (ISSUE 27), both read off the
+    operands: `rep` query rows a K/V head (q is `[H * rep, Dh]`, row r
+    belongs to head r // rep — grouped queries; the pool's blocks may
+    then come as the 2-D `[Bt * H, Dh]` they are stored as, see
+    `paged_decode_attention`), and with `windowed` a fifth prefetch
+    operand `first [S]`: the first position a slot attends. Its walk
+    then starts at group first // W, and depths before `first` are
+    masked like depths past `pos`."""
+    if windowed:
+        first_ref, q_ref, refs = refs[0], refs[1], refs[2:]
+    else:
+        first_ref, q_ref, refs = None, refs[0], refs[1:]
     k_refs, v_refs = refs[:G], refs[G:2 * G]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * G:]
-    H, dh = q_ref.shape
+    R, dh = q_ref.shape  # R = H * rep query rows
+    H = R // rep
     W = G * Bt
     i = pl.program_id(0)
     si = wslot_ref[i]
     b = wgrp_ref[i]
     pos = pos_ref[si]
     live = pos < span  # a parked row sits at or past the table's span
+    b_first = 0 if first_ref is None else jnp.where(
+        live, first_ref[si] // W, 0)
 
-    @pl.when(b == 0)
+    @pl.when(b == b_first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -319,14 +336,18 @@ def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, q_ref, *refs,
             q_ref[...].astype(k.dtype), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [H, W*H]; decode family: scale after the product
-        col = jax.lax.broadcasted_iota(jnp.int32, (H, W * H), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (H, W * H), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (R, W * H), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, W * H), 0)
+        if rep > 1:  # rep query rows share a K/V head
+            row = row // rep
         # column c is (token c // H, head c % H): row h keeps its own
         # head's columns at depths <= pos, i.e. c < (pos - b*W + 1)*H;
         # everything else — other heads, unwritten depths, whatever a
         # re-named or clamped block holds — contributes exactly 0
         head = (col & (H - 1)) if H & (H - 1) == 0 else jax.lax.rem(col, H)
         masked = (head != row) | (col >= (pos - b * W + 1) * H)
+        if first_ref is not None:
+            masked = masked | (col < (first_ref[si] - b * W) * H)
         s = jnp.where(masked, NEG_INF, s)
 
         m_prev = m_ref[...]  # [H, 1]
@@ -342,8 +363,8 @@ def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, q_ref, *refs,
             pv = jax.lax.dot_general(
                 jnp.concatenate([hi, lo], axis=0), v,
                 (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [2H, Dh]
-            pv = pv[:H] + pv[H:]
+                preferred_element_type=jnp.float32)  # [2R, Dh]
+            pv = pv[:R] + pv[R:]
         else:
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -431,46 +452,66 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
                need, room, _SMEM_BYTES, _SMEM_RESERVE))
 
 
-def _decode_worklist(tables, pos, Bt: int, G: int, span: int):
+def _decode_worklist(tables, pos, Bt: int, G: int, span: int,
+                     first=None, max_groups=None):
     """The decode call's grid, as data: one entry for every table
     group a live context names, slot after slot, and one empty entry
     for a parked slot (`pos >= span`; its step computes nothing and
-    writes zeros). -> (blk [G, S*NG], wslot [S*NG], wgrp [S*NG], n):
-    entry i < n works on group `wgrp[i]` of slot `wslot[i]`, and its
-    g-th K/V operand holds pool block `blk[g, i]`.
+    writes zeros). -> (blk [G, N], wslot [N], wgrp [N], n): entry
+    i < n works on group `wgrp[i]` of slot `wslot[i]`, and its g-th K/V
+    operand holds pool block `blk[g, i]`. With `first` [S] a slot's
+    walk starts at the group of position first[s] (the hybrid family's
+    window layers) and N = S * `max_groups`, the most groups a slot
+    can then name; otherwise N = S * NG.
 
     `blk` is what keeps the DMA to the blocks the contexts name: where
     entry i's group names no block for operand g (the tail of a
-    context's last group, a parked slot), it RE-NAMES the block that
-    operand named last, and the pipeline issues no copy for a block
-    index that did not change. Nothing is read from a re-named block
-    (the position mask). All of it is integer compares and reductions
-    on the tables and positions, the widest a fused [G, S*NG, S*NG]
-    masked max, and the same for every layer of a step, so the
-    compiled step keeps ONE copy: ~65 us of an 8 ms decode step at 32
-    slots x 16 groups on the v5e (PERF.md section 5, PR 26)."""
+    context's last group, the head of a window's first, a parked
+    slot), it RE-NAMES the block that operand named last, and the
+    pipeline issues no copy for a block index that did not change.
+    Nothing is read from a re-named block (the position mask). All of
+    it is integer compares and reductions on the tables and positions,
+    the widest a fused [G, N, N] masked max, and the same for every
+    layer of a step that shares a table, so the compiled step keeps
+    ONE copy: ~65 us of an 8 ms decode step at 32 slots x 16 groups on
+    the v5e (PERF.md section 5, PR 26). Past N = 1024 that reduction
+    is no longer small (64 slots x 64 groups: 134 M compares), and a
+    rule that looks one entry back takes its place: an operand its
+    group does not name keeps what the entry before it held if that
+    is the same slot's. Only a slot's first group can then copy a
+    block nobody reads, which long contexts make a rounding error."""
     S, mb = tables.shape
     NG = mb // G
+    per = NG if max_groups is None else min(NG, int(max_groups))
+    N = S * per
     W = G * Bt
     live = pos < span
-    ng = jnp.where(live, pos // W + 1, 1)  # [S] steps each slot takes
+    g0 = jnp.zeros_like(pos) if first is None else jnp.where(
+        live, first // W, 0)  # [S] the group each slot's walk starts at
+    ng = jnp.where(live, pos // W - g0 + 1, 1)  # [S] steps each slot takes
     ends = jnp.cumsum(ng)
-    i = jnp.arange(S * NG, dtype=jnp.int32)
+    i = jnp.arange(N, dtype=jnp.int32)
     # plain compares and reductions, nothing materialised: a search
     # (`searchsorted`) or a scan (`cummax`) is a loop of tiny programs
     # on the TPU, ~80 us a call where this is a few fusions
-    past = ends[None, :] <= i[:, None]  # [S*NG, S] slots wholly before i
+    past = ends[None, :] <= i[:, None]  # [N, S] slots wholly before i
     wslot = jnp.minimum(jnp.sum(past, axis=1, dtype=jnp.int32), S - 1)
-    wgrp = jnp.clip(
-        i - jnp.sum(jnp.where(past, ng[None, :], 0), axis=1), 0, NG - 1)
+    local = i - jnp.sum(jnp.where(past, ng[None, :], 0), axis=1)
+    wgrp = jnp.clip(g0[wslot] + local, 0, NG - 1)
     depth = wgrp[None, :] * G + jnp.arange(G, dtype=jnp.int32)[:, None]
     named = (live[wslot] & (i < ends[-1]))[None, :] \
         & (depth * Bt <= pos[wslot][None, :])
+    if first is not None:
+        named = named & ((depth + 1) * Bt > first[wslot][None, :])
+    entry = jnp.maximum(tables[wslot[None, :], depth], 0)  # -1 -> block 0
+    if N > 1024:
+        prev = jnp.concatenate([entry[:, :1], entry[:, :-1]], axis=1)
+        blk = jnp.where(named | (local <= 0)[None, :], entry, prev)
+        return blk, wslot, wgrp, ends[-1]
     # held[g, i]: the latest entry <= i at which operand g was named
     held = jnp.max(jnp.where(
         named[:, None, :] & (i[None, None, :] <= i[None, :, None]),
         i[None, None, :], -1), axis=2)
-    entry = jnp.maximum(tables[wslot[None, :], depth], 0)  # -1 -> block 0
     # an operand not named yet holds whatever entry 0 gives it: one
     # block copied once and never read
     blk = jnp.take_along_axis(entry, jnp.maximum(held, 0), axis=1)
@@ -478,34 +519,44 @@ def _decode_worklist(tables, pos, Bt: int, G: int, span: int):
 
 
 def _paged_decode(q, k_pool, v_pool, tables, pos, *, G, span, name, scale,
-                  interpret):
+                  interpret, rep=1, first=None, max_groups=None):
     """The R == 1 call: `_pa_decode_kernel` over `_decode_worklist`'s
     grid, whose LENGTH is data too (a dynamic grid bound: the steps the
     live contexts name, not slots x table groups). q [S, 1, H, Dh] ->
     out [S, H, 1, Dh], the shape `_pa_kernel` returns for R == 1 (and
     the one the benchmark's `paged_attn_roofline` finds the kernel by);
     the squeezed block dims hand the kernel q and K/V as dense
-    [H, Dh] / [Bt, H, Dh] tiles."""
+    [H, Dh] / [Bt, H, Dh] tiles. A 3-D pool `[NB, Bt * Hk, Dh]` (the
+    hybrid family's) hands its blocks over already merged, with
+    `rep` = H / Hk query rows a K/V head."""
     S, _, H, dh = q.shape
-    Bt = k_pool.shape[1]
-    blk, wslot, wgrp, n = _decode_worklist(tables, pos, Bt, G, span)
+    blk_shape = k_pool.shape[1:]
+    Bt = blk_shape[0] if k_pool.ndim == 4 else blk_shape[0] * rep // H
+    blk, wslot, wgrp, n = _decode_worklist(tables, pos, Bt, G, span,
+                                           first=first,
+                                           max_groups=max_groups)
+    zeros = (0,) * len(blk_shape)
 
-    def _slot_map(i, blk, pos, wslot, wgrp):
+    def _slot_map(i, blk, pos, wslot, wgrp, *first):
         return (wslot[i], 0, 0, 0)
 
     def _kv_map(g):
-        def _map(i, blk, pos, wslot, wgrp):
-            return (blk[g, i], 0, 0, 0)
+        def _map(i, blk, pos, wslot, wgrp, *first):
+            return (blk[g, i],) + zeros
         return _map
 
     kernel = functools.partial(
-        _pa_decode_kernel, Bt=Bt, G=G, span=span, scale=scale)
+        _pa_decode_kernel, Bt=Bt, G=G, span=span, scale=scale, rep=rep,
+        windowed=first is not None)
+    prefetch = (blk, pos, wslot, wgrp)
+    if first is not None:
+        prefetch += (jnp.asarray(first, jnp.int32),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(prefetch),
         grid=(n,),
         in_specs=[pl.BlockSpec((None, None, H, dh), _slot_map)]
-        + [pl.BlockSpec((None, Bt, H, dh), _kv_map(g)) for g in range(G)]
-        + [pl.BlockSpec((None, Bt, H, dh), _kv_map(g)) for g in range(G)],
+        + [pl.BlockSpec((None,) + blk_shape, _kv_map(g)) for g in range(G)]
+        + [pl.BlockSpec((None,) + blk_shape, _kv_map(g)) for g in range(G)],
         out_specs=pl.BlockSpec((None, H, 1, dh), _slot_map),
         scratch_shapes=[pltpu.VMEM((H, dh), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32),
@@ -518,7 +569,7 @@ def _paged_decode(q, k_pool, v_pool, tables, pos, *, G, span, name, scale,
         interpret=resolve_interpret(interpret),
         name=name,
         metadata={"kernel": name},
-    )(blk, pos, wslot, wgrp, q, *([k_pool] * G), *([v_pool] * G))
+    )(*prefetch, q, *([k_pool] * G), *([v_pool] * G))
 
 
 def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
@@ -644,7 +695,8 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, pos,
-                           interpret=None, k_scale=None, v_scale=None):
+                           interpret=None, k_scale=None, v_scale=None,
+                           first=None, max_context=None, scale=None):
     """Batched single-token paged decode attention: one query per slot.
 
     q [S, H, Dh] at per-slot positions `pos` [S] over block tables
@@ -655,7 +707,43 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     returns zeros (on a quantized pool, `_pa_kernel`'s garbage, like
     the gather path's) — nothing reads it either way.
     `k_scale`/`v_scale` [NB, H] dequantize an int8/fp8 pool inside the
-    kernel (ISSUE 14)."""
+    kernel (ISSUE 14).
+
+    Grouped queries over a merged pool (ISSUE 27), told by the shapes:
+    q [S, Hk, rep, D] over pools [NB, Bt * Hk, D] (a block's rows are
+    (token, head), the order a `[Bt, Hk, D]` block has in memory, kept
+    3-D so that the device tiles Bt * Hk rows and not Hk) -> out
+    [S, Hk, rep, D]: the `rep` queries of a K/V head attend its rows
+    (the custom call is then named `hybrid_decode_attention`).
+    The hybrid family's differential attention is this with D = two
+    projected heads side by side: a query sits in the half of D that
+    its key half occupies and is zero in the other, and the value read
+    is the pair, D wide. `first` [S] is the first position a slot
+    attends (a window layer's pos - window + 1; depths before it are
+    masked and their groups not walked), `max_context` the most
+    positions any slot can then attend, which bounds the work list;
+    `scale` replaces 1 / sqrt(D) where D is not the head's width."""
+    if k_pool.ndim == 3:
+        S, Hk, rep, D = q.shape
+        Bt = k_pool.shape[1] // Hk
+        maxb = tables.shape[1]
+        G = _group(Bt, maxb)
+        tables = jnp.asarray(tables, jnp.int32)
+        pad = -maxb % G
+        if pad:
+            tables = jnp.concatenate(
+                [tables, jnp.full((S, pad), -1, jnp.int32)], axis=1)
+        max_groups = None
+        if first is not None and max_context is not None:
+            max_groups = -(-int(max_context) // (G * Bt)) + 1
+        out = _paged_decode(
+            q.reshape(S, 1, Hk * rep, D), k_pool, v_pool, tables,
+            jnp.asarray(pos, jnp.int32), G=G, span=maxb * Bt,
+            name="hybrid_decode_attention",
+            scale=1.0 / math.sqrt(D) if scale is None else scale,
+            interpret=interpret, rep=rep, first=first,
+            max_groups=max_groups)
+        return out.reshape(S, Hk, rep, D)
     S, H, dh = q.shape
     out = _paged_attention(
         q[:, None], k_pool, v_pool, tables, pos,
@@ -664,6 +752,73 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale,
     )
     return out[:, 0]
+
+
+def _kv_write_kernel(blk_ref, off_ref, kn_ref, vn_ref, k_ref, v_ref,
+                     ko_ref, vo_ref, *, Hk: int):
+    """One slot's decode write: its token's Hk rows replace rows
+    [off * Hk, (off + 1) * Hk) of the block the slot is filling, K and
+    V at once. The new rows come tiled over the whole block, so the
+    write is a select on a row index, with no unaligned store."""
+    lo = off_ref[pl.program_id(0)] * Hk
+    row = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 0)
+    mine = (row >= lo) & (row < lo + Hk)
+    ko_ref[...] = jnp.where(mine, kn_ref[...], k_ref[...])
+    vo_ref[...] = jnp.where(mine, vn_ref[...], v_ref[...])
+
+
+def paged_kv_write(k_pool, v_pool, k_new, v_new, tables, pos,
+                   interpret=None):
+    """The decode step's K/V write into merged 3-D pools (ISSUE 27):
+    slot s's new rows `k_new[s]`, `v_new[s]` [Hk, D] land at position
+    pos[s] through tables [S, MAXB] -> (k_pool, v_pool), updated in
+    place. XLA lowers the same scatter to a loop of one small
+    dynamic-update-slice a slot (3 us each, 18 scatters a step at 64
+    slots: 3.5 ms of a 37 ms step on the v5e; PERF.md section 6, PR
+    27); here a grid step copies the slot's block in, selects the new
+    rows into it, and copies it out. A parked slot, or one whose entry
+    is unallocated, writes the pool's LAST block, which the pools keep
+    beyond what the allocator hands out so that nothing lives there."""
+    NB, rows, D = k_pool.shape
+    S, Hk = k_new.shape[0], k_new.shape[1]
+    Bt = rows // Hk
+    maxb = tables.shape[1]
+    bi = pos // Bt
+    phys = jnp.take_along_axis(tables, jnp.clip(bi, 0, maxb - 1)[:, None],
+                               axis=1)[:, 0]
+    blk = jnp.where((bi < maxb) & (phys >= 0), phys, NB - 1).astype(jnp.int32)
+    off = (pos % Bt).astype(jnp.int32)
+
+    def tiled(x):  # [S, Hk, D] -> the rows repeated down a whole block
+        return jnp.tile(x.astype(k_pool.dtype), (1, Bt, 1))
+
+    def slot(i, blk, off):
+        return (i, 0, 0)
+
+    def block(i, blk, off):
+        return (blk[i], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((None, rows, D), slot),
+                  pl.BlockSpec((None, rows, D), slot),
+                  pl.BlockSpec((None, rows, D), block),
+                  pl.BlockSpec((None, rows, D), block)],
+        out_specs=[pl.BlockSpec((None, rows, D), block),
+                   pl.BlockSpec((None, rows, D), block)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kv_write_kernel, Hk=Hk),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        # operands 0 and 1 are the prefetched block ids and offsets
+        input_output_aliases={4: 0, 5: 1},
+        interpret=resolve_interpret(interpret),
+        name="paged_kv_write",
+        metadata={"kernel": "paged_kv_write"},
+    )(blk, off, tiled(k_new), tiled(v_new), k_pool, v_pool)
 
 
 def paged_verify_attention(q, k_pool, v_pool, tables, pos,

@@ -104,7 +104,7 @@ from ..models import transformer as tlm
 from .adapters import AdapterPool
 from .integrity import (_FP_RTOL, BlockFingerprints, IntegrityError,
                         ServingSentinel)
-from .kv_blocks import KVBlockAllocator
+from .kv_blocks import KVBlockAllocator, WindowBlockTables
 from .kv_store import make_block_record, payload_crc
 from .metrics import ServingMetrics
 from .prefix_cache import PrefixCache, chain_keys
@@ -285,6 +285,19 @@ class ServingEngine(object):
     folded into the compiled steps — the decode HBM roofline's weight
     term drops ~4x independently of the KV side.
 
+    Model families (ISSUE 27): the engine asks `cfg.serving` for the
+    cache and for the bodies of its two compiled steps
+    (`models/transformer.SERVING` for the GPT block,
+    `models/sambay.SERVING` for the hybrid family) — same scheduler,
+    allocator, side-bands, sampler, spans and program names either
+    way. A hybrid family brings two more caches the engine manages:
+    window-attention pools whose blocks are freed behind the window
+    (`kv_blocks.WindowBlockTables`, inside `engine.alloc_blocks`) and
+    per-slot recurrent state, zeroed at admission
+    (`engine.state_reset`). The options such a family cannot honour
+    (`cfg.serving.refused`; hand-off import at `submit`) raise a
+    ValueError that names the option; nothing is silently ignored.
+
     Serving integrity (ISSUE 15): `integrity_traps` (default True)
     folds a per-slot non-finite trap — logits + softmax-denominator
     reduction (`transformer.logits_trap`) — into the SAME compiled
@@ -319,6 +332,29 @@ class ServingEngine(object):
                  async_dispatch=False):
         self._params = params
         self._cfg = cfg
+        # the model family's seam (ISSUE 27): its cache, the bodies of
+        # its two compiled steps, the options it cannot honour. The GPT
+        # block's is tlm.SERVING; a config of another family carries
+        # its own as `cfg.serving`
+        fam = self._family = getattr(cfg, "serving", None) or tlm.SERVING
+        asked = {"prefix_cache_tokens": prefix_cache_tokens,
+                 "kv_store": kv_store, "spec_draft_len": spec_draft_len,
+                 "decode_window": decode_window not in (None, 1),
+                 "async_dispatch": async_dispatch,
+                 "kv_quant": kv_quant != "none",
+                 "weight_quant": weight_quant,
+                 "adapter_registry": adapter_registry,
+                 "kv_fingerprints": kv_fingerprints}
+        for opt in fam.refused:
+            if asked[opt]:
+                # nothing silently ignored: a cache that holds recurrent
+                # state cannot be restored by aliasing or re-playing
+                # blocks, and the rest is not built for this family
+                raise ValueError(
+                    "%s is not supported for the %r model family (it "
+                    "keeps recurrent state beside its K/V blocks); "
+                    "refused options: %s"
+                    % (opt, fam.name, ", ".join(fam.refused)))
         # deterministic-exploration seam (ISSUE 9): the fleet threads
         # its SchedulerHook through so a controlled scheduler can park
         # a replica at engine-step granularity too; None costs one
@@ -340,7 +376,8 @@ class ServingEngine(object):
         # the positional table bounds every position (same clamp as
         # generate: a gather past it would silently clamp, not error)
         L = int(max_len or cfg.max_len)
-        L = min(L, int(params["pos"].shape[0]))
+        if "pos" in params:
+            L = min(L, int(params["pos"].shape[0]))
         self.max_len = L
         self.min_bucket = int(min_bucket)
         if max_prefills_per_step is not None and max_prefills_per_step < 1:
@@ -502,9 +539,22 @@ class ServingEngine(object):
         # allocator's stats) — tlm.kv_block_bytes is the ONE formula,
         # shared with bench.py's byte-budget sizing and
         # bench_offline's roofline
-        block_bytes = tlm.kv_block_bytes(
-            cfg.layers, cfg.heads, cfg.dim // cfg.heads, Bt, kv_quant,
-            act_itemsize=jnp.dtype(cfg.dtype).itemsize)
+        # a hybrid family's other two caches (ISSUE 27): the window
+        # layers' tables, whose blocks are freed behind the window, and
+        # per-slot recurrent state, which lives in the cache pytree
+        self._win: Optional[WindowBlockTables] = None  # guarded-by: scheduler
+        self._state_reset_fn = None
+        if fam.hybrid:
+            sizes = fam.cache_bytes(cfg, Bt)
+            block_bytes = sizes["full"]
+            self._state_bytes_per_slot = sizes["state"]
+            self._win = WindowBlockTables(
+                S, self.blocks_per_slot, Bt, cfg.window,
+                block_bytes=sizes["window"])
+        else:
+            block_bytes = tlm.kv_block_bytes(
+                cfg.layers, cfg.heads, cfg.dim // cfg.heads, Bt, kv_quant,
+                act_itemsize=jnp.dtype(cfg.dtype).itemsize)
         self.kv_block_bytes = block_bytes
         self._alloc = KVBlockAllocator(NB, Bt,
                                        block_bytes=block_bytes)  # guarded-by: scheduler
@@ -532,8 +582,7 @@ class ServingEngine(object):
                 rank=adapter_rank)
             self.metrics.adapter_pool = self._adapter_pool
 
-        self._cache = tlm.init_paged_kv_cache(cfg, NB, Bt,
-                                              kv_quant=kv_quant)
+        self._cache = fam.init_cache(cfg, NB, Bt, S, kv_quant=kv_quant)
         if weight_quant is not None:
             # quantize ONCE; the f32 tree the caller handed in is
             # theirs (fleet CRC walks / rollout see full precision) —
@@ -646,7 +695,7 @@ class ServingEngine(object):
     # compiled steps
     # ------------------------------------------------------------------
     def _make_decode(self):
-        cfg, metrics = self._cfg, self.metrics
+        cfg, metrics, fam = self._cfg, self.metrics, self._family
         Lv = self.blocks_per_slot * self.kv_block_tokens
         kernel = self.paged_kernel  # baked into the one compiled step
         kv_quant = self.kv_quant    # ditto: storage dtype is traced in
@@ -663,7 +712,7 @@ class ServingEngine(object):
             # block and the scatter DROPS the row, so a retired slot
             # can never dirty a block a future request will claim
             write_pos = jnp.where(alive, pos, jnp.int32(Lv))
-            logits, cache = tlm.paged_decode_step(
+            logits, cache = fam.decode_step(
                 params, tok, write_pos, tables, cache, cfg,
                 adapters=adapters, adapter_idx=aidx, kernel=kernel,
                 kv_quant=kv_quant,
@@ -713,7 +762,7 @@ class ServingEngine(object):
         only tokens >= j: the host checks row j before emitting row j.
         Traced exactly once per engine lifetime under the same
         "decode_step" trace name as the plain step it replaces."""
-        cfg, metrics = self._cfg, self.metrics
+        cfg, metrics, fam = self._cfg, self.metrics, self._family
         K = self.decode_window
         Lv = self.blocks_per_slot * self.kv_block_tokens
         kernel = self.paged_kernel  # baked into the one compiled step
@@ -731,7 +780,7 @@ class ServingEngine(object):
             def _iter(carry, _):
                 cache, tok, pos, alive, counts = carry
                 write_pos = jnp.where(alive, pos, jnp.int32(Lv))
-                logits, cache = tlm.paged_decode_step(
+                logits, cache = fam.decode_step(
                     params, tok, write_pos, tables, cache, cfg,
                     adapters=adapters, adapter_idx=aidx, kernel=kernel,
                     kv_quant=kv_quant,
@@ -841,7 +890,7 @@ class ServingEngine(object):
         fn = self._chunk_fns.get(Cb)
         if fn is not None:
             return fn
-        cfg, metrics = self._cfg, self.metrics
+        cfg, metrics, fam = self._cfg, self.metrics, self._family
         kernel = self.paged_kernel  # baked into the per-bucket step
         kv_quant = self.kv_quant
         deq = self._deq
@@ -852,7 +901,7 @@ class ServingEngine(object):
             metrics.count_trace("prefill_T%d" % Cb)
             if deq is not None:
                 params = deq(params)
-            logits, cache = tlm.paged_prefill_chunk(
+            logits, cache = fam.prefill_chunk(
                 params, cache, padded, start, table_row, cfg,
                 true_len=true_len, adapters=adapters, adapter_idx=aidx,
                 kernel=kernel, kv_quant=kv_quant,
@@ -910,7 +959,11 @@ class ServingEngine(object):
     # ------------------------------------------------------------------
     def _band(self, name):
         if name in self._dirty:
-            self._dev[name] = jnp.asarray(getattr(self, "_" + name))
+            host = getattr(self, "_" + name)
+            if name == "tables" and self._win is not None:
+                # the hybrid family's steps take both tables as one band
+                host = np.stack([host, self._win.tables])
+            self._dev[name] = jnp.asarray(host)
             self._dirty.discard(name)
             self.metrics.band_uploads += 1
         return self._dev[name]
@@ -1181,6 +1234,33 @@ class ServingEngine(object):
                 self._n_alloc[s] += 1
                 self._mark_dirty("tables")
 
+    def _advance_window(self, spans):
+        """The hybrid family's window tables (ISSUE 27), inside
+        `engine.alloc_blocks`: for every (slot, lo, hi) about to write
+        positions [lo, hi), free the blocks that fall wholly behind
+        the window and materialise the ones the write needs."""
+        m, win = self.metrics, self._win
+        with m.phase("engine.window_release"):
+            changed = [win.advance(int(s), int(lo), int(hi))
+                       for s, lo, hi in spans]
+            m.window_blocks_released = win.released_total
+            if any(changed):
+                self._mark_dirty("tables")
+
+    def _reset_slot_state(self, s: int):
+        """A request admitted to slot `s` starts from zero recurrent
+        state (the hybrid family; the slot's last tenant left its
+        own), and from an empty window table."""
+        if (self._win.tables[s] >= 0).any():
+            raise RuntimeError("slot %d admitted over a live window table"
+                               % s)
+        if self._state_reset_fn is None:
+            kw = {"donate_argnums": (0,)} if self._donate else {}
+            self._state_reset_fn = jax.jit(self._family.reset_slot_state,
+                                           **kw)
+        self._cache = self._state_reset_fn(self._cache, jnp.int32(s))
+        self.metrics.state_slots_reset += 1
+
     def _reclaim_for(self, need_new: int):
         """Evict idle trie chains until `need_new` blocks are
         available — but ONLY when eviction can actually bridge the gap
@@ -1218,6 +1298,8 @@ class ServingEngine(object):
         self.metrics.kv_blocks_freed_at_retire += freed
         self.metrics.kv_tail_blocks_freed += tail
         self._tables[s, :] = -1
+        if self._win is not None:
+            self._win.free(s)
         self._n_alloc[s] = 0
         self._reserved_tail[s] = 0
         self._limits[s] = 0
@@ -1277,6 +1359,11 @@ class ServingEngine(object):
             )
         if publish_len is not None and publish_len < 0:
             raise ValueError("publish_len must be >= 0 or None")
+        if handoff and self._family.hybrid:
+            raise ValueError(
+                "handoff import is not supported for the %r model family: "
+                "imported K/V blocks cannot restore its recurrent state"
+                % (self._family.name,))
         if adapter is not None:
             # resolve-or-refuse NOW: an unknown adapter (or an engine
             # with no pool) must fail the caller synchronously, never
@@ -1424,6 +1511,12 @@ class ServingEngine(object):
             self._reclaim_for(need_new)
             if not self._alloc.reserve(need_new):
                 return False  # saturated: stay queued (backpressure)
+            if self._win is not None \
+                    and not self._win.admit(s, T0 + h.max_new_tokens):
+                # the window pool holds every slot's bound, so this is
+                # structurally unreachable: kept as the loud unwind
+                self._alloc.release_reservation(need_new)
+                return False
             if pool is not None:
                 # pin the request's adapter AFTER the block
                 # reservation: a block-starved request retries every
@@ -1578,6 +1671,9 @@ class ServingEngine(object):
             h.handoff_outcome = {"imported": h.handoff_imported,
                                  "fallback": h.handoff_fallback}
             h.handoff = None  # release the payload bytes
+        if self._win is not None:
+            with self.metrics.phase("engine.state_reset", rid=h.rid):
+                self._reset_slot_state(s)
         self._n_alloc[s] = n_alias + n_imp
         self._reserved_tail[s] = need_new - n_cow - n_imp
         if pc is not None:
@@ -1674,14 +1770,25 @@ class ServingEngine(object):
         m = self.metrics
         with m.phase("engine.prefill_chunk", row="prefill_T%d" % Cb,
                      rid=h.rid, bucket=Cb, tokens=c):
+            table_row = self._tables[s]
             with m.phase("engine.alloc_blocks"):
                 self._ensure_blocks(s, cursor, cursor + c)
+                if self._win is not None:
+                    # the chunk READS the window behind it through the
+                    # table as it stands, and WRITES through the table
+                    # as the release leaves it: rows [full, window
+                    # read, window write, the slot's index]
+                    wread = self._win.tables[s].copy()
+                    self._advance_window([(s, cursor, cursor + c)])
+                    table_row = np.stack([
+                        table_row, wread, self._win.tables[s],
+                        np.full_like(table_row, s)])
             padded = np.zeros(Cb, np.int32)
             padded[:c] = h.full_prompt[cursor:cursor + c]
             fn = self._chunk_fn(Cb)
             with m.phase("engine.upload"):
                 args = (jnp.asarray(padded), jnp.int32(cursor),
-                        jnp.asarray(self._tables[s]), jnp.int32(c),
+                        jnp.asarray(table_row), jnp.int32(c),
                         jnp.float32(h.temperature))
                 adapter = self._adapter_args(jnp.int32(int(self._aidx[s])))
             with m.phase("engine.dispatch") as disp:
@@ -1961,6 +2068,15 @@ class ServingEngine(object):
         m.decode_blocks_live += int(
             (self._pos[alive] // self.kv_block_tokens + 1).sum())
         m.decode_blocks_walked += self.max_slots * self.blocks_per_slot
+        win = self._win
+        if win is not None:
+            n_live = int(alive.sum())
+            m.cache_bytes_in_use = {
+                "full": self._alloc.blocks_in_use * self.kv_block_bytes,
+                "window": win.alloc.blocks_in_use * win.alloc.block_bytes,
+                "state": n_live * self._state_bytes_per_slot}
+            m.cache_bytes_per_slot.append(
+                sum(m.cache_bytes_in_use.values()) / max(n_live, 1))
 
     def _decode_once(self):  # band-verb: sync
         """The plain (non-speculative) batched decode: one token per
@@ -1973,6 +2089,16 @@ class ServingEngine(object):
                 for s in live:
                     p = int(self._pos[s])
                     self._ensure_blocks(s, p, p + 1)
+                if self._win is not None:
+                    # a window table changes only where a write opens a
+                    # block or the window's tail leaves one
+                    Bt, p = self.kv_block_tokens, self._pos[live]
+                    edge = (p % Bt == 0) | ((p + 1 - self._win.window) % Bt
+                                            == 0)
+                    if edge.any():
+                        self._advance_window(
+                            [(s, q, q + 1) for s, q in zip(live[edge],
+                                                           p[edge])])
             *bands, aidx = self._bands(
                 "tables", "tok", "pos", "alive", "temps", "counts",
                 "base_keys", "aidx")

@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["KVBlockAllocator"]
+__all__ = ["KVBlockAllocator", "WindowBlockTables"]
 
 
 class KVBlockAllocator(object):
@@ -165,3 +165,84 @@ class KVBlockAllocator(object):
             out["block_bytes"] = self.block_bytes
             out["bytes_in_use"] = self.block_bytes * self.blocks_in_use
         return out
+
+
+class WindowBlockTables(object):
+    """Block tables of sliding-window attention layers (ISSUE 27): one
+    table row a slot, shared by every window layer (as the GPT pool's
+    one table serves every layer), over a pool of their own.
+
+    A window layer's query at position p attends p - W + 1 .. p and
+    nothing earlier, ever again: a block that lies wholly behind the
+    window is FREED as the slot advances, so a slot never holds more
+    than ceil(W / Bt) + 1 blocks whatever its context, and the pool is
+    slots x that bound, not slots x max_len. The table keeps its
+    logical indexing (entry b covers positions b * Bt ..): a freed
+    entry reads -1, and the kernels start their walk at the window's
+    first block.
+
+    Same discipline as the allocator it wraps: pure host bookkeeping,
+    confined to the engine's scheduler thread."""
+
+    def __init__(self, slots: int, blocks_per_slot: int, block_tokens: int,
+                 window: int, block_bytes=None):
+        self.window = int(window)
+        self.block_tokens = Bt = int(block_tokens)
+        self.per_slot = -(-self.window // Bt) + 1
+        self.alloc = KVBlockAllocator(int(slots) * self.per_slot, Bt,
+                                      block_bytes=block_bytes)
+        self.tables = np.full((int(slots), int(blocks_per_slot)), -1,
+                              np.int32)               # guarded-by: scheduler
+        self._tail = np.zeros(int(slots), np.int32)   # guarded-by: scheduler
+        self.released_total = 0                       # guarded-by: scheduler
+
+    def held(self, s: int) -> int:
+        return int((self.tables[s] >= 0).sum())
+
+    def admit(self, s: int, total_tokens: int) -> bool:
+        """Reserve the slot's bounded worst case: the blocks of its
+        whole context, or of one window if that is fewer."""
+        n = min(self.per_slot, -(-int(total_tokens) // self.block_tokens))
+        if not self.alloc.reserve(n):
+            return False
+        self._tail[s] = n
+        return True
+
+    def advance(self, s: int, lo: int, hi: int) -> int:
+        """Positions [lo, hi) of slot `s` are about to be written. Free
+        every block wholly behind position hi - W (the first one the
+        query at hi - 1, or any later one, attends) and materialise the
+        blocks from there to hi - 1 that the table lacks. A chunk's
+        rows before hi - W are thereby never stored: they are read by
+        the chunk itself and by nothing after it. -> whether the
+        slot's row changed (the engine then uploads the table)."""
+        Bt = self.block_tokens
+        row = self.tables[s]
+        keep = max(0, hi - self.window) // Bt
+        changed = False
+        for b in np.nonzero(row[:keep] >= 0)[0]:
+            self.alloc.decref(int(row[b]))
+            row[b] = -1
+            # the slot may grow into as many blocks again: the block
+            # just freed covers the reservation
+            self.alloc.reserve(1)
+            self._tail[s] += 1
+            self.released_total += 1
+            changed = True
+        for b in range(max(keep, lo // Bt), (hi - 1) // Bt + 1):
+            if row[b] < 0:
+                row[b] = self.alloc.alloc_reserved()
+                self._tail[s] -= 1
+                changed = True
+        return changed
+
+    def free(self, s: int):
+        """Retirement: every block back to the pool, and the
+        reservation the slot never grew into."""
+        row = self.tables[s]
+        for b in np.nonzero(row >= 0)[0]:
+            self.alloc.decref(int(row[b]))
+        row[:] = -1
+        if self._tail[s]:
+            self.alloc.release_reservation(int(self._tail[s]))
+            self._tail[s] = 0

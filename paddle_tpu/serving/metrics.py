@@ -103,6 +103,15 @@ class ServingMetrics(object):
         self.kv_tail_blocks_freed = 0     # cumulative: reserved, never
         #                                   reached (early EOS tails)
         self.cow_blocks = 0               # cumulative copy-on-writes
+        # ISSUE 27 counters — a hybrid family's three caches (None /
+        # zero for the GPT block): blocks freed behind the attention
+        # window, slots whose recurrent state was zeroed at admission,
+        # bytes resident by kind of cache (gauge), and per decode step
+        # those bytes over the live slots
+        self.window_blocks_released = 0   # cumulative
+        self.state_slots_reset = 0        # cumulative
+        self.cache_bytes_in_use = None    # gauge: {"full", "window", "state"}
+        self.cache_bytes_per_slot = _RunningStat()
         self.spec_windows = 0             # cumulative verify rows run
         self.spec_drafted = 0             # cumulative drafted tokens
         self.spec_accepted = 0            # cumulative drafts emitted
@@ -284,6 +293,9 @@ class ServingMetrics(object):
             "kv_blocks_freed_at_retire": self.kv_blocks_freed_at_retire,
             "kv_tail_blocks_freed": self.kv_tail_blocks_freed,
             "cow_blocks": self.cow_blocks,
+            "window_blocks_released": self.window_blocks_released,
+            "state_slots_reset": self.state_slots_reset,
+            "cache_bytes_in_use": self.cache_bytes_in_use,
             "spec_windows": self.spec_windows,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,

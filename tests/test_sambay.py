@@ -1,0 +1,455 @@
+"""The SambaY hybrid family (ISSUE 27): the model module against its
+plain reference, its three caches, its kernels, and the engine seam.
+
+Small on the CPU, seeded random weights from the reference's own
+initialiser (`benchmarks/chip/references/sambay_plain.py`, which
+imports nothing of the program). Tolerances: the program and the
+reference are both float32 here (conftest pins float32 matmuls), so
+they differ by summation order alone: a few 1e-6 on logits of size
+~2 through 8 layers. `TOL` = 5e-5 leaves ten times that room and is
+still 400 times below the 2e-2 the int8 control moves the same logits
+by (`test_tolerance_is_tight_enough_to_fail_the_int8_control`).
+"""
+
+import functools
+import importlib.util
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.models import sambay as sb
+from paddle_tpu.parallel import paged_attention as pa
+from paddle_tpu.parallel.ssm_update import (ssm_state_update,
+                                            ssm_state_update_reference)
+from paddle_tpu.serving import ServingEngine
+from paddle_tpu.serving.kv_blocks import WindowBlockTables
+
+TOL = 5e-5
+SHAPE = {"vocab": 300, "dim": 64, "heads": 8, "kv_heads": 4, "layers": 8,
+         "mlp_mult": 4, "window": 12}
+BT, SLOTS, MAXB = 4, 3, 16
+
+
+def _reference():
+    path = (pathlib.Path(__file__).resolve().parent.parent / "benchmarks"
+            / "chip" / "references" / "sambay_plain.py")
+    spec = importlib.util.spec_from_file_location("sambay_plain", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return _reference()
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return sb.SambaYConfig(
+        **{k: SHAPE[k] for k in ("vocab", "dim", "heads", "kv_heads",
+                                 "layers", "mlp_mult", "window")},
+        max_len=BT * MAXB, dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def params(ref):
+    return ref.init_weights(SHAPE, BT * MAXB, 3, dtype="float32")
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(0).integers(0, SHAPE["vocab"], 40).astype(
+        np.int32)
+
+
+@pytest.fixture(scope="module")
+def ref_logits(ref, params, tokens):
+    return np.asarray(ref.logits(params, tokens, SHAPE))
+
+
+def test_layer_kinds_of_the_published_depth():
+    kinds = sb.layer_kinds(32)
+    assert [l for l, k in enumerate(kinds) if k == "mamba"] == list(
+        range(0, 17, 2))
+    assert [l for l, k in enumerate(kinds) if k == "window"] == list(
+        range(1, 16, 2))
+    assert kinds[17] == "full"
+    assert [l for l, k in enumerate(kinds) if k == "gmu"] == list(
+        range(18, 32, 2))
+    assert [l for l, k in enumerate(kinds) if k == "cross"] == list(
+        range(19, 32, 2))
+
+
+def test_parameter_count_is_the_published_3p8_billion():
+    cfg = sb.SambaYConfig(vocab=200064, dim=2560, heads=40, kv_heads=20,
+                          layers=32, window=512, max_len=8192,
+                          dtype=jnp.bfloat16)
+    tree = jax.eval_shape(lambda: sb.init_params(cfg, jax.random.PRNGKey(0)))
+    n = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(tree))
+    assert 3.84e9 < n < 3.86e9
+
+
+def test_init_params_has_the_references_tree(cfg, params):
+    mine = sb.init_params(cfg, jax.random.PRNGKey(0))
+    assert (jax.tree_util.tree_structure(mine)
+            == jax.tree_util.tree_structure(params))
+    for a, b in zip(jax.tree_util.tree_leaves(mine),
+                    jax.tree_util.tree_leaves(params)):
+        assert a.shape == b.shape
+
+
+def test_full_forward_equals_the_reference(cfg, params, tokens, ref_logits):
+    got = sb.forward(params, jnp.asarray(tokens), cfg)
+    assert np.abs(np.asarray(got) - ref_logits).max() < TOL
+
+
+def test_tolerance_is_tight_enough_to_fail_the_int8_control(
+        ref, params, tokens, ref_logits):
+    ctrl = np.asarray(ref.logits(params, tokens, SHAPE, quant="int8"))
+    assert np.abs(ctrl - ref_logits).max() > 100 * TOL
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted(fn, cfg, kernel):
+    """The model's step compiled once a kernel, as the engine does."""
+    return jax.jit(functools.partial(fn, cfg=cfg, kernel=kernel))
+
+
+class _Slot(object):
+    """One slot's host bookkeeping, as the engine keeps it."""
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.cache = sb.SERVING.init_cache(cfg, 40, BT, SLOTS)
+        self.win = WindowBlockTables(SLOTS, MAXB, BT, cfg.window)
+        self.ftab = np.full((SLOTS, MAXB), -1, np.int32)
+        self.next_block = 0
+
+    def admit(self, s):
+        assert self.win.admit(s, BT * MAXB)
+        self.cache = sb.reset_slot_state(self.cache, s)
+
+    def _ensure(self, s, lo, hi):
+        for b in range(lo // BT, (hi - 1) // BT + 1):
+            if self.ftab[s, b] < 0:
+                self.ftab[s, b] = self.next_block
+                self.next_block += 1
+
+    def chunk(self, params, s, toks, cursor, c, bucket, kernel):
+        self._ensure(s, cursor, cursor + c)
+        wread = self.win.tables[s].copy()
+        self.win.advance(s, cursor, cursor + c)
+        assert self.win.held(s) <= self.win.per_slot
+        rows = np.stack([self.ftab[s], wread, self.win.tables[s],
+                         np.full(MAXB, s, np.int32)])
+        padded = np.full(bucket, 7, np.int32)  # padding is not token 0
+        padded[:c] = toks[cursor:cursor + c]
+        logits, self.cache = _jitted(sb.paged_prefill_chunk, self.cfg, kernel)(
+            params, self.cache, jnp.asarray(padded), jnp.int32(cursor),
+            jnp.asarray(rows), true_len=jnp.int32(c))
+        return np.asarray(logits)
+
+    def decode(self, params, toks_at, kernel):
+        """`toks_at`: {slot: (token, position)}; the others are parked."""
+        pos = np.full(SLOTS, MAXB * BT, np.int32)
+        tok = np.zeros(SLOTS, np.int32)
+        for s, (t, p) in toks_at.items():
+            self._ensure(s, p, p + 1)
+            self.win.advance(s, p, p + 1)
+            assert self.win.held(s) <= self.win.per_slot
+            pos[s], tok[s] = p, t
+        logits, self.cache = _jitted(sb.paged_decode_step, self.cfg, kernel)(
+            params, jnp.asarray(tok), jnp.asarray(pos),
+            jnp.asarray(np.stack([self.ftab, self.win.tables])), self.cache)
+        return np.asarray(logits)
+
+
+@pytest.mark.parametrize("kernel", ["gather", "fused"])
+def test_chunked_prefill_then_decode_equals_the_full_forward(
+        cfg, params, tokens, ref_logits, kernel):
+    """Prefill in chunks of uneven buckets (5 of 8, 14 of 16, 8 of 8:
+    padded and unpadded, the second crossing the 12-token window), then
+    decode to position 39: the logits at every chunk's last row and at
+    every decoded position are the reference's full forward's, with
+    the window release running all the way (blocks freed, the bound
+    held)."""
+    st = _Slot(cfg)
+    st.admit(1)
+    cursor = 0
+    for c, bucket in ((5, 8), (14, 16), (8, 8)):
+        got = st.chunk(params, 1, tokens, cursor, c, bucket, kernel)
+        cursor += c
+        assert np.abs(got - ref_logits[cursor - 1]).max() < TOL
+    for p in range(cursor, 40):
+        got = st.decode(params, {1: (tokens[p], p)}, kernel)
+        assert np.abs(got[1] - ref_logits[p]).max() < TOL
+    assert st.win.released_total >= 6 and st.win.held(1) <= 4
+
+
+@pytest.mark.parametrize("kernel", ["gather", "fused"])
+def test_parked_slots_state_is_bit_identical_after_other_slots_steps(
+        cfg, params, tokens, kernel):
+    st = _Slot(cfg)
+    for s in (0, 2):
+        st.admit(s)
+        st.chunk(params, s, tokens, 0, 8, 8, kernel)
+    before = jax.tree_util.tree_map(lambda a: np.asarray(a[2]).copy(),
+                                    st.cache["ssm"])
+    for p in range(8, 14):  # slot 2 parked: only slot 0 steps
+        st.decode(params, {0: (tokens[p], p)}, kernel)
+    after = jax.tree_util.tree_map(lambda a: np.asarray(a[2]),
+                                   st.cache["ssm"])
+    for a, b in zip(jax.tree_util.tree_leaves(before),
+                    jax.tree_util.tree_leaves(after)):
+        assert np.array_equal(a, b)
+    moved = jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda a: float(np.abs(np.asarray(a[0])).max()), st.cache["ssm"]))
+    assert min(moved) > 0  # slot 0's did advance
+
+
+def test_padded_bucket_rows_do_not_advance_state(cfg, params, tokens):
+    """The same 5 rows in a bucket of 8 and in a bucket of 16 (other
+    padding): the state and the conv window they leave are the same,
+    and are those of 5 rows, not of the bucket."""
+    left = []
+    for bucket in (8, 16):
+        st = _Slot(cfg)
+        st.admit(1)
+        st.chunk(params, 1, tokens, 0, 5, bucket, "gather")
+        left.append(jax.tree_util.tree_map(lambda a: np.asarray(a[1]),
+                                           st.cache["ssm"]))
+    for a, b in zip(jax.tree_util.tree_leaves(left[0]),
+                    jax.tree_util.tree_leaves(left[1])):
+        assert np.abs(a - b).max() < 1e-6
+    st = _Slot(cfg)
+    st.admit(1)
+    st.chunk(params, 1, tokens, 0, 8, 8, "gather")  # 8 true rows: differs
+    other = jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda a: np.asarray(a[1]), st.cache["ssm"]))
+    assert max(np.abs(a - b).max() for a, b in zip(
+        jax.tree_util.tree_leaves(left[0]), other)) > 1e-3
+
+
+def _engine(params, cfg, **kw):
+    kw.setdefault("paged_kernel", "gather")
+    return ServingEngine(params, cfg, max_slots=SLOTS, kv_block_tokens=BT,
+                         kv_pool_blocks=40, **kw)
+
+
+@pytest.mark.parametrize("kernel", ["gather", "fused"])
+def test_engine_serves_the_references_greedy_tokens(ref, cfg, params,
+                                                    kernel):
+    """Five requests over three slots (so slots are re-used), prompts
+    chunked at 16 into uneven buckets, contexts crossing the window:
+    every greedy token is the reference's argmax at its position, and
+    every block and reservation is back when the engine drains."""
+    eng = _engine(params, cfg, prefill_chunk_tokens=16, paged_kernel=kernel)
+    rng = np.random.default_rng(1)
+    reqs = []
+    for n, k in ((27, 10), (5, 20), (33, 8), (18, 14), (9, 30)):
+        prompt = rng.integers(0, SHAPE["vocab"], n).astype(np.int32)
+        reqs.append((prompt, eng.submit(prompt, k)))
+    eng.run()
+    for prompt, h in reqs:
+        served = np.asarray(h.tokens, np.int32)
+        want = np.asarray(ref.logits(
+            params, np.concatenate([prompt, served]), SHAPE))
+        assert np.array_equal(want[len(prompt) - 1:-1].argmax(-1), served)
+    m = eng.metrics
+    assert m.state_slots_reset == 5 and m.window_blocks_released > 0
+    assert set(m.cache_bytes_in_use) == {"full", "window", "state"}
+    assert m.decode_trace_count() == 1
+    assert eng._alloc.blocks_in_use == 0 and eng._alloc.reserved == 0
+    assert eng._win.alloc.blocks_in_use == 0 and eng._win.alloc.reserved == 0
+
+
+def test_a_reused_slot_starts_from_zero_state(ref, cfg, params):
+    """One slot, two requests one after the other: the second's tokens
+    are the reference's, which they are not when the reset at
+    admission is taken out (the planted fault the benchmark's test
+    plants too)."""
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, SHAPE["vocab"], n).astype(np.int32)
+               for n in (20, 11)]
+
+    def serve(break_reset):
+        eng = ServingEngine(params, cfg, max_slots=1, kv_block_tokens=BT,
+                            kv_pool_blocks=40, paged_kernel="gather")
+        if break_reset:
+            eng._reset_slot_state = lambda s: None
+        out = []
+        for prompt in prompts:
+            h = eng.submit(prompt, 12)
+            eng.run()
+            out.append(np.asarray(h.tokens, np.int32))
+        return out
+
+    def first_tokens_gap(served):
+        want = np.asarray(ref.logits(
+            params, np.concatenate([prompts[1], served]), SHAPE))
+        rows = want[len(prompts[1]) - 1:-1]
+        return float((rows.max(-1) - rows[np.arange(12), served]).max())
+
+    assert first_tokens_gap(serve(False)[1]) == 0.0
+    assert first_tokens_gap(serve(True)[1]) > 0.0
+
+
+@pytest.mark.parametrize("option,value", [
+    ("prefix_cache_tokens", 64), ("kv_store", object()),
+    ("spec_draft_len", 4), ("decode_window", 4), ("async_dispatch", True),
+    ("kv_quant", "int8"), ("weight_quant", "int8"),
+    ("adapter_registry", object()), ("kv_fingerprints", True)])
+def test_each_unsupported_option_is_refused_by_name(cfg, params, option,
+                                                    value):
+    with pytest.raises(ValueError, match=option):
+        _engine(params, cfg, **{option: value})
+
+
+def test_handoff_import_is_refused_by_name(cfg, params):
+    eng = _engine(params, cfg)
+    with pytest.raises(ValueError, match="handoff"):
+        eng.submit(np.arange(5, dtype=np.int32), 4, handoff=[{"key": 1}])
+
+
+def test_gpt_engines_go_through_the_same_seam():
+    from paddle_tpu.models import transformer as tlm
+
+    cfg = tlm.TransformerConfig(vocab=64, dim=32, heads=2, layers=2,
+                                max_len=32)
+    assert cfg.serving is tlm.SERVING and not tlm.SERVING.refused
+    params = tlm.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, max_slots=2, kv_block_tokens=4)
+    assert eng._family is tlm.SERVING and eng._win is None
+    h = eng.submit(np.arange(5, dtype=np.int32), 4)
+    eng.run()
+    assert np.array_equal(
+        h.result(),
+        np.asarray(tlm.generate(params, jnp.arange(5)[None], cfg, 4))[0])
+
+
+@pytest.mark.parametrize("window,bt", [(12, 4), (512, 16), (10, 4), (7, 8),
+                                       (33, 16)])
+def test_window_tables_never_hold_more_than_the_bound(window, bt):
+    """Whatever the chunks and however long the decode, a slot holds at
+    most ceil(W / Bt) + 1 blocks a window layer, every position a later
+    query can attend is still held, and all of it comes back."""
+    rng = np.random.default_rng(window * 100 + bt)
+    total = 40 * bt
+    win = WindowBlockTables(2, -(-total // bt), bt, window)
+    bound = -(-window // bt) + 1
+    assert win.alloc.num_blocks == 2 * bound
+    for s in (0, 1):
+        assert win.admit(s, total)
+    pos = [0, 0]
+    while min(pos) < total:
+        s = int(rng.integers(0, 2))
+        if pos[s] >= total:
+            continue
+        step = int(rng.choice([1, 1, 1, bt, 3 * bt + 1, 2 * window]))
+        lo, hi = pos[s], min(total, pos[s] + step)
+        win.advance(s, lo, hi)
+        pos[s] = hi
+        assert win.held(s) <= bound
+        for p in range(max(0, hi - window), hi):
+            assert win.tables[s, p // bt] >= 0
+        assert (win.tables[s, :max(0, hi - window) // bt] < 0).all()
+    assert win.released_total > 0
+    for s in (0, 1):
+        win.free(s)
+    assert win.alloc.blocks_in_use == 0 and win.alloc.reserved == 0
+
+
+def _attention_oracle(q, kp, vp, tables, pos, first, bt, scale):
+    """The grouped decode attention in plain numpy, from the pool."""
+    S, hk, rep, D = q.shape
+    out = np.zeros((S, hk, rep, D), np.float32)
+    for s in range(S):
+        if pos[s] >= tables.shape[1] * bt:
+            continue
+        lo = 0 if first is None else first[s]
+        ps = np.arange(lo, pos[s] + 1)
+        rows = np.stack([kp[tables[s, p // bt]].reshape(bt, hk, D)[p % bt]
+                         for p in ps])  # [n, hk, D]
+        vals = np.stack([vp[tables[s, p // bt]].reshape(bt, hk, D)[p % bt]
+                         for p in ps])
+        sc = np.einsum("grd,ngd->grn", q[s], rows) * scale
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        pr /= pr.sum(-1, keepdims=True)
+        out[s] = np.einsum("grn,ngd->grd", pr, vals)
+    return out
+
+
+@pytest.mark.parametrize("windowed", [False, True])
+def test_grouped_decode_kernel_equals_its_oracle(windowed):
+    """4 queries a K/V head over the merged 3-D pool, one slot parked,
+    one context inside a single block; with `first`, a walk that
+    starts mid-table over entries already freed (-1) behind it."""
+    rng = np.random.default_rng(5)
+    S, hk, rep, D, bt, maxb, nb, W = 4, 2, 4, 16, 4, 12, 60, 10
+    kp = rng.normal(size=(nb, bt * hk, D)).astype(np.float32)
+    vp = rng.normal(size=(nb, bt * hk, D)).astype(np.float32)
+    q = rng.normal(size=(S, hk, rep, D)).astype(np.float32)
+    pos = np.array([37, 2, maxb * bt, 21], np.int32)
+    tables = rng.permutation(nb)[:S * maxb].reshape(S, maxb).astype(np.int32)
+    first = None
+    if windowed:
+        first = np.maximum(pos - W + 1, 0).astype(np.int32)
+        for s in range(S):
+            tables[s, :first[s] // bt] = -1
+    got = pa.paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(tables), jnp.asarray(pos), interpret=True,
+        first=None if first is None else jnp.asarray(first),
+        max_context=W if windowed else None, scale=0.25)
+    want = _attention_oracle(q, kp, vp, tables, pos, first, bt, 0.25)
+    live = pos < maxb * bt
+    assert np.abs(np.asarray(got)[live] - want[live]).max() < 1e-5
+
+
+def test_long_worklists_name_the_same_blocks_as_short_ones():
+    """Past 1,024 entries the work list re-names by looking one entry
+    back instead of at every entry: wherever a group names a block (the
+    only blocks a step reads), both rules give the table's."""
+    rng = np.random.default_rng(9)
+    S, maxb, bt, G = 40, 256, 4, 8  # 40 x 32 groups = 1,280 entries
+    tables = rng.integers(0, 5000, (S, maxb)).astype(np.int32)
+    pos = rng.integers(0, maxb * bt, S).astype(np.int32)
+    pos[3] = maxb * bt  # parked
+    blk, wslot, wgrp, n = pa._decode_worklist(
+        jnp.asarray(tables), jnp.asarray(pos), bt, G, maxb * bt)
+    blk, wslot, wgrp, n = (np.asarray(a) for a in (blk, wslot, wgrp, n))
+    live = pos < maxb * bt
+    assert n == np.where(live, pos // (G * bt) + 1, 1).sum()
+    seen = set()
+    for i in range(n):
+        s, b = wslot[i], wgrp[i]
+        seen.add((s, b))
+        for g in range(G):
+            depth = b * G + g
+            if live[s] and depth * bt <= pos[s]:
+                assert blk[g, i] == tables[s, depth]
+    assert seen == {(s, b) for s in range(S)
+                    for b in range(pos[s] // (G * bt) + 1 if live[s] else 1)}
+
+
+def test_state_update_kernel_equals_its_reference():
+    rng = np.random.default_rng(3)
+    S, N, di = 5, 16, 256
+    f = lambda *shp: jnp.asarray(rng.normal(size=shp).astype(np.float32))
+    state, du, b, c = f(S, N, di), f(S, di), f(S, N), f(S, N)
+    delta = jnp.abs(f(S, di)) * 0.1
+    a_t = -jnp.exp(f(N, di))
+    live = jnp.asarray([True, False, True, True, False])
+    want_s, want_y = ssm_state_update_reference(state, delta, du, a_t, b, c,
+                                                live)
+    got_s, got_y = ssm_state_update(state, delta, du, a_t, b, c, live,
+                                    interpret=True)
+    assert np.abs(np.asarray(got_s) - np.asarray(want_s)).max() < 1e-6
+    assert np.abs(np.asarray(got_y)[np.asarray(live)]
+                  - np.asarray(want_y)[np.asarray(live)]).max() < 1e-5
+    # a parked slot's state comes through bit for bit
+    assert np.array_equal(np.asarray(got_s)[1], np.asarray(state)[1])

@@ -342,3 +342,91 @@ def test_smem_bound_agrees_with_the_compiler(one_chip):
 
     assert accepted(56) and compiles(56)
     assert not accepted(64) and not compiles(64)
+
+
+# ---------------------------------------------------------------------
+# the hybrid family (ISSUE 27) at the geometry of its cell,
+# `phi4flash_reason_closed`: 64 slots, 8,192 positions, 14,336 blocks
+# of 32 tokens in the full layer's pool, published widths; depth cut
+# to 8 layers, which still holds every kind of layer
+# ---------------------------------------------------------------------
+
+HY_S, HY_L, HY_NB, HY_BT = 64, 8192, 14336, 32
+HY_MAXB = HY_L // HY_BT
+
+
+def _hybrid_engine(one_chip):
+    from paddle_tpu.models import sambay as sb
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = sb.SambaYConfig(vocab=200064, dim=2560, heads=40, kv_heads=20,
+                          layers=8, window=512, max_len=HY_L,
+                          dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: sb.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = ServingEngine(params, cfg, max_slots=HY_S, kv_pool_blocks=4,
+                        kv_block_tokens=HY_BT, prefill_chunk_tokens=4096)
+    assert eng.paged_kernel == "fused"
+    cache = jax.eval_shape(
+        lambda: sb.SERVING.init_cache(cfg, HY_NB, HY_BT, HY_S))
+    return eng, _placed(params, one_chip), _placed(cache, one_chip), \
+        _sds(one_chip)
+
+
+def _metric_pattern(name):
+    import json
+    import pathlib
+
+    spec = json.loads((pathlib.Path(__file__).resolve().parent.parent
+                       / "benchmarks" / "chip" / "layer_metrics"
+                       / (name + ".json")).read_text())
+    return re.compile(spec["args"]["op_match"])
+
+
+def test_hybrid_decode_step_compiles_and_is_what_the_benchmark_reads(
+        one_chip, as_on_tpu):
+    """The decode program of the hybrid cell compiles for the chip with
+    both new kernels in it, and the two roofline metrics' patterns
+    (`op_match` of `hybrid_attn_roofline.json` and
+    `ssm_decode_roofline.json`, matched against an instruction's text
+    as a device trace names its events) find exactly the kernels'
+    calls: 4 attention calls (2 window, 1 full, 1 cross at this depth)
+    and 3 state updates, each named after its kernel in
+    `kernel_metadata`. The files are read, never edited."""
+    eng, params, cache, sds = _hybrid_engine(one_chip)
+    bands = (sds((2, HY_S, HY_MAXB), jnp.int32), sds((HY_S,), jnp.int32),
+             sds((HY_S,), jnp.int32), sds((HY_S,), jnp.bool_),
+             sds((HY_S,), jnp.float32), sds((HY_S,), jnp.int32),
+             sds((HY_S, 2), jnp.uint32))
+    text = _compile(eng._decode_fn, params, cache, *bands)
+    lines = [ln.strip() for ln in text.split("\n")]
+    for metric, kernel, calls in (
+            ("hybrid_attn_roofline", "hybrid_decode_attention", 4),
+            ("ssm_decode_roofline", "ssm_state_update", 3)):
+        rx = _metric_pattern(metric)
+        found = [ln for ln in lines if rx.search(ln)]
+        assert len(found) == calls, (metric, len(found))
+        assert all(ln.startswith("%" + kernel) and " custom-call(" in ln
+                   for ln in found)
+        assert len(re.findall(r'kernel_metadata=\{\s*"kernel":"%s"\s*\}'
+                              % kernel, text)) >= calls
+    # besides them, the three K/V writes (2 window layers, the full one)
+    assert len([ln for ln in lines if ln.startswith("%paged_kv_write")
+                and " custom-call(" in ln]) == 3
+    assert text.count("tpu_custom_call") == 10
+
+
+def test_hybrid_prefill_chunk_compiles_at_the_largest_bucket(one_chip,
+                                                              as_on_tpu):
+    eng, params, cache, sds = _hybrid_engine(one_chip)
+    assert eng._bucket(4096 - 100) == 4096
+    lower = eng._chunk_fn(4096).lower(
+        params, cache, sds((4096,), jnp.int32), sds((), jnp.int32),
+        sds((4, HY_MAXB), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.float32), sds((2,), jnp.uint32))
+    with jax.default_matmul_precision(None):
+        mem = lower.compile().memory_analysis()
+    # the tiled attention and the stepwise scan keep the temporaries
+    # bounded: about 1 GB at 32 layers, the same here (they do not
+    # add up over layers)
+    assert mem.temp_size_in_bytes < 1.5e9
