@@ -282,12 +282,9 @@ def _drive_engine(params, cfg, requests, check_text, **engine_kw):
     if check_text:
         # the module of the decode step as compiled for this device,
         # from the engine's own jitted step and its live arguments
-        if eng._decode_fn is not None:
-            fn, extra = eng._decode_fn, ()
-        else:
-            fn = eng._window_fn
-            extra = (eng._band("limits"), eng._band("eos"))
-        facts["decode_has_kernel"] = "tpu_custom_call" in fn.lower(
+        extra = ((eng._band("limits"), eng._band("eos"))
+                 if eng._use_window else ())
+        facts["decode_has_kernel"] = "tpu_custom_call" in eng._decode_fn.lower(
             eng._params, eng._cache, eng._band("tables"),
             eng._band("tok"), eng._band("pos"), eng._band("alive"),
             eng._band("temps"), eng._band("counts"),

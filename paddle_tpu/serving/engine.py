@@ -123,6 +123,9 @@ _BANDS = ("tok", "pos", "alive", "temps", "counts", "base_keys",
 # break and re-upload. Everything else in _BANDS is host-truth only
 # (the device never writes it), so uploading those mid-flight is safe.
 _DEVICE_ADVANCED = frozenset(("tok", "pos", "alive", "counts"))
+# the same bands (band_lint reads the literal above), in the order the
+# decode program returns them and `_unpack` reads them
+_ADVANCED_ORDER = ("tok", "pos", "alive", "counts")
 
 
 class EngineFailed(RuntimeError):
@@ -298,6 +301,21 @@ class ServingEngine(object):
     (`cfg.serving.refused`; hand-off import at `submit`) raise a
     ValueError that names the option; nothing is silently ignored.
 
+    One step ahead (ISSUE 28): by default (`async_dispatch=None`) the
+    plain one-token decode keeps the chip a step ahead of the host —
+    step N+1 is dispatched off step N's device-resident outputs
+    before N's result is read, and N's result is ONE packed array,
+    read once, while N+1 runs; tokens leave the engine one `step()`
+    after the step that computed them, the same tokens in the same
+    order. Retirement the next dispatch depends on (EOS, budget) is
+    decided on the device; a host event that touches a
+    device-advanced band (admission, cancel, expiry) makes the engine
+    read first and upload host truth (`decode_dispatched_ahead` /
+    `decode_chain_breaks` in the metrics count both). Speculation and
+    a family whose seam refuses `async_dispatch` keep the lock-step
+    step, as an explicit `async_dispatch=False` does. `decode_window`
+    (K > 1) folds K iterations into the one compiled step (ISSUE 19).
+
     Serving integrity (ISSUE 15): `integrity_traps` (default True)
     folds a per-slot non-finite trap — logits + softmax-denominator
     reduction (`transformer.logits_trap`) — into the SAME compiled
@@ -329,7 +347,7 @@ class ServingEngine(object):
                  integrity_traps=True, kv_fingerprints=False,
                  integrity_spike_factor=None, kv_store=None,
                  kv_store_warm=False, decode_window=None,
-                 async_dispatch=False):
+                 async_dispatch=None):
         self._params = params
         self._cfg = cfg
         # the model family's seam (ISSUE 27): its cache, the bodies of
@@ -415,15 +433,24 @@ class ServingEngine(object):
         # megabatch decode window (ISSUE 19): K decode iterations
         # folded into the ONE compiled step (a lax.scan over the plain
         # decode body) so the host scheduler runs once per K tokens
-        # instead of once per token. K=1 without async dispatch keeps
-        # the exact pre-window step (bit-identical path, same trace).
-        # `async_dispatch` enqueues window N+1 off window N's device
-        # outputs BEFORE syncing N's tokens, hiding host work under
-        # device compute; emission then runs one window behind.
+        # instead of once per token.
         dw = 1 if decode_window is None else int(decode_window)
         if dw < 1:
             raise ValueError("decode_window must be >= 1 or None")
         self.decode_window = dw
+        # `async_dispatch` keeps the chip one decode step ahead of the
+        # host (ISSUE 28): step N+1 is enqueued off step N's device
+        # outputs BEFORE N's one packed result is read, so emit,
+        # retirement and block bookkeeping for N run under N+1's device
+        # time; emission then runs one step behind. None (the default)
+        # = ahead wherever the engine can: not under speculation (its
+        # acceptance is a host decision after every verify), not for a
+        # family whose seam refuses it (its window tables advance on
+        # the host every step). False = the lock-step step: a token
+        # leaves the engine in the step() that computed it.
+        if async_dispatch is None:
+            async_dispatch = (self.spec_draft_len is None
+                              and "async_dispatch" not in fam.refused)
         self.async_dispatch = bool(async_dispatch)
         if self.spec_draft_len is not None \
                 and (dw > 1 or self.async_dispatch):
@@ -638,16 +665,16 @@ class ServingEngine(object):
         self._deadlines = False               # guarded-by: scheduler
         self._donate = bool(donate)
         self._chunk_fns: Dict[int, Any] = {}
-        # exactly ONE decode trace per engine lifetime, whatever K: the
-        # window engine never builds (so never traces) the plain step,
-        # and vice versa — both carry the trace name "decode_step"
+        # exactly ONE decode program per engine lifetime, whatever K
+        # and whichever depth: the window engine (K > 1, or one step
+        # ahead) never builds the lock-step step, and vice versa —
+        # both are `_decode` to jax.jit and "decode_step" to the trace
+        # counter
         self._use_window = dw > 1 or self.async_dispatch
-        self._decode_fn = (None if self._use_window
+        self._decode_fn = (self._make_decode_window() if self._use_window
                            else self._make_decode())
-        self._window_fn = (self._make_decode_window()
-                           if self._use_window else None)
-        # the one in-flight dispatched-not-yet-synced window record
-        # (async dispatch); sync mode never leaves one pending
+        # the one in-flight dispatched-not-yet-read step (async
+        # dispatch); the lock-step modes never leave one pending
         self._inflight: Optional[dict] = None  # guarded-by: scheduler
         self._verify_fn = (
             self._make_verify() if self.spec_draft_len else None)
@@ -694,19 +721,21 @@ class ServingEngine(object):
     # ------------------------------------------------------------------
     # compiled steps
     # ------------------------------------------------------------------
-    def _make_decode(self):
-        cfg, metrics, fam = self._cfg, self.metrics, self._family
+    def _decode_body(self):
+        """One decode iteration, the body both decode programs share:
+        the paged scatter write + attention, the greedy/sampled next
+        token on the `fold_in(base_key, count)` schedule, and the
+        ISSUE 15 numeric traps (per-slot non-finite flag + max-|logit|
+        scalar, FOLDED into the same trace; off = constant zeros, no
+        reduction in the graph)."""
+        cfg, fam = self._cfg, self._family
         Lv = self.blocks_per_slot * self.kv_block_tokens
         kernel = self.paged_kernel  # baked into the one compiled step
         kv_quant = self.kv_quant    # ditto: storage dtype is traced in
-        deq = self._deq
         traps = self.integrity_traps  # baked in: trap reduction or not
 
-        def _decode(params, cache, tables, tok, pos, alive, temps,
-                    counts, base_keys, adapters=None, aidx=None):
-            metrics.count_trace("decode_step")  # trace-time side effect
-            if deq is not None:  # int8 weights upcast INSIDE the step
-                params = deq(params)
+        def body(params, cache, tables, tok, pos, alive, temps, counts,
+                 base_keys, adapters, aidx):
             # dead slots park their write past the table span: the
             # block lookup resolves them to the out-of-range sentinel
             # block and the scatter DROPS the row, so a retired slot
@@ -726,16 +755,29 @@ class ServingEngine(object):
                 )
             )(keys, logits, safe_t).astype(jnp.int32)
             nxt = jnp.where(temps > 0, sampled, greedy)
-            # ISSUE 15 in-step numeric traps: per-slot non-finite flag
-            # + max-|logit| scalar, FOLDED into this same trace (a few
-            # reductions — decode stays compiled exactly once). Off =
-            # constant zeros, no reduction in the graph.
             if traps:
                 trap = tlm.logits_trap(logits) & alive
                 scale = tlm.logit_amax(logits, alive)
             else:
                 trap = jnp.zeros_like(alive)
                 scale = jnp.float32(0.0)
+            return cache, nxt, trap, scale
+
+        return body
+
+    def _make_decode(self):
+        """The lock-step decode program: one token per live slot, read
+        in the step() that computed it (`_decode_once`)."""
+        metrics, deq, body = self.metrics, self._deq, self._decode_body()
+
+        def _decode(params, cache, tables, tok, pos, alive, temps,
+                    counts, base_keys, adapters=None, aidx=None):
+            metrics.count_trace("decode_step")  # trace-time side effect
+            if deq is not None:  # int8 weights upcast INSIDE the step
+                params = deq(params)
+            cache, nxt, trap, scale = body(
+                params, cache, tables, tok, pos, alive, temps, counts,
+                base_keys, adapters, aidx)
             # advance the device-resident bands in-step: the steady
             # decode loop re-uploads nothing (satellite: h2d dispatch
             # off the hot path). Dead rows advance by 0, matching the
@@ -747,78 +789,80 @@ class ServingEngine(object):
         return jax.jit(_decode, **kw)
 
     def _make_decode_window(self):
-        """ONE compiled K-token decode window (ISSUE 19): a lax.scan
-        over K iterations of exactly the plain decode body — paged
-        scatter write (PR 13 kernels, PR 14 quant commit-at-open rides
-        the same scatter), greedy/sampled next token on the SAME
-        `fold_in(base_key, count)` schedule (counts advance per live
-        iteration, so sampled outputs are window-invariant), then the
+        """The decode program of the engine that runs ahead, and of a
+        K-token window (ISSUE 19): K iterations of exactly the plain
+        decode body — at K = 1 the body itself, so the program stays
+        flat; at K > 1 a lax.scan over it — each followed by the
         device-side retirement rule (`tlm.decode_window_retire`): a
-        slot hitting EOS or budget mid-window emits that final token
-        and parks — its remaining scatter writes resolve to the
-        out-of-range sentinel block and its emitted lane carries -1
-        padding the host discards. PR 15 traps are accumulated PER
-        ITERATION ([K, S] stack), so a trip in iteration j poisons
-        only tokens >= j: the host checks row j before emitting row j.
-        Traced exactly once per engine lifetime under the same
-        "decode_step" trace name as the plain step it replaces."""
-        cfg, metrics, fam = self._cfg, self.metrics, self._family
-        K = self.decode_window
-        Lv = self.blocks_per_slot * self.kv_block_tokens
-        kernel = self.paged_kernel  # baked into the one compiled step
-        kv_quant = self.kv_quant
-        deq = self._deq
-        traps = self.integrity_traps
+        slot hitting EOS or budget emits that final token and parks —
+        its remaining scatter writes resolve to the out-of-range
+        sentinel block and its emitted lane carries -1 padding the
+        host discards. Sampling counts advance per live iteration, so
+        sampled outputs are window-invariant.
 
-        def _window(params, cache, tables, tok, pos, alive, temps,
+        Everything the host reads of the step comes back in ONE int32
+        array (ISSUE 28; `_unpack` is its inverse): per iteration the
+        S emitted tokens, the S trap flags and the magnitude scalar's
+        bits — PR 15 traps stay PER ITERATION, so a trip in iteration
+        j poisons only tokens >= j — then the four advanced bands, so
+        the host holds its mirrors to the device's without a transfer
+        of their own. Traced exactly once per engine lifetime under
+        the same "decode_step" trace name, and the same `_decode`
+        program name, as the lock-step step it replaces."""
+        metrics, deq, body = self.metrics, self._deq, self._decode_body()
+        K = self.decode_window
+
+        def _decode(params, cache, tables, tok, pos, alive, temps,
                     counts, base_keys, limits, eos, adapters=None,
                     aidx=None):
             metrics.count_trace("decode_step")  # trace-time side effect
             if deq is not None:  # int8 weights upcast ONCE per window
                 params = deq(params)
 
-            def _iter(carry, _):
+            def _iter(carry, _=None):
                 cache, tok, pos, alive, counts = carry
-                write_pos = jnp.where(alive, pos, jnp.int32(Lv))
-                logits, cache = fam.decode_step(
-                    params, tok, write_pos, tables, cache, cfg,
-                    adapters=adapters, adapter_idx=aidx, kernel=kernel,
-                    kv_quant=kv_quant,
-                )
-                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                keys = jax.vmap(jax.random.fold_in)(base_keys, counts)
-                safe_t = jnp.where(temps > 0, temps, 1.0)
-                sampled = jax.vmap(
-                    lambda k, l, t: jax.random.categorical(
-                        k, l.astype(jnp.float32) / t
-                    )
-                )(keys, logits, safe_t).astype(jnp.int32)
-                nxt = jnp.where(temps > 0, sampled, greedy)
-                if traps:
-                    trap = tlm.logits_trap(logits) & alive
-                    scale = tlm.logit_amax(logits, alive)
-                else:
-                    trap = jnp.zeros_like(alive)
-                    scale = jnp.float32(0.0)
+                cache, nxt, trap, scale = body(
+                    params, cache, tables, tok, pos, alive, temps,
+                    counts, base_keys, adapters, aidx)
                 # dead lanes emit -1 padding; a live lane emits its
                 # token even on its retirement iteration (EOS/budget
                 # tokens ARE emitted, exactly like the host _emit rule)
                 emitted = jnp.where(alive, nxt, jnp.int32(-1))
-                live = alive.astype(jnp.int32)
                 nalive, npos = tlm.decode_window_retire(
                     alive, nxt, pos, limits, eos)
                 ntok = jnp.where(alive, nxt, tok)
-                return ((cache, ntok, npos, nalive, counts + live),
-                        (emitted, trap, scale))
+                row = jnp.concatenate([
+                    emitted, trap.astype(jnp.int32),
+                    jax.lax.bitcast_convert_type(
+                        scale.astype(jnp.float32), jnp.int32)[None]])
+                return ((cache, ntok, npos, nalive,
+                         counts + alive.astype(jnp.int32)), row)
 
-            carry, stacks = jax.lax.scan(
-                _iter, (cache, tok, pos, alive, counts), None, length=K)
+            carry = (cache, tok, pos, alive, counts)
+            if K == 1:
+                carry, rows = _iter(carry)
+            else:
+                carry, rows = jax.lax.scan(_iter, carry, None, length=K)
             cache, tok, pos, alive, counts = carry
-            toks, trapw, scalew = stacks  # [K, S], [K, S], [K]
-            return cache, tok, pos, alive, counts, toks, trapw, scalew
+            packed = jnp.concatenate([
+                rows.reshape(-1), tok, pos, alive.astype(jnp.int32),
+                counts])
+            return cache, tok, pos, alive, counts, packed
 
         kw = {"donate_argnums": (1,)} if self._donate else {}
-        return jax.jit(_window, **kw)
+        return jax.jit(_decode, **kw)
+
+    def _unpack(self, packed):
+        """The host's view of a decode program's packed result ->
+        (tokens [K, S], trap flags [K, S], magnitudes [K], the bands
+        (tok, pos, alive, counts) as the step left them)."""
+        K, S = self.decode_window, self.max_slots
+        flat = np.asarray(packed)
+        rows = flat[:K * (2 * S + 1)].reshape(K, 2 * S + 1)
+        tok, pos, alive, counts = flat[K * (2 * S + 1):].reshape(4, S)
+        return (rows[:, :S], rows[:, S:2 * S].astype(bool),
+                np.ascontiguousarray(rows[:, 2 * S]).view(np.float32),
+                (tok, pos, alive.astype(bool), counts))
 
     def _make_verify(self):
         """ONE compiled speculative-verify step: writes every slot's
@@ -1013,8 +1057,11 @@ class ServingEngine(object):
         the fleet, the journal)."""
         trap = np.atleast_1d(np.asarray(trap))
         verdict = self._sentinel.observe(bool(trap.any()), float(scale))
-        if verdict == "ok":
-            return
+        if verdict != "ok":
+            self._trip_verdict(verdict, trap, scale, where, slots)
+
+    def _trip_verdict(self, verdict, trap, scale, where: str, slots=None):
+        """Raise the sentinel's verdict on host values already read."""
         if verdict == "trap":
             bad = (slots if slots is not None
                    else [int(s) for s in np.nonzero(trap)[0]])
@@ -2135,58 +2182,68 @@ class ServingEngine(object):
                     self._emit(s, nxt[s])
 
     # ------------------------------------------------------------------
-    # megabatch decode window (ISSUE 19)
+    # one step ahead of the host (ISSUE 28) / megabatch window (ISSUE 19)
     # ------------------------------------------------------------------
     def _can_chain(self) -> bool:
-        """Window N+1 may chain off window N's un-synced device
-        outputs only while the device-advanced bands still carry
-        device truth: any host event since dispatch (admission,
-        retirement, cancel, expiry) dirtied one of them and the chain
-        must break — sync first, re-upload host truth, then dispatch."""
+        """Step N+1 may chain off step N's un-read device outputs only
+        while the device-advanced bands still carry device truth: any
+        host event since dispatch (admission, cancel, expiry, a
+        host-side divergence) dirtied one of them and the chain must
+        break — read first, re-upload host truth, then dispatch."""
         return not (self._dirty & _DEVICE_ADVANCED)
 
     def _window_phase(self) -> bool:
-        """The window engine's decode phase: sync the pending window
-        (if any), keep the async pipeline one window deep, or run one
-        dispatch+sync in-line (sync mode). Returns False only when
-        there is genuinely nothing to do — no live slot AND no pending
-        window (a pending window may still hold the tokens that retire
-        the final requests, so it must sync even with zero host-live
+        """The decode phase of the engine that runs ahead (and of a
+        K-token window): read the pending step (if any), keep the
+        async pipeline one step deep, or run one dispatch+read in-line
+        (lock-step windows). Returns False only when there is
+        genuinely nothing to do — no live slot AND no pending step (a
+        pending step may still hold the tokens that retire the final
+        requests, so it must be read even with zero host-live
         slots)."""
         rec, self._inflight = self._inflight, None
         if rec is None and not self._alive.any():
             return False
-        with self.metrics.phase("engine.decode", row="decode_step"):
-            if rec is not None:
-                chained = None
-                if self.async_dispatch and self._alive.any() \
-                        and self._can_chain():
-                    # enqueue window N+1 off window N's device outputs
-                    # BEFORE syncing N: the emit/schedule work below
-                    # runs under N+1's device compute (the whole point)
-                    chained = self._dispatch_window(prev=rec)
-                self._sync_window(rec)
-                self._inflight = chained
-                if chained is None and self.async_dispatch \
-                        and self._alive.any():
-                    # chain broken by a host event: host truth is
-                    # current again post-sync — refill the pipeline
-                    # this step
-                    self._inflight = self._dispatch_window()
+        m = self.metrics
+        with m.phase("engine.decode", row="decode_step"):
+            if rec is None:
+                rec = self._dispatch_window()
+                if self.async_dispatch:
+                    # one-step-behind emission: read next step
+                    self._inflight = rec
+                else:
+                    self._sync_window(rec)
                 return True
-            w = self._dispatch_window()
-            if self.async_dispatch:
-                # one-step-behind emission: sync next step
-                self._inflight = w
-            else:
-                self._sync_window(w)
+            ahead = None
+            if self.async_dispatch and self._alive.any():
+                if self._can_chain():
+                    # enqueue step N+1 off step N's device outputs
+                    # BEFORE reading N: the emit/schedule work below
+                    # runs under N+1's device compute (the whole point)
+                    ahead = self._dispatch_window(prev=rec)
+                    m.decode_dispatched_ahead += 1
+                else:
+                    m.decode_chain_breaks += 1
+            self._sync_window(rec)
+            if not self._alive.any():
+                # N retired the last live slot: a step already
+                # dispatched off it holds no lane the host would emit
+                # (parked on the device too, where the mirrors agree)
+                # — nothing to read
+                ahead = None
+            elif ahead is None and self.async_dispatch:
+                # chain broken by a host event: host truth is current
+                # again now that N is read — refill the pipeline
+                ahead = self._dispatch_window()
+            self._inflight = ahead
         return True
 
     def _dispatch_window(self, prev=None):
-        """Enqueue one compiled K-token window. `prev` chains this
-        dispatch off the given un-synced window's output bands (host
-        mirrors are one window stale then — the block horizon covers
-        2K positions so the device never writes past the table)."""
+        """Enqueue one compiled decode step (K tokens a slot). `prev`
+        chains this dispatch off the given un-read step's output bands
+        (host mirrors are one step stale then — the block horizon
+        covers 2K positions so the device never writes past the
+        table). Its packed result starts for the host at once."""
         K = self.decode_window
         live = np.nonzero(self._alive)[0]
         horizon = 2 * K if prev is not None else K
@@ -2209,70 +2266,70 @@ class ServingEngine(object):
         tables_d, temps_d, keys_d, limits_d, eos_d, aidx_d = rest_d
         adapter = self._adapter_args(aidx_d)
         with m.phase("engine.dispatch") as disp:
-            out = self._window_fn(
+            self._cache, *bands, packed = self._decode_fn(
                 self._params, self._cache, tables_d, tok_d, pos_d,
                 alive_d, temps_d, counts_d, keys_d, limits_d, eos_d,
                 **adapter)
-        self._cache = out[0]
+        packed.copy_to_host_async()
         self._count_decode_step()
-        return {"bands": out[1:5], "toks": out[5], "traps": out[6],
-                "scales": out[7], "t0": disp.t0,
+        return {"bands": bands, "packed": packed, "t0": disp.t0,
                 "slots": [(int(s), self._slot_req[int(s)])
                           for s in live]}
 
     def _sync_window(self, rec):  # band-verb: sync
-        """Sync one dispatched window and emit its tokens in iteration
-        order. Lane discipline: -1 lanes are parking padding (the slot
-        retired in an earlier iteration) and are discarded; a slot
-        whose handle changed since dispatch (expired, cancelled,
-        re-tenanted) has its remaining lanes discarded too — an
-        expired request keeps its pre-window tokens and nothing more.
-        Integrity rows are judged BEFORE their tokens emit, so a trap
-        tripping in iteration j poisons only tokens >= j (ISSUE 19
-        tentpole rule); all-parked rows are skipped so the spike EWMA
-        never ingests masked zeros."""
+        """Read one dispatched step — ONE blocking read of its packed
+        result — and emit its tokens in iteration order. Lane
+        discipline: -1 lanes are parking padding (the slot retired in
+        an earlier iteration) and are discarded; a slot whose handle
+        changed since dispatch (expired, cancelled, re-tenanted) has
+        its remaining lanes discarded too — an expired request keeps
+        its pre-window tokens and nothing more. Integrity rows are
+        judged in iteration order BEFORE any of their tokens emit, so
+        a trap tripping in iteration j poisons only tokens >= j (ISSUE
+        19 tentpole rule); all-parked rows are skipped so the spike
+        EWMA never ingests masked zeros."""
         K = self.decode_window
         m = self.metrics
         with m.phase("engine.device_wait") as wait:
-            toks = np.asarray(rec["toks"])  # [K, S] — THE sync point
+            toks, traps, scales, bands = self._unpack(rec["packed"])
         m.observe_device_interval(rec["t0"], wait.t1)
+        clean, verdict = K, "ok"  # rows before `clean` may emit
         if self.integrity_traps:
-            # the window's K rows of trap flags and magnitudes, read
-            # once; each row is judged in the loop below, before its
-            # own tokens emit
             with m.phase("engine.integrity"):
-                traps_w = np.asarray(rec["traps"])
-                scales_w = np.asarray(rec["scales"])
+                for j in range(K):
+                    if (toks[j] >= 0).any():
+                        verdict = self._sentinel.observe(
+                            bool(traps[j].any()), float(scales[j]))
+                        if verdict != "ok":
+                            clean = j
+                            break
         with m.phase("engine.emit"):
-            for j in range(K):
+            for j in range(clean):
                 row = toks[j]
-                if self.integrity_traps and (row >= 0).any():
-                    self._check_integrity(traps_w[j], scales_w[j],
-                                          "decode window")
                 for s, h in rec["slots"]:
                     if self._slot_req[s] is not h or not self._alive[s]:
                         continue  # expired/cancelled/re-tenanted: discard
                     t = int(row[s])
                     if t < 0:
                         continue  # parked lane
-                    self._pos[s] += 1  # the token just synced sat at pos
+                    self._pos[s] += 1  # the token just read sat at pos
                     self._tok[s] = t
                     self._emit(s, t)
-        # adopt the window's outputs as device truth (steady loop
-        # re-uploads nothing) — but only when the host mirrors agree:
-        # a host-side divergence (fault drills shifting emitted
+            if verdict != "ok":
+                self._trip_verdict(verdict, traps[clean], scales[clean],
+                                   "decode window")
+        # adopt the step's outputs as device truth (steady loop
+        # re-uploads nothing) — but only when the host mirrors, advanced
+        # by the emit loop above, agree with the bands the packed result
+        # carries: a host-side divergence (fault drills shifting emitted
         # tokens' EOS judgment, a mid-flight expiry) re-uploads host
         # truth instead of silently trusting the device schedule
-        ntok, npos, nalive, ncounts = rec["bands"]
-        if (np.array_equal(self._pos, np.asarray(npos))
-                and np.array_equal(self._alive, np.asarray(nalive))
-                and np.array_equal(self._counts, np.asarray(ncounts))
-                and np.array_equal(self._tok, np.asarray(ntok))):
-            self._dev["tok"], self._dev["pos"] = ntok, npos
-            self._dev["alive"], self._dev["counts"] = nalive, ncounts
+        if all(np.array_equal(getattr(self, "_" + name), band)
+               for name, band in zip(_ADVANCED_ORDER, bands)):
+            self._dev.update(zip(_ADVANCED_ORDER, rec["bands"]))
             self._dirty.difference_update(_DEVICE_ADVANCED)
         else:
-            self._mark_dirty("tok", "pos", "alive", "counts")
+            self._mark_dirty(*_DEVICE_ADVANCED)
 
     def _draft_window(self, s: int) -> np.ndarray:
         """Self-drafting by prompt lookup: continue the context's last

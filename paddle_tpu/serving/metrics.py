@@ -169,6 +169,13 @@ class ServingMetrics(object):
         # bench's headline column.
         self.device_busy_s = 0.0
         self._busy_last_end = 0.0
+        # PR 28 — how often the engine keeps the chip one decode step
+        # ahead of the host (cumulative): steps dispatched BEFORE their
+        # predecessor was read, and steps where a host event (admission,
+        # cancel, expiry, a divergence) made the engine read first. Over
+        # `decode_steps`, the first is the share of steps run ahead.
+        self.decode_dispatched_ahead = 0
+        self.decode_chain_breaks = 0
         # PR 25 — the scheduler's own phases (`phase()` below):
         # seconds by phase name since the engine last cleared it (at
         # the top of every step()), the count of step() calls, and the
@@ -271,6 +278,8 @@ class ServingMetrics(object):
             "tokens_out": self.tokens_out,
             "tokens_per_sec": round(self.tokens_out / wall, 2) if wall else None,
             "decode_steps": self.decode_steps,
+            "decode_dispatched_ahead": self.decode_dispatched_ahead,
+            "decode_chain_breaks": self.decode_chain_breaks,
             "prefills": self.prefills,
             "mean_occupancy": _mean(self.occupancy),
             "decode_blocks_live": self.decode_blocks_live,

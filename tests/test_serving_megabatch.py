@@ -23,6 +23,13 @@ EWMA + device-busy union, fleet.py autoscaler headroom clamp):
   lane duplicated, none lost).
 * Gray-failure drill at K=8 (slow) — the per-token normalization in
   action: a slow@ replica in a K=8 fleet is demoted, and ONLY it.
+* One step ahead by default (ISSUE 28) — a default-constructed engine
+  (`async_dispatch=None`) rides the same sweeps as a case of its own:
+  token-identical to the lock-step engine and to generate() under
+  staggered arrivals, EOS, cancel and expiry, greedy and sampled; a
+  steady step makes ONE blocking device-to-host read; nearly every
+  step is dispatched before its predecessor is read; a trap at step N
+  with N+1 in flight emits nothing of either.
 """
 
 import json
@@ -73,6 +80,15 @@ def _full(h):
     return np.concatenate([h.prompt, np.asarray(h.tokens, np.int32)])
 
 
+def _plant_trap(eng, packed, j, s):
+    """The packed result of a dispatched step with slot s's trap flag
+    of iteration j forged on (layout: `ServingEngine._unpack`)."""
+    flat = np.asarray(packed).copy()
+    S = eng.max_slots
+    flat[j * (2 * S + 1) + S + s] = 1
+    return flat
+
+
 @pytest.fixture(scope="module")
 def model():
     return _mk(0)
@@ -95,13 +111,13 @@ def workload(model):
 # token-identity sweep: K x async x {greedy, sampled}
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("async_on", [False, True])
+@pytest.mark.parametrize("async_on", [False, True, None])
 @pytest.mark.parametrize("K", [1, 2, 4, 8])
 def test_greedy_identity_every_window(model, workload, K, async_on):
     """The ISSUE 19 acceptance bar: for every K (and with async
-    dispatch on top) the engine is bit-identical to sequential
-    generate() under staggered arrivals, and decode is compiled
-    exactly once."""
+    dispatch on top; None = what a default-constructed engine does,
+    ISSUE 28) the engine is bit-identical to sequential generate()
+    under staggered arrivals, and decode is compiled exactly once."""
     cfg, params = model
     prompts, budgets, oracle = workload
     eng = ServingEngine(params, cfg, max_slots=2, decode_window=K,
@@ -118,8 +134,9 @@ def test_greedy_identity_every_window(model, workload, K, async_on):
     assert eng.metrics.prefill_trace_count() <= 3
 
 
-@pytest.mark.parametrize("K", [2, 4, 8])
-def test_sampled_identity_window_vs_sequential(model, K):
+@pytest.mark.parametrize("K,async_on", [(2, True), (4, True), (8, True),
+                                        (1, None), (4, None)])
+def test_sampled_identity_window_vs_sequential(model, K, async_on):
     """Sampling must be window-invariant: the fold_in(key, count)
     schedule depends on each slot's emitted-token COUNT, not on how
     many iterations one compiled step covers — a K-window async
@@ -129,14 +146,15 @@ def test_sampled_identity_window_vs_sequential(model, K):
     reqs = [(rng.randint(0, cfg.vocab, (t,)).astype(np.int32), n, temp)
             for t, n, temp in ((5, 9, 0.8), (11, 7, 1.2), (4, 10, 0.8),
                                (8, 6, 0.0))]  # greedy rides along
-    base = ServingEngine(params, cfg, max_slots=2)
+    base = ServingEngine(params, cfg, max_slots=2, async_dispatch=False)
     want = []
     for i, (p, n, temp) in enumerate(reqs):
         h = base.submit(p, n, temperature=temp, seed=100 + i)
         h.result()  # drives the engine; returns prompt + tokens
         want.append(list(h.tokens))
     eng = ServingEngine(params, cfg, max_slots=2, decode_window=K,
-                        async_dispatch=True)
+                        async_dispatch=async_on)
+    assert eng.async_dispatch
     hs = [eng.submit(p, n, temperature=temp, seed=100 + i)
           for i, (p, n, temp) in enumerate(reqs)]
     eng.run()
@@ -145,29 +163,37 @@ def test_sampled_identity_window_vs_sequential(model, K):
     assert eng.metrics.decode_trace_count() == 1
 
 
-def test_eos_mid_window_identity(model):
-    """A slot hitting EOS at a window-interior iteration retires
-    in-loop (device-side rule) and parks its remaining lanes; output
-    equals the K=1 sync engine with the same eos_id, finish_reason
-    included."""
+@pytest.mark.parametrize("kw", [
+    {"decode_window": 4, "async_dispatch": False},
+    {"decode_window": 4, "async_dispatch": True}, {}],
+    ids=["K4_sync", "K4_async", "default"])
+def test_eos_mid_window_identity(model, kw):
+    """A slot hitting EOS at a window-interior iteration — or, for the
+    default engine, at a step whose successor is already in flight —
+    retires on the device (same rule) and parks its remaining lanes;
+    output equals the lock-step engine with the same eos_id,
+    finish_reason included."""
     cfg, params = model
     p = np.arange(2, 9, dtype=np.int32)
-    base = ServingEngine(params, cfg, max_slots=1)
+    base = ServingEngine(params, cfg, max_slots=1, async_dispatch=False)
     hf = base.submit(p, 12)
     hf.result()
     eos = int(hf.tokens[2])  # EOS lands at generated index 2: mid-window
-    hb = ServingEngine(params, cfg, max_slots=1) \
+    hb = ServingEngine(params, cfg, max_slots=1, async_dispatch=False) \
         .submit(p, 12, eos_id=eos)
     hb.result()
     want = list(hb.tokens)
     assert want[-1] == eos and len(want) < 12
-    for async_on in (False, True):
-        eng = ServingEngine(params, cfg, max_slots=1, decode_window=4,
-                            async_dispatch=async_on)
-        h = eng.submit(p, 12, eos_id=eos)
-        eng.run()
-        assert list(h.tokens) == want
-        assert h.finish_reason == "eos"
+    eng = ServingEngine(params, cfg, max_slots=1, **kw)
+    h = eng.submit(p, 12, eos_id=eos)
+    eng.run()
+    assert list(h.tokens) == want
+    assert h.finish_reason == "eos"
+    # the slot and its blocks are free again: nothing of the step
+    # that ran past the EOS leaked into the next tenant
+    h2 = eng.submit(p, 5)
+    eng.run()
+    assert list(h2.tokens) == list(hf.tokens[:5])
 
 
 def test_spec_decode_composition_refused(model):
@@ -322,9 +348,7 @@ def test_trap_mid_window_poisons_only_the_tail(model):
     n0 = len(h.tokens)
     s = next(i for i, hh in enumerate(eng._slot_req) if hh is h)
     rec = eng._dispatch_window()  # a REAL window off current state
-    traps = np.asarray(rec["traps"]).copy()
-    traps[2, s] = True
-    rec["traps"] = traps
+    rec["packed"] = _plant_trap(eng, rec["packed"], 2, s)
     with pytest.raises(IntegrityError) as ei:
         eng._sync_window(rec)
     assert ei.value.kind == "trap"
@@ -337,8 +361,8 @@ def test_trap_mid_window_poisons_only_the_tail(model):
 # per-token health gauges
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("async_on", [False, True])
-def test_expiry_at_window_boundary_keeps_pre_window_tokens(model,
+@pytest.mark.parametrize("K,async_on", [(4, False), (4, True), (1, None)])
+def test_expiry_at_window_boundary_keeps_pre_window_tokens(model, K,
                                                            async_on):
     """The documented enforcement granularity: a deadline dying
     mid-window expires the request at the NEXT window boundary — every
@@ -348,13 +372,13 @@ def test_expiry_at_window_boundary_keeps_pre_window_tokens(model,
     cfg, params = model
     p = np.arange(3, 10, dtype=np.int32)
     want = list(_oracle(params, cfg, p, 24)[len(p):])
-    eng = ServingEngine(params, cfg, max_slots=2, decode_window=4,
+    eng = ServingEngine(params, cfg, max_slots=2, decode_window=K,
                         async_dispatch=async_on)
     h = eng.submit(p, 24, deadline_at=time.monotonic() + 3600.0)
     while len(h.tokens) < 5:
         eng.step()
     n0 = len(h.tokens)
-    assert (n0 - 1) % 4 == 0  # prefill token + whole windows only
+    assert (n0 - 1) % K == 0  # prefill token + whole windows only
     h.deadline_at = time.monotonic() - 1.0  # dies mid-window
     eng.step()
     assert h.done and h.finish_reason == "expired"
@@ -364,6 +388,150 @@ def test_expiry_at_window_boundary_keeps_pre_window_tokens(model,
     h2 = eng.submit(p, 6)  # discarded lanes freed the slot cleanly
     eng.run()
     assert list(h2.tokens) == want[:6]
+
+
+@pytest.mark.parametrize("async_on", [None, False])
+def test_cancel_mid_decode_identity(model, async_on):
+    """A request cancelled between steps — for the default engine with
+    a step in flight that still decodes it — keeps a prefix of its
+    oracle tokens and nothing of the step in flight; its neighbour and
+    the slot's next tenant are oracle-identical."""
+    cfg, params = model
+    pa_, pb = np.arange(3, 10, dtype=np.int32), np.arange(5, 16,
+                                                          dtype=np.int32)
+    want_a = list(_oracle(params, cfg, pa_, 20)[len(pa_):])
+    want_b = list(_oracle(params, cfg, pb, 14)[len(pb):])
+    eng = ServingEngine(params, cfg, max_slots=2, async_dispatch=async_on)
+    ha, hb = eng.submit(pa_, 20), eng.submit(pb, 14)
+    while len(ha.tokens) < 6:
+        eng.step()
+    n0 = len(ha.tokens)
+    assert eng.cancel(ha.rid)
+    hc = eng.submit(pa_, 9)  # re-tenants the cancelled slot
+    eng.run()
+    assert ha.finish_reason == "cancelled"
+    assert list(ha.tokens) == want_a[:n0]
+    assert list(hb.tokens) == want_b
+    assert list(hc.tokens) == want_a[:9]
+    assert eng.metrics.decode_trace_count() == 1
+
+
+def _steady_engine(model, n_new=40):
+    """A default engine past its admissions: two slots decoding, no
+    host event to come for `n_new` steps."""
+    cfg, params = model
+    eng = ServingEngine(params, cfg, max_slots=2)
+    hs = [eng.submit(np.arange(2, 2 + t, dtype=np.int32), n_new)
+          for t in (5, 9)]
+    while min(len(h.tokens) for h in hs) < 3:
+        eng.step()
+    return eng, hs
+
+
+def test_steady_step_makes_one_blocking_read(model, monkeypatch):
+    """ISSUE 28's second half: everything the host needs of a step —
+    tokens, trap flags, magnitude, the advanced bands — comes back in
+    ONE array. Every device-to-host read the engine makes goes through
+    `np.asarray`; counted here by the phase open around it, a steady
+    step makes exactly one, under `engine.device_wait` —
+    `engine.integrity` judges host values and reads nothing."""
+    from paddle_tpu.serving import engine as engine_mod
+
+    eng, hs = _steady_engine(model)
+    open_phases, reads = [], []
+    real_phase = eng.metrics.phase
+
+    class _Tracked(object):
+        def __init__(self, name, ph):
+            self.name, self.ph = name, ph
+
+        def __enter__(self):
+            open_phases.append(self.name)
+            return self.ph.__enter__()
+
+        def __exit__(self, *exc):
+            open_phases.pop()
+            return self.ph.__exit__(*exc)
+
+    class _Numpy(object):
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(x, *a, **kw):
+            if isinstance(x, jax.Array):
+                reads.append(open_phases[-1] if open_phases else None)
+            return np.asarray(x, *a, **kw)
+
+    monkeypatch.setattr(
+        eng.metrics, "phase",
+        lambda name, row=None, **kw: _Tracked(name,
+                                              real_phase(name, row, **kw)))
+    monkeypatch.setattr(engine_mod, "np", _Numpy())
+    rows = eng.metrics.ops.rows
+    waits0, integ0 = rows["engine.device_wait"][0], \
+        rows["engine.integrity"][0]
+    n = 12
+    for _ in range(n):
+        before = len(reads)
+        eng.step()
+        assert reads[before:] == ["engine.device_wait"]
+    assert rows["engine.device_wait"][0] - waits0 == n
+    assert rows["engine.integrity"][0] - integ0 == n  # judged, not read
+    monkeypatch.undo()
+    eng.run()
+    cfg, params = model
+    for h in hs:
+        np.testing.assert_array_equal(
+            _full(h), _oracle(params, cfg, h.prompt, 40))
+
+
+def test_steady_run_is_dispatched_ahead(model):
+    """With no host event after the admissions, every decode step but
+    the pipeline's first is dispatched before its predecessor is read:
+    the counters say so, in `report()` too."""
+    eng, hs = _steady_engine(model)
+    m = eng.metrics
+    steps0, ahead0, breaks0 = (m.decode_steps, m.decode_dispatched_ahead,
+                               m.decode_chain_breaks)
+    for _ in range(20):
+        eng.step()
+    assert m.decode_steps - steps0 == 20
+    assert m.decode_dispatched_ahead - ahead0 == 20
+    assert m.decode_chain_breaks == breaks0
+    eng.run()
+    rep = m.report()
+    assert rep["decode_dispatched_ahead"] == m.decode_dispatched_ahead
+    assert rep["decode_chain_breaks"] == m.decode_chain_breaks
+    # admissions and the last step included, the share stays high
+    assert m.decode_dispatched_ahead / m.decode_steps >= 0.8
+    assert m.decode_trace_count() == 1
+
+
+def test_trap_with_next_step_in_flight_emits_neither(model):
+    """The integrity rule one step ahead: a step's trap flags are
+    judged before any of its tokens reach a handle. A trap planted in
+    step N, read while N+1 is already in flight, emits no token of N
+    or N+1 and latches the engine."""
+    from paddle_tpu.serving import EngineFailed
+
+    eng, hs = _steady_engine(model)
+    n0 = [len(h.tokens) for h in hs]
+    steps0 = eng.metrics.decode_steps
+    rec = eng._inflight  # step N, dispatched and not yet read
+    s = rec["slots"][0][0]
+    rec["packed"] = _plant_trap(eng, rec["packed"], 0, s)
+    with pytest.raises(IntegrityError) as ei:
+        eng.step()
+    assert ei.value.kind == "trap"
+    assert eng.metrics.decode_steps == steps0 + 1  # N+1 was in flight
+    assert [len(h.tokens) for h in hs] == n0
+    assert eng._inflight is None  # N+1 is never read
+    with pytest.raises(EngineFailed):
+        eng.step()
+    for h in hs:
+        assert isinstance(h.error, EngineFailed)
+    assert [len(h.tokens) for h in hs] == n0
 
 
 def test_step_ewma_normalized_per_token():

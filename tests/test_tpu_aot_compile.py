@@ -196,13 +196,7 @@ def test_decode_kernel_at_the_cells_geometry_is_what_the_benchmark_reads(
     hold the compiled text to that pattern, read from the file — a
     kernel change that moves the result's shape fails here, not as
     `output_malformed` on the chip. The file is read, never edited."""
-    import json
-    import pathlib
-
-    spec = json.loads((pathlib.Path(__file__).resolve().parent.parent
-                       / "benchmarks" / "chip" / "layer_metrics"
-                       / "paged_attn_roofline.json").read_text())
-    rx = re.compile(spec["args"]["op_match"])
+    rx = _metric_pattern("paged_attn_roofline")
     sds = _sds(one_chip)
     slots, blocks = 32, 3500
     pool = sds((blocks, BT, H, DH), jnp.bfloat16)
@@ -247,19 +241,48 @@ def _engine(one_chip, **kw):
             bands, sds)
 
 
-@pytest.mark.parametrize("window", [1, 8])
-def test_engine_decode_step_compiles(one_chip, as_on_tpu, window):
-    kw = {} if window == 1 else {"decode_window": window,
-                                 "async_dispatch": True}
+def _decode_text(eng, params, cache, bands, sds):
+    """The engine's one decode program, compiled for the chip."""
+    extra = ((sds((S,), jnp.int32), sds((S,), jnp.int32))
+             if eng._use_window else ())  # limits, eos
+    return _compile(eng._decode_fn, params, cache, *bands, *extra)
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"async_dispatch": False},
+    {"decode_window": 8, "async_dispatch": True}],
+    ids=["default", "lockstep", "window8"])
+def test_engine_decode_step_compiles(one_chip, as_on_tpu, kw):
     eng, params, cache, bands, sds = _engine(one_chip, **kw)
-    if window == 1:
-        fn, extra = eng._decode_fn, ()
-    else:
-        fn = eng._window_fn
-        extra = (sds((S,), jnp.int32), sds((S,), jnp.int32))
     # one kernel call per layer
-    assert _compile(fn, params, cache, *bands,
-                    *extra).count("tpu_custom_call") >= 2
+    assert _decode_text(eng, params, cache, bands,
+                        sds).count("tpu_custom_call") >= 2
+
+
+def test_default_decode_program_is_what_the_benchmark_reads(one_chip,
+                                                            as_on_tpu):
+    """The yardstick of the serving cells (ISSUE 28): a default engine
+    runs one step ahead, and its decode program is still the one
+    `decode_step_ms` and `paged_attn_roofline` find — named so that
+    their `program_match` (read from
+    benchmarks/chip/layer_metrics/decode_step_ms.json, never edited)
+    matches it as a device trace names it, `jit_<function>(<id>)`, and
+    FLAT: at K = 1 the step body is called directly, so the kernel's
+    custom call sits in the entry computation, once a layer, where
+    `op_match` finds it — not inside a one-trip loop's body."""
+    eng, params, cache, bands, sds = _engine(one_chip)
+    assert eng.async_dispatch and eng.decode_window == 1
+    text = _decode_text(eng, params, cache, bands, sds)
+    module = re.match(r"HloModule (\S+?),", text).group(1)
+    program = _metric_spec("decode_step_ms")["args"]["program_match"]
+    assert _metric_spec("paged_attn_roofline")["args"][
+        "program_match"] == program
+    assert re.search(program, module + "(1)")
+    assert " while(" not in text
+    entry = text[text.index("\nENTRY "):]
+    found = _metric_pattern("paged_attn_roofline").findall(entry)
+    assert len(found) == 2  # the two layers' calls
+    assert eng.metrics.decode_trace_count() == 1
 
 
 def test_engine_prefill_step_compiles_at_the_largest_bucket(one_chip,
@@ -373,14 +396,17 @@ def _hybrid_engine(one_chip):
         _sds(one_chip)
 
 
-def _metric_pattern(name):
+def _metric_spec(name):
     import json
     import pathlib
 
-    spec = json.loads((pathlib.Path(__file__).resolve().parent.parent
+    return json.loads((pathlib.Path(__file__).resolve().parent.parent
                        / "benchmarks" / "chip" / "layer_metrics"
                        / (name + ".json")).read_text())
-    return re.compile(spec["args"]["op_match"])
+
+
+def _metric_pattern(name):
+    return re.compile(_metric_spec(name)["args"]["op_match"])
 
 
 def test_hybrid_decode_step_compiles_and_is_what_the_benchmark_reads(
