@@ -1074,154 +1074,6 @@ def bench_serving_decode(max_slots=None, n_requests=None):
     }
 
 
-def bench_serving_megabatch(max_slots=None, n_requests=None,
-                            windows=(1, 4, 8)):
-    """Megabatch decode window (ISSUE 19): ONE fixed-seed Poisson trace
-    replayed across (decode_window=K, async_dispatch) variants — K in
-    {1, 4, 8} each sync and async. Headline column is the
-    host-overhead fraction (wall minus device-step time, over wall):
-    folding K decode iterations into the one compiled step amortizes
-    the per-token host round-trip K ways, and async dispatch hides
-    the remaining scheduler work under device compute. Also reported:
-    steps/token (the amortization itself) and band-upload counts (the
-    steady window loop must re-upload nothing, like K=1). Two hard
-    raises keep the row honest: (a) any output divergence across
-    variants (greedy AND sampled requests ride the same trace — the
-    window must be token-identical to the sequential path), (b)
-    host-overhead(K=8, async) >= host-overhead(K=1, sync) — the whole
-    point of the window, measured, on every backend. Compiles are
-    paid by an unmeasured warm-up request per variant, so the
-    overhead columns compare steady-state loops, not trace time;
-    decode must trace exactly ONCE per variant regardless of K."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.models import transformer as tlm
-    from paddle_tpu.serving import ServingEngine
-
-    cpu = jax.default_backend() == "cpu"
-    if cpu:
-        # smoke shape: deliberately TINY model so the per-step host
-        # scheduler cost is a visible fraction of wall (a fat model
-        # would bury the contrast under CPU matmul time; on-chip the
-        # real shape below has the same property for free)
-        dim, heads, layers_n, vocab, max_len = 64, 4, 2, 128, 128
-        max_slots = max_slots or 4
-        n_requests = n_requests or 12
-        p_lo, p_hi, budget, rate = 4, 16, 32, 4.0
-        dtype = jnp.float32
-    else:
-        dim, heads, layers_n, vocab, max_len = 512, 8, 8, 32000, 1024
-        max_slots = max_slots or 16
-        n_requests = n_requests or 64
-        p_lo, p_hi, budget, rate = 64, 512, 128, 1.0
-        dtype = jnp.bfloat16
-
-    cfg = tlm.TransformerConfig(vocab=vocab, dim=dim, heads=heads,
-                                layers=layers_n, max_len=max_len,
-                                dtype=dtype)
-    params = tlm.init_params(cfg, jax.random.PRNGKey(0))
-    rng = np.random.RandomState(0)
-    arrive_at = np.floor(
-        np.cumsum(rng.exponential(1.0 / rate, n_requests))
-    ).astype(int)
-    # mixed trace: even requests greedy, odd requests sampled (the
-    # fold_in(count) schedule must make sampling window-invariant
-    # too). Budgets are FIXED at a multiple of every K so no variant
-    # pays window-quantization waste (a request retiring mid-window
-    # parks the remainder — real, but a different effect than the
-    # host-overhead amortization this row isolates; the identity
-    # tests cover mid-window retirement).
-    reqs = [
-        (
-            rng.randint(0, vocab,
-                        rng.randint(p_lo, p_hi + 1)).astype(np.int32),
-            budget,
-            0.0 if j % 2 == 0 else 0.8,
-        )
-        for j in range(n_requests)
-    ]
-
-    def drive(K, async_on):
-        eng = ServingEngine(params, cfg, max_slots=max_slots,
-                            decode_window=K, async_dispatch=async_on)
-        # warm-up request: pays the decode trace + one prefill bucket
-        # outside the measured trace (counters are deltas below)
-        eng.submit(np.arange(1, 9, dtype=np.int32), 4)
-        eng.run()
-        busy0 = eng.metrics.device_busy_s
-        up0 = eng.metrics.band_uploads
-        st0 = eng.metrics.decode_steps
-        tk0 = eng.metrics.tokens_out
-        handles = []
-        t0 = time.time()
-        i = step = 0
-        while i < n_requests or eng.live_slots or eng.queue_depth \
-                or eng.prefilling_slots:
-            while i < n_requests and arrive_at[i] <= step:
-                p, n, temp = reqs[i]
-                handles.append(
-                    eng.submit(p, n, temperature=temp, seed=1000 + i))
-                i += 1
-            if not eng.step() and i < n_requests:
-                step = max(step + 1, int(arrive_at[i]))  # idle gap: jump
-                continue
-            step += 1
-        wall = time.time() - t0
-        if eng.metrics.decode_trace_count() != 1:
-            raise RuntimeError(
-                "serving_megabatch: decode traced %d times at K=%d "
-                "async=%s (must be exactly once per engine lifetime)"
-                % (eng.metrics.decode_trace_count(), K, async_on))
-        busy = eng.metrics.device_busy_s - busy0
-        toks = eng.metrics.tokens_out - tk0
-        steps = eng.metrics.decode_steps - st0
-        outs = tuple(tuple(h.tokens) for h in handles)
-        return outs, {
-            "host_overhead_frac": round(
-                max(0.0, wall - busy) / wall, 4) if wall else None,
-            "steps_per_token": round(steps / max(1, toks), 4),
-            "band_uploads": eng.metrics.band_uploads - up0,
-            "decode_steps": steps,
-            "tokens_out": toks,
-            "wall_s": round(wall, 4),
-        }
-
-    variants = {}
-    base = None
-    for K in windows:
-        for async_on in (False, True):
-            outs, row = drive(K, async_on)
-            if base is None:
-                base = outs
-            elif outs != base:
-                raise RuntimeError(
-                    "serving_megabatch: output divergence at K=%d "
-                    "async=%s vs the K=%d sync baseline — the decode "
-                    "window is not token-identical"
-                    % (K, async_on, windows[0]))
-            variants["K%d_%s" % (K, "async" if async_on else "sync")] \
-                = row
-    lo = variants["K%d_async" % windows[-1]]["host_overhead_frac"]
-    hi = variants["K%d_sync" % windows[0]]["host_overhead_frac"]
-    if lo >= hi:
-        raise RuntimeError(
-            "serving_megabatch: host-overhead(K=%d, async)=%.4f is not "
-            "below host-overhead(K=%d, sync)=%.4f — the window buys "
-            "nothing" % (windows[-1], lo, windows[0], hi))
-    return {
-        "variants": variants,
-        "host_overhead_K%d_async" % windows[-1]: lo,
-        "host_overhead_K%d_sync" % windows[0]: hi,
-        "outputs_identical": True,
-        "n_requests": n_requests,
-        "max_slots": max_slots,
-        "arrival": "poisson(rate=%g/step, seed=0)" % rate,
-        "model": {"dim": dim, "heads": heads, "layers": layers_n,
-                  "vocab": vocab, "max_len": max_len},
-    }
-
-
 def bench_serving_shared_prefix(n_requests=None, families=None,
                                 header_len=None, family_len=None,
                                 max_slots=None, dim=None, heads=None,
@@ -2909,6 +2761,45 @@ def bench_serving_multitenant(n_requests=None, max_slots=None, dim=None,
     }
 
 
+def garble_behind_a_canary():
+    """A FaultInjector for replica 1 of a canary fleet whose garble@
+    begins at a step the DRILL chooses, so that the outcome hangs on
+    no thread's timing. A known-answer canary can only vouch for what
+    is still open when it is judged: a request that completes on the
+    garbled replica before the next canary's verdict is delivered
+    garbled. `garble@N` from an arbitrary step raced exactly that
+    (short requests against the canary period). Set `.fleet`, then
+    `.wanted = True`: the fault begins at a step boundary of the
+    replica's own thread (`tick()`) at which its engine holds a canary
+    already decoding that still owes a token (the mismatch is certain)
+    and a real request decoding too (there IS tainted progress), while
+    every real request owes at least two tokens more than the canary
+    — a step emits one token a slot, so the canary's verdict rides an
+    earlier handshake than any real completion."""
+    from paddle_tpu.distributed.fault_injection import FaultInjector
+
+    class _Injector(FaultInjector):
+        fleet, wanted, active = None, False, True  # tick at every step
+
+        def tick(self):
+            step = FaultInjector.tick(self)
+            if self.wanted and not self._garbled:
+                # the replica thread's own table (fleet rid -> engine
+                # handle; canary rids are negative), read on that thread
+                sv = self.fleet._replicas[1]._serving
+                owed = lambda sh: sh.max_new_tokens - len(sh.tokens)
+                canary = [sh for rid, sh in sv.items()
+                          if rid < 0 and sh.tokens and not sh.done]
+                real = [sh for rid, sh in sv.items() if rid >= 0]
+                self._garbled = (
+                    len(canary) == 1 and any(sh.tokens for sh in real)
+                    and all(owed(sh) >= owed(canary[0]) + 2
+                            for sh in real))
+            return step
+
+    return _Injector("")
+
+
 def bench_serving_integrity(n_requests=None, max_slots=None, dim=None,
                             heads=None, layers_n=None, vocab=None,
                             max_len=None, canary_interval_s=None):
@@ -2992,7 +2883,8 @@ def bench_serving_integrity(n_requests=None, max_slots=None, dim=None,
         # quarantine's fresh incarnation composes its engine kwargs
         # again and must come up CLEAN (a sticky garble re-armed on
         # the replacement would just trip it again, forever)
-        inj = FaultInjector("")
+        garble = fault is not None and fault.startswith("garble")
+        inj = garble_behind_a_canary() if garble else FaultInjector("")
         armed = {"used": False}
 
         def kw_for(i):
@@ -3033,7 +2925,9 @@ def bench_serving_integrity(n_requests=None, max_slots=None, dim=None,
                         "no clean canary within 60s of a warm fleet: "
                         "the canary machinery is broken")
                 time.sleep(0.01)
-            if fault is not None:
+            if garble:
+                inj.fleet, inj.wanted = fleet, True
+            elif fault is not None:
                 inj.arm(fault)  # fires on replica 1's next steps
             t0 = time.time()
             hs, i, step = [], 0, 0
@@ -3045,6 +2939,16 @@ def bench_serving_integrity(n_requests=None, max_slots=None, dim=None,
                     break
                 time.sleep(0.004)
                 step += 1
+            # the garble begins only once a canary finds replica 1
+            # mid-decode: repeat the trace's requests until it has
+            deadline = time.monotonic() + 120.0
+            while garble and not inj.garbled:
+                if time.monotonic() >= deadline:
+                    raise RuntimeError("no canary met a decoding "
+                                       "request on replica 1 in 120s")
+                hs.append(fleet.submit(*reqs[len(hs) % n_requests]))
+                while not (inj.garbled or hs[-1].done):
+                    time.sleep(0.004)
             outs = [list(h.result(timeout=600)) for h in hs]
             wall = time.time() - t0
             if fault is not None:
@@ -3091,7 +2995,8 @@ def bench_serving_integrity(n_requests=None, max_slots=None, dim=None,
             ("flip", "flip@2", "fingerprint")):
         rec = run_once(fault)
         dst = rec["stats"]
-        if rec["outputs"] != clean["outputs"]:
+        if rec["outputs"] != [clean["outputs"][k % n_requests]
+                              for k in range(len(rec["outputs"]))]:
             raise RuntimeError(
                 "%s drill outputs diverge from the clean run: a "
                 "corrupt token survived quarantine + taint-aware "
@@ -4477,12 +4382,6 @@ def main():
         # cancelled-terminal DFA audit are deterministic offline; every
         # timing is host wall-clock (CPU-honest shape, PERF.md)
         run("serving_frontdoor", bench_serving_frontdoor)
-        # megabatch decode window (ISSUE 19): K-token compiled window +
-        # async dispatch vs the K=1 sync baseline on one fixed-seed
-        # Poisson trace — host-overhead fraction, steps/token, and
-        # band uploads are deterministic offline; output identity and
-        # the overhead drop hard-raise in-bench
-        run("serving_megabatch", bench_serving_megabatch)
         run("transformer_lm", bench_transformer_lm)
         # larger-matmul flagship: dim=1024 keeps every matmul MXU-shaped
         # (the dim=512 row leaves lane headroom), so this is the MFU

@@ -282,13 +282,12 @@ def _drive_engine(params, cfg, requests, check_text, **engine_kw):
     if check_text:
         # the module of the decode step as compiled for this device,
         # from the engine's own jitted step and its live arguments
-        extra = ((eng._band("limits"), eng._band("eos"))
-                 if eng._use_window else ())
         facts["decode_has_kernel"] = "tpu_custom_call" in eng._decode_fn.lower(
             eng._params, eng._cache, eng._band("tables"),
             eng._band("tok"), eng._band("pos"), eng._band("alive"),
             eng._band("temps"), eng._band("counts"),
-            eng._band("base_keys"), *extra).compile().as_text()
+            eng._band("base_keys"), eng._band("limits"),
+            eng._band("eos")).compile().as_text()
     return {"tokens": [list(h.tokens) for h in hs],
             "reasons": [h.finish_reason for h in hs], "facts": facts}
 
@@ -448,7 +447,7 @@ def phase_serve(args, devices):
           "default engine's first token %d is not the reference's "
           "argmax within tolerance" % first)
 
-    # --- the paths that had never compiled: int8 KV, window + async --
+    # --- the path that had never compiled: int8 KV --------------------
     short_i = (0, probe_i, len(greedy) - 1)
     short = [greedy[i] for i in short_i]
     short_default = [res["tokens"][i] for i in short_i]
@@ -468,19 +467,6 @@ def phase_serve(args, devices):
     check(ref_pre[first] >= ref_pre.max() - 4 * tol,
           "int8-KV engine's first token %d is not the reference's "
           "argmax within tolerance" % first)
-
-    res_w = _drive_engine(params, cfg, short, on_chip, decode_window=8,
-                          async_dispatch=True, **kernel_kw, **pool)
-    emit(phase="serve", step="engine_window8_async", **res_w["facts"],
-         identical_to_default_engine=res_w["tokens"] == short_default)
-    _check_served("window", res_w, short, cfg)
-    # the window folds the SAME step: tokens are identical, not close
-    check(res_w["tokens"] == short_default,
-          "K=8 async window tokens differ from the K=1 engine")
-    steps_w = res_w["facts"]["decode_steps"]
-    check(steps_w < new - 1,
-          "K=8 windows took %d scheduler steps for %d decode tokens"
-          % (steps_w, new - 1))
 
     # --- how a user reaches it: fleet + front door + wire client -----
     from paddle_tpu.analysis.protocol_lint import verify_journal

@@ -682,7 +682,7 @@ class _Serving(object):
     name = "sambay"
     hybrid = True
     refused = ("prefix_cache_tokens", "kv_store", "spec_draft_len",
-               "decode_window", "async_dispatch", "kv_quant", "weight_quant",
+               "async_dispatch", "kv_quant", "weight_quant",
                "adapter_registry", "kv_fingerprints")
     cache_bytes = staticmethod(cache_bytes)
     reset_slot_state = staticmethod(reset_slot_state)

@@ -1019,15 +1019,16 @@ def logit_amax(logits, mask=None):
     return jnp.max(a)
 
 
-def decode_window_retire(alive, nxt, pos, limits, eos_ids):
-    """In-window retirement mask for the megabatch decode scan (ISSUE
-    19) — the branch-free device mirror of the host scheduler's
-    `_emit` rule, applied per scan iteration so a K-token window
-    retires slots exactly where the sequential host loop would:
+def decode_retire(alive, nxt, pos, limits, eos_ids):
+    """Device-side retirement for the serving engine's decode step
+    (ISSUE 19) — the branch-free device mirror of the host scheduler's
+    `_emit` rule, applied after the step's token is chosen so a step
+    chained off this one's outputs retires slots exactly where the
+    host loop would:
 
-      * a slot that samples its EOS token this iteration emits that
-        token and goes dead for the REST of the window (EOS itself is
-        kept — same as the host, which appends then retires);
+      * a slot that samples its EOS token this step emits that token
+        and goes dead (EOS itself is kept — same as the host, which
+        appends then retires);
       * a slot whose advanced position reaches ``limits - 1`` (i.e. it
         has now emitted ``max_new_tokens`` tokens, the host's
         ``len(tokens) >= max_new_tokens`` budget rule at the decode
@@ -1035,13 +1036,13 @@ def decode_window_retire(alive, nxt, pos, limits, eos_ids):
         token and parks;
       * dead slots do not advance — their position is frozen so the
         caller's ``where(alive, pos, out_of_range)`` parking keeps all
-        of their remaining scatter writes out of range, and their
-        emitted lane carries the ``-1`` padding the host discards.
+        of their later scatter writes out of range, and their emitted
+        lane carries the ``-1`` padding the host discards.
 
     ``eos_ids`` is a per-slot int32 band with ``-1`` meaning "no EOS
     configured" (the ``>= 0`` guard below), so a vocab-less sentinel
-    never matches a real token. Pure element-wise jnp — safe inside
-    any traced scan body, no data-dependent Python branching."""
+    never matches a real token. Pure element-wise jnp, no
+    data-dependent Python branching."""
     live = alive.astype(jnp.int32)
     npos = pos + live
     hit_eos = (eos_ids >= 0) & (nxt == eos_ids)
@@ -1079,7 +1080,7 @@ __all__ += ["init_paged_kv_cache", "paged_decode_step",
             "paged_prefill_chunk", "paged_verify_step",
             "kv_storage_dtype", "kv_block_bytes",
             "logits_trap", "logit_amax", "paged_block_fingerprint",
-            "decode_window_retire"]
+            "decode_retire"]
 
 
 def generate(params, prompt, cfg: TransformerConfig, max_new_tokens,

@@ -116,8 +116,8 @@ __all__ = ["ServingEngine", "ServingHandle", "EngineFailed",
 _BANDS = ("tok", "pos", "alive", "temps", "counts", "base_keys",
           "tables", "limits", "aidx", "eos")
 
-# bands the compiled decode window ADVANCES on device (ISSUE 19): a
-# host-side event that dirties any of these between dispatch and sync
+# bands the compiled decode step ADVANCES on device (ISSUE 19): a
+# host-side event that dirties any of these between dispatch and read
 # (admission, retirement, cancel, expiry, spec acceptance) means the
 # device copies no longer carry host truth — the async chain must
 # break and re-upload. Everything else in _BANDS is host-truth only
@@ -301,20 +301,25 @@ class ServingEngine(object):
     (`cfg.serving.refused`; hand-off import at `submit`) raise a
     ValueError that names the option; nothing is silently ignored.
 
-    One step ahead (ISSUE 28): by default (`async_dispatch=None`) the
-    plain one-token decode keeps the chip a step ahead of the host —
-    step N+1 is dispatched off step N's device-resident outputs
-    before N's result is read, and N's result is ONE packed array,
-    read once, while N+1 runs; tokens leave the engine one `step()`
-    after the step that computed them, the same tokens in the same
-    order. Retirement the next dispatch depends on (EOS, budget) is
-    decided on the device; a host event that touches a
-    device-advanced band (admission, cancel, expiry) makes the engine
-    read first and upload host truth (`decode_dispatched_ahead` /
-    `decode_chain_breaks` in the metrics count both). Speculation and
-    a family whose seam refuses `async_dispatch` keep the lock-step
-    step, as an explicit `async_dispatch=False` does. `decode_window`
-    (K > 1) folds K iterations into the one compiled step (ISSUE 19).
+    One decode program, one decode loop, two depths (ISSUE 29): the
+    plain one-token decode is ONE compiled program (`_make_decode`)
+    whatever the family — it retires a slot that hit EOS or its
+    budget on the device and hands the host ONE packed array (tokens,
+    trap flags, the magnitude's bits, the four advanced bands), read
+    once — driven by ONE loop (`_decode_phase`). `async_dispatch` is
+    the loop's depth. True (what None resolves to wherever the engine
+    can, ISSUE 28): the chip runs a step ahead of the host — step N+1
+    is dispatched off step N's device-resident outputs before N's
+    result is read; tokens leave the engine one `step()` after the
+    step that computed them, the same tokens in the same order; a host
+    event that touches a device-advanced band (admission, cancel,
+    expiry) makes the engine read first and upload host truth
+    (`decode_dispatched_ahead` / `decode_chain_breaks` in the metrics
+    count both). False: dispatch and read in the same `step()` — what
+    None resolves to under speculation (acceptance is a host decision
+    after every verify) and for a family whose seam refuses
+    `async_dispatch` (the hybrid family decides its window release on
+    the host every step).
 
     Serving integrity (ISSUE 15): `integrity_traps` (default True)
     folds a per-slot non-finite trap — logits + softmax-denominator
@@ -346,8 +351,7 @@ class ServingEngine(object):
                  kv_quant="none", weight_quant=None,
                  integrity_traps=True, kv_fingerprints=False,
                  integrity_spike_factor=None, kv_store=None,
-                 kv_store_warm=False, decode_window=None,
-                 async_dispatch=None):
+                 kv_store_warm=False, async_dispatch=None):
         self._params = params
         self._cfg = cfg
         # the model family's seam (ISSUE 27): its cache, the bodies of
@@ -357,7 +361,6 @@ class ServingEngine(object):
         fam = self._family = getattr(cfg, "serving", None) or tlm.SERVING
         asked = {"prefix_cache_tokens": prefix_cache_tokens,
                  "kv_store": kv_store, "spec_draft_len": spec_draft_len,
-                 "decode_window": decode_window not in (None, 1),
                  "async_dispatch": async_dispatch,
                  "kv_quant": kv_quant != "none",
                  "weight_quant": weight_quant,
@@ -430,41 +433,31 @@ class ServingEngine(object):
         self.spec_draft_len = (
             int(spec_draft_len) if spec_draft_len and int(spec_draft_len) >= 2
             else None)
-        # megabatch decode window (ISSUE 19): K decode iterations
-        # folded into the ONE compiled step (a lax.scan over the plain
-        # decode body) so the host scheduler runs once per K tokens
-        # instead of once per token.
-        dw = 1 if decode_window is None else int(decode_window)
-        if dw < 1:
-            raise ValueError("decode_window must be >= 1 or None")
-        self.decode_window = dw
-        # `async_dispatch` keeps the chip one decode step ahead of the
-        # host (ISSUE 28): step N+1 is enqueued off step N's device
-        # outputs BEFORE N's one packed result is read, so emit,
-        # retirement and block bookkeeping for N run under N+1's device
-        # time; emission then runs one step behind. None (the default)
-        # = ahead wherever the engine can: not under speculation (its
-        # acceptance is a host decision after every verify), not for a
-        # family whose seam refuses it (its window tables advance on
-        # the host every step). False = the lock-step step: a token
-        # leaves the engine in the step() that computed it.
+        # `async_dispatch` is the decode loop's depth. True keeps the
+        # chip one decode step ahead of the host (ISSUE 28): step N+1
+        # is enqueued off step N's device outputs BEFORE N's one packed
+        # result is read, so emit, retirement and block bookkeeping for
+        # N run under N+1's device time; emission then runs one step
+        # behind. None (the default) = ahead wherever the engine can:
+        # not under speculation (its acceptance is a host decision
+        # after every verify), not for a family whose seam refuses it
+        # (its window tables advance on the host every step). False =
+        # lock-step: a token leaves the engine in the step() that
+        # computed it.
         if async_dispatch is None:
             async_dispatch = (self.spec_draft_len is None
                               and "async_dispatch" not in fam.refused)
         self.async_dispatch = bool(async_dispatch)
-        if self.spec_draft_len is not None \
-                and (dw > 1 or self.async_dispatch):
-            # spec decode is itself a multi-token window with HOST-side
-            # acceptance after every verify — composing it with a
-            # device-side decode window (or deferring its sync) would
-            # need acceptance folded into the scan. Loud refusal
-            # instead of a silently wrong schedule (ISSUE 19 allows
-            # either composition or refusal; this is the refusal).
+        if self.spec_draft_len is not None and self.async_dispatch:
+            # speculative acceptance is a HOST decision after every
+            # verify: deferring its read would need acceptance folded
+            # into the compiled step. Loud refusal instead of a
+            # silently wrong schedule.
             raise ValueError(
-                "spec_draft_len composes with neither decode_window>1 "
-                "nor async_dispatch: speculative acceptance is a host "
+                "spec_draft_len does not compose with "
+                "async_dispatch=True: speculative acceptance is a host "
                 "decision after every verify step — run spec with "
-                "decode_window=1 and async_dispatch=False")
+                "async_dispatch=None or False")
         # paged-attention kernel selector (ISSUE 13): "fused" runs the
         # Pallas kernels that attend THROUGH the block table
         # (parallel/paged_attention.py — no per-layer gathered view);
@@ -640,8 +633,8 @@ class ServingEngine(object):
         # slot each request's q/v deltas gather from (0 = zero adapter)
         self._aidx = np.zeros(S, np.int32)    # guarded-by: scheduler
         # per-slot EOS id band (ISSUE 19): -1 = no EOS configured. The
-        # compiled decode window retires slots in-loop, so the EOS rule
-        # must live on device too (K=1 sync keeps judging on host).
+        # compiled decode step retires slots itself, so the EOS rule
+        # lives on device too.
         self._eos = np.full(S, -1, np.int32)  # guarded-by: scheduler
         self._n_alloc = np.zeros(S, np.int32)  # table entries >= 0  # guarded-by: scheduler
         self._reserved_tail = np.zeros(S, np.int32)  # guarded-by: scheduler
@@ -665,16 +658,12 @@ class ServingEngine(object):
         self._deadlines = False               # guarded-by: scheduler
         self._donate = bool(donate)
         self._chunk_fns: Dict[int, Any] = {}
-        # exactly ONE decode program per engine lifetime, whatever K
-        # and whichever depth: the window engine (K > 1, or one step
-        # ahead) never builds the lock-step step, and vice versa —
-        # both are `_decode` to jax.jit and "decode_step" to the trace
-        # counter
-        self._use_window = dw > 1 or self.async_dispatch
-        self._decode_fn = (self._make_decode_window() if self._use_window
-                           else self._make_decode())
+        # exactly ONE decode program per engine lifetime, the same at
+        # either depth of the loop: `_decode` to jax.jit and
+        # "decode_step" to the trace counter
+        self._decode_fn = self._make_decode()
         # the one in-flight dispatched-not-yet-read step (async
-        # dispatch); the lock-step modes never leave one pending
+        # dispatch); the lock-step depth never leaves one pending
         self._inflight: Optional[dict] = None  # guarded-by: scheduler
         self._verify_fn = (
             self._make_verify() if self.spec_draft_len else None)
@@ -721,21 +710,40 @@ class ServingEngine(object):
     # ------------------------------------------------------------------
     # compiled steps
     # ------------------------------------------------------------------
-    def _decode_body(self):
-        """One decode iteration, the body both decode programs share:
-        the paged scatter write + attention, the greedy/sampled next
-        token on the `fold_in(base_key, count)` schedule, and the
-        ISSUE 15 numeric traps (per-slot non-finite flag + max-|logit|
-        scalar, FOLDED into the same trace; off = constant zeros, no
-        reduction in the graph)."""
+    def _make_decode(self):
+        """The engine's one plain decode program (ISSUE 29): one token
+        per live slot through the model family's decode step — the
+        paged scatter write + attention, the greedy/sampled next token
+        on the `fold_in(base_key, count)` schedule, and the ISSUE 15
+        numeric traps (per-slot non-finite flag + max-|logit| scalar,
+        FOLDED into the same trace; off = constant zeros, no reduction
+        in the graph) — followed by the device-side retirement rule
+        (`tlm.decode_retire`): a slot hitting EOS or budget emits that
+        final token and parks — its next scatter write resolves to the
+        out-of-range sentinel block and its emitted lane carries -1
+        padding the host discards — so a step chained off this one's
+        outputs needs no host decision.
+
+        Everything the host reads of the step comes back in ONE int32
+        array (ISSUE 28; `_unpack` is its inverse): the S emitted
+        tokens, the S trap flags and the magnitude scalar's bits, then
+        the four advanced bands, so the host holds its mirrors to the
+        device's without a transfer of their own. Traced exactly once
+        per engine lifetime, as `_decode` to jax.jit and "decode_step"
+        to the trace counter."""
         cfg, fam = self._cfg, self._family
+        metrics, deq = self.metrics, self._deq
         Lv = self.blocks_per_slot * self.kv_block_tokens
         kernel = self.paged_kernel  # baked into the one compiled step
         kv_quant = self.kv_quant    # ditto: storage dtype is traced in
         traps = self.integrity_traps  # baked in: trap reduction or not
 
-        def body(params, cache, tables, tok, pos, alive, temps, counts,
-                 base_keys, adapters, aidx):
+        def _decode(params, cache, tables, tok, pos, alive, temps,
+                    counts, base_keys, limits, eos, adapters=None,
+                    aidx=None):
+            metrics.count_trace("decode_step")  # trace-time side effect
+            if deq is not None:  # int8 weights upcast INSIDE the step
+                params = deq(params)
             # dead slots park their write past the table span: the
             # block lookup resolves them to the out-of-range sentinel
             # block and the scatter DROPS the row, so a retired slot
@@ -761,107 +769,34 @@ class ServingEngine(object):
             else:
                 trap = jnp.zeros_like(alive)
                 scale = jnp.float32(0.0)
-            return cache, nxt, trap, scale
-
-        return body
-
-    def _make_decode(self):
-        """The lock-step decode program: one token per live slot, read
-        in the step() that computed it (`_decode_once`)."""
-        metrics, deq, body = self.metrics, self._deq, self._decode_body()
-
-        def _decode(params, cache, tables, tok, pos, alive, temps,
-                    counts, base_keys, adapters=None, aidx=None):
-            metrics.count_trace("decode_step")  # trace-time side effect
-            if deq is not None:  # int8 weights upcast INSIDE the step
-                params = deq(params)
-            cache, nxt, trap, scale = body(
-                params, cache, tables, tok, pos, alive, temps, counts,
-                base_keys, adapters, aidx)
-            # advance the device-resident bands in-step: the steady
-            # decode loop re-uploads nothing (satellite: h2d dispatch
-            # off the hot path). Dead rows advance by 0, matching the
-            # untouched host mirrors.
-            live = alive.astype(jnp.int32)
-            return cache, nxt, pos + live, counts + live, trap, scale
-
-        kw = {"donate_argnums": (1,)} if self._donate else {}
-        return jax.jit(_decode, **kw)
-
-    def _make_decode_window(self):
-        """The decode program of the engine that runs ahead, and of a
-        K-token window (ISSUE 19): K iterations of exactly the plain
-        decode body — at K = 1 the body itself, so the program stays
-        flat; at K > 1 a lax.scan over it — each followed by the
-        device-side retirement rule (`tlm.decode_window_retire`): a
-        slot hitting EOS or budget emits that final token and parks —
-        its remaining scatter writes resolve to the out-of-range
-        sentinel block and its emitted lane carries -1 padding the
-        host discards. Sampling counts advance per live iteration, so
-        sampled outputs are window-invariant.
-
-        Everything the host reads of the step comes back in ONE int32
-        array (ISSUE 28; `_unpack` is its inverse): per iteration the
-        S emitted tokens, the S trap flags and the magnitude scalar's
-        bits — PR 15 traps stay PER ITERATION, so a trip in iteration
-        j poisons only tokens >= j — then the four advanced bands, so
-        the host holds its mirrors to the device's without a transfer
-        of their own. Traced exactly once per engine lifetime under
-        the same "decode_step" trace name, and the same `_decode`
-        program name, as the lock-step step it replaces."""
-        metrics, deq, body = self.metrics, self._deq, self._decode_body()
-        K = self.decode_window
-
-        def _decode(params, cache, tables, tok, pos, alive, temps,
-                    counts, base_keys, limits, eos, adapters=None,
-                    aidx=None):
-            metrics.count_trace("decode_step")  # trace-time side effect
-            if deq is not None:  # int8 weights upcast ONCE per window
-                params = deq(params)
-
-            def _iter(carry, _=None):
-                cache, tok, pos, alive, counts = carry
-                cache, nxt, trap, scale = body(
-                    params, cache, tables, tok, pos, alive, temps,
-                    counts, base_keys, adapters, aidx)
-                # dead lanes emit -1 padding; a live lane emits its
-                # token even on its retirement iteration (EOS/budget
-                # tokens ARE emitted, exactly like the host _emit rule)
-                emitted = jnp.where(alive, nxt, jnp.int32(-1))
-                nalive, npos = tlm.decode_window_retire(
-                    alive, nxt, pos, limits, eos)
-                ntok = jnp.where(alive, nxt, tok)
-                row = jnp.concatenate([
-                    emitted, trap.astype(jnp.int32),
-                    jax.lax.bitcast_convert_type(
-                        scale.astype(jnp.float32), jnp.int32)[None]])
-                return ((cache, ntok, npos, nalive,
-                         counts + alive.astype(jnp.int32)), row)
-
-            carry = (cache, tok, pos, alive, counts)
-            if K == 1:
-                carry, rows = _iter(carry)
-            else:
-                carry, rows = jax.lax.scan(_iter, carry, None, length=K)
-            cache, tok, pos, alive, counts = carry
+            # dead lanes emit -1 padding; a live lane emits its token
+            # even on its retirement step (EOS/budget tokens ARE
+            # emitted, exactly like the host _emit rule)
+            emitted = jnp.where(alive, nxt, jnp.int32(-1))
+            nalive, npos = tlm.decode_retire(alive, nxt, pos, limits, eos)
+            ntok = jnp.where(alive, nxt, tok)
+            row = jnp.concatenate([
+                emitted, trap.astype(jnp.int32),
+                jax.lax.bitcast_convert_type(
+                    scale.astype(jnp.float32), jnp.int32)[None]])
+            ncounts = counts + alive.astype(jnp.int32)
             packed = jnp.concatenate([
-                rows.reshape(-1), tok, pos, alive.astype(jnp.int32),
-                counts])
-            return cache, tok, pos, alive, counts, packed
+                row, ntok, npos, nalive.astype(jnp.int32), ncounts])
+            return cache, ntok, npos, nalive, ncounts, packed
 
         kw = {"donate_argnums": (1,)} if self._donate else {}
         return jax.jit(_decode, **kw)
 
     def _unpack(self, packed):
-        """The host's view of a decode program's packed result ->
-        (tokens [K, S], trap flags [K, S], magnitudes [K], the bands
-        (tok, pos, alive, counts) as the step left them)."""
-        K, S = self.decode_window, self.max_slots
+        """The host's view of a decode step's packed result ->
+        (tokens [S], trap flags [S], the magnitude, the bands (tok,
+        pos, alive, counts) as the step left them). The one blocking
+        device-to-host read of a decode step."""
+        S = self.max_slots
         flat = np.asarray(packed)
-        rows = flat[:K * (2 * S + 1)].reshape(K, 2 * S + 1)
-        tok, pos, alive, counts = flat[K * (2 * S + 1):].reshape(4, S)
-        return (rows[:, :S], rows[:, S:2 * S].astype(bool),
-                np.ascontiguousarray(rows[:, 2 * S]).view(np.float32),
+        tok, pos, alive, counts = flat[2 * S + 1:].reshape(4, S)
+        return (flat[:S], flat[S:2 * S].astype(bool),
+                float(flat[2 * S:2 * S + 1].view(np.float32)[0]),
                 (tok, pos, alive.astype(bool), counts))
 
     def _make_verify(self):
@@ -1838,7 +1773,7 @@ class ServingEngine(object):
                         jnp.asarray(table_row), jnp.int32(c),
                         jnp.float32(h.temperature))
                 adapter = self._adapter_args(jnp.int32(int(self._aidx[s])))
-            with m.phase("engine.dispatch") as disp:
+            with m.phase("engine.dispatch"):
                 self._cache, first, trap_d, scale_d = fn(
                     self._params, self._cache, *args, st["key"], **adapter)
             st["cursor"] = cursor + c
@@ -1860,7 +1795,6 @@ class ServingEngine(object):
                                           "prefill chunk", slots=[s])
             h.ttft_s = wait.t1 - h.submit_t
             m.ttft_s.append(h.ttft_s)
-            m.observe_device_interval(disp.t0, wait.t1)
             m.prefills += 1
             self._publish(s, h)
             with m.phase("engine.emit"):
@@ -1875,7 +1809,7 @@ class ServingEngine(object):
                 # resume_len
                 self._counts[s] = h.resume_len
                 self._base_keys[s] = np.asarray(jax.random.PRNGKey(h.seed))
-                # device-side EOS judgment for the decode window (-1 =
+                # device-side EOS judgment for the decode step (-1 =
                 # none); the _mark_dirty() below re-uploads it with
                 # everything else
                 self._eos[s] = -1 if h.eos_id is None else int(h.eos_id)
@@ -2040,14 +1974,8 @@ class ServingEngine(object):
             raise
         # step-latency EWMA INCLUDES the injector tick: an injected
         # gray stall (slow@) is exactly what the fleet's health score
-        # must see here. Normalized PER TOKEN (ISSUE 19 satellite): a
-        # K-token window legitimately takes ~K x longer per step and
-        # must not read as a gray stall or shift the fleet's live-
-        # median demotion threshold. The STATIC window size, not the
-        # emitted count — a low-occupancy window still does K
-        # iterations of device work, and dividing by fewer emitted
-        # tokens would make an idle replica read slow (false demotion).
-        m.observe_step(whole.t1 - whole.t0, tokens=self.decode_window)
+        # must see here
+        m.observe_step(whole.t1 - whole.t0)
         return out
 
     def _step_inner(self) -> bool:
@@ -2077,19 +2005,15 @@ class ServingEngine(object):
             chunks += 1
             progressed = True
 
-        if self._use_window:
-            # window engines must reach _window_phase even with no
-            # host-live slot: a pending async window may still hold
-            # the tokens that retire the last requests
-            if not self._window_phase():
+        if self.spec_draft_len is not None:
+            if not self._alive.any():
                 return progressed
-        elif not self._alive.any():
-            return progressed
-        elif self.spec_draft_len is not None:
             with self.metrics.phase("engine.decode", row="spec_verify"):
                 self._spec_step()
-        else:
-            self._decode_once()
+        elif not self._decode_phase():
+            # reached even with no host-live slot: a step in flight
+            # may still hold the tokens that retire the last requests
+            return progressed
 
         frag = 0
         for s in np.nonzero(self._alive)[0]:
@@ -2103,8 +2027,8 @@ class ServingEngine(object):
         return True
 
     def _count_decode_step(self):
-        """One decode step (a K-token window, a verify step) was
-        dispatched: slot occupancy, and how much of the block tables
+        """One decode step (plain, or a verify step) was dispatched:
+        slot occupancy, and how much of the block tables
         the live contexts name against all of it (S x MAXB entries) —
         `decode_blocks_live / decode_blocks_walked` is the share of a
         whole-table walk that finds a block, i.e. what the decode
@@ -2125,64 +2049,8 @@ class ServingEngine(object):
             m.cache_bytes_per_slot.append(
                 sum(m.cache_bytes_in_use.values()) / max(n_live, 1))
 
-    def _decode_once(self):  # band-verb: sync
-        """The plain (non-speculative) batched decode: one token per
-        live slot, bands advanced on device so a steady loop uploads
-        nothing (tables change only at a block-boundary append)."""
-        m = self.metrics
-        with m.phase("engine.decode", row="decode_step"):
-            live = np.nonzero(self._alive)[0]
-            with m.phase("engine.alloc_blocks"):
-                for s in live:
-                    p = int(self._pos[s])
-                    self._ensure_blocks(s, p, p + 1)
-                if self._win is not None:
-                    # a window table changes only where a write opens a
-                    # block or the window's tail leaves one
-                    Bt, p = self.kv_block_tokens, self._pos[live]
-                    edge = (p % Bt == 0) | ((p + 1 - self._win.window) % Bt
-                                            == 0)
-                    if edge.any():
-                        self._advance_window(
-                            [(s, q, q + 1) for s, q in zip(live[edge],
-                                                           p[edge])])
-            *bands, aidx = self._bands(
-                "tables", "tok", "pos", "alive", "temps", "counts",
-                "base_keys", "aidx")
-            adapter = self._adapter_args(aidx)
-            with m.phase("engine.dispatch") as disp:
-                self._cache, nxt_d, pos_d, counts_d, trap_d, scale_d = \
-                    self._decode_fn(self._params, self._cache, *bands,
-                                    **adapter)
-            with m.phase("engine.device_wait") as wait:
-                nxt = np.asarray(nxt_d)  # blocks; tokens are real
-            if self.integrity_traps:
-                # a tripped slot becomes an integrity event INSTEAD of
-                # an emitted token: checked before the emit loop below,
-                # so no token from a poisoned step reaches a handle
-                with m.phase("engine.integrity"):
-                    self._check_integrity(trap_d, np.asarray(scale_d),
-                                          "decode")
-            # the decode step advanced tok/pos/counts on device; adopt
-            # its outputs so an admission-free step re-uploads nothing.
-            # (Dead rows: device tok holds this step's don't-care
-            # sample, host keeps the stale final token — both are
-            # masked and parked, and an admission re-dirties every band
-            # anyway.)
-            self._dev["tok"], self._dev["pos"], self._dev["counts"] = (
-                nxt_d, pos_d, counts_d)
-            self._dirty.difference_update(("tok", "pos", "counts"))
-            m.observe_device_interval(disp.t0, wait.t1)
-            self._count_decode_step()
-
-            with m.phase("engine.emit"):
-                self._pos[live] += 1  # the token just cached sat at pos
-                for s in live:
-                    self._tok[s] = nxt[s]
-                    self._emit(s, nxt[s])
-
     # ------------------------------------------------------------------
-    # one step ahead of the host (ISSUE 28) / megabatch window (ISSUE 19)
+    # the decode loop: one program, read in the same step() or one later
     # ------------------------------------------------------------------
     def _can_chain(self) -> bool:
         """Step N+1 may chain off step N's un-read device outputs only
@@ -2192,61 +2060,59 @@ class ServingEngine(object):
         break — read first, re-upload host truth, then dispatch."""
         return not (self._dirty & _DEVICE_ADVANCED)
 
-    def _window_phase(self) -> bool:
-        """The decode phase of the engine that runs ahead (and of a
-        K-token window): read the pending step (if any), keep the
-        async pipeline one step deep, or run one dispatch+read in-line
-        (lock-step windows). Returns False only when there is
-        genuinely nothing to do — no live slot AND no pending step (a
-        pending step may still hold the tokens that retire the final
-        requests, so it must be read even with zero host-live
-        slots)."""
+    def _decode_phase(self) -> bool:
+        """The plain (non-speculative) decode phase of a step(): read
+        the step in flight (if any) and keep the pipeline one step
+        deep (`async_dispatch`), or dispatch and read one step in-line
+        (lock-step). Returns False only when there is genuinely
+        nothing to do — no live slot AND no step in flight (one may
+        still hold the tokens that retire the final requests, so it
+        must be read even with zero host-live slots)."""
         rec, self._inflight = self._inflight, None
         if rec is None and not self._alive.any():
             return False
         m = self.metrics
         with m.phase("engine.decode", row="decode_step"):
             if rec is None:
-                rec = self._dispatch_window()
+                rec = self._dispatch_decode()
                 if self.async_dispatch:
                     # one-step-behind emission: read next step
                     self._inflight = rec
                 else:
-                    self._sync_window(rec)
+                    self._read_decode(rec)
                 return True
             ahead = None
-            if self.async_dispatch and self._alive.any():
+            if self._alive.any():
                 if self._can_chain():
                     # enqueue step N+1 off step N's device outputs
                     # BEFORE reading N: the emit/schedule work below
                     # runs under N+1's device compute (the whole point)
-                    ahead = self._dispatch_window(prev=rec)
+                    ahead = self._dispatch_decode(prev=rec)
                     m.decode_dispatched_ahead += 1
                 else:
                     m.decode_chain_breaks += 1
-            self._sync_window(rec)
+            self._read_decode(rec)
             if not self._alive.any():
                 # N retired the last live slot: a step already
                 # dispatched off it holds no lane the host would emit
                 # (parked on the device too, where the mirrors agree)
                 # — nothing to read
                 ahead = None
-            elif ahead is None and self.async_dispatch:
+            elif ahead is None:
                 # chain broken by a host event: host truth is current
                 # again now that N is read — refill the pipeline
-                ahead = self._dispatch_window()
+                ahead = self._dispatch_decode()
             self._inflight = ahead
         return True
 
-    def _dispatch_window(self, prev=None):
-        """Enqueue one compiled decode step (K tokens a slot). `prev`
+    def _dispatch_decode(self, prev=None):
+        """Enqueue one compiled decode step (a token a slot). `prev`
         chains this dispatch off the given un-read step's output bands
         (host mirrors are one step stale then — the block horizon
-        covers 2K positions so the device never writes past the
+        covers 2 positions so the device never writes past the
         table). Its packed result starts for the host at once."""
-        K = self.decode_window
         live = np.nonzero(self._alive)[0]
-        horizon = 2 * K if prev is not None else K
+        horizon = 2 if prev is not None else 1
         m = self.metrics
         with m.phase("engine.alloc_blocks"):
             for s in live:
@@ -2256,6 +2122,18 @@ class ServingEngine(object):
                 # limits-2)
                 self._ensure_blocks(
                     s, p, min(p + horizon, int(self._limits[s]) - 1))
+            if self._win is not None:
+                # a window table changes only where a write opens a
+                # block or the window's tail leaves one (this family
+                # refuses `async_dispatch`: the host mirrors are
+                # current, the write is at pos)
+                Bt, p = self.kv_block_tokens, self._pos[live]
+                edge = (p % Bt == 0) | ((p + 1 - self._win.window) % Bt
+                                        == 0)
+                if edge.any():
+                    self._advance_window(
+                        [(s, q, q + 1) for s, q in zip(live[edge],
+                                                       p[edge])])
         rest = ("tables", "temps", "base_keys", "limits", "eos", "aidx")
         if prev is None:
             tok_d, pos_d, alive_d, counts_d, *rest_d = self._bands(
@@ -2265,59 +2143,48 @@ class ServingEngine(object):
             rest_d = self._bands(*rest)
         tables_d, temps_d, keys_d, limits_d, eos_d, aidx_d = rest_d
         adapter = self._adapter_args(aidx_d)
-        with m.phase("engine.dispatch") as disp:
+        with m.phase("engine.dispatch"):
             self._cache, *bands, packed = self._decode_fn(
                 self._params, self._cache, tables_d, tok_d, pos_d,
                 alive_d, temps_d, counts_d, keys_d, limits_d, eos_d,
                 **adapter)
         packed.copy_to_host_async()
         self._count_decode_step()
-        return {"bands": bands, "packed": packed, "t0": disp.t0,
+        return {"bands": bands, "packed": packed,
                 "slots": [(int(s), self._slot_req[int(s)])
                           for s in live]}
 
-    def _sync_window(self, rec):  # band-verb: sync
+    def _read_decode(self, rec):  # band-verb: sync
         """Read one dispatched step — ONE blocking read of its packed
-        result — and emit its tokens in iteration order. Lane
-        discipline: -1 lanes are parking padding (the slot retired in
-        an earlier iteration) and are discarded; a slot whose handle
-        changed since dispatch (expired, cancelled, re-tenanted) has
-        its remaining lanes discarded too — an expired request keeps
-        its pre-window tokens and nothing more. Integrity rows are
-        judged in iteration order BEFORE any of their tokens emit, so
-        a trap tripping in iteration j poisons only tokens >= j (ISSUE
-        19 tentpole rule); all-parked rows are skipped so the spike
-        EWMA never ingests masked zeros."""
-        K = self.decode_window
+        result — and emit its tokens. Lane discipline: -1 lanes are
+        parking padding (the slot was dead on the device at dispatch)
+        and are discarded; a slot whose handle changed since dispatch
+        (expired, cancelled, re-tenanted) has its lane discarded too —
+        an expired request keeps the tokens already read and nothing
+        more. The trap flags and the magnitude are judged, from the
+        host values just read, BEFORE any token emits: a tripped step
+        becomes an integrity event INSTEAD of tokens; an all-parked
+        step is skipped so the spike EWMA never ingests masked
+        zeros."""
         m = self.metrics
-        with m.phase("engine.device_wait") as wait:
-            toks, traps, scales, bands = self._unpack(rec["packed"])
-        m.observe_device_interval(rec["t0"], wait.t1)
-        clean, verdict = K, "ok"  # rows before `clean` may emit
+        with m.phase("engine.device_wait"):
+            toks, traps, scale, bands = self._unpack(rec["packed"])
         if self.integrity_traps:
             with m.phase("engine.integrity"):
-                for j in range(K):
-                    if (toks[j] >= 0).any():
-                        verdict = self._sentinel.observe(
-                            bool(traps[j].any()), float(scales[j]))
-                        if verdict != "ok":
-                            clean = j
-                            break
+                verdict = ("ok" if not (toks >= 0).any() else
+                           self._sentinel.observe(bool(traps.any()), scale))
+                if verdict != "ok":
+                    self._trip_verdict(verdict, traps, scale, "decode")
         with m.phase("engine.emit"):
-            for j in range(clean):
-                row = toks[j]
-                for s, h in rec["slots"]:
-                    if self._slot_req[s] is not h or not self._alive[s]:
-                        continue  # expired/cancelled/re-tenanted: discard
-                    t = int(row[s])
-                    if t < 0:
-                        continue  # parked lane
-                    self._pos[s] += 1  # the token just read sat at pos
-                    self._tok[s] = t
-                    self._emit(s, t)
-            if verdict != "ok":
-                self._trip_verdict(verdict, traps[clean], scales[clean],
-                                   "decode window")
+            for s, h in rec["slots"]:
+                if self._slot_req[s] is not h or not self._alive[s]:
+                    continue  # expired/cancelled/re-tenanted: discard
+                t = int(toks[s])
+                if t < 0:
+                    continue  # parked lane
+                self._pos[s] += 1  # the token just read sat at pos
+                self._tok[s] = t
+                self._emit(s, t)
         # adopt the step's outputs as device truth (steady loop
         # re-uploads nothing) — but only when the host mirrors, advanced
         # by the emit loop above, agree with the bands the packed result
@@ -2374,17 +2241,16 @@ class ServingEngine(object):
         with phase("engine.upload"):
             window_d = jnp.asarray(window)
         adapter = self._adapter_args(aidx_d)
-        with phase("engine.dispatch") as disp:
+        with phase("engine.dispatch"):
             self._cache, cand_d, trap_d, scale_d = self._verify_fn(
                 self._params, self._cache, tables_d, window_d, pos_d,
                 alive_d, limits_d, temps_d, counts_d, keys_d, **adapter)
-        with phase("engine.device_wait") as wait:
+        with phase("engine.device_wait"):
             cand = np.asarray(cand_d)  # blocks; candidates are real
         if self.integrity_traps:
             with phase("engine.integrity"):
                 self._check_integrity(trap_d, np.asarray(scale_d),
                                       "spec verify")
-        metrics.observe_device_interval(disp.t0, wait.t1)
         self._count_decode_step()
         with phase("engine.emit"):
             for s in live:

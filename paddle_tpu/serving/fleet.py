@@ -1615,10 +1615,7 @@ class ServingFleet(object):
                            pressure)
       scale_up_headroom_s  also spawn when any open request's deadline
                            headroom drops below this while requests
-                           outnumber live replicas (None = off);
-                           clamped up to one decode-window's wall time
-                           on a decode_window=K fleet (ISSUE 19:
-                           deadlines enforce at window granularity)
+                           outnumber live replicas (None = off)
       scale_down_idle_s    retire a replica only after low load (open
                            requests < live replicas) holds this long
                            (sustained-idle hysteresis)
@@ -3806,24 +3803,6 @@ class ServingFleet(object):
         self._fail_over(i, rep, exc)
 
     # -- autoscaling (ISSUE 11) ------------------------------------------
-    def _window_headroom_s(self) -> float:  # holds: _cond
-        """Deadline enforcement granularity (ISSUE 19): the widest live
-        replica's decode window in wall seconds — window size K times
-        its PER-TOKEN step EWMA (the gauge is already normalized by
-        K). 0.0 for a K=1 fleet, so the pre-window autoscaler behavior
-        is untouched."""
-        w = 0.0
-        for i in range(self.max_replicas):
-            rep = self._replicas[i]
-            if self._state[i] != _LIVE or rep is None:
-                continue
-            k = int(rep._engine_kw.get("decode_window") or 1)
-            if k <= 1:
-                continue
-            st = self._rep_stats[i] or {}
-            w = max(w, k * float(st.get("step_ewma_s", 0.0)))
-        return w
-
     def _scale_sweep(self, now: float):  # thread: monitor, holds: _cond
         """Queue-driven elasticity: spawn when open requests outrun
         live capacity (or deadline headroom shrinks under real
@@ -3846,18 +3825,10 @@ class ServingFleet(object):
                 and open_n > n_live:
             # deadline pressure counts only under real queueing (more
             # open requests than replicas): a single tight-deadline
-            # request on an idle fleet needs routing, not capacity.
-            # Headroom is clamped to at least one decode-window's wall
-            # time (ISSUE 19): a decode_window=K engine enforces
-            # deadlines every K tokens, so slack thinner than one
-            # window is already unservable — spawning for it cannot
-            # help, and waiting for it to shrink further would spawn
-            # too late for the requests a new replica CAN still serve.
-            headroom = max(self.scale_up_headroom_s,
-                           self._window_headroom_s())
+            # request on an idle fleet needs routing, not capacity
             for h in self._handles.values():
                 if h.deadline_at is not None and not h._probe \
-                        and h.deadline_at - now < headroom:
+                        and h.deadline_at - now < self.scale_up_headroom_s:
                     pressure = True
                     break
         if pressure:

@@ -161,14 +161,6 @@ class ServingMetrics(object):
         # — report() surfaces its record/byte/quarantine counters
         # (serving/kv_store.py KVBlockStore)
         self.kv_store = None
-        # PR 19 — device-busy accumulator: wall time with at least one
-        # compiled step in flight (dispatch -> sync), folded as a UNION
-        # of intervals via a last-end watermark so overlapping async
-        # windows never double count. host-overhead fraction =
-        # (wall - device_busy_s) / wall is the serving_megabatch
-        # bench's headline column.
-        self.device_busy_s = 0.0
-        self._busy_last_end = 0.0
         # PR 28 — how often the engine keeps the chip one decode step
         # ahead of the host (cumulative): steps dispatched BEFORE their
         # predecessor was read, and steps where a host event (admission,
@@ -190,14 +182,10 @@ class ServingMetrics(object):
     STEP_EWMA_ALPHA = 0.5  # fast decay: ~3 healthy steps erase a spike
     SLOW_STEP_S = 0.5  # a step() slower than this is kept and logged
 
-    def observe_step(self, seconds: float, tokens: int = 1):
-        """Fold one engine-step wall time into the step-latency EWMA,
-        normalized PER TOKEN (ISSUE 19): a decode_window=K engine's
-        step legitimately covers K tokens of work, and the fleet's
-        gray-failure score compares this gauge across replicas that
-        may run different K. `tokens` is the step's token capacity
-        (the static window size), so K=1 keeps the original per-step
-        semantics exactly."""
+    def observe_step(self, seconds: float):
+        """Fold one engine-step wall time into the step-latency EWMA
+        (the fleet's gray-failure score compares this gauge across
+        replicas)."""
         a = self.STEP_EWMA_ALPHA
         if seconds > self.SLOW_STEP_S:
             # which phase held the step: time of `engine.step` that no
@@ -208,21 +196,10 @@ class ServingMetrics(object):
                               for k, v in self.step_phases.items()}}
             self.slow_steps.append(rec)
             _LOG.warning("slow engine step: %r", rec)
-        seconds = seconds / max(1, int(tokens))
         if self.step_ewma_s == 0.0:
             self.step_ewma_s = seconds
         else:
             self.step_ewma_s = a * seconds + (1.0 - a) * self.step_ewma_s
-
-    def observe_device_interval(self, start: float, end: float):
-        """Fold one dispatch->sync span into the device-busy union.
-        Spans arrive in sync order; overlap with an earlier span (an
-        async window chained before its predecessor synced) counts
-        once — only time past the watermark accrues."""
-        lo = max(start, self._busy_last_end)
-        if end > lo:
-            self.device_busy_s += end - lo
-            self._busy_last_end = end
 
     # -- recording ------------------------------------------------------
     def count_trace(self, name: str):
@@ -316,10 +293,6 @@ class ServingMetrics(object):
             "resumed_requests": self.resumed_requests,
             "resume_tokens_reused": self.resume_tokens_reused,
             "step_ewma_s": round(self.step_ewma_s, 6),
-            "device_busy_s": round(self.device_busy_s, 4),
-            "host_overhead_frac": round(
-                max(0.0, wall - self.device_busy_s) / wall, 4)
-            if wall else None,
             "paged_kernel": self.paged_kernel,
             "kv_quant": self.kv_quant,
             "weight_quant": self.weight_quant,

@@ -82,3 +82,52 @@ def _fresh_programs():
     switch_startup_program(prev_startup)
     switch_scope(prev_scope)
     mesh_mod.set_default_mesh(prev_mesh)
+
+
+class _EngineWatch(object):
+    """Which spans are open, and under which span each device-to-host
+    read (`np.asarray` of a jax.Array in serving/engine.py) is made."""
+
+    def __init__(self, eng, monkeypatch):
+        from paddle_tpu.serving import engine as engine_mod
+
+        self.open, self.reads, self.opened = [], [], []
+        real_phase, watch = eng.metrics.phase, self
+
+        class _Tracked(object):
+            def __init__(self, name, ph):
+                self.name, self.ph = name, ph
+
+            def __enter__(self):
+                watch.opened.append((self.name, tuple(watch.open)))
+                watch.open.append(self.name)
+                return self.ph.__enter__()
+
+            def __exit__(self, *exc):
+                watch.open.pop()
+                return self.ph.__exit__(*exc)
+
+        class _Numpy(object):
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            @staticmethod
+            def asarray(x, *a, **kw):
+                if isinstance(x, jax.Array):
+                    watch.reads.append(watch.open[-1] if watch.open
+                                       else None)
+                return np.asarray(x, *a, **kw)
+
+        monkeypatch.setattr(
+            eng.metrics, "phase",
+            lambda name, row=None, **kw: _Tracked(
+                name, real_phase(name, row, **kw)))
+        monkeypatch.setattr(engine_mod, "np", _Numpy())
+        self.undo = monkeypatch.undo
+
+
+@pytest.fixture
+def watch_engine(monkeypatch):
+    """`watch_engine(eng)` -> an `_EngineWatch` on that ServingEngine
+    for the rest of the test (or until its `.undo()`)."""
+    return lambda eng: _EngineWatch(eng, monkeypatch)

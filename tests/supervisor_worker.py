@@ -42,6 +42,11 @@ Env:   PADDLE_WORKER_ID    logical id (set by the Supervisor)
        SUP_TASK_SLEEP      extra seconds per task (paces the queue drain)
        SUP_IDLE_GRACE_S    keep polling an empty queue this long before
                            exiting 0 (covers a dead peer's lease timeout)
+       SUP_START_AFTER     "<ckpt dir>:<step>": do not register (so take
+                           no lease) before that directory holds a
+                           checkpoint of at least that step — a drill's
+                           healthy peers wait for the victim's first
+                           tasks, whichever process came up first
 """
 
 import json
@@ -118,6 +123,15 @@ def main():
         # cadence the supervisor sees is the steady-state one
         xw, yw = batch_for(0)
         exe.run(main_p, feed={"x": xw, "y": yw}, fetch_list=[grad_var])
+
+        start_after = os.environ.get("SUP_START_AFTER")
+        if start_after:
+            # before registering: an unregistered process is under the
+            # supervisor's spawn grace, not its heartbeat deadline
+            gate_dir, _, gate_step = start_after.rpartition(":")
+            while not any(s >= int(gate_step)
+                          for s, _ in ckpt._list_step_dirs(gate_dir)):
+                time.sleep(0.05)
 
         client = RemoteCoordinator(addr, retry_deadline_s=20.0,
                                    backoff_base_s=0.05)

@@ -693,43 +693,6 @@ def test_serving_frontdoor_registered_in_bench_main():
     assert '"serving_frontdoor", bench_serving_frontdoor' in src
 
 
-@pytest.mark.slow  # ~20s: 4 engine variants, each paying its compile
-# on an unmeasured warm-up request; tier-1 keeps the registration pin
-# below and the full identity sweep in test_serving_megabatch.py
-def test_serving_megabatch_workload_contract():
-    """ISSUE 19 acceptance: the `serving_megabatch` row cannot decay
-    into a no-op — one fixed-seed mixed greedy/sampled Poisson trace
-    replayed across (decode_window, async_dispatch) variants must be
-    token-identical everywhere, trace decode exactly once per variant
-    (hard-raised in-bench), and show host-overhead(K=8, async) below
-    host-overhead(K=1, sync) — the measured amortization the tentpole
-    claims. The assertions here pin the row's shape: the headline
-    overhead pair, per-variant steps/token (strictly amortized at
-    K=8) and band-upload counts (a steady window loop re-uploads
-    nothing new per K)."""
-    rec = bench.bench_serving_megabatch(n_requests=8, windows=(1, 8))
-    assert rec["outputs_identical"], rec
-    assert len(rec["variants"]) == 4, rec
-    lo = rec["host_overhead_K8_async"]
-    hi = rec["host_overhead_K1_sync"]
-    assert lo is not None and hi is not None and lo < hi, rec
-    for name, row in rec["variants"].items():
-        assert row["host_overhead_frac"] is not None, (name, row)
-        assert row["steps_per_token"] > 0, (name, row)
-        assert row["band_uploads"] >= 0, (name, row)
-    assert rec["variants"]["K8_sync"]["steps_per_token"] \
-        < rec["variants"]["K1_sync"]["steps_per_token"], rec
-
-
-def test_serving_megabatch_registered_in_bench_main():
-    """The workload is wired into bench.main()'s side-workload list
-    (the registration is what lands it in the driver's record)."""
-    import inspect
-
-    src = inspect.getsource(bench.main)
-    assert '"serving_megabatch", bench_serving_megabatch' in src
-
-
 def test_serving_kv_handoff_registered_in_bench_main():
     """The workload is wired into bench.main()'s side-workload list
     (the registration is what lands it in the driver's record)."""

@@ -300,13 +300,261 @@ def test_a_reused_slot_starts_from_zero_state(ref, cfg, params):
 
 @pytest.mark.parametrize("option,value", [
     ("prefix_cache_tokens", 64), ("kv_store", object()),
-    ("spec_draft_len", 4), ("decode_window", 4), ("async_dispatch", True),
+    ("spec_draft_len", 4), ("async_dispatch", True),
     ("kv_quant", "int8"), ("weight_quant", "int8"),
     ("adapter_registry", object()), ("kv_fingerprints", True)])
 def test_each_unsupported_option_is_refused_by_name(cfg, params, option,
                                                     value):
     with pytest.raises(ValueError, match=option):
         _engine(params, cfg, **{option: value})
+
+
+# ---------------------------------------------------------------------
+# the family on the engine's one decode loop, lock-step (ISSUE 29)
+# ---------------------------------------------------------------------
+
+def _assert_reference_greedy(ref, params, prompt, served):
+    """Every served token is the reference's argmax at its position."""
+    served = np.asarray(served, np.int32)
+    want = np.asarray(ref.logits(
+        params, np.concatenate([prompt, served]), SHAPE))
+    assert np.array_equal(
+        want[len(prompt) - 1:len(prompt) - 1 + len(served)].argmax(-1),
+        served)
+
+
+def _prompts(seed, *lengths):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, SHAPE["vocab"], n).astype(np.int32)
+            for n in lengths]
+
+
+def _slot_of(eng, h):
+    return next(s for s, hh in enumerate(eng._slot_req) if hh is h)
+
+
+def test_the_family_rides_the_shared_loop_lockstep(cfg, params):
+    """No second program or loop for the family: its decode program is
+    the engine's one `_make_decode` (limits and EOS bands in, one
+    packed result out), the loop's depth is lock-step because the seam
+    refuses `async_dispatch` — and only that and what re-uses cached
+    blocks: `decode_window` is no option of any engine."""
+    eng = _engine(params, cfg)
+    assert not eng.async_dispatch and eng._inflight is None
+    assert "async_dispatch" in sb.SERVING.refused
+    assert "decode_window" not in sb.SERVING.refused
+    with pytest.raises(TypeError, match="decode_window"):
+        _engine(params, cfg, decode_window=4)
+    hs = [eng.submit(p, 14) for p in _prompts(10, 9, 21)]
+    while min(len(h.tokens) for h in hs) < 4:
+        eng.step()
+        assert eng._inflight is None  # read in the step that dispatched
+    m = eng.metrics
+    assert m.decode_dispatched_ahead == 0 and m.decode_chain_breaks == 0
+    eng.run()
+    assert m.decode_trace_count() == 1
+
+
+def test_eos_mid_run_serves_the_references_tokens(ref, cfg, params):
+    """A request whose EOS lands mid-run is retired ON THE DEVICE by
+    the shared program (the lock-step program left it to the host):
+    its tokens are the reference's up to and including the EOS, its
+    neighbour's and the slot's next tenant's are the reference's, and
+    every block of the three caches is back at the end."""
+    pa_, pb, pc = _prompts(11, 19, 30, 7)
+    probe = _engine(params, cfg, prefill_chunk_tokens=16)
+    hp = probe.submit(pa_, 16)
+    probe.run()
+    eos = int(hp.tokens[5])
+    n_eos = list(hp.tokens).index(eos) + 1
+    eng = _engine(params, cfg, prefill_chunk_tokens=16)
+    ha, hb = eng.submit(pa_, 16, eos_id=eos), eng.submit(pb, 20)
+    while not ha.done:
+        eng.step()
+    hc = eng.submit(pc, 9)  # re-tenants the slot the device retired
+    eng.run()
+    assert ha.finish_reason == "eos" and len(ha.tokens) == n_eos < 16
+    assert list(ha.tokens) == list(hp.tokens[:n_eos])
+    for prompt, h in ((pa_, ha), (pb, hb), (pc, hc)):
+        _assert_reference_greedy(ref, params, prompt, h.tokens)
+    assert (len(hb.tokens), len(hc.tokens)) == (20, 9)
+    assert eng.metrics.decode_trace_count() == 1
+    assert eng._alloc.blocks_in_use == 0 and eng._alloc.reserved == 0
+    assert eng._win.alloc.blocks_in_use == 0 and eng._win.alloc.reserved == 0
+
+
+@pytest.mark.parametrize("how", ["cancel", "expire"])
+def test_cancel_and_expiry_mid_decode_keep_the_references_tokens(
+        ref, cfg, params, how):
+    """A request cancelled, or expired, between two decode steps keeps
+    the tokens already read — a prefix of the reference's — and its
+    window blocks and recurrent state do not leak into the neighbour
+    or into the slot's next tenant."""
+    import time
+
+    pa_, pb, pc = _prompts(12, 17, 26, 11)
+    eng = _engine(params, cfg)
+    ha = eng.submit(pa_, 30, deadline_at=time.monotonic() + 3600.0)
+    hb = eng.submit(pb, 18)
+    while len(ha.tokens) < 7:
+        eng.step()
+    n0 = len(ha.tokens)
+    if how == "cancel":
+        assert eng.cancel(ha.rid)
+    else:
+        ha.deadline_at = time.monotonic() - 1.0
+        eng.step()
+    assert ha.done and ha.finish_reason == (
+        "cancelled" if how == "cancel" else "expired")
+    assert len(ha.tokens) == n0
+    hc = eng.submit(pc, 10)
+    eng.run()
+    for prompt, h in ((pa_, ha), (pb, hb), (pc, hc)):
+        _assert_reference_greedy(ref, params, prompt, h.tokens)
+    assert (len(hb.tokens), len(hc.tokens)) == (18, 10)
+    assert eng._win.alloc.blocks_in_use == 0 and eng._win.alloc.reserved == 0
+
+
+def _steady(params, cfg, n_new=40):
+    """Two requests past their prefills, decoding across the window."""
+    eng = _engine(params, cfg)
+    prompts = _prompts(13, 10, 15)
+    hs = [eng.submit(p, n_new) for p in prompts]
+    while min(len(h.tokens) for h in hs) < 3:
+        eng.step()
+    return eng, prompts, hs
+
+
+def test_a_steady_hybrid_step_makes_one_blocking_read(ref, cfg, params,
+                                                      watch_engine):
+    """The family's decode step made three device-to-host reads on the
+    lock-step program (the tokens, then the trap flags and the
+    magnitude); on the shared one it makes ONE, of the packed result,
+    under `engine.device_wait` — `engine.integrity` judges host
+    values."""
+    eng, prompts, hs = _steady(params, cfg)
+    watch = watch_engine(eng)
+    for _ in range(12):
+        before = len(watch.reads)
+        eng.step()
+        assert watch.reads[before:] == ["engine.device_wait"]
+    watch.undo()
+    eng.run()
+    for prompt, h in zip(prompts, hs):
+        _assert_reference_greedy(ref, params, prompt, h.tokens)
+
+
+def test_window_release_still_lies_inside_alloc_blocks(cfg, params,
+                                                       watch_engine):
+    """The window tables advance where they did — beneath
+    `engine.alloc_blocks`, now the shared dispatch's — and only on the
+    steps whose write opens a block or whose window's tail leaves
+    one."""
+    eng, _, hs = _steady(params, cfg)
+    watch = watch_engine(eng)
+    released0 = eng.metrics.window_blocks_released
+    n = 16  # crosses the 12-token window and four block edges a slot
+    for _ in range(n):
+        eng.step()
+    rel = [stack for name, stack in watch.opened
+           if name == "engine.window_release"]
+    assert rel and all(stack == ("engine.step", "engine.decode",
+                                 "engine.alloc_blocks") for stack in rel)
+    assert len(rel) < n  # not every step: only at an edge
+    assert eng.metrics.window_blocks_released > released0
+    assert all(eng._win.held(_slot_of(eng, h)) <= 12 // BT + 1 for h in hs)
+
+
+def test_a_trapped_hybrid_step_emits_nothing_of_itself(ref, cfg, params):
+    """The trap flags ride the packed result and are judged from the
+    host values BEFORE any token of the step emits: a trap forged into
+    a real dispatched hybrid step raises and leaves every handle as it
+    was."""
+    from paddle_tpu.serving import IntegrityError
+
+    eng, prompts, hs = _steady(params, cfg)
+    n0 = [len(h.tokens) for h in hs]
+    rec = eng._dispatch_decode()
+    flat = np.asarray(rec["packed"]).copy()
+    flat[eng.max_slots + _slot_of(eng, hs[1])] = 1  # `_unpack`'s layout
+    rec["packed"] = flat
+    with pytest.raises(IntegrityError) as ei:
+        eng._read_decode(rec)
+    assert ei.value.kind == "trap"
+    assert [len(h.tokens) for h in hs] == n0
+    for prompt, h in zip(prompts, hs):
+        _assert_reference_greedy(ref, params, prompt, h.tokens)
+
+
+@pytest.mark.parametrize("how", ["eos", "budget"])
+def test_a_device_retired_slot_leaves_what_a_host_retired_one_did(
+        cfg, params, how):
+    """EOS and budget are decided on the device now. The slot such a
+    request leaves — its recurrent state, its conv window, its window
+    table and pool blocks — is bit for bit what the same request
+    leaves when the HOST retires it at the same token (a cancel), also
+    after its neighbour has stepped on past it."""
+    pn, pa_ = _prompts(14, 22, 13)
+    n = 9
+
+    def serve(kind):
+        eng = _engine(params, cfg)
+        hn = eng.submit(pn, 40)  # the neighbour: alive throughout
+        kw = {}
+        if kind == "eos":
+            kw["eos_id"] = eos
+        ha = eng.submit(pa_, n if kind == "budget" else 30, **kw)
+        s = None
+        while not ha.done:
+            eng.step()
+            s = _slot_of(eng, ha) if not ha.done else s
+            if kind == "cancel" and len(ha.tokens) == n:
+                eng.cancel(ha.rid)
+        for _ in range(5):
+            eng.step()  # the neighbour steps on; the slot stays parked
+        assert not hn.done
+        state = [np.asarray(a[s]).copy() for a in
+                 jax.tree_util.tree_leaves(eng._cache["ssm"])]
+        return (list(ha.tokens), ha.finish_reason, state,
+                eng._win.tables[s].copy(), eng._win.held(s),
+                eng._win.alloc.blocks_in_use, list(hn.tokens))
+
+    probe = _engine(params, cfg)
+    hp = probe.submit(pa_, 30)
+    probe.run()
+    # an EOS id whose first occurrence is the n-th token
+    assert hp.tokens[n - 1] not in hp.tokens[:n - 1]
+    eos = int(hp.tokens[n - 1])
+    toks, reason, state, table, held, in_use, neighbour = serve(how)
+    h_toks, h_reason, h_state, h_table, h_held, h_in_use, h_neighbour = \
+        serve("cancel")
+    assert reason == how and h_reason == "cancelled"
+    assert toks == h_toks == list(hp.tokens[:n])
+    assert len(state) == len(h_state) > 0
+    for a, b in zip(state, h_state):
+        assert np.array_equal(a, b)
+    assert np.abs(state[0]).max() > 0  # the state of a served request
+    assert (table == -1).all() and np.array_equal(table, h_table)
+    assert held == h_held == 0 and in_use == h_in_use
+    assert neighbour == h_neighbour
+
+
+def test_hybrid_decode_is_traced_once_across_waves(cfg, params):
+    """One decode program per engine lifetime on the shared loop too:
+    a second wave — other lengths, an EOS, a cancel — retraces
+    nothing."""
+    eng = _engine(params, cfg, prefill_chunk_tokens=16)
+    for p in _prompts(15, 6, 19, 33, 12):
+        eng.submit(p, 9)
+    eng.run()
+    assert eng.metrics.decode_trace_count() == 1
+    before = dict(eng.metrics.trace_counts)
+    hs = [eng.submit(p, 12, eos_id=7) for p in _prompts(16, 6, 19, 33, 12)]
+    eng.step()
+    eng.cancel(hs[0].rid)
+    eng.run()
+    assert eng.metrics.trace_counts == before
+    assert all(h.done for h in hs)
 
 
 def test_handoff_import_is_refused_by_name(cfg, params):

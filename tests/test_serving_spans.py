@@ -217,8 +217,7 @@ def test_phase_folds_rows_attrs_and_the_older_name():
 
 
 @pytest.mark.parametrize("kw,row", [
-    ({"decode_window": 4}, "decode_step"),
-    ({"decode_window": 4, "async_dispatch": True}, "decode_step"),
+    ({"async_dispatch": False}, "decode_step"),
     ({"spec_draft_len": 3}, "spec_verify"),
 ])
 def test_the_other_decode_paths_emit_the_same_names(model, kw, row):

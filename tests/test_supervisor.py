@@ -362,6 +362,17 @@ def _argv_for(tmp_path, addr):
     return argv
 
 
+def _after(tmp_path, victim, step):
+    """SUP_START_AFTER for a drill's healthy peers: take no lease
+    before the victim has checkpointed `step` tasks. The three
+    processes come up in any order and seconds apart under load, and
+    two peers drain a dozen 50 ms shards in well under a second: a
+    victim that came up last found the queue empty, met its fault
+    with nothing accumulated, and resumed from None (the hang drill
+    failed so about one run in two beside a loaded suite)."""
+    return "%s:%d" % (_worker_paths(tmp_path, victim)[1], step)
+
+
 def _read_out(tmp_path, wid):
     out, _ = _worker_paths(tmp_path, wid)
     with open(out) as f:
@@ -426,6 +437,8 @@ def test_supervisor_kill_recovery_exact(tmp_path):
         extra = {"SUP_TASK_SLEEP": "0.05"}
         if wid == victim:
             extra["PADDLE_FAULT"] = "kill@3"  # boundary-preempt: 2 tasks in
+        else:
+            extra["SUP_START_AFTER"] = _after(tmp_path, victim, 2)
         return _job_env(extra)
 
     sup = Supervisor(
@@ -491,6 +504,8 @@ def test_supervisor_hang_detected_and_recovered(tmp_path):
         extra = {"SUP_TASK_SLEEP": "0.05"}
         if wid == victim:
             extra["PADDLE_FAULT"] = "hang@2"  # 1 task in, then livelock
+        else:
+            extra["SUP_START_AFTER"] = _after(tmp_path, victim, 1)
         return _job_env(extra)
 
     sup = Supervisor(

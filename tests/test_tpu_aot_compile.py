@@ -243,20 +243,43 @@ def _engine(one_chip, **kw):
 
 def _decode_text(eng, params, cache, bands, sds):
     """The engine's one decode program, compiled for the chip."""
-    extra = ((sds((S,), jnp.int32), sds((S,), jnp.int32))
-             if eng._use_window else ())  # limits, eos
-    return _compile(eng._decode_fn, params, cache, *bands, *extra)
+    limits_eos = (sds((S,), jnp.int32), sds((S,), jnp.int32))
+    return _compile(eng._decode_fn, params, cache, *bands, *limits_eos)
 
 
-@pytest.mark.parametrize("kw", [
-    {}, {"async_dispatch": False},
-    {"decode_window": 8, "async_dispatch": True}],
-    ids=["default", "lockstep", "window8"])
+def _without_locations(text):
+    """Compiled text less what names the source: the stack-frame
+    tables at its head, the ids into them, and op metadata."""
+    head = text.find("\nFileNames")
+    if head >= 0:
+        text = text[:head] + text[text.index("\n\n",
+                                             text.index("StackFrames")):]
+    text = re.sub(r",? ?stack_frame_id=\d+", "", text)
+    return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+
+@pytest.mark.parametrize("kw", [{}, {"async_dispatch": False}],
+                         ids=["default", "lockstep"])
 def test_engine_decode_step_compiles(one_chip, as_on_tpu, kw):
     eng, params, cache, bands, sds = _engine(one_chip, **kw)
     # one kernel call per layer
     assert _decode_text(eng, params, cache, bands,
                         sds).count("tpu_custom_call") >= 2
+
+
+def test_both_depths_compile_the_same_decode_program(one_chip, as_on_tpu):
+    """One program, two depths (ISSUE 29): the lock-step engine's
+    decode program is the default engine's, instruction for
+    instruction — `async_dispatch` chooses when the host reads a step,
+    never what the chip runs."""
+    texts = []
+    for kw in ({}, {"async_dispatch": False}):
+        eng, params, cache, bands, sds = _engine(one_chip, **kw)
+        texts.append(_without_locations(
+            _decode_text(eng, params, cache, bands, sds)))
+        assert eng.async_dispatch == (not kw)
+    assert texts[0] == texts[1]
+    assert texts[0].count("tpu_custom_call") >= 2
 
 
 def test_default_decode_program_is_what_the_benchmark_reads(one_chip,
@@ -267,11 +290,10 @@ def test_default_decode_program_is_what_the_benchmark_reads(one_chip,
     their `program_match` (read from
     benchmarks/chip/layer_metrics/decode_step_ms.json, never edited)
     matches it as a device trace names it, `jit_<function>(<id>)`, and
-    FLAT: at K = 1 the step body is called directly, so the kernel's
-    custom call sits in the entry computation, once a layer, where
-    `op_match` finds it — not inside a one-trip loop's body."""
+    FLAT: the kernel's custom call sits in the entry computation, once
+    a layer, where `op_match` finds it — not inside a loop's body."""
     eng, params, cache, bands, sds = _engine(one_chip)
-    assert eng.async_dispatch and eng.decode_window == 1
+    assert eng.async_dispatch
     text = _decode_text(eng, params, cache, bands, sds)
     module = re.match(r"HloModule (\S+?),", text).group(1)
     program = _metric_spec("decode_step_ms")["args"]["program_match"]
@@ -409,6 +431,46 @@ def _metric_pattern(name):
     return re.compile(_metric_spec(name)["args"]["op_match"])
 
 
+def _hybrid_decode_text(eng, params, cache, sds):
+    bands = (sds((2, HY_S, HY_MAXB), jnp.int32), sds((HY_S,), jnp.int32),
+             sds((HY_S,), jnp.int32), sds((HY_S,), jnp.bool_),
+             sds((HY_S,), jnp.float32), sds((HY_S,), jnp.int32),
+             sds((HY_S, 2), jnp.uint32), sds((HY_S,), jnp.int32),
+             sds((HY_S,), jnp.int32))  # ..., limits, eos
+    return _compile(eng._decode_fn, params, cache, *bands)
+
+
+def test_hybrid_decode_program_is_the_one_the_benchmark_finds(one_chip,
+                                                              as_on_tpu):
+    """The hybrid family rides the shared loop (ISSUE 29), lock-step:
+    its decode program is built by the one `_make_decode`, so at the
+    cell's geometry (64 slots, 8,192 positions in blocks of 32) it is
+    still the program `decode_step_ms` and `hybrid_attn_roofline` look
+    for — their `program_match` (read from the metric files, never
+    edited) matches the module as a device trace names it — and FLAT:
+    no loop, both kernels' calls in the entry computation where
+    `op_match` finds them, one packed result beside the cache and the
+    four advanced bands."""
+    eng, params, cache, sds = _hybrid_engine(one_chip)
+    assert not eng.async_dispatch and eng._win is not None
+    text = _hybrid_decode_text(eng, params, cache, sds)
+    module = re.match(r"HloModule (\S+?),", text).group(1)
+    for metric in ("decode_step_ms", "hybrid_attn_roofline",
+                   "ssm_decode_roofline"):
+        program = _metric_spec(metric)["args"]["program_match"]
+        assert re.search(program, module + "(1)"), (metric, module)
+    assert " while(" not in text
+    entry = text[text.index("\nENTRY "):]
+    lines = [ln.strip() for ln in entry.split("\n")]
+    for metric, calls in (("hybrid_attn_roofline", 4),
+                          ("ssm_decode_roofline", 3)):
+        rx = _metric_pattern(metric)
+        assert len([ln for ln in lines if rx.search(ln)]) == calls, metric
+    # the host's one read: tokens, trap flags, magnitude, four bands
+    assert re.search(r"s32\[%d\]" % (6 * HY_S + 1), entry)
+    assert eng.metrics.decode_trace_count() == 1
+
+
 def test_hybrid_decode_step_compiles_and_is_what_the_benchmark_reads(
         one_chip, as_on_tpu):
     """The decode program of the hybrid cell compiles for the chip with
@@ -420,11 +482,7 @@ def test_hybrid_decode_step_compiles_and_is_what_the_benchmark_reads(
     and 3 state updates, each named after its kernel in
     `kernel_metadata`. The files are read, never edited."""
     eng, params, cache, sds = _hybrid_engine(one_chip)
-    bands = (sds((2, HY_S, HY_MAXB), jnp.int32), sds((HY_S,), jnp.int32),
-             sds((HY_S,), jnp.int32), sds((HY_S,), jnp.bool_),
-             sds((HY_S,), jnp.float32), sds((HY_S,), jnp.int32),
-             sds((HY_S, 2), jnp.uint32))
-    text = _compile(eng._decode_fn, params, cache, *bands)
+    text = _hybrid_decode_text(eng, params, cache, sds)
     lines = [ln.strip() for ln in text.split("\n")]
     for metric, kernel, calls in (
             ("hybrid_attn_roofline", "hybrid_decode_attention", 4),
