@@ -678,12 +678,19 @@ class _Serving(object):
     cache, its two compiled bodies, and the engine options it cannot
     honour. This family keeps recurrent state, which block aliasing
     cannot restore, so everything that re-uses or re-plays cached
-    blocks is refused by name (ROADMAP B.I.5 keeps the snapshots)."""
+    blocks is refused by name (ROADMAP B.I.5 keeps the snapshots).
+    The decode loop's depth is not among them (ISSUE 30): the window's
+    first position is read off the device's own `pos` band in
+    `paged_decode_step`, and the engine advances the window tables
+    for the position the dispatched step writes, so a default engine
+    runs this family one step ahead of the host like the GPT block.
+    Speculation stays refused, so the family never reaches the
+    verify step."""
     name = "sambay"
     hybrid = True
     refused = ("prefix_cache_tokens", "kv_store", "spec_draft_len",
-               "async_dispatch", "kv_quant", "weight_quant",
-               "adapter_registry", "kv_fingerprints")
+               "kv_quant", "weight_quant", "adapter_registry",
+               "kv_fingerprints")
     cache_bytes = staticmethod(cache_bytes)
     reset_slot_state = staticmethod(reset_slot_state)
 

@@ -317,9 +317,12 @@ class ServingEngine(object):
     (`decode_dispatched_ahead` / `decode_chain_breaks` in the metrics
     count both). False: dispatch and read in the same `step()` — what
     None resolves to under speculation (acceptance is a host decision
-    after every verify) and for a family whose seam refuses
-    `async_dispatch` (the hybrid family decides its window release on
-    the host every step).
+    after every verify) and nowhere else. The hybrid family runs ahead
+    like the GPT block (ISSUE 30): its window tables are advanced at
+    each dispatch for the position THAT step writes — the host
+    mirror's, or one past it when the step is chained — so a release
+    is never decided further ahead than the step being dispatched
+    (`_dispatch_decode` says why that is safe).
 
     Serving integrity (ISSUE 15): `integrity_traps` (default True)
     folds a per-slot non-finite trap — logits + softmax-denominator
@@ -440,8 +443,9 @@ class ServingEngine(object):
         # N run under N+1's device time; emission then runs one step
         # behind. None (the default) = ahead wherever the engine can:
         # not under speculation (its acceptance is a host decision
-        # after every verify), not for a family whose seam refuses it
-        # (its window tables advance on the host every step). False =
+        # after every verify), nor for a family whose seam refuses it
+        # (none does: the hybrid family's window tables advance for
+        # the position the dispatched step writes, ISSUE 30). False =
         # lock-step: a token leaves the engine in the step() that
         # computed it.
         if async_dispatch is None:
@@ -2110,7 +2114,31 @@ class ServingEngine(object):
         chains this dispatch off the given un-read step's output bands
         (host mirrors are one step stale then — the block horizon
         covers 2 positions so the device never writes past the
-        table). Its packed result starts for the host at once."""
+        table). Its packed result starts for the host at once.
+
+        A family with window tables (ISSUE 30) has them advanced here
+        for the position THIS step writes, `pos + horizon - 1`: the
+        mirror's on a fresh dispatch, one past it on a chained one
+        (every host-live slot advances by exactly one in the step in
+        flight, or retires in it). Never further ahead than the step
+        being dispatched, which is why running a step ahead is safe:
+
+        * a block released here may still be named by the table of
+          the step in flight. Each dispatch carries its own uploaded
+          snapshot of `tables`, the device runs programs in order, and
+          whatever takes the block next (another slot's write in this
+          step, a later chunk) is queued behind the step in flight;
+        * a slot the step in flight retires on the device is still
+          host-live here. Its advance is wasted but harmless: it stays
+          inside the slot's reservation (`q < limits - 1`, as for the
+          full pool's blocks), the device parks the lane, and
+          `_free_slot_blocks` returns every block and the unreached
+          reservation when that step is read — `held(s) <= per_slot`
+          at every dispatch;
+        * a chain break reads first, and the fresh dispatch advances
+          at the now-current `pos`: every position is visited once, in
+          order (the edge test below relies on it), and a position
+          visited twice finds `advance` idempotent."""
         live = np.nonzero(self._alive)[0]
         horizon = 2 if prev is not None else 1
         m = self.metrics
@@ -2124,16 +2152,16 @@ class ServingEngine(object):
                     s, p, min(p + horizon, int(self._limits[s]) - 1))
             if self._win is not None:
                 # a window table changes only where a write opens a
-                # block or the window's tail leaves one (this family
-                # refuses `async_dispatch`: the host mirrors are
-                # current, the write is at pos)
-                Bt, p = self.kv_block_tokens, self._pos[live]
-                edge = (p % Bt == 0) | ((p + 1 - self._win.window) % Bt
-                                        == 0)
+                # block or the window's tail leaves one; `q` is the
+                # position this step writes (a slot on its last write
+                # in the step in flight has none)
+                Bt, q = self.kv_block_tokens, self._pos[live] + (horizon - 1)
+                edge = ((q % Bt == 0) | ((q + 1 - self._win.window) % Bt
+                                         == 0)) & (q < self._limits[live] - 1)
                 if edge.any():
                     self._advance_window(
-                        [(s, q, q + 1) for s, q in zip(live[edge],
-                                                       p[edge])])
+                        [(s, w, w + 1) for s, w in zip(live[edge],
+                                                       q[edge])])
         rest = ("tables", "temps", "base_keys", "limits", "eos", "aidx")
         if prev is None:
             tok_d, pos_d, alive_d, counts_d, *rest_d = self._bands(

@@ -300,8 +300,7 @@ def test_a_reused_slot_starts_from_zero_state(ref, cfg, params):
 
 @pytest.mark.parametrize("option,value", [
     ("prefix_cache_tokens", 64), ("kv_store", object()),
-    ("spec_draft_len", 4), ("async_dispatch", True),
-    ("kv_quant", "int8"), ("weight_quant", "int8"),
+    ("spec_draft_len", 4), ("kv_quant", "int8"), ("weight_quant", "int8"),
     ("adapter_registry", object()), ("kv_fingerprints", True)])
 def test_each_unsupported_option_is_refused_by_name(cfg, params, option,
                                                     value):
@@ -310,8 +309,14 @@ def test_each_unsupported_option_is_refused_by_name(cfg, params, option,
 
 
 # ---------------------------------------------------------------------
-# the family on the engine's one decode loop, lock-step (ISSUE 29)
+# the family on the engine's one decode loop (ISSUE 29), at both of its
+# depths (ISSUE 30): one step ahead of the host (what None resolves to,
+# as for the GPT block) and lock-step
 # ---------------------------------------------------------------------
+
+DEPTHS = pytest.mark.parametrize("depth", [None, False],
+                                 ids=["ahead", "lockstep"])
+
 
 def _assert_reference_greedy(ref, params, prompt, served):
     """Every served token is the reference's argmax at its position."""
@@ -333,41 +338,71 @@ def _slot_of(eng, h):
     return next(s for s, hh in enumerate(eng._slot_req) if hh is h)
 
 
-def test_the_family_rides_the_shared_loop_lockstep(cfg, params):
+def _pools_are_whole(eng):
+    """Every block and reservation of the full layer's pool and of the
+    window pools is back."""
+    return all(a.blocks_in_use == 0 and a.reserved == 0
+               for a in (eng._alloc, eng._win.alloc))
+
+
+@DEPTHS
+def test_the_family_rides_the_shared_loop_at_both_depths(ref, cfg, params,
+                                                         depth):
     """No second program or loop for the family: its decode program is
-    the engine's one `_make_decode` (limits and EOS bands in, one
-    packed result out), the loop's depth is lock-step because the seam
-    refuses `async_dispatch` — and only that and what re-uses cached
-    blocks: `decode_window` is no option of any engine."""
-    eng = _engine(params, cfg)
-    assert not eng.async_dispatch and eng._inflight is None
-    assert "async_dispatch" in sb.SERVING.refused
+    the engine's one `_make_decode`, and the loop's depth is the GPT
+    block's — None resolves to one step ahead, because the window
+    tables advance for the position the dispatched step writes (the
+    seam refuses only what re-uses cached blocks; `decode_window` is
+    no option of any engine). Over the 12-token window and a dozen
+    block edges a slot, at either depth: the reference's greedy
+    tokens, at most ceil(W / Bt) + 1 window blocks a slot at every
+    step, nothing drawn past a slot's reservation, all of it back."""
+    eng = _engine(params, cfg, async_dispatch=depth)
+    assert eng.async_dispatch == (depth is None) and eng._inflight is None
+    assert "async_dispatch" not in sb.SERVING.refused
+    assert "spec_draft_len" in sb.SERVING.refused
     assert "decode_window" not in sb.SERVING.refused
     with pytest.raises(TypeError, match="decode_window"):
         _engine(params, cfg, decode_window=4)
-    hs = [eng.submit(p, 14) for p in _prompts(10, 9, 21)]
-    while min(len(h.tokens) for h in hs) < 4:
-        eng.step()
-        assert eng._inflight is None  # read in the step that dispatched
+    prompts, budgets = _prompts(10, 9, 21, 14), (40, 30, 50)
+    hs = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+    win, bound = eng._win, 12 // BT + 1
+    assert win.per_slot == bound
+    while eng.step():
+        assert all(win.held(s) <= bound for s in range(SLOTS))
+        assert (win._tail >= 0).all()
+        if depth is False:
+            assert eng._inflight is None  # read in the step that dispatched
     m = eng.metrics
-    assert m.decode_dispatched_ahead == 0 and m.decode_chain_breaks == 0
-    eng.run()
-    assert m.decode_trace_count() == 1
+    if depth is None:
+        # every step but the one after each admission wave and the
+        # retirements' was dispatched before its predecessor was read
+        assert m.decode_dispatched_ahead > 0.8 * m.decode_steps
+    else:
+        assert m.decode_dispatched_ahead == 0 and m.decode_chain_breaks == 0
+    for prompt, h, n in zip(prompts, hs, budgets):
+        assert len(h.tokens) == n and h.finish_reason == "budget"
+        _assert_reference_greedy(ref, params, prompt, h.tokens)
+    assert m.decode_trace_count() == 1 and m.window_blocks_released > 0
+    assert _pools_are_whole(eng)
 
 
-def test_eos_mid_run_serves_the_references_tokens(ref, cfg, params):
+@DEPTHS
+def test_eos_mid_run_serves_the_references_tokens(ref, cfg, params, depth):
     """A request whose EOS lands mid-run is retired ON THE DEVICE by
-    the shared program (the lock-step program left it to the host):
-    its tokens are the reference's up to and including the EOS, its
-    neighbour's and the slot's next tenant's are the reference's, and
-    every block of the three caches is back at the end."""
+    the shared program (the lock-step program left it to the host) —
+    one step ahead, while the step chained off it is already queued
+    with the slot's window advanced one position further: its tokens
+    are the reference's up to and including the EOS, its neighbour's
+    and the slot's next tenant's are the reference's, and every block
+    of the three caches is back at the end."""
     pa_, pb, pc = _prompts(11, 19, 30, 7)
     probe = _engine(params, cfg, prefill_chunk_tokens=16)
     hp = probe.submit(pa_, 16)
     probe.run()
     eos = int(hp.tokens[5])
     n_eos = list(hp.tokens).index(eos) + 1
-    eng = _engine(params, cfg, prefill_chunk_tokens=16)
+    eng = _engine(params, cfg, prefill_chunk_tokens=16, async_dispatch=depth)
     ha, hb = eng.submit(pa_, 16, eos_id=eos), eng.submit(pb, 20)
     while not ha.done:
         eng.step()
@@ -379,21 +414,24 @@ def test_eos_mid_run_serves_the_references_tokens(ref, cfg, params):
         _assert_reference_greedy(ref, params, prompt, h.tokens)
     assert (len(hb.tokens), len(hc.tokens)) == (20, 9)
     assert eng.metrics.decode_trace_count() == 1
-    assert eng._alloc.blocks_in_use == 0 and eng._alloc.reserved == 0
-    assert eng._win.alloc.blocks_in_use == 0 and eng._win.alloc.reserved == 0
+    assert _pools_are_whole(eng)
 
 
+@DEPTHS
 @pytest.mark.parametrize("how", ["cancel", "expire"])
 def test_cancel_and_expiry_mid_decode_keep_the_references_tokens(
-        ref, cfg, params, how):
+        ref, cfg, params, how, depth):
     """A request cancelled, or expired, between two decode steps keeps
-    the tokens already read — a prefix of the reference's — and its
-    window blocks and recurrent state do not leak into the neighbour
-    or into the slot's next tenant."""
+    the tokens already read — a prefix of the reference's; one step
+    ahead the lane of the step in flight is discarded — and its window
+    blocks and recurrent state do not leak into the neighbour or into
+    the slot's next tenant: the chain breaks, the step in flight is
+    read, and the fresh dispatch advances the tables at the mirrors'
+    now-current positions."""
     import time
 
     pa_, pb, pc = _prompts(12, 17, 26, 11)
-    eng = _engine(params, cfg)
+    eng = _engine(params, cfg, async_dispatch=depth)
     ha = eng.submit(pa_, 30, deadline_at=time.monotonic() + 3600.0)
     hb = eng.submit(pb, 18)
     while len(ha.tokens) < 7:
@@ -412,7 +450,9 @@ def test_cancel_and_expiry_mid_decode_keep_the_references_tokens(
     for prompt, h in ((pa_, ha), (pb, hb), (pc, hc)):
         _assert_reference_greedy(ref, params, prompt, h.tokens)
     assert (len(hb.tokens), len(hc.tokens)) == (18, 10)
-    assert eng._win.alloc.blocks_in_use == 0 and eng._win.alloc.reserved == 0
+    if depth is None:
+        assert eng.metrics.decode_chain_breaks > 0
+    assert _pools_are_whole(eng)
 
 
 def _steady(params, cfg, n_new=40):
@@ -486,56 +526,83 @@ def test_a_trapped_hybrid_step_emits_nothing_of_itself(ref, cfg, params):
         _assert_reference_greedy(ref, params, prompt, h.tokens)
 
 
+RETIRE_AT = 9  # the token a request is retired at, one way or another
+
+
+def _serve_and_retire(params, cfg, kind, depth, eos=None):
+    """A request retired at its RETIRE_AT-th token — by its EOS or its
+    budget (on the device) or by a cancel (on the host) — beside a
+    neighbour that lives on -> (finish reason, what the slot and the
+    neighbour were left as five steps later). The engine is then
+    drained and every pool must be whole."""
+    pn, pa_ = _prompts(14, 22, 13)
+    n = RETIRE_AT
+    eng = _engine(params, cfg, async_dispatch=depth)
+    hn = eng.submit(pn, 40)  # the neighbour: alive throughout
+    kw = {"eos_id": eos} if kind == "eos" else {}
+    ha = eng.submit(pa_, n if kind == "budget" else 30, **kw)
+    s = None
+    while not ha.done:
+        eng.step()
+        s = _slot_of(eng, ha) if not ha.done else s
+        if kind == "cancel" and len(ha.tokens) == n:
+            eng.cancel(ha.rid)
+    for _ in range(5):
+        eng.step()  # the neighbour steps on; the slot stays parked
+    assert not hn.done
+    state = [np.asarray(a[s]).copy() for a in
+             jax.tree_util.tree_leaves(eng._cache["ssm"])]
+    win = eng._win
+    # all the window pool holds is the neighbour's, within its bound
+    assert win.alloc.blocks_in_use == win.held(_slot_of(eng, hn)) \
+        <= win.per_slot
+    left = (list(ha.tokens), state, win.tables[s].copy(), win.held(s),
+            list(hn.tokens))
+    eng.run()
+    assert _pools_are_whole(eng)
+    return ha.finish_reason, left
+
+
+@pytest.fixture(scope="module")
+def host_retired(cfg, params):
+    """-> (an EOS id whose first occurrence is the request's
+    RETIRE_AT-th token, the request's tokens left alone, what a
+    lock-step cancel at that token leaves)."""
+    probe = _engine(params, cfg, async_dispatch=False)
+    hp = probe.submit(_prompts(14, 22, 13)[1], 30)
+    probe.run()
+    n = RETIRE_AT
+    assert hp.tokens[n - 1] not in hp.tokens[:n - 1]
+    return (int(hp.tokens[n - 1]), list(hp.tokens),
+            _serve_and_retire(params, cfg, "cancel", False))
+
+
+@DEPTHS
 @pytest.mark.parametrize("how", ["eos", "budget"])
 def test_a_device_retired_slot_leaves_what_a_host_retired_one_did(
-        cfg, params, how):
-    """EOS and budget are decided on the device now. The slot such a
+        cfg, params, host_retired, how, depth):
+    """EOS and budget are decided on the device. The slot such a
     request leaves — its recurrent state, its conv window, its window
     table and pool blocks — is bit for bit what the same request
-    leaves when the HOST retires it at the same token (a cancel), also
-    after its neighbour has stepped on past it."""
-    pn, pa_ = _prompts(14, 22, 13)
-    n = 9
-
-    def serve(kind):
-        eng = _engine(params, cfg)
-        hn = eng.submit(pn, 40)  # the neighbour: alive throughout
-        kw = {}
-        if kind == "eos":
-            kw["eos_id"] = eos
-        ha = eng.submit(pa_, n if kind == "budget" else 30, **kw)
-        s = None
-        while not ha.done:
-            eng.step()
-            s = _slot_of(eng, ha) if not ha.done else s
-            if kind == "cancel" and len(ha.tokens) == n:
-                eng.cancel(ha.rid)
-        for _ in range(5):
-            eng.step()  # the neighbour steps on; the slot stays parked
-        assert not hn.done
-        state = [np.asarray(a[s]).copy() for a in
-                 jax.tree_util.tree_leaves(eng._cache["ssm"])]
-        return (list(ha.tokens), ha.finish_reason, state,
-                eng._win.tables[s].copy(), eng._win.held(s),
-                eng._win.alloc.blocks_in_use, list(hn.tokens))
-
-    probe = _engine(params, cfg)
-    hp = probe.submit(pa_, 30)
-    probe.run()
-    # an EOS id whose first occurrence is the n-th token
-    assert hp.tokens[n - 1] not in hp.tokens[:n - 1]
-    eos = int(hp.tokens[n - 1])
-    toks, reason, state, table, held, in_use, neighbour = serve(how)
-    h_toks, h_reason, h_state, h_table, h_held, h_in_use, h_neighbour = \
-        serve("cancel")
+    leaves when the HOST retires it at the same token (a lock-step
+    cancel), also after its neighbour has stepped on past it. One step
+    ahead the slot is retired on the device while the step chained off
+    it is already queued — its window advanced one position further
+    (not at all on a budget's last write: the clamp), its lane parked
+    there — and every window block and reservation still comes back
+    when the retiring step is read."""
+    eos, alone, (h_reason, host_left) = host_retired
+    reason, left = _serve_and_retire(params, cfg, how, depth, eos=eos)
+    toks, state, table, held, neighbour = left
+    h_toks, h_state, h_table, h_held, h_neighbour = host_left
     assert reason == how and h_reason == "cancelled"
-    assert toks == h_toks == list(hp.tokens[:n])
+    assert toks == h_toks == alone[:RETIRE_AT]
     assert len(state) == len(h_state) > 0
     for a, b in zip(state, h_state):
         assert np.array_equal(a, b)
     assert np.abs(state[0]).max() > 0  # the state of a served request
     assert (table == -1).all() and np.array_equal(table, h_table)
-    assert held == h_held == 0 and in_use == h_in_use
+    assert held == h_held == 0
     assert neighbour == h_neighbour
 
 
@@ -608,6 +675,105 @@ def test_window_tables_never_hold_more_than_the_bound(window, bt):
     assert win.released_total > 0
     for s in (0, 1):
         win.free(s)
+    assert win.alloc.blocks_in_use == 0 and win.alloc.reserved == 0
+
+
+def _one_ahead(win, s, pos, limit):
+    """The engine's rule for a chained dispatch (`_dispatch_decode`
+    with `prev`): the step in flight writes at `pos`, this one at
+    pos + 1, and nothing is written past limit - 2."""
+    q = pos + 1
+    if q < limit - 1:
+        win.advance(s, q, q + 1)
+
+
+@pytest.mark.parametrize("window,bt", [(12, 4), (33, 16), (10, 4), (7, 8)])
+def test_an_advance_ahead_of_a_commit_that_never_comes_is_returned(window,
+                                                                    bt):
+    """One step ahead the tables are advanced for position pos + 1
+    while the step that writes pos is in flight; if that step retires
+    the slot (an EOS), pos + 1 is never written. Wherever in a block
+    or a window the retirement falls, the slot has held no more than
+    its bound, drawn nothing past its reservation, and everything is
+    back after `free`."""
+    total = 4 * max(window, bt)
+    win = WindowBlockTables(2, -(-total // bt), bt, window)
+    bound = -(-window // bt) + 1
+    for stop in range(1, total - 1, max(1, bt // 4)):
+        assert win.admit(0, total) and win.admit(1, total)
+        win.advance(0, 0, 1)  # the fresh dispatch at the mirror's pos
+        win.advance(1, 0, total // 2)  # a neighbour's chunk: its own blocks
+        for pos in range(stop):
+            _one_ahead(win, 0, pos, total)
+            assert win.held(0) <= bound and win._tail[0] >= 0
+            # what the step in flight and the queued one attend is held
+            for p in range(max(0, pos + 2 - window), pos + 2):
+                assert win.tables[0, p // bt] >= 0
+        # the step at `stop - 1` retired the slot; `stop` stays unwritten
+        win.free(0)
+        assert win.held(0) == 0 and win._tail[0] == 0
+        assert win.alloc.blocks_in_use == win.held(1) <= bound
+        win.free(1)
+        assert win.alloc.blocks_in_use == 0 and win.alloc.reserved == 0
+
+
+@pytest.mark.parametrize("total", [5, 8, 9, 16, 17, 40])
+def test_a_slots_last_write_draws_nothing_past_its_reservation(total):
+    """A slot on its last write (position limit - 2) in the step in
+    flight has no position in the step chained off it: the rule skips
+    it, as `_ensure_blocks` does for the full pool, so a request
+    shorter than a window — whose reservation is its own
+    ceil(total / Bt) blocks, not the window's — never draws on a
+    neighbour's reservation, whichever way its length falls on a
+    block edge."""
+    bt, window = 4, 12
+    win = WindowBlockTables(2, 16, bt, window)
+    assert win.admit(0, total) and win.admit(1, 64)
+    other = int(win._tail[1])
+    reserved = int(win._tail[0])
+    assert reserved == min(win.per_slot, -(-total // bt))
+    win.advance(0, 0, 1)
+    for pos in range(total - 1):  # the writes: 0 .. limit - 2
+        _one_ahead(win, 0, pos, total)
+        assert 0 <= win._tail[0] and win.held(0) + win._tail[0] == reserved
+        assert win._tail[1] == other and win.held(1) == 0
+    # the last position any dispatch advanced for is limit - 2
+    assert win.tables[0, (total - 2) // bt] >= 0
+    assert (win.tables[0, (total - 2) // bt + 1:] < 0).all()
+    win.free(0)
+    win.free(1)
+    assert win.alloc.blocks_in_use == 0 and win.alloc.reserved == 0
+
+
+def test_a_block_released_ahead_may_go_to_another_slot_in_the_same_call():
+    """The dispatch of step N+1 releases the block slot 0's window
+    leaves and, in the same pass over the live slots, hands that very
+    block to slot 1, whose write opens one — while step N, in flight,
+    still names it in slot 0's row of ITS table. Safe on the device
+    (each dispatch carries its own snapshot; N+1's write is queued
+    behind N); on the host the tables never name a block twice, the
+    bound holds and the pool is whole at the end."""
+    bt, window = 4, 12
+    win = WindowBlockTables(2, 16, bt, window)
+    assert win.admit(0, 64) and win.admit(1, 64)
+    win.advance(0, 0, 14)  # a chunk: positions 0 .. 13
+    win.advance(1, 0, 8)   # positions 0 .. 7: the next write opens block 2
+    in_flight = win.tables.copy()  # step N's snapshot: slot 0 writes at 14
+    leaving = int(win.tables[0, 0])
+    assert leaving >= 0
+    released = win.released_total
+    # step N+1, one position ahead: slot 0 at 15, whose window (4 .. 15)
+    # leaves block 0; slot 1 at 8
+    assert win.advance(0, 15, 16) and win.advance(1, 8, 9)
+    assert win.released_total == released + 1
+    assert win.tables[0, 0] == -1 and win.tables[1, 2] == leaving
+    assert in_flight[0, 0] == leaving  # still named by the step in flight
+    live = win.tables[win.tables >= 0]
+    assert len(set(live.tolist())) == len(live) == win.alloc.blocks_in_use
+    assert max(win.held(0), win.held(1)) <= win.per_slot
+    assert not win.advance(0, 15, 16)  # visited twice: idempotent
+    win.free(0)
+    win.free(1)
     assert win.alloc.blocks_in_use == 0 and win.alloc.reserved == 0
 
 

@@ -267,19 +267,36 @@ def test_engine_decode_step_compiles(one_chip, as_on_tpu, kw):
                         sds).count("tpu_custom_call") >= 2
 
 
-def test_both_depths_compile_the_same_decode_program(one_chip, as_on_tpu):
-    """One program, two depths (ISSUE 29): the lock-step engine's
-    decode program is the default engine's, instruction for
+def _family_decode_text(family, one_chip, **kw):
+    """-> (engine, its decode program compiled for the chip), the GPT
+    block's or the hybrid family's."""
+    if family == "hybrid":
+        eng, params, cache, sds = _hybrid_engine(one_chip, **kw)
+        return eng, _hybrid_decode_text(eng, params, cache, sds)
+    eng, params, cache, bands, sds = _engine(one_chip, **kw)
+    return eng, _decode_text(eng, params, cache, bands, sds)
+
+
+@pytest.mark.parametrize("family", ["gpt", "hybrid"])
+def test_both_depths_compile_the_same_decode_program(one_chip, as_on_tpu,
+                                                     family):
+    """One program, two depths (ISSUE 29), for every family (ISSUE 30:
+    the hybrid family's default resolves to ahead too): the lock-step
+    engine's decode program is the default engine's, instruction for
     instruction — `async_dispatch` chooses when the host reads a step,
-    never what the chip runs."""
+    never what the chip runs — and its name still matches the pattern
+    `decode_step_ms` reads it by."""
     texts = []
     for kw in ({}, {"async_dispatch": False}):
-        eng, params, cache, bands, sds = _engine(one_chip, **kw)
-        texts.append(_without_locations(
-            _decode_text(eng, params, cache, bands, sds)))
+        eng, text = _family_decode_text(family, one_chip, **kw)
+        texts.append(_without_locations(text))
         assert eng.async_dispatch == (not kw)
+        assert (eng._win is not None) == (family == "hybrid")
     assert texts[0] == texts[1]
     assert texts[0].count("tpu_custom_call") >= 2
+    module = re.match(r"HloModule (\S+?),", texts[0]).group(1)
+    assert re.search(_metric_spec("decode_step_ms")["args"]["program_match"],
+                     module + "(1)")
 
 
 def test_default_decode_program_is_what_the_benchmark_reads(one_chip,
@@ -400,7 +417,7 @@ HY_S, HY_L, HY_NB, HY_BT = 64, 8192, 14336, 32
 HY_MAXB = HY_L // HY_BT
 
 
-def _hybrid_engine(one_chip):
+def _hybrid_engine(one_chip, **kw):
     from paddle_tpu.models import sambay as sb
     from paddle_tpu.serving import ServingEngine
 
@@ -410,7 +427,8 @@ def _hybrid_engine(one_chip):
     params = jax.eval_shape(
         lambda: sb.init_params(cfg, jax.random.PRNGKey(0)))
     eng = ServingEngine(params, cfg, max_slots=HY_S, kv_pool_blocks=4,
-                        kv_block_tokens=HY_BT, prefill_chunk_tokens=4096)
+                        kv_block_tokens=HY_BT, prefill_chunk_tokens=4096,
+                        **kw)
     assert eng.paged_kernel == "fused"
     cache = jax.eval_shape(
         lambda: sb.SERVING.init_cache(cfg, HY_NB, HY_BT, HY_S))
@@ -442,8 +460,9 @@ def _hybrid_decode_text(eng, params, cache, sds):
 
 def test_hybrid_decode_program_is_the_one_the_benchmark_finds(one_chip,
                                                               as_on_tpu):
-    """The hybrid family rides the shared loop (ISSUE 29), lock-step:
-    its decode program is built by the one `_make_decode`, so at the
+    """The hybrid family rides the shared loop (ISSUE 29), one step
+    ahead of the host by default like the GPT block (ISSUE 30): its
+    decode program is built by the one `_make_decode`, so at the
     cell's geometry (64 slots, 8,192 positions in blocks of 32) it is
     still the program `decode_step_ms` and `hybrid_attn_roofline` look
     for — their `program_match` (read from the metric files, never
@@ -452,7 +471,7 @@ def test_hybrid_decode_program_is_the_one_the_benchmark_finds(one_chip,
     `op_match` finds them, one packed result beside the cache and the
     four advanced bands."""
     eng, params, cache, sds = _hybrid_engine(one_chip)
-    assert not eng.async_dispatch and eng._win is not None
+    assert eng.async_dispatch and eng._win is not None
     text = _hybrid_decode_text(eng, params, cache, sds)
     module = re.match(r"HloModule (\S+?),", text).group(1)
     for metric in ("decode_step_ms", "hybrid_attn_roofline",
