@@ -1,0 +1,581 @@
+"""A Mamba-2 / grouped-query hybrid language model (ISSUE 31).
+
+The block family of granite-4.0-h-micro (`model_type`
+`granitemoehybrid`, dense: no routed experts): pre-RMSNorm residual
+layers whose mixer is a Mamba-2 (SSD) layer or, at the depths
+`layer_types` names, plain grouped-query attention; a SwiGLU MLP in
+every layer; a tied head; no positional encoding of any kind; and four
+published constants where other families have none:
+
+    x0     = embedding_multiplier * E[token]
+    x     <- x + residual_multiplier * mixer(RMSNorm(x))
+    x     <- x + residual_multiplier * MLP(RMSNorm(x))
+    logits = RMSNorm(x) E^T / logits_scaling
+    attention scores scaled by attention_multiplier (not 1/sqrt(dh))
+
+Mamba-2 layer (H heads of P channels, di = H P, N state columns, one
+B/C group): [z | xBC | dt] = h W_in (di | di + 2N | H); xBC through a
+causal depthwise conv of `d_conv` taps with bias, then silu, then
+split x [H, P], B [N], C [N]; dt = softplus(dt + dt_bias) [H];
+A = -exp(A_log) [H]; the recurrence of `parallel/ssd_update.py`;
+y += D[h] x; out = RMSNorm(y * silu(z)) W_out (the gate BEFORE the
+norm, which runs over all di channels).
+
+One stack (`_stack`) runs every mode; a mode is the `mixer` it hands
+the stack, dispatched on the layer's kind, as `sambay._stack` is:
+
+  forward               whole sequence, no cache, the SEQUENTIAL
+                        recurrence (the oracle of the cached modes)
+  paged_decode_step     one token a slot through the two caches
+  paged_prefill_chunk   a [C]-token chunk of ONE slot through them,
+                        the recurrence in its blocked matrix form
+
+The two caches (`init_cache`), served by ServingEngine through
+`SERVING` (`caches = ("paged", "state")`: no window tables):
+
+  kv    a paged pool an attention layer, all on the engine's ONE
+        block table: {"k", "v"} [NB, Bt * Hk/2, 2 dh]
+  ssm   per-slot state, no position axis: "s" [S, N, di] float32 (2 MB
+        a layer and slot at the published widths, the largest cache of
+        the family) and the last d_conv - 1 rows of the conv's input
+        "conv" [S, d_conv - 1, di + 2N]
+
+A pool row holds two K (or V) heads side by side, 2 dh = 128 wide,
+and a block's rows are (token, pair): the bytes of a `[Bt, Hk, dh]`
+block in that order, 3-D so that the device's 16 x 128 tiles are full
+where 64-wide rows would fill half of each (`sambay.py` says the same
+of its pools). The decode kernel scores a query against a whole row:
+it sits in the half its K head occupies and is zero in the other, and
+of the 2 dh-wide value read its own head's half is kept.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+
+from .sambay import _conv, _mlp, _pool_view, _scatter_chunk, _scatter_rows
+from .transformer import _paged_kernel_check
+
+__all__ = ["GraniteHybridConfig", "init_params", "forward", "init_cache",
+           "cache_bytes", "paged_decode_step", "paged_prefill_chunk",
+           "reset_slot_state", "param_count", "SERVING"]
+
+_NEG = -1e30  # finite mask fill (parallel/kernel_utils.NEG_INF)
+
+
+class GraniteHybridConfig:
+    def __init__(self, vocab=256, dim=64, heads=4, kv_heads=2, head_dim=16,
+                 layer_types=("mamba", "attention"), layers=None, mlp_mult=4,
+                 mamba_heads=8, mamba_head_dim=16, d_state=16, d_conv=4,
+                 chunk=256, embedding_multiplier=12.0,
+                 residual_multiplier=0.22, attention_multiplier=0.015625,
+                 logits_scaling=8.0, eps=1e-5, max_len=1024,
+                 dtype=jnp.float32):
+        if heads % kv_heads or kv_heads % 2:
+            raise ValueError(
+                "grouped queries share a K/V head and the pool holds two "
+                "K/V heads a row: heads %% kv_heads == 0, kv_heads even "
+                "(got %d, %d)" % (heads, kv_heads))
+        bad = set(layer_types) - {"mamba", "attention"}
+        if bad:
+            raise ValueError("layer_types holds %r" % sorted(bad))
+        self.vocab, self.dim, self.heads = vocab, dim, heads
+        self.kv_heads, self.dh = kv_heads, head_dim
+        self.rep = heads // kv_heads
+        self.kinds = tuple(layer_types)
+        self.layers = len(self.kinds)
+        if layers is not None and int(layers) != self.layers:
+            raise ValueError("layers %d, layer_types names %d"
+                             % (layers, self.layers))
+        self.mlp_mult = mlp_mult
+        self.mamba_heads, self.mamba_head_dim = mamba_heads, mamba_head_dim
+        self.d_inner = mamba_heads * mamba_head_dim
+        self.d_state, self.d_conv, self.chunk = d_state, d_conv, chunk
+        self.conv_dim = self.d_inner + 2 * d_state
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.residual_multiplier = float(residual_multiplier)
+        self.attention_multiplier = float(attention_multiplier)
+        self.logits_scaling = float(logits_scaling)
+        self.eps, self.max_len, self.dtype = eps, max_len, dtype
+        self.groups = kv_heads // 2  # pool rows a token: two heads each
+        self.serving = SERVING
+
+
+def _mixer_shapes(cfg, kind):
+    d, di, N, H = cfg.dim, cfg.d_inner, cfg.d_state, cfg.mamba_heads
+    if kind == "mamba":
+        return {"in_proj": (d, 2 * di + 2 * N + H),
+                "conv_w": (cfg.conv_dim, cfg.d_conv),
+                "conv_b": (cfg.conv_dim,), "dt_bias": (H,), "A_log": (H,),
+                "D": (H,), "norm": (di,), "out_proj": (di, d)}
+    return {"wqkv": (d, (cfg.heads + 2 * cfg.kv_heads) * cfg.dh),
+            "wo": (cfg.heads * cfg.dh, d)}
+
+
+def param_shapes(cfg: GraniteHybridConfig):
+    d, m = cfg.dim, cfg.mlp_mult * cfg.dim
+    return {"embed": (cfg.vocab, d), "norm_f": (d,), "blocks": [
+        {"norm1": (d,), "mixer": _mixer_shapes(cfg, kind), "norm2": (d,),
+         "w_gu": (d, 2 * m), "w_down": (m, d)} for kind in cfg.kinds]}
+
+
+def param_count(cfg: GraniteHybridConfig) -> int:
+    """Parameters of the tree `init_params` makes, from shapes alone."""
+    return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
+
+
+def init_params(cfg: GraniteHybridConfig, key) -> Dict[str, Any]:
+    """Seeded random weights in `cfg.dtype`: matrices N(0, 1/rows)
+    (the tied embedding N(0, 1/vocab): a larger one makes every token
+    predict itself through the tie), norm gains near 1, the conv uniform
+    +-d_conv^-1/2 with a bias near 0, and the Mamba-2 leaves by the
+    published initialisers (A uniform in [1, 16], dt bias the inverse
+    softplus of a log-uniform step in [1e-3, 1e-1], D = 1)."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+
+    def leaf(i, path, shp):
+        k = jax.random.fold_in(key, i)
+        name = str(getattr(path[-1], "key", "w"))
+        if name == "A_log":
+            return jnp.log(jax.random.uniform(k, shp, jnp.float32, 1.0, 16.0))
+        if name == "D":
+            return jnp.ones(shp, jnp.float32)
+        if name == "dt_bias":
+            dt = jnp.exp(jax.random.uniform(k, shp) * math.log(100.0)
+                         + math.log(1e-3))
+            return dt + jnp.log(-jnp.expm1(-dt))
+        if name == "conv_w":
+            bound = shp[1] ** -0.5
+            return jax.random.uniform(k, shp, jnp.float32, -bound, bound)
+        n = jax.random.normal(k, shp, jnp.float32)
+        if name.startswith("norm"):
+            return 1.0 + 0.1 * n
+        if len(shp) == 1:
+            return 0.1 * n
+        return n / math.sqrt(shp[0])
+
+    return jax.tree_util.tree_unflatten(treedef, [
+        leaf(i, path, shp).astype(cfg.dtype)
+        for i, (path, shp) in enumerate(flat)])
+
+
+# ---------------------------------------------------------------------
+# pieces every mode shares
+# ---------------------------------------------------------------------
+
+
+def _rms(x, w, eps):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _stack(params, x, cfg, mixer):
+    """Every layer in its residual form; `mixer(kind, h, p)` is the
+    mode's (it owns whatever cache the mode has). -> the final norm's
+    output."""
+    r = cfg.residual_multiplier
+    for blk, kind in zip(params["blocks"], cfg.kinds):
+        with jax.named_scope("granite_" + kind):
+            x = x + r * mixer(kind, _rms(x, blk["norm1"], cfg.eps),
+                              blk["mixer"])
+        with jax.named_scope("granite_mlp"):
+            x = x + r * _mlp(_rms(x, blk["norm2"], cfg.eps), blk)
+    return _rms(x, params["norm_f"], cfg.eps)
+
+
+def _embed(params, tokens, cfg):
+    return params["embed"][tokens] * cfg.embedding_multiplier
+
+
+def _head(params, x, cfg):
+    return jnp.matmul(x, params["embed"].T,
+                      preferred_element_type=jnp.float32) / cfg.logits_scaling
+
+
+def _split_in(h, p, cfg):
+    """h -> (z [.., di], xBC [.., di + 2N] before the conv, dt [.., H])."""
+    zxd = h @ p["in_proj"]
+    di, c = cfg.d_inner, cfg.conv_dim
+    return zxd[..., :di], zxd[..., di:di + c], zxd[..., di + c:]
+
+
+def _ssd_inputs(xbc, dt, p, cfg):
+    """The conv's output and the raw step -> what the recurrence
+    reads, in float32: (x [.., di], dt [.., H], A [H], B, C [.., N])."""
+    f32 = jnp.float32
+    di, N = cfg.d_inner, cfg.d_state
+    xbc = xbc.astype(f32)
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+    return (xbc[..., :di], dt, -jnp.exp(p["A_log"].astype(f32)),
+            xbc[..., di:di + N], xbc[..., di + N:])
+
+
+def _heads(v, cfg):  # [.., H] -> [.., di]: a head's value at its channels
+    return jnp.repeat(v, cfg.mamba_head_dim, axis=-1)
+
+
+def _mamba_out(y, x, z, p, cfg):
+    """The recurrence's output y (float32) -> the mixer's output: the
+    skip D x, the gate, the norm over all di channels, W_out."""
+    y = y + _heads(p["D"].astype(jnp.float32), cfg) * x
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    return _rms(y, p["norm"], cfg.eps).astype(z.dtype) @ p["out_proj"]
+
+
+def _split_qkv(h, p, cfg):
+    """-> q [.., Hk, rep, dh], k and v [.., Hk, dh]."""
+    qkv = h @ p["wqkv"]
+    nq, nk = cfg.heads * cfg.dh, cfg.kv_heads * cfg.dh
+    lead = h.shape[:-1]
+    return (qkv[..., :nq].reshape(lead + (cfg.kv_heads, cfg.rep, cfg.dh)),
+            qkv[..., nq:nq + nk].reshape(lead + (cfg.kv_heads, cfg.dh)),
+            qkv[..., nq + nk:].reshape(lead + (cfg.kv_heads, cfg.dh)))
+
+
+def _pairs(kv, cfg):  # [.., Hk, dh] -> [.., Hk/2, 2 dh], the pool's rows
+    return kv.reshape(kv.shape[:-2] + (cfg.groups, 2 * cfg.dh))
+
+
+def _attend(q, k, v, qpos, kpos, cfg):
+    """q [Q, Hk, rep, dh] at positions qpos [Q] over k, v [K, Hk, dh]
+    at positions kpos [K] -> [Q, Hk * rep * dh]: causal; a key at a
+    negative position is nobody's."""
+    f32 = jnp.float32
+    s = jnp.einsum("qhrd,khd->hrqk", q, k,
+                   preferred_element_type=f32) * cfg.attention_multiplier
+    ok = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
+    prob = jax.nn.softmax(jnp.where(ok[None, None], s, _NEG), axis=-1)
+    o = jnp.einsum("hrqk,khd->qhrd", prob.astype(v.dtype), v,
+                   preferred_element_type=f32).astype(q.dtype)
+    return o.reshape(o.shape[0], -1)
+
+
+# ---------------------------------------------------------------------
+# whole sequence, no cache
+# ---------------------------------------------------------------------
+
+
+def forward(params, tokens, cfg: GraniteHybridConfig):
+    """tokens [T] -> float32 logits [T, vocab]: the whole sequence at
+    once, no cache, no kernel, the recurrence row after row."""
+    from ..parallel.ssd_update import ssd_chunk_scan_reference
+
+    pos = jnp.arange(tokens.shape[0])
+
+    def mixer(kind, h, p):
+        if kind == "mamba":
+            z, xbc, dt = _split_in(h, p, cfg)
+            rows = jnp.concatenate(
+                [jnp.zeros((cfg.d_conv - 1, cfg.conv_dim), xbc.dtype), xbc])
+            x, dt, a, B, C = _ssd_inputs(_conv(rows, p), dt, p, cfg)
+            s0 = jnp.zeros((cfg.d_state, cfg.d_inner), jnp.float32)
+            _, y = ssd_chunk_scan_reference(s0, dt, x, a, B, C)
+            return _mamba_out(y, x, z, p, cfg)
+        q, k, v = _split_qkv(h, p, cfg)
+        return _attend(q, k, v, pos, pos, cfg) @ p["wo"]
+
+    return _head(params, _stack(params, _embed(params, tokens, cfg), cfg,
+                                mixer), cfg)
+
+
+# ---------------------------------------------------------------------
+# the two caches
+# ---------------------------------------------------------------------
+
+
+def init_cache(cfg: GraniteHybridConfig, num_blocks: int, block_tokens: int,
+               slots: int):
+    dt = cfg.dtype
+    rows, D = int(block_tokens) * cfg.groups, 2 * cfg.dh
+    # one block more than the allocator hands out: where the fused
+    # decode write sends a parked slot's rows (paged_kv_write)
+    shape = (int(num_blocks) + 1, rows, D)
+    return {
+        "kv": [{"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+               for k in cfg.kinds if k == "attention"],
+        "ssm": [{"s": jnp.zeros((slots, cfg.d_state, cfg.d_inner),
+                                jnp.float32),
+                 "conv": jnp.zeros((slots, cfg.d_conv - 1, cfg.conv_dim), dt)}
+                for k in cfg.kinds if k == "mamba"],
+    }
+
+
+def cache_bytes(cfg: GraniteHybridConfig, block_tokens: int) -> Dict[str, int]:
+    """Bytes of one block over all the attention layers' pools (they
+    share the table, so an allocated block is one in each), and of one
+    slot's state over all the Mamba-2 layers."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    kinds = cfg.kinds
+    return {"full": (kinds.count("attention") * 2 * block_tokens
+                     * cfg.kv_heads * cfg.dh * item),
+            "state": kinds.count("mamba") * (
+                4 * cfg.d_state * cfg.d_inner
+                + (cfg.d_conv - 1) * cfg.conv_dim * item)}
+
+
+def reset_slot_state(cache, slot):
+    """Zero one slot's recurrent state in every Mamba-2 layer: what a
+    request admitted to the slot must start from."""
+    return dict(cache, ssm=[
+        {"s": st["s"].at[slot].set(0.0), "conv": st["conv"].at[slot].set(0)}
+        for st in cache["ssm"]])
+
+
+def _view(pool, tab, cfg, Bt):
+    """The rows the table names, as positions and K/V heads:
+    [..., MAXB * Bt, Hk, dh]."""
+    v = _pool_view(pool, tab, cfg, Bt)
+    return v.reshape(v.shape[:-2] + (cfg.kv_heads, cfg.dh))
+
+
+# ---------------------------------------------------------------------
+# decode: one token a slot
+# ---------------------------------------------------------------------
+
+
+def _place_queries(q, cfg):
+    """q [S, Hk, rep, dh] -> [S, Hk/2, 2 rep, 2 dh]: K/V head 2g + j is
+    half j of pair g's row, so its `rep` queries sit in that half and
+    are zero in the other: one 2 dh-wide product against the row is
+    q . k of the query's own head."""
+    S = q.shape[0]
+    q = q.reshape(S, cfg.groups, 2, cfg.rep, 1, cfg.dh)
+    eye = jnp.eye(2, dtype=q.dtype)[None, None, :, None, :, None]
+    return (q * eye).reshape(S, cfg.groups, 2 * cfg.rep, 2 * cfg.dh)
+
+
+def _own_halves(o, cfg):
+    """o [S, Hk/2, 2 rep, 2 dh] (P V over the pair's row, every query)
+    -> [S, heads * dh]: of each read, the half that is the query's own
+    V head."""
+    S = o.shape[0]
+    o = o.reshape(S, cfg.groups, 2, cfg.rep, 2, cfg.dh)
+    o = jnp.stack([o[:, :, 0, :, 0], o[:, :, 1, :, 1]], axis=2)
+    return o.reshape(S, cfg.heads * cfg.dh)
+
+
+def paged_decode_step(params, token, pos, tables, cache,
+                      cfg: GraniteHybridConfig, kernel="gather"):
+    """One decode step through the two caches: token [S] at per-row
+    positions `pos` [S], `tables` [S, MAXB] -> (float32 logits
+    [S, vocab], updated cache). A parked row (pos >= MAXB * Bt) writes
+    no K/V and leaves its slot's state bit-identical; its logits are
+    garbage nothing reads. With kernel="fused" the attention reads and
+    writes and the state updates are Pallas kernels
+    (parallel/paged_attention.py: the grouped-query decode call with
+    `scale` = attention_multiplier; parallel/ssd_update.py); "gather"
+    is the same arithmetic in XLA."""
+    from ..parallel.paged_attention import (paged_decode_attention,
+                                            paged_kv_write)
+    from ..parallel.ssd_update import (ssd_state_update,
+                                       ssd_state_update_reference)
+
+    _paged_kernel_check(kernel)
+    S, maxb = tables.shape
+    Bt = cache["kv"][0]["k"].shape[1] // cfg.groups
+    live = pos < maxb * Bt
+    new = {"kv": [], "ssm": []}
+    it = {"kv": iter(cache["kv"]), "ssm": iter(cache["ssm"])}
+    update = (ssd_state_update if kernel == "fused"
+              else ssd_state_update_reference)
+
+    def mixer(kind, h, p):
+        if kind == "mamba":
+            st = next(it["ssm"])
+            z, xbc, dt = _split_in(h, p, cfg)
+            rows = jnp.concatenate([st["conv"], xbc[:, None]], axis=1)
+            xbc = jax.vmap(lambda r: _conv(r, p)[0])(rows)
+            x, dt, a, B, C = _ssd_inputs(xbc, dt, p, cfg)
+            s, y = update(st["s"], _heads(dt * a, cfg), _heads(dt, cfg) * x,
+                          B, C, live)
+            new["ssm"].append({"s": s, "conv": jnp.where(
+                live[:, None, None], rows[:, 1:], st["conv"])})
+            return _mamba_out(y, x, z, p, cfg)
+        kv = next(it["kv"])
+        q, k, v = _split_qkv(h, p, cfg)
+        k, v = _pairs(k, cfg), _pairs(v, cfg)
+        if kernel == "fused":
+            kv = dict(zip("kv", paged_kv_write(kv["k"], kv["v"], k, v,
+                                               tables, pos)))
+            o = _own_halves(paged_decode_attention(
+                _place_queries(q, cfg), kv["k"], kv["v"], tables, pos,
+                scale=cfg.attention_multiplier), cfg)
+        else:
+            kv = {"k": _scatter_rows(kv["k"], tables, pos, k, cfg, Bt),
+                  "v": _scatter_rows(kv["v"], tables, pos, v, cfg, Bt)}
+            kpos = jnp.arange(maxb * Bt)
+            o = jax.vmap(
+                lambda q1, k1, v1, p1: _attend(q1[None], k1, v1, p1[None],
+                                               kpos, cfg)[0]
+            )(q, _view(kv["k"], tables, cfg, Bt),
+              _view(kv["v"], tables, cfg, Bt), pos)
+        new["kv"].append(kv)
+        return o @ p["wo"]
+
+    x = _stack(params, _embed(params, token, cfg), cfg, mixer)
+    return _head(params, x, cfg), new
+
+
+# ---------------------------------------------------------------------
+# prefill: a chunk of one slot
+# ---------------------------------------------------------------------
+
+
+def _chunk_attend(q, k, v, start_pos, cfg):
+    """q [C, Hk, rep, dh], row i at position start_pos + i, over the
+    slot's span k, v [K, Hk, dh] (index = position) -> [C, heads * dh],
+    causal. Queries 512 rows at a time, keys 1,024 at a time with the
+    running (max, sum, acc) of an online softmax, and only the key
+    tiles up to a query tile's last position are walked (a trip count
+    read off `start_pos`), so the work follows the context and not
+    the table's span. One softmax over the whole span instead ran 130
+    times slower on the v5e at 8,192 positions (0.196 s a layer
+    against 1.5 ms: my chip run, PR 31): its reductions took the
+    contraction over dh off the matrix unit."""
+    f32 = jnp.float32
+    C, Hk, rep, dh = q.shape
+    K = k.shape[0]
+    tile = min(512, C)
+    KT = 1024 if K % 1024 == 0 else K
+    kh, vh = k.transpose(1, 0, 2), v.transpose(1, 0, 2)  # [Hk, K, dh]
+    batched = (((2,), (2,)), ((0,), (0,)))
+    outs = []
+    for i in range(0, C, tile):
+        qh = q[i:i + tile].transpose(1, 2, 0, 3).reshape(Hk, rep * tile, dh)
+        qpos = jnp.tile(start_pos + i + jnp.arange(tile), rep)
+
+        def body(j, state, qh=qh, qpos=qpos):
+            m, l, acc = state
+            ks = jax.lax.dynamic_slice_in_dim(kh, j * KT, KT, axis=1)
+            vs = jax.lax.dynamic_slice_in_dim(vh, j * KT, KT, axis=1)
+            s = jax.lax.dot_general(
+                qh, ks, batched,
+                preferred_element_type=f32) * cfg.attention_multiplier
+            kpos = j * KT + jnp.arange(KT)
+            s = jnp.where((kpos[None, :] <= qpos[:, None])[None], s, _NEG)
+            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            a = jnp.exp(m - m_new)
+            pv = jax.lax.dot_general(
+                p.astype(vs.dtype), vs, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=f32)
+            return m_new, l * a + p.sum(-1, keepdims=True), acc * a + pv
+
+        m0 = jnp.full((Hk, rep * tile, 1), _NEG, f32)
+        _, l, acc = jax.lax.fori_loop(
+            0, jnp.minimum((start_pos + i + tile + KT - 1) // KT, K // KT),
+            body, (m0, jnp.zeros_like(m0),
+                   jnp.zeros((Hk, rep * tile, dh), f32)))
+        o = (acc / l).reshape(Hk, rep, tile, dh).transpose(2, 0, 1, 3)
+        outs.append(o.reshape(tile, Hk * rep * dh).astype(q.dtype))
+    return jnp.concatenate(outs)
+
+
+def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
+                        cfg: GraniteHybridConfig, true_len=None,
+                        kernel="gather"):
+    """Extend ONE slot by a [C]-token chunk whose first row sits at
+    `start_pos` -> (float32 logits of row true_len - 1 [vocab],
+    updated cache). `table_rows` [2, MAXB]: the slot's row of the
+    block table, and a row whose first entry is the slot's index. Rows
+    past `true_len` pad the bucket: they do not advance the state
+    (dt = 0: a row that changes nothing), which leaves the chunk as
+    the state after row true_len - 1, carried from where the last
+    chunk left it, as the conv's last d_conv - 1 input rows are.
+
+    All of it is XLA in either `kernel`: the recurrence in its blocked
+    matrix form (`ssd_chunk_scan`, cfg.chunk rows a block), and the
+    attention over the slot's span gathered through the table after
+    the chunk's own rows are written (`_chunk_attend`), whatever
+    position the chunk starts at."""
+    from ..parallel.ssd_update import ssd_chunk_scan
+
+    _paged_kernel_check(kernel)
+    (C,) = chunk.shape
+    tab, slot = table_rows[0], table_rows[1, 0]
+    maxb = tab.shape[0]
+    Bt = cache["kv"][0]["k"].shape[1] // cfg.groups
+    if true_len is None:
+        true_len = C
+    offs = jnp.arange(C)
+    valid = offs < true_len
+    wpos = jnp.where(valid, start_pos + offs, jnp.int32(maxb * Bt))
+    new = {"kv": [], "ssm": []}
+    it = {"kv": iter(cache["kv"]), "ssm": iter(cache["ssm"])}
+
+    def mixer(kind, h, p):
+        if kind == "mamba":
+            st = next(it["ssm"])
+            z, xbc, dt = _split_in(h, p, cfg)
+            rows = jnp.concatenate([st["conv"][slot], xbc])
+            x, dt, a, B, C_ = _ssd_inputs(_conv(rows, p), dt, p, cfg)
+            dt = jnp.where(valid[:, None], dt, 0.0)
+            s, y = ssd_chunk_scan(st["s"][slot], dt, x, a, B, C_,
+                                  block=cfg.chunk)
+            # the conv's next window: the d_conv - 1 rows up to true_len
+            tail = jax.lax.dynamic_slice_in_dim(rows, true_len,
+                                                cfg.d_conv - 1)
+            new["ssm"].append({"s": st["s"].at[slot].set(s),
+                               "conv": st["conv"].at[slot].set(tail)})
+            return _mamba_out(y, x, z, p, cfg)
+        kv = next(it["kv"])
+        q, k, v = _split_qkv(h, p, cfg)
+        kv = {"k": _scatter_chunk(kv["k"], tab, start_pos, wpos, true_len,
+                                  _pairs(k, cfg), cfg, Bt),
+              "v": _scatter_chunk(kv["v"], tab, start_pos, wpos, true_len,
+                                  _pairs(v, cfg), cfg, Bt)}
+        new["kv"].append(kv)
+        o = _chunk_attend(q, _view(kv["k"], tab, cfg, Bt),
+                          _view(kv["v"], tab, cfg, Bt), start_pos, cfg)
+        return o @ p["wo"]
+
+    x = _stack(params, _embed(params, chunk, cfg), cfg, mixer)
+    xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0, keepdims=False)
+    return _head(params, xl, cfg), new
+
+
+class _Serving(object):
+    """What ServingEngine asks a model family for (the seam
+    `models/transformer.py` and `models/sambay.py` fill too). This
+    family's caches are the paged pools on the engine's one table and
+    per-slot recurrent state: no window tables. The state cannot be
+    restored by aliasing blocks, so everything that re-uses or
+    re-plays cached blocks is refused by name, as the SambaY family
+    refuses it (ROADMAP B.I.5 keeps the snapshots)."""
+    name = "granite_hybrid"
+    caches = ("paged", "state")
+    refused = ("prefix_cache_tokens", "kv_store", "spec_draft_len",
+               "kv_quant", "weight_quant", "adapter_registry",
+               "kv_fingerprints")
+    cache_bytes = staticmethod(cache_bytes)
+    reset_slot_state = staticmethod(reset_slot_state)
+
+    # the engine hands every family the same keywords; the ones this
+    # family refuses at construction arrive here as their defaults
+    @staticmethod
+    def decode_step(params, token, pos, tables, cache, cfg, adapters=None,
+                    adapter_idx=None, kernel="gather", kv_quant="none"):
+        return paged_decode_step(params, token, pos, tables, cache, cfg,
+                                 kernel=kernel)
+
+    @staticmethod
+    def prefill_chunk(params, cache, chunk, start_pos, table_rows, cfg,
+                      true_len=None, adapters=None, adapter_idx=None,
+                      kernel="gather", kv_quant="none"):
+        return paged_prefill_chunk(params, cache, chunk, start_pos,
+                                   table_rows, cfg, true_len=true_len,
+                                   kernel=kernel)
+
+    @staticmethod
+    def init_cache(cfg, num_blocks, block_tokens, slots, kv_quant="none"):
+        return init_cache(cfg, num_blocks, block_tokens, slots)
+
+
+SERVING = _Serving()

@@ -687,7 +687,7 @@ class _Serving(object):
     Speculation stays refused, so the family never reaches the
     verify step."""
     name = "sambay"
-    hybrid = True
+    caches = ("paged", "window", "state")
     refused = ("prefix_cache_tokens", "kv_store", "spec_draft_len",
                "kv_quant", "weight_quant", "adapter_registry",
                "kv_fingerprints")

@@ -1306,10 +1306,14 @@ __all__ += ["beam_search_generate"]
 class _Serving(object):
     """What ServingEngine asks a model family for (ISSUE 27): its
     cache, the bodies of its two compiled steps, and the engine options
-    it cannot honour. The GPT block honours them all; `models/sambay.py`
-    fills the same seam for the hybrid family."""
+    it cannot honour, and the caches it keeps (`caches`: "paged" is
+    the block pool on the engine's one table; "window" adds window
+    tables whose blocks are freed behind the window; "state" adds
+    per-slot recurrent state, zeroed at admission). The GPT block keeps
+    the pool alone and honours every option; `models/sambay.py` and
+    `models/granite_hybrid.py` fill the same seam for theirs."""
     name = "gpt"
-    hybrid = False
+    caches = ("paged",)
     refused = ()
     decode_step = staticmethod(paged_decode_step)
     prefill_chunk = staticmethod(paged_prefill_chunk)

@@ -288,18 +288,33 @@ class ServingEngine(object):
     folded into the compiled steps — the decode HBM roofline's weight
     term drops ~4x independently of the KV side.
 
-    Model families (ISSUE 27): the engine asks `cfg.serving` for the
-    cache and for the bodies of its two compiled steps
-    (`models/transformer.SERVING` for the GPT block,
-    `models/sambay.SERVING` for the hybrid family) — same scheduler,
-    allocator, side-bands, sampler, spans and program names either
-    way. A hybrid family brings two more caches the engine manages:
-    window-attention pools whose blocks are freed behind the window
-    (`kv_blocks.WindowBlockTables`, inside `engine.alloc_blocks`) and
-    per-slot recurrent state, zeroed at admission
-    (`engine.state_reset`). The options such a family cannot honour
-    (`cfg.serving.refused`; hand-off import at `submit`) raise a
-    ValueError that names the option; nothing is silently ignored.
+    Model families (ISSUE 27, 31): the engine asks `cfg.serving` for
+    the cache and for the bodies of its two compiled steps — same
+    scheduler, allocator, side-bands, sampler, spans and program names
+    whatever the family — and for the caches it keeps
+    (`cfg.serving.caches`), and builds those and no others:
+
+      models/transformer.SERVING      ("paged",): the GPT block, one
+                                      block pool on one table
+      models/sambay.SERVING           ("paged", "window", "state")
+      models/granite_hybrid.SERVING   ("paged", "state"): Mamba-2 +
+                                      grouped-query layers, a pool an
+                                      attention layer on the ONE table
+
+    "window": window-attention pools whose blocks are freed behind the
+    window (`kv_blocks.WindowBlockTables`, inside
+    `engine.alloc_blocks`; both tables then ride the steps as one
+    band). "state": per-slot recurrent state in the cache pytree,
+    zeroed at admission (`engine.state_reset`), counted by
+    `state_slots_reset` and, by kind beside the pools, in
+    `cache_bytes_in_use` / `cache_bytes_per_slot`; a prefill chunk is
+    told its slot's index in a row beside its table row. The options
+    a family cannot honour (`cfg.serving.refused`: both families with
+    state refuse the prefix cache, the KV store, speculation, KV and
+    weight quantization, adapters and fingerprints — block aliasing
+    cannot restore a recurrent state; hand-off import at `submit`)
+    raise a ValueError that names the option; nothing is silently
+    ignored.
 
     One decode program, one decode loop, two depths (ISSUE 29): the
     plain one-token decode is ONE compiled program (`_make_decode`)
@@ -563,18 +578,23 @@ class ServingEngine(object):
         # allocator's stats) — tlm.kv_block_bytes is the ONE formula,
         # shared with bench.py's byte-budget sizing and
         # bench_offline's roofline
-        # a hybrid family's other two caches (ISSUE 27): the window
-        # layers' tables, whose blocks are freed behind the window, and
-        # per-slot recurrent state, which lives in the cache pytree
+        # the caches a family declares beside the paged pool (ISSUE
+        # 27, 31): window layers' tables, whose blocks are freed behind
+        # the window, and per-slot recurrent state, which lives in the
+        # cache pytree and is zeroed at admission. Each is built and
+        # handled only for a family that has it
         self._win: Optional[WindowBlockTables] = None  # guarded-by: scheduler
+        self._has_state = "state" in fam.caches
+        self._state_bytes_per_slot = 0
         self._state_reset_fn = None
-        if fam.hybrid:
+        if self._has_state or "window" in fam.caches:
             sizes = fam.cache_bytes(cfg, Bt)
             block_bytes = sizes["full"]
-            self._state_bytes_per_slot = sizes["state"]
-            self._win = WindowBlockTables(
-                S, self.blocks_per_slot, Bt, cfg.window,
-                block_bytes=sizes["window"])
+            self._state_bytes_per_slot = sizes.get("state", 0)
+            if "window" in fam.caches:
+                self._win = WindowBlockTables(
+                    S, self.blocks_per_slot, Bt, cfg.window,
+                    block_bytes=sizes["window"])
         else:
             block_bytes = tlm.kv_block_bytes(
                 cfg.layers, cfg.heads, cfg.dim // cfg.heads, Bt, kv_quant,
@@ -944,7 +964,7 @@ class ServingEngine(object):
         if name in self._dirty:
             host = getattr(self, "_" + name)
             if name == "tables" and self._win is not None:
-                # the hybrid family's steps take both tables as one band
+                # a family with window layers takes both tables as one band
                 host = np.stack([host, self._win.tables])
             self._dev[name] = jnp.asarray(host)
             self._dirty.discard(name)
@@ -1235,9 +1255,9 @@ class ServingEngine(object):
 
     def _reset_slot_state(self, s: int):
         """A request admitted to slot `s` starts from zero recurrent
-        state (the hybrid family; the slot's last tenant left its
-        own), and from an empty window table."""
-        if (self._win.tables[s] >= 0).any():
+        state (the slot's last tenant left its own) and, where the
+        family has window layers, from an empty window table."""
+        if self._win is not None and (self._win.tables[s] >= 0).any():
             raise RuntimeError("slot %d admitted over a live window table"
                                % s)
         if self._state_reset_fn is None:
@@ -1345,7 +1365,7 @@ class ServingEngine(object):
             )
         if publish_len is not None and publish_len < 0:
             raise ValueError("publish_len must be >= 0 or None")
-        if handoff and self._family.hybrid:
+        if handoff and self._has_state:
             raise ValueError(
                 "handoff import is not supported for the %r model family: "
                 "imported K/V blocks cannot restore its recurrent state"
@@ -1657,7 +1677,7 @@ class ServingEngine(object):
             h.handoff_outcome = {"imported": h.handoff_imported,
                                  "fallback": h.handoff_fallback}
             h.handoff = None  # release the payload bytes
-        if self._win is not None:
+        if self._has_state:
             with self.metrics.phase("engine.state_reset", rid=h.rid):
                 self._reset_slot_state(s)
         self._n_alloc[s] = n_alias + n_imp
@@ -1769,6 +1789,11 @@ class ServingEngine(object):
                     table_row = np.stack([
                         table_row, wread, self._win.tables[s],
                         np.full_like(table_row, s)])
+                elif self._has_state:
+                    # rows [the table's, the slot's index]: the chunk
+                    # carries the slot's own state
+                    table_row = np.stack([table_row,
+                                          np.full_like(table_row, s)])
             padded = np.zeros(Cb, np.int32)
             padded[:c] = h.full_prompt[cursor:cursor + c]
             fn = self._chunk_fn(Cb)
@@ -2044,14 +2069,18 @@ class ServingEngine(object):
             (self._pos[alive] // self.kv_block_tokens + 1).sum())
         m.decode_blocks_walked += self.max_slots * self.blocks_per_slot
         win = self._win
-        if win is not None:
+        if win is not None or self._has_state:
+            # by kind of cache, the kinds the family has
             n_live = int(alive.sum())
-            m.cache_bytes_in_use = {
-                "full": self._alloc.blocks_in_use * self.kv_block_bytes,
-                "window": win.alloc.blocks_in_use * win.alloc.block_bytes,
-                "state": n_live * self._state_bytes_per_slot}
+            used = {"full": self._alloc.blocks_in_use * self.kv_block_bytes}
+            if win is not None:
+                used["window"] = (win.alloc.blocks_in_use
+                                  * win.alloc.block_bytes)
+            if self._has_state:
+                used["state"] = n_live * self._state_bytes_per_slot
+            m.cache_bytes_in_use = used
             m.cache_bytes_per_slot.append(
-                sum(m.cache_bytes_in_use.values()) / max(n_live, 1))
+                sum(used.values()) / max(n_live, 1))
 
     # ------------------------------------------------------------------
     # the decode loop: one program, read in the same step() or one later

@@ -533,3 +533,127 @@ def test_hybrid_prefill_chunk_compiles_at_the_largest_bucket(one_chip,
     # bounded: about 1 GB at 32 layers, the same here (they do not
     # add up over layers)
     assert mem.temp_size_in_bytes < 1.5e9
+
+
+# ---------------------------------------------------------------------
+# the Mamba-2 / grouped-query family (ISSUE 31) at the geometry of its
+# cell, `granite4hmicro_reason_closed`: 64 slots, 8,192 positions,
+# 11,264 blocks of 32 tokens, published widths; depth cut to one
+# 10-layer period, which holds both kinds of layer (9 Mamba-2, 1
+# attention)
+# ---------------------------------------------------------------------
+
+GR_S, GR_L, GR_NB, GR_BT, GR_CHUNK = 64, 8192, 11264, 32, 2048
+GR_MAXB = GR_L // GR_BT
+
+
+def _granite_engine(one_chip, **kw):
+    from paddle_tpu.models import granite_hybrid as gh
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = gh.GraniteHybridConfig(
+        vocab=100352, dim=2048, heads=32, kv_heads=8, head_dim=64,
+        layer_types=["mamba"] * 5 + ["attention"] + ["mamba"] * 4,
+        mlp_mult=4, mamba_heads=64, mamba_head_dim=64, d_state=128,
+        d_conv=4, chunk=256, max_len=GR_L, dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: gh.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = ServingEngine(params, cfg, max_slots=GR_S, kv_pool_blocks=4,
+                        kv_block_tokens=GR_BT, min_bucket=GR_CHUNK,
+                        prefill_chunk_tokens=GR_CHUNK, **kw)
+    assert eng.paged_kernel == "fused" and eng._win is None
+    cache = jax.eval_shape(
+        lambda: gh.SERVING.init_cache(cfg, GR_NB, GR_BT, GR_S))
+    return eng, _placed(params, one_chip), _placed(cache, one_chip), \
+        _sds(one_chip)
+
+
+def test_ssd_state_update_kernel_carries_its_name(one_chip):
+    """The one-token Mamba-2 state update at the cell's size (64 slots
+    of [128, 4096] float32) compiles for the chip, in place, as ONE
+    custom call named after the kernel, the name in `kernel_metadata`:
+    what `ssd_decode_roofline`'s `op_match` finds in a device trace."""
+    from paddle_tpu.parallel.ssd_update import ssd_state_update
+
+    sds = _sds(one_chip)
+    f32 = jnp.float32
+    text = _compile(
+        lambda *a: ssd_state_update(*a, interpret=False),
+        sds((GR_S, 128, 4096), f32), sds((GR_S, 4096), f32),
+        sds((GR_S, 4096), f32), sds((GR_S, 128), f32),
+        sds((GR_S, 128), f32), sds((GR_S,), jnp.bool_))
+    lines = [ln.strip() for ln in text.split("\n")]
+    found = [ln for ln in lines
+             if _metric_pattern("ssd_decode_roofline").search(ln)]
+    assert len(found) == 1 and " custom-call(" in found[0]
+    assert 'custom_call_target="tpu_custom_call"' in found[0]
+    assert re.search(r'kernel_metadata=\{\s*"kernel":"ssd_state_update"\s*\}',
+                     text)
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_granite_decode_program_is_the_one_the_benchmark_finds(one_chip,
+                                                               as_on_tpu):
+    """This family rides the shared loop too, one step ahead by
+    default: at the cell's geometry its decode program is the one
+    `decode_step_ms`, `ssd_decode_roofline` and `gqa_attn_roofline` look
+    for (their `program_match`, read from the metric files), FLAT, with
+    exactly the kernels' calls where `op_match` finds them: 9 state
+    updates and 1 grouped-query attention call at this depth, each
+    named after its kernel, beside the one K/V write; one packed
+    result for the host."""
+    eng, params, cache, sds = _granite_engine(one_chip)
+    assert eng.async_dispatch and eng._has_state
+    bands = (sds((GR_S, GR_MAXB), jnp.int32), sds((GR_S,), jnp.int32),
+             sds((GR_S,), jnp.int32), sds((GR_S,), jnp.bool_),
+             sds((GR_S,), jnp.float32), sds((GR_S,), jnp.int32),
+             sds((GR_S, 2), jnp.uint32), sds((GR_S,), jnp.int32),
+             sds((GR_S,), jnp.int32))  # ..., limits, eos
+    text = _compile(eng._decode_fn, params, cache, *bands)
+    module = re.match(r"HloModule (\S+?),", text).group(1)
+    for metric in ("decode_step_ms", "ssd_decode_roofline",
+                   "gqa_attn_roofline"):
+        program = _metric_spec(metric)["args"]["program_match"]
+        assert re.search(program, module + "(1)"), (metric, module)
+    assert " while(" not in text
+    entry = text[text.index("\nENTRY "):]
+    lines = [ln.strip() for ln in entry.split("\n")]
+    for metric, kernel, calls in (
+            ("ssd_decode_roofline", "ssd_state_update", 9),
+            ("gqa_attn_roofline", "hybrid_decode_attention", 1)):
+        rx = _metric_pattern(metric)
+        found = [ln for ln in lines if rx.search(ln)]
+        assert len(found) == calls, (metric, len(found))
+        assert all(ln.startswith("%" + kernel) and " custom-call(" in ln
+                   for ln in found)
+        assert len(re.findall(r'kernel_metadata=\{\s*"kernel":"%s"\s*\}'
+                              % kernel, text)) >= calls
+    assert len([ln for ln in lines if ln.startswith("%paged_kv_write")
+                and " custom-call(" in ln]) == 1
+    assert text.count("tpu_custom_call") == 11
+    # the mixers' and the MLP's named scopes, in this family's text only
+    for scope in ("granite_mamba", "granite_attention", "granite_mlp"):
+        assert scope in text
+    assert re.search(r"s32\[%d\]" % (6 * GR_S + 1), entry)
+    assert eng.metrics.decode_trace_count() == 1
+
+
+def test_granite_prefill_chunk_compiles_at_its_one_bucket(one_chip,
+                                                          as_on_tpu):
+    """The cell's one chunk program (2,048 rows; `min_bucket` makes it
+    the only one) compiles for the chip under its own name, the blocked
+    scan as a loop of matrix products, with bounded temporaries."""
+    eng, params, cache, sds = _granite_engine(one_chip)
+    assert eng._bucket(1) == eng._bucket(GR_CHUNK - 100) == GR_CHUNK
+    lower = eng._chunk_fn(GR_CHUNK).lower(
+        params, cache, sds((GR_CHUNK,), jnp.int32), sds((), jnp.int32),
+        sds((2, GR_MAXB), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.float32), sds((2,), jnp.uint32))
+    with jax.default_matmul_precision(None):
+        compiled = lower.compile()
+    text = compiled.as_text()
+    assert re.match(r"HloModule jit__chunk[,.]", text)
+    assert "granite_mamba" in text and " while(" in text
+    assert "ssd_state_update" not in text  # the decode step's kernel
+    # ~0.6 GB at 10 layers, 1.15 GB at 40 (weights' copies in flight)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
