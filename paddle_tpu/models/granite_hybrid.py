@@ -310,11 +310,14 @@ def init_cache(cfg: GraniteHybridConfig, num_blocks: int, block_tokens: int,
 def cache_bytes(cfg: GraniteHybridConfig, block_tokens: int) -> Dict[str, int]:
     """Bytes of one block over all the attention layers' pools (they
     share the table, so an allocated block is one in each), and of one
-    slot's state over all the Mamba-2 layers."""
+    slot's state over all the Mamba-2 layers; `call_block` is one
+    block of ONE pool, K + V: what a decode attention call moves a
+    table entry (its grid step is sized by it,
+    `parallel/paged_attention.py`)."""
     item = jnp.dtype(cfg.dtype).itemsize
     kinds = cfg.kinds
-    return {"full": (kinds.count("attention") * 2 * block_tokens
-                     * cfg.kv_heads * cfg.dh * item),
+    blk = 2 * block_tokens * cfg.kv_heads * cfg.dh * item
+    return {"full": kinds.count("attention") * blk, "call_block": blk,
             "state": kinds.count("mamba") * (
                 4 * cfg.d_state * cfg.d_inner
                 + (cfg.d_conv - 1) * cfg.conv_dim * item)}

@@ -356,11 +356,14 @@ def init_cache(cfg: SambaYConfig, num_blocks: int, block_tokens: int,
 
 def cache_bytes(cfg: SambaYConfig, block_tokens: int) -> Dict[str, int]:
     """Bytes of one block of the full pool, of one block over all the
-    window pools, and of one slot's state over all the Mamba layers."""
+    window pools, and of one slot's state over all the Mamba layers;
+    `call_block` is one block of ONE pool, K + V: what a decode
+    attention call moves a table entry (its grid step is sized by it,
+    `parallel/paged_attention.py`)."""
     item = jnp.dtype(cfg.dtype).itemsize
     blk = 2 * block_tokens * cfg.kv_heads * cfg.dh * item
     n_m = sum(k == "mamba" for k in cfg.kinds)
-    return {"full": blk,
+    return {"full": blk, "call_block": blk,
             "window": blk * sum(k == "window" for k in cfg.kinds),
             "state": n_m * cfg.d_inner * (4 * cfg.d_state
                                           + (cfg.d_conv - 1) * item)}

@@ -14,7 +14,9 @@ table and positions as SCALAR-PREFETCH operands
 (PrefetchScalarGridSpec), so the pipeline DMAs each group's G K/V
 blocks [Bt, H, Dh] straight from the pool buffer into VMEM (G blocks
 per step so the score tile spans G*Bt >= 128 tokens — the reference
-pages_per_compute_block idea) — the "gather" is the index map, and no
+pages_per_compute_block idea; the merged-pool decode call takes more
+where 128 tokens weigh little, see "What sizes a grid step" below) —
+the "gather" is the index map, and no
 HBM-resident contiguous view ever exists. Blockwise online softmax
 (running (max, sum, acc), the flash_attention.py discipline) keeps
 VMEM at one group of blocks plus the accumulators, regardless of
@@ -38,6 +40,31 @@ Two kernel bodies, chosen by the shape of the call and nothing else
     512 grid steps whatever the contexts; a parked slot 58 us against
     a live one's 24), this body 0.21 ms — 75 % of what the HBM allows
     (PERF.md section 5, PR 26).
+
+What sizes a grid step (ISSUE 32). A step of the decode body costs
+what its DMA costs or what its own work costs, whichever is more, and
+its own work has a part that does not shrink with the step (G copies
+issued and waited for, the (m, l, acc) read-modify-write, the
+`pl.when`s). `_group` sizes a step by tokens: 128 of them, which in
+the GPT pool (8,192 B a token, K + V) is 1 MiB and 1.28 us of DMA at
+the v5e's 819 GB/s — enough to hide the rest (82 % of the HBM
+roofline). A token of the hybrid families' merged pools weighs 5,120
+(SambaY) or 2,048 B (granite), so 128 tokens are 0.80 or 0.32 us of
+DMA, and the call read 76 % and 43 % of its roofline. The merged-pool
+caller therefore sizes a step by BYTES (`_bytes_group`): the fewest
+blocks, `_group`'s doubled, whose K + V reach `_STEP_BYTES` = 1 MiB —
+16 blocks (512 tokens) for granite's 64 KiB block, 8 (256 tokens) for
+SambaY's 160 KiB; a function of the pool's block shape and dtype, no
+option. Measured on the v5e, the kernel alone at the cells' geometry,
+64 slots at contexts of 1.5-4.7 k (`tools/time_decode_attention.py`;
+PERF.md section 6, PR 32), microseconds a call at G = 4, 8, 16, 32:
+granite 1,167, 971, 874, 854 (least 507); SambaY's shared pool 1,661,
+1,425, 1,441, 1,452 (least 1,267); a SambaY window pool 331, 325,
+369, 500 (least 206: past 8 a step's tile work outgrows a 512-token
+window). Cutting the masks, the NEG_INF `where`s and the hi + lo
+split out of the body moved a call by 1-6 %: the step's length beside
+its DMA is the fixed part and the two products, not the tile's
+element-wise work, so the body is as it was.
 
 Masking mirrors the gather primitives exactly: row r of a window based
 at `base` attends positions <= base + r, so unwritten depths — and the
@@ -388,11 +415,37 @@ _VMEM_BYTES = 16 << 20
 
 
 def _group(Bt: int, maxb: int) -> int:
-    """Table entries per grid step: enough for the per-head score tile
-    [R, G*Bt] to fill the 128-lane dim, capped at the whole table for
-    tiny configs (the score tile then equals the array dim, which
-    Mosaic also accepts)."""
+    """Table entries per grid step, by TOKENS: enough for the per-head
+    score tile [R, G*Bt] to fill the 128-lane dim, capped at the whole
+    table for tiny configs (the score tile then equals the array dim,
+    which Mosaic also accepts). What every call on a 4-D pool takes;
+    in the GPT cells' pool 128 tokens are 1 MiB of K + V, which is
+    what lets the decode step's DMA set its length."""
     return max(1, min(-(-128 // Bt), maxb))
+
+
+# K + V bytes a grid step of the merged-pool decode call carries at
+# least: what the GPT call's step carries (8 blocks of 16 tokens x
+# 8,192 B). Read on the v5e (module text; PERF.md section 6, PR 32):
+# granite's call, whose 128 tokens are 256 KiB, 1,167 us at 256 KiB a
+# step, 971 at 512 KiB, 874 at 1 MiB, 854 at 2 MiB; SambaY's, whose
+# are 640 KiB, 1,661 at 640 KiB, 1,425 at 1.25 MiB, 1,441 at 2.5 MiB,
+# its window call 331, 325, 369: past 1 MiB nothing is left to gain
+# and a window call loses
+_STEP_BYTES = 1 << 20
+
+
+def _bytes_group(Bt: int, maxb: int, block_bytes: int) -> int:
+    """Table entries per grid step of the merged-pool decode call, by
+    BYTES: the fewest blocks, `_group`'s doubled, whose K + V
+    (`block_bytes` a block: its rows x width x 2 x the dtype's size)
+    reach `_STEP_BYTES`, never more than the table holds. A step then
+    moves 1-2 MiB whatever a token weighs, and a pool whose 128 tokens
+    already weigh that much keeps `_group`'s."""
+    G = _group(Bt, maxb)
+    while G * block_bytes < _STEP_BYTES and 2 * G <= maxb:
+        G *= 2
+    return G
 
 
 def _row_tile(H: int, dh: int, W: int, q_itemsize: int,
@@ -420,17 +473,21 @@ def _smem_padded(rows: int, cols: int) -> int:
 
 
 def check_paged_smem(slots: int, maxb: int, block_tokens: int,
-                     heads: int, quant: bool):
+                     heads: int, quant: bool, block_bytes=None):
     """Refuse a geometry whose prefetch operands cannot fit scalar
     memory — at construction, with the arithmetic, instead of at the
     first step's compile. One kernel call prefetches the block tables
     [S, MAXB], the row bases [S] and, on a quantized pool, the two
     flat scale rows [S, MAXB*H] — all padded to SMEM's (8, 128) word
     tiles (a 1-D operand counted as one such row of tiles, which is
-    on the safe side), MAXB first padded to a whole number of groups."""
-    G = _group(block_tokens, maxb)
+    on the safe side), MAXB first padded to a whole number of groups.
+    `block_bytes` (K + V of one block) marks a merged 3-D pool, whose
+    only kernel call is the decode call at `_bytes_group`'s group."""
+    merged = block_bytes is not None
+    G = (_bytes_group(block_tokens, maxb, block_bytes) if merged
+         else _group(block_tokens, maxb))
     mb = -(-maxb // G) * G
-    need = _smem_padded(slots, mb) + _smem_padded(1, slots)
+    need = 0 if merged else _smem_padded(slots, mb) + _smem_padded(1, slots)
     if quant:
         need += 2 * _smem_padded(slots, mb * heads)
     else:
@@ -450,6 +507,14 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
             % (" and the KV scales" if quant else "", slots, maxb,
                " x (1 + 2 x %d heads)" % heads if quant else "",
                need, room, _SMEM_BYTES, _SMEM_RESERVE))
+
+
+# the longest work list whose re-naming looks at every entry: the GPT
+# cells' 32 slots x 16 groups. At 64 slots x 16 groups of 16 blocks
+# (N = 1,024) that rule's [G, N, N] reduction took the list from 220
+# to 421 us a step on the v5e, at N = 512 and G = 32 from 220 to 403
+# (PERF.md section 6, PR 32)
+_LOOKBACK_FROM = 512
 
 
 def _decode_worklist(tables, pos, Bt: int, G: int, span: int,
@@ -474,9 +539,10 @@ def _decode_worklist(tables, pos, Bt: int, G: int, span: int,
     the widest a fused [G, N, N] masked max, and the same for every
     layer of a step that shares a table, so the compiled step keeps
     ONE copy: ~65 us of an 8 ms decode step at 32 slots x 16 groups on
-    the v5e (PERF.md section 5, PR 26). Past N = 1024 that reduction
-    is no longer small (64 slots x 64 groups: 134 M compares), and a
-    rule that looks one entry back takes its place: an operand its
+    the v5e (PERF.md section 5, PR 26). Past N = `_LOOKBACK_FROM` that
+    reduction is no longer small (64 slots x 16 groups of 16 blocks:
+    16.8 M compares, 200 us), and a rule that looks one entry back
+    takes its place: an operand its
     group does not name keeps what the entry before it held if that
     is the same slot's. Only a slot's first group can then copy a
     block nobody reads, which long contexts make a rounding error."""
@@ -504,7 +570,7 @@ def _decode_worklist(tables, pos, Bt: int, G: int, span: int,
     if first is not None:
         named = named & ((depth + 1) * Bt > first[wslot][None, :])
     entry = jnp.maximum(tables[wslot[None, :], depth], 0)  # -1 -> block 0
-    if N > 1024:
+    if N > _LOOKBACK_FROM:
         prev = jnp.concatenate([entry[:, :1], entry[:, :-1]], axis=1)
         blk = jnp.where(named | (local <= 0)[None, :], entry, prev)
         return blk, wslot, wgrp, ends[-1]
@@ -722,12 +788,20 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     attends (a window layer's pos - window + 1; depths before it are
     masked and their groups not walked), `max_context` the most
     positions any slot can then attend, which bounds the work list;
-    `scale` replaces 1 / sqrt(D) where D is not the head's width."""
+    `scale` replaces 1 / sqrt(D) where D is not the head's width.
+    This call's grid step is sized by the bytes it moves, not by 128
+    tokens (`_bytes_group`, from the pool's block shape and dtype: 16
+    blocks for granite's pool, 8 for SambaY's; ISSUE 32) — the table
+    pads to a whole number of THOSE groups and a window's work list
+    holds ceil(max_context / (G * Bt)) + 1 entries a slot. The number
+    of columns an online-softmax step folds is all that differs
+    between two group sizes, so logits move within float tolerance."""
     if k_pool.ndim == 3:
         S, Hk, rep, D = q.shape
         Bt = k_pool.shape[1] // Hk
         maxb = tables.shape[1]
-        G = _group(Bt, maxb)
+        G = _bytes_group(Bt, maxb, 2 * k_pool.shape[1] * D
+                         * k_pool.dtype.itemsize)
         tables = jnp.asarray(tables, jnp.int32)
         pad = -maxb % G
         if pad:

@@ -499,15 +499,6 @@ class ServingEngine(object):
                 % (pk,))
         self.paged_kernel = pk
         tlm._kv_quant_check(kv_quant)
-        if pk == "fused":
-            # the kernels keep every slot's block table (and, on a
-            # quantized pool, the named blocks' scales) in scalar
-            # memory: a geometry that cannot fit is refused HERE, with
-            # the arithmetic, not by the first step's compile
-            from ..parallel.paged_attention import check_paged_smem
-
-            check_paged_smem(S, self.blocks_per_slot, Bt, cfg.heads,
-                             kv_quant != "none")
         # per-block KV quantization (ISSUE 14): the pool's storage
         # dtype, fixed for the engine's lifetime (baked into the cache
         # pytree AND the compiled steps). 'none' keeps the exact
@@ -587,9 +578,11 @@ class ServingEngine(object):
         self._has_state = "state" in fam.caches
         self._state_bytes_per_slot = 0
         self._state_reset_fn = None
+        call_block = None  # a merged 3-D pool's block, K + V
         if self._has_state or "window" in fam.caches:
             sizes = fam.cache_bytes(cfg, Bt)
             block_bytes = sizes["full"]
+            call_block = sizes["call_block"]
             self._state_bytes_per_slot = sizes.get("state", 0)
             if "window" in fam.caches:
                 self._win = WindowBlockTables(
@@ -599,6 +592,17 @@ class ServingEngine(object):
             block_bytes = tlm.kv_block_bytes(
                 cfg.layers, cfg.heads, cfg.dim // cfg.heads, Bt, kv_quant,
                 act_itemsize=jnp.dtype(cfg.dtype).itemsize)
+        if pk == "fused":
+            # the kernels keep every slot's block table or work list
+            # (and, on a quantized pool, the named blocks' scales) in
+            # scalar memory: a geometry that cannot fit is refused
+            # HERE, with the arithmetic, not by the first step's
+            # compile. A merged pool's decode call sizes its grid step
+            # by the block's bytes (ISSUE 32), so the check is told them
+            from ..parallel.paged_attention import check_paged_smem
+
+            check_paged_smem(S, self.blocks_per_slot, Bt, cfg.heads,
+                             kv_quant != "none", block_bytes=call_block)
         self.kv_block_bytes = block_bytes
         self._alloc = KVBlockAllocator(NB, Bt,
                                        block_bytes=block_bytes)  # guarded-by: scheduler

@@ -102,6 +102,7 @@ def test_parameter_count_is_the_published_3p19_billion(ref):
     # the state of one slot: 2 MB a layer in float32, 75.5 MB + conv rows
     assert gh.cache_bytes(cfg, 32) == {
         "full": 4 * 2 * 32 * 8 * 64 * 4,
+        "call_block": 2 * 32 * 8 * 64 * 4,  # one pool's block, K + V
         "state": 36 * (128 * 4096 * 4 + 3 * 4352 * 4)}
 
 

@@ -588,3 +588,214 @@ def test_fused_engine_refuses_a_geometry_past_scalar_memory():
                {"paged_kernel": "gather", "kv_quant": "int8"}):
         ServingEngine(params, cfg, max_slots=64, kv_block_tokens=16,
                       kv_pool_blocks=2, **kw)
+
+
+# ---------------------------------------------------------------------
+# the merged-pool decode call (`hybrid_decode_attention`, ISSUEs 27 and
+# 32): grouped queries over a 3-D pool whose grid step is sized by the
+# bytes it moves
+# ---------------------------------------------------------------------
+
+# 8-token blocks: `_group` gives 16 to a step (128 tokens), the byte
+# rule — its target set to 32 of these small blocks — 32 (256 tokens);
+# 96 table entries make six groups of the one, three of the other
+_M_BT, _M_MAXB, _M_D, _M_WIN = 8, 96, 16, 100
+_M_SPAN = _M_BT * _M_MAXB
+# contexts of one token, on both sides of a block's edge, of BOTH
+# group sizes' edges, of the window's (first = 0, 0, 1), the span's
+# last two, and windows whose first position opens a group of either
+# size (first = 128, 255, 256)
+_M_EDGES = [0, 7, 8, 126, 127, 128, 254, 255, 256, 98, 99, 100,
+            _M_SPAN - 2, _M_SPAN - 1, 227, 354, 355, 511, 512, 301]
+
+
+def _merged_case(hk, rep, windowed, pool, seed=0, garbage=False):
+    """-> (q, k_pool, v_pool, tables, pos, first): one live row for
+    every edge and two parked ones (first and last) whose table rows
+    name real blocks; a live row names exactly the blocks it attends
+    (a window row none behind its window). With `garbage` every -1 of
+    the tables names some allocated block instead."""
+    rng = np.random.RandomState(seed)
+    dt = jnp.float32 if pool == "f32" else jnp.bfloat16
+    pos = np.array([_M_SPAN] + _M_EDGES + [_M_SPAN], np.int32)
+    S = len(pos)
+    first = np.maximum(pos - _M_WIN + 1, 0).astype(np.int32)
+    live = pos < _M_SPAN
+    lo = (first // _M_BT if windowed else np.zeros(S, np.int64)) * live
+    hi = np.where(live, pos // _M_BT + 1, 3)
+    NB = int((hi - lo).sum()) + 2
+    order = rng.permutation(NB - 1)  # the last block stays nobody's
+    tables = np.full((S, _M_MAXB), -1, np.int32)
+    at = 0
+    for s in range(S):
+        n = hi[s] - lo[s]
+        tables[s, lo[s]:hi[s]] = order[at:at + n]
+        at += n
+    k = jnp.asarray(rng.randn(NB, _M_BT * hk, _M_D), dt)
+    v = jnp.asarray(rng.randn(NB, _M_BT * hk, _M_D), dt)
+    # a query the pool's dtype holds exactly (see the 4-D test above)
+    q = jnp.asarray(rng.randn(S, hk, rep, _M_D), dt).astype(jnp.float32)
+    if garbage:
+        fill = rng.randint(0, NB, tables.shape).astype(np.int32)
+        tables = np.where(tables < 0, fill, tables)
+    return (q, k, v, jnp.asarray(tables), jnp.asarray(pos),
+            jnp.asarray(first) if windowed else None)
+
+
+def _merged_oracle(q, k, v, tables, pos, first, hk):
+    """Plain softmax attention through the table, in float64."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    tables, pos = np.asarray(tables), np.asarray(pos)
+    out = np.zeros(q.shape)
+    for s in range(len(pos)):
+        if pos[s] >= _M_SPAN:
+            continue
+        ps = np.arange(0 if first is None else int(first[s]), pos[s] + 1)
+        blk = tables[s, ps // _M_BT]
+        assert (blk >= 0).all()
+        rows = k[blk].reshape(len(ps), _M_BT, hk, _M_D)[
+            np.arange(len(ps)), ps % _M_BT]  # [n, hk, D]
+        vals = v[blk].reshape(len(ps), _M_BT, hk, _M_D)[
+            np.arange(len(ps)), ps % _M_BT]
+        sc = np.einsum("grd,ngd->grn", q[s], rows) / np.sqrt(_M_D)
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        out[s] = np.einsum("grn,ngd->grd", pr / pr.sum(-1, keepdims=True),
+                           vals)
+    return out
+
+
+def _merged_call(case, monkeypatch, by):
+    """The call with its group taken from `_group` ("tokens": the byte
+    target set to nothing) or from the byte rule ("bytes": the target
+    set to 32 of this geometry's blocks) -> (out, G)."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    q, k, v, tables, pos, first = case
+    block_bytes = 2 * k.shape[1] * k.shape[2] * k.dtype.itemsize
+    monkeypatch.setattr(pa, "_STEP_BYTES",
+                        32 * block_bytes if by == "bytes" else 0)
+    G = pa._bytes_group(_M_BT, _M_MAXB, block_bytes)
+    assert pa._group(_M_BT, _M_MAXB) == 16
+    assert G == {"tokens": 16, "bytes": 32}[by]
+    out = pa.paged_decode_attention(
+        q, k, v, tables, pos, interpret=True, first=first,
+        max_context=_M_WIN if first is not None else None)
+    return np.asarray(out), G
+
+
+@pytest.mark.parametrize("by", ["tokens", "bytes"])
+@pytest.mark.parametrize("pool", ["f32", "bf16"])
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("hk,rep", [(4, 8), (10, 4), (1, 2)])
+def test_merged_pool_decode_call_is_plain_softmax_at_either_group(
+        hk, rep, windowed, pool, by, monkeypatch):
+    """The grouped-query call over a merged 3-D pool at granite's,
+    SambaY's and a one-row geometry, over the whole context or a
+    window of it, on both pool dtypes, with its grid step sized by 128
+    tokens or by bytes: every live row is plain softmax attention
+    through the table at the pinned tolerance on every edge a context
+    can sit on; a parked row is zeros whatever its table names; and a
+    table whose unallocated entries (-1) name real blocks instead
+    gives the same bits (the garbage-row invariant: nothing is read
+    from a block the context does not name)."""
+    case = _merged_case(hk, rep, windowed, pool, seed=hk)
+    got, G = _merged_call(case, monkeypatch, by)
+    want = _merged_oracle(*case, hk)
+    parked = np.asarray(case[4]) >= _M_SPAN
+    assert parked.sum() == 2
+    np.testing.assert_allclose(got[~parked], want[~parked],
+                               rtol=_RTOL, atol=_ATOL)
+    np.testing.assert_array_equal(got[parked], 0.0)
+    filled = _merged_case(hk, rep, windowed, pool, seed=hk, garbage=True)
+    assert (np.asarray(filled[3]) >= 0).all()
+    again, _ = _merged_call(filled, monkeypatch, by)
+    np.testing.assert_array_equal(again, got)
+
+
+def test_group_rule_reads_the_pools_block_and_nothing_else():
+    """The merged-pool call's group is the fewest blocks, `_group`'s
+    doubled, whose K + V reach `_STEP_BYTES`: granite's 32-token block
+    of 4 pair-rows (64 KiB) -> 16, SambaY's of 10 (160 KiB) -> 8, a
+    block as heavy as the GPT pool's rows (8,192 B a token) ->
+    `_group`'s own; never past the table; and `check_paged_smem`
+    counts the work list of that same group."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    bt, maxb = 32, 256
+    kv = lambda rows, item=2: 2 * bt * rows * 128 * item  # noqa: E731
+    assert pa._group(bt, maxb) == 4
+    assert pa._bytes_group(bt, maxb, kv(4)) == 16
+    assert pa._bytes_group(bt, maxb, kv(10)) == 8
+    assert pa._bytes_group(bt, maxb, kv(16)) == 4 == pa._group(bt, maxb)
+    assert pa._bytes_group(bt, maxb, kv(4, item=4)) == 8  # the dtype counts
+    assert pa._bytes_group(bt, 8, kv(1)) == 8  # capped at the table
+    assert pa._bytes_group(bt, 6, kv(1)) == 4  # `_group`'s, doubled 0 times
+    for rows in (4, 10, 16):
+        G = pa._bytes_group(bt, maxb, kv(rows))
+        assert kv(rows) * G >= pa._STEP_BYTES > kv(rows) * G // 2 \
+            or G == pa._group(bt, maxb)
+
+    # the scalar memory the call is refused by is its own work list's:
+    # [G, S * NG] block names + two [S * NG] rows + positions
+    def need(slots, G):
+        steps = slots * maxb // G
+        return (pa._smem_padded(G, steps) + pa._smem_padded(1, slots)
+                + 2 * pa._smem_padded(1, steps))
+
+    room = pa._SMEM_BYTES - pa._SMEM_RESERVE
+    for rows in (4, 10):
+        G = pa._bytes_group(bt, maxb, kv(rows))
+        fits = max(s for s in range(8, 4096, 8) if need(s, G) <= room)
+        pa.check_paged_smem(fits, maxb, bt, 32, False,
+                            block_bytes=kv(rows))
+        with pytest.raises(ValueError, match="scalar memory"):
+            pa.check_paged_smem(fits + 8, maxb, bt, 32, False,
+                                block_bytes=kv(rows))
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("rule", ["every_entry", "look_back"])
+def test_worklist_names_the_tables_blocks_on_both_sides_of_its_switch(
+        rule, windowed, monkeypatch):
+    """`_decode_worklist` at granite's geometry (64 slots, groups of 16
+    blocks of 32 tokens, 16 groups a slot: N = 1,024) with its switch
+    put on either side of the list's length — the re-naming that looks
+    at every entry, and the one that looks one entry back, which is the
+    one N = 1,024 gets (the GPT cells' N = 512 keeps the other): either
+    way the list walks exactly the (slot, group) pairs the live
+    contexts name, slot after slot, and wherever a group names a block
+    its operand holds the table's; a parked slot takes one entry."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    slots, maxb, bt, G, win = 64, 256, 32, 16, 512
+    assert 32 * (maxb // G) <= pa._LOOKBACK_FROM < slots * (maxb // G)
+    rng = np.random.default_rng(7)
+    span = maxb * bt
+    tables = rng.integers(0, 11264, (slots, maxb)).astype(np.int32)
+    pos = rng.integers(0, span, slots).astype(np.int32)
+    pos[:6] = [0, G * bt - 1, G * bt, span - 1, span, win - 1]
+    first = np.maximum(pos - win + 1, 0).astype(np.int32)
+    max_groups = -(-win // (G * bt)) + 1 if windowed else None
+    N = slots * (max_groups or maxb // G)
+    monkeypatch.setattr(pa, "_LOOKBACK_FROM",
+                        N if rule == "every_entry" else N - 1)
+    blk, wslot, wgrp, n = (np.asarray(a) for a in pa._decode_worklist(
+        jnp.asarray(tables), jnp.asarray(pos), bt, G, span,
+        first=jnp.asarray(first) if windowed else None,
+        max_groups=max_groups))
+    assert blk.shape == (G, N)
+    live = pos < span
+    want = []
+    for s in range(slots):
+        g0 = first[s] // (G * bt) if windowed and live[s] else 0
+        last = pos[s] // (G * bt) if live[s] else 0
+        want += [(s, b) for b in range(g0, last + 1)]
+    assert n == len(want) <= blk.shape[1]
+    assert list(zip(wslot[:n].tolist(), wgrp[:n].tolist())) == want
+    for i, (s, b) in enumerate(want):
+        for g in range(G):
+            depth = b * G + g
+            named = live[s] and depth * bt <= pos[s] and (
+                not windowed or (depth + 1) * bt > first[s])
+            if named:
+                assert blk[g, i] == tables[s, depth]

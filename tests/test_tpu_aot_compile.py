@@ -17,6 +17,7 @@ library: under pytest-xdist only the worker that is handed this file
 may touch it, and every worker must collect the same tests.
 """
 
+import hashlib
 import re
 
 import jax
@@ -297,6 +298,39 @@ def test_both_depths_compile_the_same_decode_program(one_chip, as_on_tpu,
     module = re.match(r"HloModule (\S+?),", texts[0]).group(1)
     assert re.search(_metric_spec("decode_step_ms")["args"]["program_match"],
                      module + "(1)")
+
+
+# the GPT decode program as PR 31's tree compiles it (ISSUE 32 changed
+# the merged-pool caller of the decode kernel and nothing the GPT block
+# is handed): sha256 of `_decode_text(*_engine(...))` less locations
+# and less the kernels' embedded bodies (serialized with the line
+# numbers of paged_attention.py), and of the decode call's jaxpr at
+# the cells' geometry, which holds the kernel's body itself. A PR
+# that MEANS to change the GPT decode program replaces both (the
+# failing assertion prints the new one) and says so in CHANGES.md.
+_GPT_DECODE_TEXT_SHA = (
+    "207dbcb3618c62203ec77a9cbb40da5535f5bbfde4b48f75749cdb0da5756e3e")
+_GPT_DECODE_CALL_JAXPR_SHA = (
+    "8792e8e088ffe1e215a99c09a7b5fb41b0828c93d1986e4b645b8503d14b1553")
+
+
+def test_gpt_decode_program_is_the_text_the_parent_compiled(one_chip,
+                                                            as_on_tpu):
+    text = _without_locations(_decode_text(*_engine(one_chip)))
+    text = re.sub(r'"body":"[^"]*"', '"body":""', text)
+    assert text.count('"body":""') == 2  # the two layers' decode kernels
+    assert hashlib.sha256(text.encode()).hexdigest() == _GPT_DECODE_TEXT_SHA
+    sh = jax.ShapeDtypeStruct
+    pool = sh((3500, BT, H, DH), jnp.bfloat16)
+    with jax.default_matmul_precision(None):  # the chip's own, as `_compile`
+        jaxpr = str(jax.make_jaxpr(
+            lambda q, k, v, t, p: pa.paged_decode_attention(
+                q, k, v, t, p, interpret=False))(
+            sh((32, H, DH), jnp.bfloat16), pool, pool,
+            sh((32, MAXB), jnp.int32), sh((32,), jnp.int32)))
+    assert hashlib.sha256(jaxpr.encode()).hexdigest() \
+        == _GPT_DECODE_CALL_JAXPR_SHA
+
 
 
 def test_default_decode_program_is_what_the_benchmark_reads(one_chip,
@@ -657,3 +691,56 @@ def test_granite_prefill_chunk_compiles_at_its_one_bucket(one_chip,
     assert "ssd_state_update" not in text  # the decode step's kernel
     # ~0.6 GB at 10 layers, 1.15 GB at 40 (weights' copies in flight)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+# ---------------------------------------------------------------------
+# the merged-pool decode call alone, at both hybrid cells' geometry,
+# with the grid step the byte rule gives it (ISSUE 32)
+# ---------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("metric,rows,rep,blocks,window,group,steps", [
+    ("gqa_attn_roofline", 4, 8, GR_NB + 1, None, 16, GR_S * 16),
+    ("hybrid_attn_roofline", 10, 4, HY_NB + 1, None, 8, HY_S * 32),
+    ("hybrid_attn_roofline", 10, 4, HY_S * 17 + 1, 512, 8, HY_S * 3),
+], ids=["granite", "sambay_full", "sambay_window"])
+def test_merged_pool_decode_call_compiles_with_its_byte_sized_step(
+        one_chip, metric, rows, rep, blocks, window, group, steps):
+    """`hybrid_decode_attention` at the geometry of
+    `granite4hmicro_reason_closed` (4 pair-rows a token, 8 queries a
+    row) and of `phi4flash_reason_closed` (10 and 4; the shared pool,
+    and a window pool with `first` and `max_context` 512): 64 slots,
+    8,192 positions in 32-token blocks, bf16. The byte rule gives the
+    one 16 blocks a grid step and the other 8, the work list is
+    [group, slots x groups a slot] (a window walks 512 / 256 + 1 = 3),
+    Mosaic accepts the 2 x group K/V operands and the wider score tile
+    inside the memory a program scopes, and the compiled call is still
+    the ONE instruction the cell's roofline metric finds (`op_match`,
+    read from its file), named after the kernel."""
+    sds = _sds(one_chip)
+    pool = sds((blocks, HY_BT * rows, 128), jnp.bfloat16)
+    assert pa._bytes_group(HY_BT, HY_MAXB,
+                           2 * HY_BT * rows * 128 * 2) == group
+    args = [sds((HY_S, rows, rep, 128), jnp.bfloat16), pool, pool,
+            sds((HY_S, HY_MAXB), jnp.int32), sds((HY_S,), jnp.int32)]
+    if window:
+        args.append(sds((HY_S,), jnp.int32))
+    text = _compile(
+        lambda q, k, v, t, p, *first: pa.paged_decode_attention(
+            q, k, v, t, p, interpret=False, first=first[0] if first else None,
+            max_context=window, scale=0.125), *args)
+    lines = [ln.strip() for ln in text.split("\n")]
+    found = [ln for ln in lines if _metric_pattern(metric).search(ln)]
+    assert len(found) == 1 and " custom-call(" in found[0]
+    call = found[0][found[0].index(" custom-call("):
+                    found[0].index("custom_call_target")]
+    # the grid's length, four or five prefetch operands and q, then K
+    # and V, an operand a block of the group; the work list is
+    # [group, steps]
+    assert call.count("%") == (6 if window else 5) + 1 + 2 * group
+    assert call.count("%k.1") == call.count("%v.1") == group
+    assert re.search(r"s32\[%d,%d\]" % (group, steps), text)
+    assert re.search(r'kernel_metadata=\{\s*"kernel":'
+                     r'"hybrid_decode_attention"\s*\}', text)
+    assert text.count("tpu_custom_call") == 1
+
