@@ -557,6 +557,7 @@ class _Serving(object):
     refused = ("prefix_cache_tokens", "kv_store", "spec_draft_len",
                "kv_quant", "weight_quant", "adapter_registry",
                "kv_fingerprints")
+    refusal = "it keeps recurrent state beside its K/V blocks"
     cache_bytes = staticmethod(cache_bytes)
     reset_slot_state = staticmethod(reset_slot_state)
 

@@ -300,6 +300,8 @@ class ServingEngine(object):
       models/granite_hybrid.SERVING   ("paged", "state"): Mamba-2 +
                                       grouped-query layers, a pool an
                                       attention layer on the ONE table
+      models/afmoe.SERVING            ("paged", "window"): routed
+                                      experts, window + full layers
 
     "window": window-attention pools whose blocks are freed behind the
     window (`kv_blocks.WindowBlockTables`, inside
@@ -308,13 +310,21 @@ class ServingEngine(object):
     zeroed at admission (`engine.state_reset`), counted by
     `state_slots_reset` and, by kind beside the pools, in
     `cache_bytes_in_use` / `cache_bytes_per_slot`; a prefill chunk is
-    told its slot's index in a row beside its table row. The options
-    a family cannot honour (`cfg.serving.refused`: both families with
-    state refuse the prefix cache, the KV store, speculation, KV and
-    weight quantization, adapters and fingerprints — block aliasing
-    cannot restore a recurrent state; hand-off import at `submit`)
-    raise a ValueError that names the option; nothing is silently
-    ignored.
+    told its slot's index in a row beside its table row. The two are
+    independent: a family may keep window tables without state
+    (`models/afmoe.SERVING`, `("paged", "window")`: nothing is reset at
+    admission). The options a family cannot honour
+    (`cfg.serving.refused`: every family with state or window tables
+    refuses the prefix cache, the KV store, speculation, KV and weight
+    quantization, adapters and fingerprints; hand-off import at
+    `submit`) raise a ValueError that names the option and gives the
+    family's own reason (`cfg.serving.refusal`: a recurrent state that
+    block aliasing cannot restore, blocks freed behind the window);
+    nothing is silently ignored. A family whose decode step computes
+    counters on the device (`cfg.serving.step_counters`: the router's,
+    for a family with routed experts) returns them beside the logits;
+    they ride the step's one packed result into the metrics of the
+    same names.
 
     One decode program, one decode loop, two depths (ISSUE 29): the
     plain one-token decode is ONE compiled program (`_make_decode`)
@@ -386,28 +396,31 @@ class ServingEngine(object):
                  "kv_fingerprints": kv_fingerprints}
         for opt in fam.refused:
             if asked[opt]:
-                # nothing silently ignored: a cache that holds recurrent
-                # state cannot be restored by aliasing or re-playing
-                # blocks, and the rest is not built for this family
+                # nothing silently ignored; the reason is the family's
+                # own (`refusal`: recurrent state that block aliasing
+                # cannot restore, window blocks freed behind the window)
                 raise ValueError(
-                    "%s is not supported for the %r model family (it "
-                    "keeps recurrent state beside its K/V blocks); "
+                    "%s is not supported for the %r model family (%s); "
                     "refused options: %s"
-                    % (opt, fam.name, ", ".join(fam.refused)))
+                    % (opt, fam.name, fam.refusal, ", ".join(fam.refused)))
         # deterministic-exploration seam (ISSUE 9): the fleet threads
         # its SchedulerHook through so a controlled scheduler can park
         # a replica at engine-step granularity too; None costs one
         # attribute test per step
         self._sched_hook = scheduler_hook
         if getattr(cfg, "moe_experts", 0):
-            # reference_moe's capacity cutoff couples rows: padded
-            # chunk rows would compete with real rows for expert slots
-            # and silently change real outputs (prefill_chunk
-            # docstring) — refuse loudly instead
+            # the GPT block's Switch layer (parallel/moe.py) has a
+            # capacity cutoff that couples rows: padded chunk rows
+            # would compete with real rows for expert slots and
+            # silently change real outputs (prefill_chunk docstring) —
+            # refuse loudly instead. Routed experts are served by a
+            # family whose layer has no capacity
+            # (parallel/routed_experts.py, ISSUE 33)
             raise ValueError(
-                "ServingEngine serves dense models only; MoE configs "
-                "(moe_experts > 0) are not bit-stable under "
-                "padded/chunked prefill")
+                "the Switch layer of moe_experts > 0 has a capacity "
+                "cutoff that couples rows: not bit-stable under "
+                "padded/chunked prefill, so not served (a family "
+                "built on parallel/routed_experts.py is)")
         S = int(max_slots)
         if S < 1:
             raise ValueError("max_slots must be >= 1")
@@ -576,6 +589,10 @@ class ServingEngine(object):
         # handled only for a family that has it
         self._win: Optional[WindowBlockTables] = None  # guarded-by: scheduler
         self._has_state = "state" in fam.caches
+        # counters a family's decode step computes on the device (the
+        # router's, for a family with experts): running stats of the
+        # metrics under the same names, read off the packed result
+        self._step_counters = tuple(getattr(fam, "step_counters", ()))
         self._state_bytes_per_slot = 0
         self._state_reset_fn = None
         call_block = None  # a merged 3-D pool's block, K + V
@@ -777,7 +794,7 @@ class ServingEngine(object):
             # block and the scatter DROPS the row, so a retired slot
             # can never dirty a block a future request will claim
             write_pos = jnp.where(alive, pos, jnp.int32(Lv))
-            logits, cache = fam.decode_step(
+            logits, cache, *stats = fam.decode_step(
                 params, tok, write_pos, tables, cache, cfg,
                 adapters=adapters, adapter_idx=aidx, kernel=kernel,
                 kv_quant=kv_quant,
@@ -808,8 +825,11 @@ class ServingEngine(object):
                 jax.lax.bitcast_convert_type(
                     scale.astype(jnp.float32), jnp.int32)[None]])
             ncounts = counts + alive.astype(jnp.int32)
+            # a family's own step counters (`step_counters`, int32, one
+            # a name) ride the same array, last
             packed = jnp.concatenate([
-                row, ntok, npos, nalive.astype(jnp.int32), ncounts])
+                row, ntok, npos, nalive.astype(jnp.int32), ncounts]
+                + [st.astype(jnp.int32) for st in stats])
             return cache, ntok, npos, nalive, ncounts, packed
 
         kw = {"donate_argnums": (1,)} if self._donate else {}
@@ -818,14 +838,15 @@ class ServingEngine(object):
     def _unpack(self, packed):
         """The host's view of a decode step's packed result ->
         (tokens [S], trap flags [S], the magnitude, the bands (tok,
-        pos, alive, counts) as the step left them). The one blocking
-        device-to-host read of a decode step."""
+        pos, alive, counts) as the step left them, the family's step
+        counters). The one blocking device-to-host read of a decode
+        step."""
         S = self.max_slots
         flat = np.asarray(packed)
-        tok, pos, alive, counts = flat[2 * S + 1:].reshape(4, S)
+        tok, pos, alive, counts = flat[2 * S + 1:6 * S + 1].reshape(4, S)
         return (flat[:S], flat[S:2 * S].astype(bool),
                 float(flat[2 * S:2 * S + 1].view(np.float32)[0]),
-                (tok, pos, alive.astype(bool), counts))
+                (tok, pos, alive.astype(bool), counts), flat[6 * S + 1:])
 
     def _make_verify(self):
         """ONE compiled speculative-verify step: writes every slot's
@@ -1369,11 +1390,12 @@ class ServingEngine(object):
             )
         if publish_len is not None and publish_len < 0:
             raise ValueError("publish_len must be >= 0 or None")
-        if handoff and self._has_state:
+        if handoff and (self._has_state or self._win is not None):
+            # imported K/V blocks restore neither a recurrent state nor
+            # a window table: the family's own reason says which
             raise ValueError(
-                "handoff import is not supported for the %r model family: "
-                "imported K/V blocks cannot restore its recurrent state"
-                % (self._family.name,))
+                "handoff import is not supported for the %r model family "
+                "(%s)" % (self._family.name, self._family.refusal))
         if adapter is not None:
             # resolve-or-refuse NOW: an unknown adapter (or an engine
             # with no pool) must fail the caller synchronously, never
@@ -2229,7 +2251,9 @@ class ServingEngine(object):
         zeros."""
         m = self.metrics
         with m.phase("engine.device_wait"):
-            toks, traps, scale, bands = self._unpack(rec["packed"])
+            toks, traps, scale, bands, stats = self._unpack(rec["packed"])
+        for name, v in zip(self._step_counters, stats):
+            getattr(m, name).append(float(v))
         if self.integrity_traps:
             with m.phase("engine.integrity"):
                 verdict = ("ok" if not (toks >= 0).any() else

@@ -112,6 +112,13 @@ class ServingMetrics(object):
         self.state_slots_reset = 0        # cumulative
         self.cache_bytes_in_use = None    # gauge: {"full", "window", "state"}
         self.cache_bytes_per_slot = _RunningStat()
+        # ISSUE 33 counters — a family with routed experts computes them
+        # on the device and the decode step's packed result carries
+        # them (`step_counters` of the family's seam; empty otherwise):
+        # per decode step, the distinct experts its live rows reached,
+        # summed over the expert layers, and the fullest expert's rows
+        self.moe_experts_hit = _RunningStat()
+        self.moe_rows_max = _RunningStat()
         self.spec_windows = 0             # cumulative verify rows run
         self.spec_drafted = 0             # cumulative drafted tokens
         self.spec_accepted = 0            # cumulative drafts emitted
@@ -282,6 +289,8 @@ class ServingMetrics(object):
             "window_blocks_released": self.window_blocks_released,
             "state_slots_reset": self.state_slots_reset,
             "cache_bytes_in_use": self.cache_bytes_in_use,
+            "mean_moe_experts_hit": _mean(self.moe_experts_hit),
+            "mean_moe_rows_max": _mean(self.moe_rows_max),
             "spec_windows": self.spec_windows,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,

@@ -390,7 +390,8 @@ def test_packed_result_carries_what_the_program_returns(model):
     the tokens of live lanes and -1 for parked ones, no trap, a finite
     magnitude, and the four advanced bands equal to the device arrays
     the next step would chain off — with the slot that just spent its
-    budget retired ON THE DEVICE."""
+    budget retired ON THE DEVICE — and, last, the counters of a family
+    whose step computes some (none here)."""
     cfg, params = model
     eng = ServingEngine(params, cfg, max_slots=3, async_dispatch=False)
     ha = eng.submit(np.arange(2, 9, dtype=np.int32), 3)  # budget: 3
@@ -398,7 +399,8 @@ def test_packed_result_carries_what_the_program_returns(model):
     while len(ha.tokens) < 2:
         eng.step()  # both prefilled, one decode step read
     rec = eng._dispatch_decode()
-    toks, traps, scale, bands = eng._unpack(rec["packed"])
+    toks, traps, scale, bands, stats = eng._unpack(rec["packed"])
+    assert len(stats) == 0  # this family's step computes no counters
     slots = {h.rid: s for s, h in rec["slots"]}
     sa, sb = slots[ha.rid], slots[hb.rid]
     dead = [s for s in range(3) if s not in (sa, sb)]

@@ -522,7 +522,7 @@ def test_moe_config_rejected_loudly():
     # prefill is not bit-stable for MoE — the engine refuses instead of
     # silently serving wrong tokens (PR 5 review hardening)
     cfg, params = _mk(moe_experts=2)
-    with pytest.raises(ValueError, match="dense models only"):
+    with pytest.raises(ValueError, match="capacity cutoff that couples rows"):
         ServingEngine(params, cfg, max_slots=2)
 
 

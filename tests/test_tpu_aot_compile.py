@@ -744,3 +744,144 @@ def test_merged_pool_decode_call_compiles_with_its_byte_sized_step(
                      r'"hybrid_decode_attention"\s*\}', text)
     assert text.count("tpu_custom_call") == 1
 
+
+
+# ---------------------------------------------------------------------
+# the sparse-expert family (ISSUE 33): window tables without state,
+# rotated window keys, the grouped expert product — at the cell's
+# geometry (trinitymini_reason_closed: 64 slots, 8,192 positions in
+# blocks of 32, 128 experts top-8 of 2048 x 1024), depth 1 dense + 2
+# expert layers (window, window, full)
+# ---------------------------------------------------------------------
+
+AF_S, AF_BT, AF_L, AF_NB = 64, 32, 8192, 14336
+AF_MAXB = AF_L // AF_BT
+
+
+def _afmoe_engine(one_chip, **kw):
+    from paddle_tpu.models import afmoe as af
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = af.AfmoeConfig(
+        vocab=200192, dim=2048, heads=32, kv_heads=4, head_dim=128,
+        layer_types=["sliding_attention", "sliding_attention",
+                     "full_attention"],
+        num_dense_layers=1, dense_width=6144, expert_width=1024,
+        n_experts=128, top_k=8, route_scale=2.826, route_norm=True,
+        window=2048, max_len=AF_L, dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: af.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = ServingEngine(params, cfg, max_slots=AF_S, kv_pool_blocks=4,
+                        kv_block_tokens=AF_BT, prefill_chunk_tokens=4096,
+                        **kw)
+    assert eng.paged_kernel == "fused"
+    cache = jax.eval_shape(
+        lambda: af.SERVING.init_cache(cfg, AF_NB, AF_BT, AF_S))
+    return eng, _placed(params, one_chip), _placed(cache, one_chip), \
+        _sds(one_chip)
+
+
+@pytest.mark.parametrize("rows,tm,tiles", [(512, 16, 152), (32768, 128, 383)])
+def test_grouped_expert_product_carries_its_name(one_chip, rows, tm, tiles):
+    """The routed experts' layer at the cell's two geometries (a
+    decode step's 512 (token, choice) pairs in 16-row tiles, a
+    4,096-token chunk's 32,768 in 128-row tiles, over 128 experts of
+    2048 x 1024, bf16) compiles for the chip as TWO custom calls named
+    after the kernel, the name in `kernel_metadata`: what
+    `moe_expert_roofline`'s `op_match` finds in a device trace."""
+    from paddle_tpu.parallel import routed_experts as rx
+
+    sds = _sds(one_chip)
+    N, k, E, d, m = rows // 8, 8, 128, 2048, 1024
+    assert rx.row_tile(rows, E) == tm
+    bf = jnp.bfloat16
+    text = _compile(
+        lambda u, idx, w, gu, dn, valid: rx.expert_ffn(
+            u, idx, w, {"w_gu": gu, "w_down": dn}, valid, kernel="fused",
+            interpret=False),
+        sds((N, d), bf), sds((N, k), jnp.int32), sds((N, k), jnp.float32),
+        sds((E, d, 2 * m), bf), sds((E, m, d), bf), sds((N,), jnp.bool_))
+    lines = [ln.strip() for ln in text.split("\n")]
+    found = [ln for ln in lines
+             if _metric_pattern("moe_expert_roofline").search(ln)]
+    assert len(found) == 2 and all(" custom-call(" in ln for ln in found)
+    # the tile-aligned layout: the pairs + 15 (127) rows an expert
+    assert "[%d,2048]" % (tiles * tm) in found[0]
+    assert len(re.findall(
+        r'kernel_metadata=\{\s*"kernel":"moe_grouped_matmul"\s*\}',
+        text)) >= 2
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_afmoe_decode_program_is_the_one_the_benchmark_finds(one_chip,
+                                                             as_on_tpu):
+    """The fourth family rides the shared loop, one step ahead by
+    default, with window tables and NO state handling: at the cell's
+    geometry its decode program is the one `decode_step_ms`,
+    `moe_expert_roofline` and `swa_attn_roofline` look for (their
+    `program_match`, read from the metric files), FLAT, with exactly
+    the kernels' calls where `op_match` finds them — at this depth 3
+    attention calls (two over the window pools, one over the full
+    layer's) and 4 grouped products (two an expert layer), each named
+    after its kernel, beside the three K/V writes — and ONE packed
+    result for the host that carries the router's two counters behind
+    the bands."""
+    eng, params, cache, sds = _afmoe_engine(one_chip)
+    assert eng.async_dispatch and eng._win is not None
+    assert not eng._has_state and eng._step_counters == (
+        "moe_experts_hit", "moe_rows_max")
+    bands = (sds((2, AF_S, AF_MAXB), jnp.int32), sds((AF_S,), jnp.int32),
+             sds((AF_S,), jnp.int32), sds((AF_S,), jnp.bool_),
+             sds((AF_S,), jnp.float32), sds((AF_S,), jnp.int32),
+             sds((AF_S, 2), jnp.uint32), sds((AF_S,), jnp.int32),
+             sds((AF_S,), jnp.int32))  # ..., limits, eos
+    text = _compile(eng._decode_fn, params, cache, *bands)
+    module = re.match(r"HloModule (\S+?),", text).group(1)
+    for metric in ("decode_step_ms", "moe_expert_roofline",
+                   "swa_attn_roofline"):
+        program = _metric_spec(metric)["args"]["program_match"]
+        assert re.search(program, module + "(1)"), (metric, module)
+    assert " while(" not in text
+    entry = text[text.index("\nENTRY "):]
+    lines = [ln.strip() for ln in entry.split("\n")]
+    for metric, kernel, calls in (
+            ("moe_expert_roofline", "moe_grouped_matmul", 4),
+            ("swa_attn_roofline", "hybrid_decode_attention", 3)):
+        rx = _metric_pattern(metric)
+        found = [ln for ln in lines if rx.search(ln)]
+        assert len(found) == calls, (metric, len(found))
+        assert all(ln.startswith("%" + kernel) and " custom-call(" in ln
+                   for ln in found)
+        assert len(re.findall(r'kernel_metadata=\{\s*"kernel":"%s"\s*\}'
+                              % kernel, text)) >= calls
+    assert len([ln for ln in lines if ln.startswith("%paged_kv_write")
+                and " custom-call(" in ln]) == 3
+    assert text.count("tpu_custom_call") == 10
+    for scope in ("afmoe_attention", "afmoe_router", "afmoe_experts",
+                  "afmoe_shared"):
+        assert scope in text
+    # tokens, trap flags, magnitude, four bands, then the two counters
+    assert re.search(r"s32\[%d\]" % (6 * AF_S + 3), entry)
+    assert eng.metrics.decode_trace_count() == 1
+
+
+def test_afmoe_prefill_chunk_compiles_at_the_largest_bucket(one_chip,
+                                                            as_on_tpu):
+    """The cell's largest chunk program (4,096 rows: 32,768 routed
+    pairs through the same grouped product, the band of a window layer
+    tile by tile) compiles for the chip under its own name, with
+    bounded temporaries."""
+    eng, params, cache, sds = _afmoe_engine(one_chip)
+    lower = eng._chunk_fn(4096).lower(
+        params, cache, sds((4096,), jnp.int32), sds((), jnp.int32),
+        sds((4, AF_MAXB), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.float32), sds((2,), jnp.uint32))
+    with jax.default_matmul_precision(None):
+        compiled = lower.compile()
+    text = compiled.as_text()
+    assert re.match(r"HloModule jit__chunk[,.]", text)
+    assert "moe_grouped_matmul" in text and "afmoe_experts" in text
+    assert "hybrid_decode_attention" not in text  # the decode step's
+    # 1.17 GB at the cell's five layers (the routed rows' float32
+    # products and the combine's gather)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
