@@ -269,29 +269,72 @@ def test_blocked_scan_rows_with_a_zero_step_change_nothing():
         ssd_chunk_scan(s0, dt[:20], x[:20], a, b[:20], c[:20], block=8)
 
 
-@pytest.mark.parametrize("di", [256, 4096, 2560])
-def test_state_update_kernel_equals_its_reference(di):
+@pytest.mark.parametrize("S,N,di,batch,slots_a_batch,parked", [
+    (4, 8, 256, None, 4, 1), (4, 8, 4096, None, 4, 1),
+    (4, 8, 2560, None, 4, 1), (6, 16, 256, 2, 2, 1), (5, 16, 256, 2, 1, 1),
+    (7, 32, 128, 1, 1, 6), (1, 8, 256, None, 1, None),
+    (1, 8, 256, None, 1, 0), (3, 128, 4096, None, 3, 1),
+    (8, 128, 4096, None, 4, 5), (5, 128, 4096, None, 1, 0),
+], ids=["256", "4096", "2560", "two_a_batch", "five_slots_by_two",
+        "one_a_batch", "one_slot", "one_slot_parked", "cell_three",
+        "cell_four_a_batch", "cell_five_one_a_batch"])
+def test_state_update_kernel_equals_its_reference(S, N, di, batch,
+                                                  slots_a_batch, parked,
+                                                  monkeypatch):
     """The Pallas kernel, interpreted, against plain jax.numpy: every
     live slot's state and output row, and a parked slot's state bit
-    for bit what it was — a slot's whole rows a grid step (256
-    channels), or 2,048 channels a step (4,096); a width 2,048 does
-    not divide is refused."""
-    S, N = 4, 8
+    for bit what it was, on every branch of the batch rule
+    (`_step_slots`; `batch` slots' bytes stand in for `_STEP_BYTES`
+    where the small shapes need more than one batch): all the slots in
+    one batch (the first three: 256 channels, 4,096, and 2,560, which
+    2,048-channel tiles once refused), several batches of two, a slot
+    count two does not divide (one a batch), one a batch by the bytes,
+    a single slot, and the cell's [128, 4096] slot — three in one
+    batch, eight in two batches of four as the cell's 64 go, five one
+    at a time."""
+    from paddle_tpu.parallel import ssd_update
+
+    if batch is not None:
+        monkeypatch.setattr(ssd_update, "_STEP_BYTES", batch * N * di * 4)
+    assert ssd_update._step_slots(S, N * di * 4) == slots_a_batch
     rng = np.random.default_rng(0)
     f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
     state, dtx, b, c = f(S, N, di), f(S, di), f(S, N), f(S, N)
     da = -jnp.abs(f(S, di))
-    live = jnp.asarray([True, False, True, True])
-    if di % 2048 and di > 2048:
-        with pytest.raises(ValueError, match="tiles"):
-            ssd_state_update(state, da, dtx, b, c, live, interpret=True)
-        return
-    want_s, want_y = ssd_state_update_reference(state, da, dtx, b, c, live)
-    got_s, got_y = ssd_state_update(state, da, dtx, b, c, live,
+    live = np.ones(S, bool)
+    if parked is not None:
+        live[parked] = False
+    want_s, want_y = ssd_state_update_reference(state, da, dtx, b, c,
+                                                jnp.asarray(live))
+    got_s, got_y = ssd_state_update(state, da, dtx, b, c, jnp.asarray(live),
                                     interpret=True)
-    assert np.abs(np.asarray(got_s - want_s)).max() < 1e-6
-    assert np.abs(np.asarray(got_y - want_y))[np.asarray(live)].max() < 1e-5
-    assert np.array_equal(np.asarray(got_s[1]), np.asarray(state[1]))
+    # 1e-6 and 1e-5 as they stand wherever float32 can meet them; at
+    # the cell's 128 state columns the state's draws pass 16 (one ulp:
+    # 1.9e-6) and y, a sum of 128 products, 64 (one ulp: 7.6e-6): there,
+    # and only there, the bounds follow the largest value over 8
+    scale = ((lambda want: 1.0) if N < 128 else
+             (lambda want: max(1.0, float(jnp.abs(want).max()) / 8)))
+    assert np.abs(np.asarray(got_s - want_s)).max() < 1e-6 * scale(want_s)
+    if live.any():
+        assert (np.abs(np.asarray(got_y - want_y))[live].max()
+                < 1e-5 * scale(want_y))
+    if parked is not None:
+        assert np.array_equal(np.asarray(got_s[parked]),
+                              np.asarray(state[parked]))
+
+
+def test_state_update_kernel_refuses_what_vmem_cannot_hold():
+    """Two batches and the call's rows (da, dtx, B, C and y of EVERY
+    slot) lie in VMEM: a slot too large for that, or slots x channels
+    too many — a bound the 2,048-channel grid did not have — is
+    refused with its reason before Mosaic sees it."""
+    z = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    for S, di in ((2, 1 << 17), (4096, 1024)):
+        with pytest.raises(ValueError, match="VMEM"):
+            jax.eval_shape(
+                functools.partial(ssd_state_update, interpret=True),
+                z(S, 128, di), z(S, di), z(S, di), z(S, 128), z(S, 128),
+                jax.ShapeDtypeStruct((S,), jnp.bool_))
 
 
 # ---------------------------------------------------------------------

@@ -248,6 +248,15 @@ def _decode_text(eng, params, cache, bands, sds):
     return _compile(eng._decode_fn, params, cache, *bands, *limits_eos)
 
 
+def _text_digest(text):
+    """sha256 of a compiled program's text less locations and less the
+    kernels' embedded bodies (serialized with their files' line
+    numbers) -> (digest, kernels)."""
+    text, kernels = re.subn(r'"body":"[^"]*"', '"body":""',
+                            _without_locations(text))
+    return hashlib.sha256(text.encode()).hexdigest(), kernels
+
+
 def _without_locations(text):
     """Compiled text less what names the source: the stack-frame
     tables at its head, the ids into them, and op metadata."""
@@ -316,10 +325,9 @@ _GPT_DECODE_CALL_JAXPR_SHA = (
 
 def test_gpt_decode_program_is_the_text_the_parent_compiled(one_chip,
                                                             as_on_tpu):
-    text = _without_locations(_decode_text(*_engine(one_chip)))
-    text = re.sub(r'"body":"[^"]*"', '"body":""', text)
-    assert text.count('"body":""') == 2  # the two layers' decode kernels
-    assert hashlib.sha256(text.encode()).hexdigest() == _GPT_DECODE_TEXT_SHA
+    # two kernels: the two layers' decode calls
+    assert _text_digest(_decode_text(*_engine(one_chip))) == (
+        _GPT_DECODE_TEXT_SHA, 2)
     sh = jax.ShapeDtypeStruct
     pool = sh((3500, BT, H, DH), jnp.bfloat16)
     with jax.default_matmul_precision(None):  # the chip's own, as `_compile`
@@ -606,13 +614,18 @@ def test_ssd_state_update_kernel_carries_its_name(one_chip):
     """The one-token Mamba-2 state update at the cell's size (64 slots
     of [128, 4096] float32) compiles for the chip, in place, as ONE
     custom call named after the kernel, the name in `kernel_metadata`:
-    what `ssd_decode_roofline`'s `op_match` finds in a device trace."""
-    from paddle_tpu.parallel.ssd_update import ssd_state_update
+    what `ssd_decode_roofline`'s `op_match` finds in a device trace.
+    The batch rule gives the cell four slots a batch (ISSUE 34), and
+    Mosaic accepts the two batches and the call's rows in the VMEM the
+    call asks for, twice what it holds (beyond the 16 MiB a call is
+    scoped by default)."""
+    from paddle_tpu.parallel import ssd_update
 
+    assert ssd_update._step_slots(GR_S, 128 * 4096 * 4) == 4
     sds = _sds(one_chip)
     f32 = jnp.float32
     text = _compile(
-        lambda *a: ssd_state_update(*a, interpret=False),
+        lambda *a: ssd_update.ssd_state_update(*a, interpret=False),
         sds((GR_S, 128, 4096), f32), sds((GR_S, 4096), f32),
         sds((GR_S, 4096), f32), sds((GR_S, 128), f32),
         sds((GR_S, 128), f32), sds((GR_S,), jnp.bool_))
@@ -621,9 +634,19 @@ def test_ssd_state_update_kernel_carries_its_name(one_chip):
              if _metric_pattern("ssd_decode_roofline").search(ln)]
     assert len(found) == 1 and " custom-call(" in found[0]
     assert 'custom_call_target="tpu_custom_call"' in found[0]
+    # the state operand (the call's fourth) is the state result
+    assert "output_to_operand_aliasing={{0}: (3, {})}" in found[0]
     assert re.search(r'kernel_metadata=\{\s*"kernel":"ssd_state_update"\s*\}',
                      text)
     assert text.count("tpu_custom_call") == 1
+    call = text[text.index(found[0][:40]):]
+    scoped = int(re.search(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', call).group(1))
+    # twice what the call holds: the two batches and its rows, the
+    # rows double-buffered (da/dtx, y, and B/C padded to 128 lanes)
+    held = 2 * 4 * 128 * 4096 * 4 + 2 * GR_S * (3 * 4096 + 2 * 128) * 4
+    assert scoped == 2 * held <= ssd_update._VMEM_BYTES
 
 
 def test_granite_decode_program_is_the_one_the_benchmark_finds(one_chip,
@@ -813,6 +836,15 @@ def test_grouped_expert_product_carries_its_name(one_chip, rows, tm, tiles):
     assert text.count("tpu_custom_call") == 2
 
 
+def _afmoe_decode_text(eng, params, cache, sds):
+    bands = (sds((2, AF_S, AF_MAXB), jnp.int32), sds((AF_S,), jnp.int32),
+             sds((AF_S,), jnp.int32), sds((AF_S,), jnp.bool_),
+             sds((AF_S,), jnp.float32), sds((AF_S,), jnp.int32),
+             sds((AF_S, 2), jnp.uint32), sds((AF_S,), jnp.int32),
+             sds((AF_S,), jnp.int32))  # ..., limits, eos
+    return _compile(eng._decode_fn, params, cache, *bands)
+
+
 def test_afmoe_decode_program_is_the_one_the_benchmark_finds(one_chip,
                                                              as_on_tpu):
     """The fourth family rides the shared loop, one step ahead by
@@ -830,12 +862,7 @@ def test_afmoe_decode_program_is_the_one_the_benchmark_finds(one_chip,
     assert eng.async_dispatch and eng._win is not None
     assert not eng._has_state and eng._step_counters == (
         "moe_experts_hit", "moe_rows_max")
-    bands = (sds((2, AF_S, AF_MAXB), jnp.int32), sds((AF_S,), jnp.int32),
-             sds((AF_S,), jnp.int32), sds((AF_S,), jnp.bool_),
-             sds((AF_S,), jnp.float32), sds((AF_S,), jnp.int32),
-             sds((AF_S, 2), jnp.uint32), sds((AF_S,), jnp.int32),
-             sds((AF_S,), jnp.int32))  # ..., limits, eos
-    text = _compile(eng._decode_fn, params, cache, *bands)
+    text = _afmoe_decode_text(eng, params, cache, sds)
     module = re.match(r"HloModule (\S+?),", text).group(1)
     for metric in ("decode_step_ms", "moe_expert_roofline",
                    "swa_attn_roofline"):
@@ -885,3 +912,26 @@ def test_afmoe_prefill_chunk_compiles_at_the_largest_bucket(one_chip,
     # 1.17 GB at the cell's five layers (the routed rows' float32
     # products and the combine's gather)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# the SambaY and the sparse-expert decode programs as PR 33's tree
+# compiles them at their cells' geometry (ISSUE 34 changed the granite
+# family's state-update kernel and nothing these two are handed):
+# sha256 of the compiled text less locations and less the kernels'
+# embedded bodies, as `_GPT_DECODE_TEXT_SHA` above. A PR that MEANS to
+# change one of them replaces its digest (the failing assertion prints
+# the new one) and says so in CHANGES.md.
+_DECODE_TEXT_SHA = {
+    "hybrid": "fc6585d77a8b3bbdbcb94b371f5d1a8b13848788150cb7203628c2079fca0052",
+    "afmoe": "a14ad06027fdda8002081f90785b6c02becec9977e46d397c7ef1f5fb6db7298",
+}
+
+
+@pytest.mark.parametrize("family", sorted(_DECODE_TEXT_SHA))
+def test_other_families_decode_programs_are_the_text_the_parent_compiled(
+        one_chip, as_on_tpu, family):
+    if family == "hybrid":
+        text = _hybrid_decode_text(*_hybrid_engine(one_chip))
+    else:
+        text = _afmoe_decode_text(*_afmoe_engine(one_chip))
+    assert _text_digest(text)[0] == _DECODE_TEXT_SHA[family]
