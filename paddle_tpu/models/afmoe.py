@@ -66,6 +66,7 @@ import jax.numpy as jnp
 
 from .granite_hybrid import _chunk_attend, _view
 from .sambay import _mlp, _scatter_chunk, _scatter_rows
+from .scopes import scope
 from .transformer import _paged_kernel_check
 
 __all__ = ["AfmoeConfig", "init_params", "param_shapes", "param_count",
@@ -242,49 +243,56 @@ def moe_ffn(u32, p, cfg: AfmoeConfig, valid, kernel="gather"):
     from ..parallel.routed_experts import expert_ffn, route
 
     u = u32.astype(cfg.dtype)
-    with jax.named_scope("afmoe_router"):
-        idx, w = route(u32, p["router"], p["router_bias"], cfg.top_k,
-                       cfg.route_scale, cfg.route_norm)
-    with jax.named_scope("afmoe_experts"):
-        out, stats = expert_ffn(u, idx, w, p["experts"], valid,
-                                held=cfg.experts_held, kernel=kernel)
+    idx, w = route(u32, p["router"], p["router_bias"], cfg.top_k,
+                   cfg.route_scale, cfg.route_norm)
+    out, stats = expert_ffn(u, idx, w, p["experts"], valid,
+                            held=cfg.experts_held, kernel=kernel)
     if cfg.shared_expert_held:
-        with jax.named_scope("afmoe_shared"):
-            out = out + _mlp(u, p["shared"]).astype(jnp.float32)
+        out = out + _mlp(u, p["shared"]).astype(jnp.float32)
     return out, stats
 
 
 def _stack(params, x, cfg, attn, valid, kernel="gather"):
-    """Every layer in its residual form; `attn(kind, h, p)` is the
-    mode's (it owns whatever cache the mode has). `valid` [rows]: the
-    rows that reach experts. -> (the final norm's output, the expert
-    layers' stats summed / maxed: int32 [2])."""
+    """Every layer in its residual form, each part under its device
+    scope (`scopes.py`: a branch's four norms and its residual add
+    with the branch; an expert layer, router to combine and the shared
+    expert, is `lm_experts`, a leading dense layer `lm_mlp`);
+    `attn(kind, h, p)` is the mode's (it owns whatever cache the mode
+    has). `valid` [rows]: the rows that reach experts. -> (the final
+    norm's output, the expert layers' stats summed / maxed: int32
+    [2])."""
     dt, eps = x.dtype, cfg.eps
     hit, fullest = jnp.int32(0), jnp.int32(0)
     for l, (blk, kind) in enumerate(zip(params["blocks"], cfg.kinds)):
-        with jax.named_scope("afmoe_attention"):
+        with scope("lm_attention"):
             a = attn(kind, _rms32(x, blk["norm1"], eps).astype(dt),
                      blk["attn"])
-        x = x + _rms32(a, blk["norm2"], eps).astype(dt)
-        u32 = _rms32(x, blk["norm3"], eps)
-        if l < cfg.num_dense_layers:
-            m = _mlp(u32.astype(dt), blk["ffn"])
-        else:
-            m, stats = moe_ffn(u32, blk["ffn"], cfg, valid, kernel)
-            hit, fullest = hit + stats[0], jnp.maximum(fullest, stats[1])
-        x = x + _rms32(m, blk["norm4"], eps).astype(dt)
-    return (_rms32(x, params["norm_f"], eps).astype(dt),
-            jnp.stack([hit, fullest]))
+            x = x + _rms32(a, blk["norm2"], eps).astype(dt)
+        dense = l < cfg.num_dense_layers
+        with scope("lm_mlp" if dense else "lm_experts"):
+            u32 = _rms32(x, blk["norm3"], eps)
+            if dense:
+                m = _mlp(u32.astype(dt), blk["ffn"])
+            else:
+                m, stats = moe_ffn(u32, blk["ffn"], cfg, valid, kernel)
+                hit = hit + stats[0]
+                fullest = jnp.maximum(fullest, stats[1])
+            x = x + _rms32(m, blk["norm4"], eps).astype(dt)
+    with scope("lm_head"):
+        x = _rms32(x, params["norm_f"], eps).astype(dt)
+    return x, jnp.stack([hit, fullest])
 
 
 def _embed(params, tokens, cfg):
-    return params["embed"][tokens] * jnp.asarray(math.sqrt(cfg.dim),
-                                                 cfg.dtype)
+    with scope("lm_embed"):
+        return params["embed"][tokens] * jnp.asarray(math.sqrt(cfg.dim),
+                                                     cfg.dtype)
 
 
 def _head(params, x):
-    return jnp.matmul(x, params["head"].T,
-                      preferred_element_type=jnp.float32)
+    with scope("lm_head"):
+        return jnp.matmul(x, params["head"].T,
+                          preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------
@@ -519,7 +527,9 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
 
     x, _ = _stack(params, _embed(params, chunk, cfg), cfg, attn, valid,
                   kernel)
-    xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0, keepdims=False)
+    with scope("lm_head"):
+        xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0,
+                                          keepdims=False)
     return _head(params, xl), new
 
 

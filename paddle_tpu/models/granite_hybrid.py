@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from .sambay import _conv, _mlp, _pool_view, _scatter_chunk, _scatter_rows
+from .scopes import scope
 from .transformer import _paged_kernel_check
 
 __all__ = ["GraniteHybridConfig", "init_params", "forward", "init_cache",
@@ -176,27 +177,34 @@ def _rms(x, w, eps):
     return (y * w.astype(jnp.float32)).astype(x.dtype)
 
 
+_MIXER_SCOPE = {"mamba": "lm_state", "attention": "lm_attention"}
+
+
 def _stack(params, x, cfg, mixer):
-    """Every layer in its residual form; `mixer(kind, h, p)` is the
-    mode's (it owns whatever cache the mode has). -> the final norm's
-    output."""
+    """Every layer in its residual form, each part under its device
+    scope (`scopes.py`); `mixer(kind, h, p)` is the mode's (it owns
+    whatever cache the mode has). -> the final norm's output."""
     r = cfg.residual_multiplier
     for blk, kind in zip(params["blocks"], cfg.kinds):
-        with jax.named_scope("granite_" + kind):
+        with scope(_MIXER_SCOPE[kind]):
             x = x + r * mixer(kind, _rms(x, blk["norm1"], cfg.eps),
                               blk["mixer"])
-        with jax.named_scope("granite_mlp"):
+        with scope("lm_mlp"):
             x = x + r * _mlp(_rms(x, blk["norm2"], cfg.eps), blk)
-    return _rms(x, params["norm_f"], cfg.eps)
+    with scope("lm_head"):
+        return _rms(x, params["norm_f"], cfg.eps)
 
 
 def _embed(params, tokens, cfg):
-    return params["embed"][tokens] * cfg.embedding_multiplier
+    with scope("lm_embed"):
+        return params["embed"][tokens] * cfg.embedding_multiplier
 
 
 def _head(params, x, cfg):
-    return jnp.matmul(x, params["embed"].T,
-                      preferred_element_type=jnp.float32) / cfg.logits_scaling
+    with scope("lm_head"):
+        return jnp.matmul(
+            x, params["embed"].T,
+            preferred_element_type=jnp.float32) / cfg.logits_scaling
 
 
 def _split_in(h, p, cfg):
@@ -540,7 +548,9 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
         return o @ p["wo"]
 
     x = _stack(params, _embed(params, chunk, cfg), cfg, mixer)
-    xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0, keepdims=False)
+    with scope("lm_head"):
+        xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0,
+                                          keepdims=False)
     return _head(params, xl, cfg), new
 
 

@@ -56,6 +56,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from .scopes import scope
 from .transformer import _paged_kernel_check, _phys_rows
 
 __all__ = ["SambaYConfig", "layer_kinds", "init_params", "forward",
@@ -184,12 +185,29 @@ def _mlp(h, blk):
 
 
 def _stack(params, x, cfg, mixer):
-    """Every layer in its residual form; `mixer(l, kind, h, p)` is the
-    mode's (it owns whatever cache the mode has)."""
+    """Every layer in its residual form, each part under its device
+    scope (`scopes.py`: the Mamba layers and the gated memory units
+    are `lm_state`, every kind of attention `lm_attention`);
+    `mixer(l, kind, h, p)` is the mode's (it owns whatever cache the
+    mode has)."""
     for l, (blk, kind) in enumerate(zip(params["blocks"], cfg.kinds)):
-        x = x + mixer(l, kind, _ln(x, blk["ln1"]), blk["mixer"])
-        x = x + _mlp(_ln(x, blk["ln2"]), blk)
-    return _ln(x, params["ln_f"])
+        with scope("lm_state" if kind in ("mamba", "gmu")
+                   else "lm_attention"):
+            x = x + mixer(l, kind, _ln(x, blk["ln1"]), blk["mixer"])
+        with scope("lm_mlp"):
+            x = x + _mlp(_ln(x, blk["ln2"]), blk)
+    with scope("lm_head"):
+        return _ln(x, params["ln_f"])
+
+
+def _embed(params, tokens):
+    with scope("lm_embed"):
+        return params["embed"][tokens]
+
+
+def _head(params, x):
+    with scope("lm_head"):
+        return x @ params["embed"].T
 
 
 def _place_queries(q, cfg):
@@ -324,8 +342,8 @@ def forward(params, tokens, cfg: SambaYConfig):
                     cfg.window if kind == "window" else 0, cfg)
         return _attn_out(o, p, l, cfg, h.dtype)
 
-    x = _stack(params, params["embed"][tokens], cfg, mixer)
-    return x @ params["embed"].T
+    x = _stack(params, _embed(params, tokens), cfg, mixer)
+    return _head(params, x)
 
 
 # ---------------------------------------------------------------------
@@ -516,8 +534,8 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: SambaYConfig,
                 o = attend(q, kv, tab, None)
         return _attn_out(o, p, l, cfg, h.dtype)
 
-    x = _stack(params, params["embed"][token], cfg, mixer)
-    return x @ params["embed"].T, new
+    x = _stack(params, _embed(params, token), cfg, mixer)
+    return _head(params, x), new
 
 
 # ---------------------------------------------------------------------
@@ -670,9 +688,11 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
                 o = full_attend(q)
         return _attn_out(o, p, l, cfg, h.dtype)
 
-    x = _stack(params, params["embed"][chunk], cfg, mixer)
-    xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0, keepdims=False)
-    return xl @ params["embed"].T, new
+    x = _stack(params, _embed(params, chunk), cfg, mixer)
+    with scope("lm_head"):
+        xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0,
+                                          keepdims=False)
+    return _head(params, xl), new
 
 
 class _Serving(object):

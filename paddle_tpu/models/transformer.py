@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.attention import sequence_parallel_attention
+from .scopes import scope
 
 __all__ = ["TransformerConfig", "init_params", "param_specs", "forward",
            "loss_fn", "make_train_step"]
@@ -743,59 +744,63 @@ def paged_decode_step(params, token, pos, tables, cache,
     B = token.shape[0]
     dh = cfg.dim // cfg.heads
     NB, Bt = cache[0]["k"].shape[0], cache[0]["k"].shape[1]
-    x = params["embed"][token] + params["pos"][pos]
+    with scope("lm_embed"):
+        x = params["embed"][token] + params["pos"][pos]
     new_cache = []
     for li, (blk, kv) in enumerate(zip(params["blocks"], cache)):
-        h = _ln(x, blk["ln1"])
-        q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
-        q = q.reshape(B, cfg.heads, dh)
-        k = (h @ blk["wk"]).reshape(B, cfg.heads, dh)
-        v = v.reshape(B, cfg.heads, dh)
-        pk, off = _phys_rows(tables, pos, NB, Bt)
-        if quant:
-            ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
-                                     k, qmax)
-            cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
-                                     v, qmax)
-            new_cache.append({"k": ck, "v": cv,
-                              "k_scale": ksc, "v_scale": vsc})
-        else:
-            ksc = vsc = None
-            ck = kv["k"].at[pk, off].set(k.astype(kv["k"].dtype))
-            cv = kv["v"].at[pk, off].set(v.astype(kv["v"].dtype))
-            new_cache.append({"k": ck, "v": cv})
-        if kernel == "fused":
-            from ..parallel.paged_attention import paged_decode_attention
+        with scope("lm_attention"):
+            h = _ln(x, blk["ln1"])
+            q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
+            q = q.reshape(B, cfg.heads, dh)
+            k = (h @ blk["wk"]).reshape(B, cfg.heads, dh)
+            v = v.reshape(B, cfg.heads, dh)
+            pk, off = _phys_rows(tables, pos, NB, Bt)
+            if quant:
+                ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
+                                         k, qmax)
+                cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
+                                         v, qmax)
+                new_cache.append({"k": ck, "v": cv,
+                                  "k_scale": ksc, "v_scale": vsc})
+            else:
+                ksc = vsc = None
+                ck = kv["k"].at[pk, off].set(k.astype(kv["k"].dtype))
+                cv = kv["v"].at[pk, off].set(v.astype(kv["v"].dtype))
+                new_cache.append({"k": ck, "v": cv})
+            if kernel == "fused":
+                from ..parallel.paged_attention import paged_decode_attention
 
-            o = paged_decode_attention(
-                q, ck, cv, tables, pos, k_scale=ksc, v_scale=vsc
-            ).reshape(B, cfg.dim)
-        elif quant:
-            # f32 dequantized view: cast the attention output back to
-            # the activation dtype so quantization never silently
-            # promotes a bf16 model's residual stream (the fused
-            # kernel's out dtype is q's already)
-            o = _cached_attention(
-                q, _paged_deq_view(ck, ksc, tables),
-                _paged_deq_view(cv, vsc, tables), pos
-            ).astype(x.dtype).reshape(B, cfg.dim)
-        else:
-            o = _cached_attention(
-                q, _paged_view(ck, tables), _paged_view(cv, tables), pos
-            ).reshape(B, cfg.dim)
-        x = x + o @ blk["wo"]
-        h = _ln(x, blk["ln2"])
-        if "moe" in blk:
-            from ..parallel.moe import reference_moe
+                o = paged_decode_attention(
+                    q, ck, cv, tables, pos, k_scale=ksc, v_scale=vsc
+                ).reshape(B, cfg.dim)
+            elif quant:
+                # f32 dequantized view: cast the attention output back to
+                # the activation dtype so quantization never silently
+                # promotes a bf16 model's residual stream (the fused
+                # kernel's out dtype is q's already)
+                o = _cached_attention(
+                    q, _paged_deq_view(ck, ksc, tables),
+                    _paged_deq_view(cv, vsc, tables), pos
+                ).astype(x.dtype).reshape(B, cfg.dim)
+            else:
+                o = _cached_attention(
+                    q, _paged_view(ck, tables), _paged_view(cv, tables), pos
+                ).reshape(B, cfg.dim)
+            x = x + o @ blk["wo"]
+        with scope("lm_experts" if "moe" in blk else "lm_mlp"):
+            h = _ln(x, blk["ln2"])
+            if "moe" in blk:
+                from ..parallel.moe import reference_moe
 
-            mp = blk["moe"]
-            x = x + reference_moe(
-                h, mp["gate_w"], mp["w1"], mp["b1"], mp["w2"], mp["b2"]
-            )
-        else:
-            x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
-    x = _ln(x, params["ln_f"])
-    return x @ params["embed"].T, new_cache
+                mp = blk["moe"]
+                x = x + reference_moe(
+                    h, mp["gate_w"], mp["w1"], mp["b1"], mp["w2"], mp["b2"]
+                )
+            else:
+                x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
+    with scope("lm_head"):
+        logits = _ln(x, params["ln_f"]) @ params["embed"].T
+    return logits, new_cache
 
 
 def paged_prefill_chunk(params, cache, chunk, start_pos, table_row,
@@ -833,65 +838,69 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_row,
     offs = jnp.arange(C)
     positions = start_pos + offs  # [C] global rows of the chunk
     wpos = jnp.where(offs < true_len, positions, jnp.int32(Lv))
-    x = params["embed"][chunk][None] + params["pos"][positions][None]
+    with scope("lm_embed"):
+        x = params["embed"][chunk][None] + params["pos"][positions][None]
     new_cache = []
     for li, (blk, kv) in enumerate(zip(params["blocks"], cache)):
-        h = _ln(x, blk["ln1"])
-        q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
-        q = q.reshape(1, C, cfg.heads, dh)
-        k = (h @ blk["wk"]).reshape(1, C, cfg.heads, dh)
-        v = v.reshape(1, C, cfg.heads, dh)
-        pk, off = _phys_rows(table_row, wpos, NB, Bt)
-        if quant:
-            # call-commit: the chunk's whole fill of each opened block
-            # is real prompt content (never speculative), so the
-            # block scale sees every row — the best absmax available
-            ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
-                                     k[0], qmax, commit_from_call=True)
-            cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
-                                     v[0], qmax, commit_from_call=True)
-            new_cache.append({"k": ck, "v": cv,
-                              "k_scale": ksc, "v_scale": vsc})
-        else:
-            ksc = vsc = None
-            ck = kv["k"].at[pk, off].set(k[0].astype(kv["k"].dtype))
-            cv = kv["v"].at[pk, off].set(v[0].astype(kv["v"].dtype))
-            new_cache.append({"k": ck, "v": cv})
-        if kernel == "fused":
-            from ..parallel.paged_attention import (
-                paged_prefill_attention)
-
-            o = paged_prefill_attention(
-                q[0], ck, cv, table_row, start_pos,
-                k_scale=ksc, v_scale=vsc)[None]
-        else:
+        with scope("lm_attention"):
+            h = _ln(x, blk["ln1"])
+            q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
+            q = q.reshape(1, C, cfg.heads, dh)
+            k = (h @ blk["wk"]).reshape(1, C, cfg.heads, dh)
+            v = v.reshape(1, C, cfg.heads, dh)
+            pk, off = _phys_rows(table_row, wpos, NB, Bt)
             if quant:
-                slot_k = _paged_deq_view(ck, ksc, table_row[None])
-                slot_v = _paged_deq_view(cv, vsc, table_row[None])
+                # call-commit: the chunk's whole fill of each opened block
+                # is real prompt content (never speculative), so the
+                # block scale sees every row — the best absmax available
+                ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
+                                         k[0], qmax, commit_from_call=True)
+                cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
+                                         v[0], qmax, commit_from_call=True)
+                new_cache.append({"k": ck, "v": cv,
+                                  "k_scale": ksc, "v_scale": vsc})
             else:
-                slot_k = _paged_view(ck, table_row[None])  # [1, Lv, H, dh]
-                slot_v = _paged_view(cv, table_row[None])
-            s = jnp.einsum("bthd,bshd->bhts", q * scale, slot_k)
-            mask = jnp.arange(Lv)[None, :] <= positions[:, None]
-            s = jnp.where(mask[None, None], s, _NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhts,bshd->bthd", p, slot_v).astype(x.dtype)
-        x = x + o.reshape(1, C, cfg.dim) @ blk["wo"]
-        h = _ln(x, blk["ln2"])
-        if "moe" in blk:
-            from ..parallel.moe import reference_moe
+                ksc = vsc = None
+                ck = kv["k"].at[pk, off].set(k[0].astype(kv["k"].dtype))
+                cv = kv["v"].at[pk, off].set(v[0].astype(kv["v"].dtype))
+                new_cache.append({"k": ck, "v": cv})
+            if kernel == "fused":
+                from ..parallel.paged_attention import (
+                    paged_prefill_attention)
 
-            mp = blk["moe"]
-            flat = h.reshape(C, cfg.dim)
-            y = reference_moe(flat, mp["gate_w"], mp["w1"], mp["b1"],
-                              mp["w2"], mp["b2"])
-            x = x + y.reshape(1, C, cfg.dim)
-        else:
-            x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
-    xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=1,
-                                      keepdims=False)  # [1, dim]
-    xl = _ln(xl, params["ln_f"])
-    return (xl @ params["embed"].T)[0], new_cache
+                o = paged_prefill_attention(
+                    q[0], ck, cv, table_row, start_pos,
+                    k_scale=ksc, v_scale=vsc)[None]
+            else:
+                if quant:
+                    slot_k = _paged_deq_view(ck, ksc, table_row[None])
+                    slot_v = _paged_deq_view(cv, vsc, table_row[None])
+                else:
+                    slot_k = _paged_view(ck, table_row[None])  # [1, Lv, H, dh]
+                    slot_v = _paged_view(cv, table_row[None])
+                s = jnp.einsum("bthd,bshd->bhts", q * scale, slot_k)
+                mask = jnp.arange(Lv)[None, :] <= positions[:, None]
+                s = jnp.where(mask[None, None], s, _NEG_INF)
+                p = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bhts,bshd->bthd", p, slot_v).astype(x.dtype)
+            x = x + o.reshape(1, C, cfg.dim) @ blk["wo"]
+        with scope("lm_experts" if "moe" in blk else "lm_mlp"):
+            h = _ln(x, blk["ln2"])
+            if "moe" in blk:
+                from ..parallel.moe import reference_moe
+
+                mp = blk["moe"]
+                flat = h.reshape(C, cfg.dim)
+                y = reference_moe(flat, mp["gate_w"], mp["w1"], mp["b1"],
+                                  mp["w2"], mp["b2"])
+                x = x + y.reshape(1, C, cfg.dim)
+            else:
+                x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
+    with scope("lm_head"):
+        xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=1,
+                                          keepdims=False)  # [1, dim]
+        logits = (_ln(xl, params["ln_f"]) @ params["embed"].T)[0]
+    return logits, new_cache
 
 
 def paged_verify_step(params, cache, window, pos, wpos, tables,
@@ -930,59 +939,63 @@ def paged_verify_step(params, cache, window, pos, wpos, tables,
     Lv = tables.shape[1] * Bt
     scale = 1.0 / math.sqrt(dh)
     positions = pos[:, None] + jnp.arange(K)[None, :]  # [S, K]
-    x = params["embed"][window] + params["pos"][positions]
+    with scope("lm_embed"):
+        x = params["embed"][window] + params["pos"][positions]
     new_cache = []
     for li, (blk, kv) in enumerate(zip(params["blocks"], cache)):
-        h = _ln(x, blk["ln1"])
-        q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
-        q = q.reshape(S, K, cfg.heads, dh)
-        k = (h @ blk["wk"]).reshape(S, K, cfg.heads, dh)
-        v = v.reshape(S, K, cfg.heads, dh)
-        pk, off = _phys_rows(tables, wpos, NB, Bt)  # [S, K]
-        if quant:
-            ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
-                                     k, qmax)
-            cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
-                                     v, qmax)
-            new_cache.append({"k": ck, "v": cv,
-                              "k_scale": ksc, "v_scale": vsc})
-        else:
-            ksc = vsc = None
-            ck = kv["k"].at[pk, off].set(k.astype(kv["k"].dtype))
-            cv = kv["v"].at[pk, off].set(v.astype(kv["v"].dtype))
-            new_cache.append({"k": ck, "v": cv})
-        if kernel == "fused":
-            from ..parallel.paged_attention import (
-                paged_verify_attention)
-
-            o = paged_verify_attention(q, ck, cv, tables, pos,
-                                       k_scale=ksc, v_scale=vsc)
-        else:
+        with scope("lm_attention"):
+            h = _ln(x, blk["ln1"])
+            q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
+            q = q.reshape(S, K, cfg.heads, dh)
+            k = (h @ blk["wk"]).reshape(S, K, cfg.heads, dh)
+            v = v.reshape(S, K, cfg.heads, dh)
+            pk, off = _phys_rows(tables, wpos, NB, Bt)  # [S, K]
             if quant:
-                kview = _paged_deq_view(ck, ksc, tables)
-                vview = _paged_deq_view(cv, vsc, tables)
+                ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
+                                         k, qmax)
+                cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
+                                         v, qmax)
+                new_cache.append({"k": ck, "v": cv,
+                                  "k_scale": ksc, "v_scale": vsc})
             else:
-                kview = _paged_view(ck, tables)  # [S, Lv, H, dh]
-                vview = _paged_view(cv, tables)
-            s = jnp.einsum("bthd,bshd->bhts", q * scale, kview)
-            mask = jnp.arange(Lv)[None, None, :] <= positions[:, :, None]
-            s = jnp.where(mask[:, None], s, _NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhts,bshd->bthd", p, vview).astype(x.dtype)
-        x = x + o.reshape(S, K, cfg.dim) @ blk["wo"]
-        h = _ln(x, blk["ln2"])
-        if "moe" in blk:
-            from ..parallel.moe import reference_moe
+                ksc = vsc = None
+                ck = kv["k"].at[pk, off].set(k.astype(kv["k"].dtype))
+                cv = kv["v"].at[pk, off].set(v.astype(kv["v"].dtype))
+                new_cache.append({"k": ck, "v": cv})
+            if kernel == "fused":
+                from ..parallel.paged_attention import (
+                    paged_verify_attention)
 
-            mp = blk["moe"]
-            flat = h.reshape(S * K, cfg.dim)
-            y = reference_moe(flat, mp["gate_w"], mp["w1"], mp["b1"],
-                              mp["w2"], mp["b2"])
-            x = x + y.reshape(S, K, cfg.dim)
-        else:
-            x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
-    x = _ln(x, params["ln_f"])
-    return x @ params["embed"].T, new_cache
+                o = paged_verify_attention(q, ck, cv, tables, pos,
+                                           k_scale=ksc, v_scale=vsc)
+            else:
+                if quant:
+                    kview = _paged_deq_view(ck, ksc, tables)
+                    vview = _paged_deq_view(cv, vsc, tables)
+                else:
+                    kview = _paged_view(ck, tables)  # [S, Lv, H, dh]
+                    vview = _paged_view(cv, tables)
+                s = jnp.einsum("bthd,bshd->bhts", q * scale, kview)
+                mask = jnp.arange(Lv)[None, None, :] <= positions[:, :, None]
+                s = jnp.where(mask[:, None], s, _NEG_INF)
+                p = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bhts,bshd->bthd", p, vview).astype(x.dtype)
+            x = x + o.reshape(S, K, cfg.dim) @ blk["wo"]
+        with scope("lm_experts" if "moe" in blk else "lm_mlp"):
+            h = _ln(x, blk["ln2"])
+            if "moe" in blk:
+                from ..parallel.moe import reference_moe
+
+                mp = blk["moe"]
+                flat = h.reshape(S * K, cfg.dim)
+                y = reference_moe(flat, mp["gate_w"], mp["w1"], mp["b1"],
+                                  mp["w2"], mp["b2"])
+                x = x + y.reshape(S, K, cfg.dim)
+            else:
+                x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
+    with scope("lm_head"):
+        logits = _ln(x, params["ln_f"]) @ params["embed"].T
+    return logits, new_cache
 
 
 def logits_trap(logits):

@@ -101,6 +101,7 @@ import numpy as np
 from ..distributed import fault_injection as _fi
 from ..fluid.core.kernels_sequence import bucket_pow2
 from ..models import transformer as tlm
+from ..models.scopes import scope
 from .adapters import AdapterPool
 from .integrity import (_FP_RTOL, BlockFingerprints, IntegrityError,
                         ServingSentinel)
@@ -799,37 +800,42 @@ class ServingEngine(object):
                 adapters=adapters, adapter_idx=aidx, kernel=kernel,
                 kv_quant=kv_quant,
             )
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            keys = jax.vmap(jax.random.fold_in)(base_keys, counts)
-            safe_t = jnp.where(temps > 0, temps, 1.0)
-            sampled = jax.vmap(
-                lambda k, l, t: jax.random.categorical(
-                    k, l.astype(jnp.float32) / t
-                )
-            )(keys, logits, safe_t).astype(jnp.int32)
-            nxt = jnp.where(temps > 0, sampled, greedy)
-            if traps:
-                trap = tlm.logits_trap(logits) & alive
-                scale = tlm.logit_amax(logits, alive)
-            else:
-                trap = jnp.zeros_like(alive)
-                scale = jnp.float32(0.0)
-            # dead lanes emit -1 padding; a live lane emits its token
-            # even on its retirement step (EOS/budget tokens ARE
-            # emitted, exactly like the host _emit rule)
-            emitted = jnp.where(alive, nxt, jnp.int32(-1))
-            nalive, npos = tlm.decode_retire(alive, nxt, pos, limits, eos)
-            ntok = jnp.where(alive, nxt, tok)
-            row = jnp.concatenate([
-                emitted, trap.astype(jnp.int32),
-                jax.lax.bitcast_convert_type(
-                    scale.astype(jnp.float32), jnp.int32)[None]])
-            ncounts = counts + alive.astype(jnp.int32)
-            # a family's own step counters (`step_counters`, int32, one
-            # a name) ride the same array, last
-            packed = jnp.concatenate([
-                row, ntok, npos, nalive.astype(jnp.int32), ncounts]
-                + [st.astype(jnp.int32) for st in stats])
+            # the step's tail under its device scopes (models/scopes.py)
+            with scope("step_sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                keys = jax.vmap(jax.random.fold_in)(base_keys, counts)
+                safe_t = jnp.where(temps > 0, temps, 1.0)
+                sampled = jax.vmap(
+                    lambda k, l, t: jax.random.categorical(
+                        k, l.astype(jnp.float32) / t
+                    )
+                )(keys, logits, safe_t).astype(jnp.int32)
+                nxt = jnp.where(temps > 0, sampled, greedy)
+            with scope("step_traps"):
+                if traps:
+                    trap = tlm.logits_trap(logits) & alive
+                    scale = tlm.logit_amax(logits, alive)
+                else:
+                    trap = jnp.zeros_like(alive)
+                    scale = jnp.float32(0.0)
+            with scope("step_retire"):
+                # dead lanes emit -1 padding; a live lane emits its
+                # token even on its retirement step (EOS/budget tokens
+                # ARE emitted, exactly like the host _emit rule)
+                emitted = jnp.where(alive, nxt, jnp.int32(-1))
+                nalive, npos = tlm.decode_retire(alive, nxt, pos, limits,
+                                                 eos)
+                ntok = jnp.where(alive, nxt, tok)
+                row = jnp.concatenate([
+                    emitted, trap.astype(jnp.int32),
+                    jax.lax.bitcast_convert_type(
+                        scale.astype(jnp.float32), jnp.int32)[None]])
+                ncounts = counts + alive.astype(jnp.int32)
+                # a family's own step counters (`step_counters`, int32,
+                # one a name) ride the same array, last
+                packed = jnp.concatenate([
+                    row, ntok, npos, nalive.astype(jnp.int32), ncounts]
+                    + [st.astype(jnp.int32) for st in stats])
             return cache, ntok, npos, nalive, ncounts, packed
 
         kw = {"donate_argnums": (1,)} if self._donate else {}
@@ -876,35 +882,38 @@ class ServingEngine(object):
                 adapters=adapters, adapter_idx=aidx, kernel=kernel,
                 kv_quant=kv_quant,
             )
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            # per-position sampling keys: position i of a slot whose
-            # request has emitted `counts` tokens samples token index
-            # counts + i — the SAME fold_in schedule the plain decode
-            # path uses, so sampled outputs are spec-invariant
-            idx = counts[:, None] + jnp.arange(K)[None, :]
-            keys = jax.vmap(
-                jax.vmap(jax.random.fold_in, in_axes=(None, 0)),
-                in_axes=(0, 0),
-            )(base_keys, idx)
-            safe_t = jnp.where(temps > 0, temps, 1.0)
-            sampled = jax.vmap(
-                jax.vmap(
-                    lambda k, l, t: jax.random.categorical(
-                        k, l.astype(jnp.float32) / t
+            with scope("step_sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                # per-position sampling keys: position i of a slot
+                # whose request has emitted `counts` tokens samples
+                # token index counts + i — the SAME fold_in schedule
+                # the plain decode path uses, so sampled outputs are
+                # spec-invariant
+                idx = counts[:, None] + jnp.arange(K)[None, :]
+                keys = jax.vmap(
+                    jax.vmap(jax.random.fold_in, in_axes=(None, 0)),
+                    in_axes=(0, 0),
+                )(base_keys, idx)
+                safe_t = jnp.where(temps > 0, temps, 1.0)
+                sampled = jax.vmap(
+                    jax.vmap(
+                        lambda k, l, t: jax.random.categorical(
+                            k, l.astype(jnp.float32) / t
+                        ),
+                        in_axes=(0, 0, None),
                     ),
-                    in_axes=(0, 0, None),
-                ),
-                in_axes=(0, 0, 0),
-            )(keys, logits, safe_t).astype(jnp.int32)
-            cand = jnp.where((temps > 0)[:, None], sampled, greedy)
+                    in_axes=(0, 0, 0),
+                )(keys, logits, safe_t).astype(jnp.int32)
+                cand = jnp.where((temps > 0)[:, None], sampled, greedy)
             # ISSUE 15 traps over the whole [S, K] window, reduced to
             # per-slot (any corrupt row in a slot's window trips it)
-            if traps:
-                trap = tlm.logits_trap(logits).any(axis=-1) & alive
-                scale = tlm.logit_amax(logits, alive)
-            else:
-                trap = jnp.zeros_like(alive)
-                scale = jnp.float32(0.0)
+            with scope("step_traps"):
+                if traps:
+                    trap = tlm.logits_trap(logits).any(axis=-1) & alive
+                    scale = tlm.logit_amax(logits, alive)
+                else:
+                    trap = jnp.zeros_like(alive)
+                    scale = jnp.float32(0.0)
             return cache, cand, trap, scale
 
         kw = {"donate_argnums": (1,)} if self._donate else {}
@@ -934,24 +943,26 @@ class ServingEngine(object):
                 true_len=true_len, adapters=adapters, adapter_idx=aidx,
                 kernel=kernel, kv_quant=kv_quant,
             )
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            sampled = jax.random.categorical(
-                key,
-                logits.astype(jnp.float32)
-                / jnp.where(temp > 0, temp, 1.0),
-            ).astype(jnp.int32)
-            first = jnp.where(temp > 0, sampled, greedy)
+            with scope("step_sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                sampled = jax.random.categorical(
+                    key,
+                    logits.astype(jnp.float32)
+                    / jnp.where(temp > 0, temp, 1.0),
+                ).astype(jnp.int32)
+                first = jnp.where(temp > 0, sampled, greedy)
             # ISSUE 15 trap on the chunk's last-token logits. A NaN
             # written MID-chunk propagates: attention over a NaN K/V
             # row yields NaN logits at the final chunk, which is the
             # only chunk the host reads back anyway (mid-prompt chunks
             # stay dispatch-only so prefill keeps overlapping decode)
-            if traps:
-                trap = tlm.logits_trap(logits)
-                scale = tlm.logit_amax(logits)
-            else:
-                trap = jnp.bool_(False)
-                scale = jnp.float32(0.0)
+            with scope("step_traps"):
+                if traps:
+                    trap = tlm.logits_trap(logits)
+                    scale = tlm.logit_amax(logits)
+                else:
+                    trap = jnp.bool_(False)
+                    scale = jnp.float32(0.0)
             return cache, first, trap, scale
 
         kw = {"donate_argnums": (1,)} if self._donate else {}
@@ -2083,17 +2094,11 @@ class ServingEngine(object):
 
     def _count_decode_step(self):
         """One decode step (plain, or a verify step) was dispatched:
-        slot occupancy, and how much of the block tables
-        the live contexts name against all of it (S x MAXB entries) —
-        `decode_blocks_live / decode_blocks_walked` is the share of a
-        whole-table walk that finds a block, i.e. what the decode
-        kernel's work list keeps of the old (slots, groups) grid."""
+        slot occupancy and, for a family with window tables or state,
+        the bytes its caches hold by kind."""
         m, alive = self.metrics, self._alive
         m.decode_steps += 1
         m.occupancy.append(float(alive.sum()) / self.max_slots)
-        m.decode_blocks_live += int(
-            (self._pos[alive] // self.kv_block_tokens + 1).sum())
-        m.decode_blocks_walked += self.max_slots * self.blocks_per_slot
         win = self._win
         if win is not None or self._has_state:
             # by kind of cache, the kinds the family has

@@ -70,10 +70,6 @@ class ServingMetrics(object):
         self.decode_steps = 0
         self.tokens_out = 0
         self.occupancy = _RunningStat()  # live slots / max_slots per decode
-        # per decode step: table entries the live contexts name / all
-        # S x MAXB of them (what a whole-table grid walks)
-        self.decode_blocks_live = 0
-        self.decode_blocks_walked = 0
         self.queue_wait_s = _RunningStat()  # submit -> admission
         self.ttft_s = _RunningStat()  # submit -> first token
         # PR 4 counters — same O(1) discipline (ints + RunningStat, no
@@ -266,8 +262,6 @@ class ServingMetrics(object):
             "decode_chain_breaks": self.decode_chain_breaks,
             "prefills": self.prefills,
             "mean_occupancy": _mean(self.occupancy),
-            "decode_blocks_live": self.decode_blocks_live,
-            "decode_blocks_walked": self.decode_blocks_walked,
             "mean_queue_wait_s": _mean(self.queue_wait_s),
             "max_queue_wait_s": round(self.queue_wait_s.max, 6)
             if self.queue_wait_s.count else None,

@@ -293,12 +293,6 @@ def test_metrics_report_and_profiler_table(capsys):
     assert rep["tokens_out"] == 4 * 5
     assert rep["prefills"] == 4
     assert 0.0 < rep["mean_occupancy"] <= 1.0
-    # a whole-table walk is 2 slots x the table row's width a step; a
-    # live slot's context (at most 12 + 5 tokens) names 1 or 2 entries
-    walked = rep["decode_steps"] * 2 * eng.blocks_per_slot
-    assert rep["decode_blocks_walked"] == walked
-    live_slot_steps = round(rep["mean_occupancy"] * 2 * rep["decode_steps"])
-    assert live_slot_steps <= rep["decode_blocks_live"] <= 2 * live_slot_steps
     assert rep["decode_traces"] == 1
     assert rep["tokens_per_sec"] > 0
     assert rep["mean_ttft_s"] >= rep["mean_queue_wait_s"] >= 0.0

@@ -71,14 +71,19 @@ def as_on_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def _compile(fn, *args, **kwargs):
+def _compiled(fn, *args, **kwargs):
     """Compile `fn` (a function, or one of the engine's jitted steps)
     for the devices its argument shapes are placed on, at the chip's
     own matmul precision (conftest pins float32 for the numeric
-    tests); returns the compiled module's text."""
+    tests)."""
     lower = fn.lower if hasattr(fn, "lower") else jax.jit(fn).lower
     with jax.default_matmul_precision(None):
-        return lower(*args, **kwargs).compile().as_text()
+        return lower(*args, **kwargs).compile()
+
+
+def _compile(fn, *args, **kwargs):
+    """-> the compiled module's text."""
+    return _compiled(fn, *args, **kwargs).as_text()
 
 
 def _placed(tree, sharding):
@@ -323,11 +328,9 @@ _GPT_DECODE_CALL_JAXPR_SHA = (
     "8792e8e088ffe1e215a99c09a7b5fb41b0828c93d1986e4b645b8503d14b1553")
 
 
-def test_gpt_decode_program_is_the_text_the_parent_compiled(one_chip,
-                                                            as_on_tpu):
+def test_gpt_decode_program_is_the_text_the_parent_compiled(programs):
     # two kernels: the two layers' decode calls
-    assert _text_digest(_decode_text(*_engine(one_chip))) == (
-        _GPT_DECODE_TEXT_SHA, 2)
+    assert _text_digest(programs("gpt").text) == (_GPT_DECODE_TEXT_SHA, 2)
     sh = jax.ShapeDtypeStruct
     pool = sh((3500, BT, H, DH), jnp.bfloat16)
     with jax.default_matmul_precision(None):  # the chip's own, as `_compile`
@@ -341,8 +344,7 @@ def test_gpt_decode_program_is_the_text_the_parent_compiled(one_chip,
 
 
 
-def test_default_decode_program_is_what_the_benchmark_reads(one_chip,
-                                                            as_on_tpu):
+def test_default_decode_program_is_what_the_benchmark_reads(programs):
     """The yardstick of the serving cells (ISSUE 28): a default engine
     runs one step ahead, and its decode program is still the one
     `decode_step_ms` and `paged_attn_roofline` find — named so that
@@ -351,9 +353,8 @@ def test_default_decode_program_is_what_the_benchmark_reads(one_chip,
     matches it as a device trace names it, `jit_<function>(<id>)`, and
     FLAT: the kernel's custom call sits in the entry computation, once
     a layer, where `op_match` finds it — not inside a loop's body."""
-    eng, params, cache, bands, sds = _engine(one_chip)
+    eng, text = programs("gpt").eng, programs("gpt").text
     assert eng.async_dispatch
-    text = _decode_text(eng, params, cache, bands, sds)
     module = re.match(r"HloModule (\S+?),", text).group(1)
     program = _metric_spec("decode_step_ms")["args"]["program_match"]
     assert _metric_spec("paged_attn_roofline")["args"][
@@ -366,15 +367,9 @@ def test_default_decode_program_is_what_the_benchmark_reads(one_chip,
     assert eng.metrics.decode_trace_count() == 1
 
 
-def test_engine_prefill_step_compiles_at_the_largest_bucket(one_chip,
-                                                            as_on_tpu):
-    eng, params, cache, _, sds = _engine(one_chip)
-    assert eng._bucket(L - 100) == L
-    assert _compile(
-        eng._chunk_fn(L), params, cache, sds((L,), jnp.int32),
-        sds((), jnp.int32), sds((MAXB,), jnp.int32), sds((), jnp.int32),
-        sds((), jnp.float32), sds((2,), jnp.uint32),
-    ).count("tpu_custom_call") >= 2
+def test_engine_prefill_step_compiles_at_the_largest_bucket(programs):
+    assert programs("gpt").eng._bucket(L - 100) == L
+    assert programs("gpt", "chunk").text.count("tpu_custom_call") >= 2
 
 
 def test_data_parallel_step_has_an_all_reduce(topo):
@@ -491,17 +486,32 @@ def _metric_pattern(name):
     return re.compile(_metric_spec(name)["args"]["op_match"])
 
 
+def _bands(sds, slots, *table):
+    """A decode step's arguments behind the cache: the block tables
+    [*table], then token, position, alive, temperature, count, base
+    key, limit and EOS a slot."""
+    S_ = (slots,)
+    return (sds(table, jnp.int32), sds(S_, jnp.int32), sds(S_, jnp.int32),
+            sds(S_, jnp.bool_), sds(S_, jnp.float32), sds(S_, jnp.int32),
+            sds((slots, 2), jnp.uint32), sds(S_, jnp.int32),
+            sds(S_, jnp.int32))
+
+
+def _chunk_args(sds, rows, *table):
+    """A chunk step's arguments behind the cache: the padded tokens,
+    the start, the slot's table rows [*table], the true length, the
+    temperature and the key."""
+    return (sds((rows,), jnp.int32), sds((), jnp.int32),
+            sds(table, jnp.int32), sds((), jnp.int32),
+            sds((), jnp.float32), sds((2,), jnp.uint32))
+
+
 def _hybrid_decode_text(eng, params, cache, sds):
-    bands = (sds((2, HY_S, HY_MAXB), jnp.int32), sds((HY_S,), jnp.int32),
-             sds((HY_S,), jnp.int32), sds((HY_S,), jnp.bool_),
-             sds((HY_S,), jnp.float32), sds((HY_S,), jnp.int32),
-             sds((HY_S, 2), jnp.uint32), sds((HY_S,), jnp.int32),
-             sds((HY_S,), jnp.int32))  # ..., limits, eos
-    return _compile(eng._decode_fn, params, cache, *bands)
+    return _compile(eng._decode_fn, params, cache,
+                    *_bands(sds, HY_S, 2, HY_S, HY_MAXB))
 
 
-def test_hybrid_decode_program_is_the_one_the_benchmark_finds(one_chip,
-                                                              as_on_tpu):
+def test_hybrid_decode_program_is_the_one_the_benchmark_finds(programs):
     """The hybrid family rides the shared loop (ISSUE 29), one step
     ahead of the host by default like the GPT block (ISSUE 30): its
     decode program is built by the one `_make_decode`, so at the
@@ -512,9 +522,8 @@ def test_hybrid_decode_program_is_the_one_the_benchmark_finds(one_chip,
     no loop, both kernels' calls in the entry computation where
     `op_match` finds them, one packed result beside the cache and the
     four advanced bands."""
-    eng, params, cache, sds = _hybrid_engine(one_chip)
+    eng, text = programs("hybrid").eng, programs("hybrid").text
     assert eng.async_dispatch and eng._win is not None
-    text = _hybrid_decode_text(eng, params, cache, sds)
     module = re.match(r"HloModule (\S+?),", text).group(1)
     for metric in ("decode_step_ms", "hybrid_attn_roofline",
                    "ssm_decode_roofline"):
@@ -533,7 +542,7 @@ def test_hybrid_decode_program_is_the_one_the_benchmark_finds(one_chip,
 
 
 def test_hybrid_decode_step_compiles_and_is_what_the_benchmark_reads(
-        one_chip, as_on_tpu):
+        programs):
     """The decode program of the hybrid cell compiles for the chip with
     both new kernels in it, and the two roofline metrics' patterns
     (`op_match` of `hybrid_attn_roofline.json` and
@@ -542,8 +551,7 @@ def test_hybrid_decode_step_compiles_and_is_what_the_benchmark_reads(
     calls: 4 attention calls (2 window, 1 full, 1 cross at this depth)
     and 3 state updates, each named after its kernel in
     `kernel_metadata`. The files are read, never edited."""
-    eng, params, cache, sds = _hybrid_engine(one_chip)
-    text = _hybrid_decode_text(eng, params, cache, sds)
+    text = programs("hybrid").text
     lines = [ln.strip() for ln in text.split("\n")]
     for metric, kernel, calls in (
             ("hybrid_attn_roofline", "hybrid_decode_attention", 4),
@@ -561,16 +569,9 @@ def test_hybrid_decode_step_compiles_and_is_what_the_benchmark_reads(
     assert text.count("tpu_custom_call") == 10
 
 
-def test_hybrid_prefill_chunk_compiles_at_the_largest_bucket(one_chip,
-                                                              as_on_tpu):
-    eng, params, cache, sds = _hybrid_engine(one_chip)
-    assert eng._bucket(4096 - 100) == 4096
-    lower = eng._chunk_fn(4096).lower(
-        params, cache, sds((4096,), jnp.int32), sds((), jnp.int32),
-        sds((4, HY_MAXB), jnp.int32), sds((), jnp.int32),
-        sds((), jnp.float32), sds((2,), jnp.uint32))
-    with jax.default_matmul_precision(None):
-        mem = lower.compile().memory_analysis()
+def test_hybrid_prefill_chunk_compiles_at_the_largest_bucket(programs):
+    assert programs("hybrid").eng._bucket(4096 - 100) == 4096
+    mem = programs("hybrid", "chunk").compiled.memory_analysis()
     # the tiled attention and the stepwise scan keep the temporaries
     # bounded: about 1 GB at 32 layers, the same here (they do not
     # add up over layers)
@@ -649,8 +650,7 @@ def test_ssd_state_update_kernel_carries_its_name(one_chip):
     assert scoped == 2 * held <= ssd_update._VMEM_BYTES
 
 
-def test_granite_decode_program_is_the_one_the_benchmark_finds(one_chip,
-                                                               as_on_tpu):
+def test_granite_decode_program_is_the_one_the_benchmark_finds(programs):
     """This family rides the shared loop too, one step ahead by
     default: at the cell's geometry its decode program is the one
     `decode_step_ms`, `ssd_decode_roofline` and `gqa_attn_roofline` look
@@ -659,14 +659,8 @@ def test_granite_decode_program_is_the_one_the_benchmark_finds(one_chip,
     updates and 1 grouped-query attention call at this depth, each
     named after its kernel, beside the one K/V write; one packed
     result for the host."""
-    eng, params, cache, sds = _granite_engine(one_chip)
+    eng, text = programs("granite").eng, programs("granite").text
     assert eng.async_dispatch and eng._has_state
-    bands = (sds((GR_S, GR_MAXB), jnp.int32), sds((GR_S,), jnp.int32),
-             sds((GR_S,), jnp.int32), sds((GR_S,), jnp.bool_),
-             sds((GR_S,), jnp.float32), sds((GR_S,), jnp.int32),
-             sds((GR_S, 2), jnp.uint32), sds((GR_S,), jnp.int32),
-             sds((GR_S,), jnp.int32))  # ..., limits, eos
-    text = _compile(eng._decode_fn, params, cache, *bands)
     module = re.match(r"HloModule (\S+?),", text).group(1)
     for metric in ("decode_step_ms", "ssd_decode_roofline",
                    "gqa_attn_roofline"):
@@ -688,29 +682,25 @@ def test_granite_decode_program_is_the_one_the_benchmark_finds(one_chip,
     assert len([ln for ln in lines if ln.startswith("%paged_kv_write")
                 and " custom-call(" in ln]) == 1
     assert text.count("tpu_custom_call") == 11
-    # the mixers' and the MLP's named scopes, in this family's text only
-    for scope in ("granite_mamba", "granite_attention", "granite_mlp"):
-        assert scope in text
+    # the mixers' and the MLP's device scopes, under the names every
+    # family shares (ISSUE 35); the family-prefixed ones are gone
+    for scope in ("lm_state", "lm_attention", "lm_mlp"):
+        assert "/%s/" % scope in text
+    assert not re.search(r'op_name="[^"]*granite_', text)
     assert re.search(r"s32\[%d\]" % (6 * GR_S + 1), entry)
     assert eng.metrics.decode_trace_count() == 1
 
 
-def test_granite_prefill_chunk_compiles_at_its_one_bucket(one_chip,
-                                                          as_on_tpu):
+def test_granite_prefill_chunk_compiles_at_its_one_bucket(programs):
     """The cell's one chunk program (2,048 rows; `min_bucket` makes it
     the only one) compiles for the chip under its own name, the blocked
     scan as a loop of matrix products, with bounded temporaries."""
-    eng, params, cache, sds = _granite_engine(one_chip)
+    eng = programs("granite").eng
     assert eng._bucket(1) == eng._bucket(GR_CHUNK - 100) == GR_CHUNK
-    lower = eng._chunk_fn(GR_CHUNK).lower(
-        params, cache, sds((GR_CHUNK,), jnp.int32), sds((), jnp.int32),
-        sds((2, GR_MAXB), jnp.int32), sds((), jnp.int32),
-        sds((), jnp.float32), sds((2,), jnp.uint32))
-    with jax.default_matmul_precision(None):
-        compiled = lower.compile()
-    text = compiled.as_text()
+    compiled = programs("granite", "chunk").compiled
+    text = programs("granite", "chunk").text
     assert re.match(r"HloModule jit__chunk[,.]", text)
-    assert "granite_mamba" in text and " while(" in text
+    assert "/lm_state/" in text and " while(" in text
     assert "ssd_state_update" not in text  # the decode step's kernel
     # ~0.6 GB at 10 layers, 1.15 GB at 40 (weights' copies in flight)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
@@ -836,17 +826,7 @@ def test_grouped_expert_product_carries_its_name(one_chip, rows, tm, tiles):
     assert text.count("tpu_custom_call") == 2
 
 
-def _afmoe_decode_text(eng, params, cache, sds):
-    bands = (sds((2, AF_S, AF_MAXB), jnp.int32), sds((AF_S,), jnp.int32),
-             sds((AF_S,), jnp.int32), sds((AF_S,), jnp.bool_),
-             sds((AF_S,), jnp.float32), sds((AF_S,), jnp.int32),
-             sds((AF_S, 2), jnp.uint32), sds((AF_S,), jnp.int32),
-             sds((AF_S,), jnp.int32))  # ..., limits, eos
-    return _compile(eng._decode_fn, params, cache, *bands)
-
-
-def test_afmoe_decode_program_is_the_one_the_benchmark_finds(one_chip,
-                                                             as_on_tpu):
+def test_afmoe_decode_program_is_the_one_the_benchmark_finds(programs):
     """The fourth family rides the shared loop, one step ahead by
     default, with window tables and NO state handling: at the cell's
     geometry its decode program is the one `decode_step_ms`,
@@ -858,11 +838,10 @@ def test_afmoe_decode_program_is_the_one_the_benchmark_finds(one_chip,
     after its kernel, beside the three K/V writes — and ONE packed
     result for the host that carries the router's two counters behind
     the bands."""
-    eng, params, cache, sds = _afmoe_engine(one_chip)
+    eng, text = programs("afmoe").eng, programs("afmoe").text
     assert eng.async_dispatch and eng._win is not None
     assert not eng._has_state and eng._step_counters == (
         "moe_experts_hit", "moe_rows_max")
-    text = _afmoe_decode_text(eng, params, cache, sds)
     module = re.match(r"HloModule (\S+?),", text).group(1)
     for metric in ("decode_step_ms", "moe_expert_roofline",
                    "swa_attn_roofline"):
@@ -884,54 +863,211 @@ def test_afmoe_decode_program_is_the_one_the_benchmark_finds(one_chip,
     assert len([ln for ln in lines if ln.startswith("%paged_kv_write")
                 and " custom-call(" in ln]) == 3
     assert text.count("tpu_custom_call") == 10
-    for scope in ("afmoe_attention", "afmoe_router", "afmoe_experts",
-                  "afmoe_shared"):
-        assert scope in text
+    # the layer parts' device scopes, under the names every family
+    # shares (ISSUE 35: router, experts and the shared expert are one
+    # part, `lm_experts`; the leading dense layer's MLP is `lm_mlp`)
+    for scope in ("lm_attention", "lm_experts", "lm_mlp"):
+        assert "/%s/" % scope in text
+    assert not re.search(r'op_name="[^"]*afmoe_', text)
     # tokens, trap flags, magnitude, four bands, then the two counters
     assert re.search(r"s32\[%d\]" % (6 * AF_S + 3), entry)
     assert eng.metrics.decode_trace_count() == 1
 
 
-def test_afmoe_prefill_chunk_compiles_at_the_largest_bucket(one_chip,
-                                                            as_on_tpu):
+def test_afmoe_prefill_chunk_compiles_at_the_largest_bucket(programs):
     """The cell's largest chunk program (4,096 rows: 32,768 routed
     pairs through the same grouped product, the band of a window layer
     tile by tile) compiles for the chip under its own name, with
     bounded temporaries."""
-    eng, params, cache, sds = _afmoe_engine(one_chip)
-    lower = eng._chunk_fn(4096).lower(
-        params, cache, sds((4096,), jnp.int32), sds((), jnp.int32),
-        sds((4, AF_MAXB), jnp.int32), sds((), jnp.int32),
-        sds((), jnp.float32), sds((2,), jnp.uint32))
-    with jax.default_matmul_precision(None):
-        compiled = lower.compile()
-    text = compiled.as_text()
+    compiled = programs("afmoe", "chunk").compiled
+    text = programs("afmoe", "chunk").text
     assert re.match(r"HloModule jit__chunk[,.]", text)
-    assert "moe_grouped_matmul" in text and "afmoe_experts" in text
+    assert "moe_grouped_matmul" in text and "/lm_experts/" in text
     assert "hybrid_decode_attention" not in text  # the decode step's
     # 1.17 GB at the cell's five layers (the routed rows' float32
     # products and the combine's gather)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
-# the SambaY and the sparse-expert decode programs as PR 33's tree
-# compiles them at their cells' geometry (ISSUE 34 changed the granite
-# family's state-update kernel and nothing these two are handed):
-# sha256 of the compiled text less locations and less the kernels'
-# embedded bodies, as `_GPT_DECODE_TEXT_SHA` above. A PR that MEANS to
-# change one of them replaces its digest (the failing assertion prints
-# the new one) and says so in CHANGES.md.
+# the SambaY, the Mamba-2 / grouped-query and the sparse-expert decode
+# programs as PR 34's tree compiles them at their cells' geometry
+# (ISSUE 35 named the parts of every family's compiled steps, and a
+# name is metadata: the programs are the parent's, instruction for
+# instruction): sha256 of the compiled text less locations and less
+# the kernels' embedded bodies, as `_GPT_DECODE_TEXT_SHA` above. A PR
+# that MEANS to change one of them replaces its digest (the failing
+# assertion prints the new one) and says so in CHANGES.md.
 _DECODE_TEXT_SHA = {
     "hybrid": "fc6585d77a8b3bbdbcb94b371f5d1a8b13848788150cb7203628c2079fca0052",
+    "granite": "efc43696468aec752beebca38adf0c5e26298bde9df189b2fff3bc564db7492a",
     "afmoe": "a14ad06027fdda8002081f90785b6c02becec9977e46d397c7ef1f5fb6db7298",
 }
 
 
 @pytest.mark.parametrize("family", sorted(_DECODE_TEXT_SHA))
 def test_other_families_decode_programs_are_the_text_the_parent_compiled(
-        one_chip, as_on_tpu, family):
-    if family == "hybrid":
-        text = _hybrid_decode_text(*_hybrid_engine(one_chip))
+        programs, family):
+    assert _text_digest(programs(family).text)[0] == _DECODE_TEXT_SHA[family]
+
+
+# ---------------------------------------------------------------------
+# the device scopes (ISSUE 35): every family's compiled steps name
+# their parts from the one vocabulary of `models/scopes.py`, which a
+# device trace carries as each operation's framework op name
+# (benchmarks/chip/lib/scopes.py reads it). Each family's decode
+# program and largest chunk program, at its cell's geometry (the GPT
+# block's at the smoke's), are compiled ONCE a module and shared with
+# the tests above that read them.
+# ---------------------------------------------------------------------
+
+_FAMILIES = {  # family -> (engine, its largest chunk, its table rows)
+    "gpt": (_engine, L, (MAXB,)),
+    "hybrid": (_hybrid_engine, 4096, (4, HY_MAXB)),
+    "granite": (_granite_engine, GR_CHUNK, (2, GR_MAXB)),
+    "afmoe": (_afmoe_engine, 4096, (4, AF_MAXB)),
+}
+# the decode step's block tables: [(kinds of table,) slots, entries]
+_DECODE_TABLES = {"gpt": (S, MAXB), "hybrid": (2, HY_S, HY_MAXB),
+                  "granite": (GR_S, GR_MAXB), "afmoe": (2, AF_S, AF_MAXB)}
+
+
+class _Program(object):
+    def __init__(self, eng, compiled):
+        self.eng, self.compiled = eng, compiled
+        self.text = compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def programs(one_chip):
+    """`programs(family, which="decode")` -> the family's decode or
+    chunk program compiled for the chip (`.compiled`, `.text`) and
+    the engine it was lowered from (`.eng`); a whole step compiles in
+    5-60 s, so each is made once and kept for the module."""
+    made = {}
+
+    def get(family, which="decode"):
+        if (family, which) not in made:
+            engine, rows, table = _FAMILIES[family]
+            with pytest.MonkeyPatch.context() as mp:  # as `as_on_tpu`
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                eng, params, cache, *_, sds = engine(one_chip)
+                if which == "decode":
+                    tables = _DECODE_TABLES[family]
+                    fn = eng._decode_fn
+                    args = _bands(sds, tables[-2], *tables)
+                else:
+                    fn = eng._chunk_fn(rows)
+                    args = _chunk_args(sds, rows, *table)
+                made[family, which] = _Program(
+                    eng, _compiled(fn, params, cache, *args))
+        return made[family, which]
+
+    return get
+
+
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%[\w.\-]+ = ")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$")
+
+
+def _stepwise_instructions(text):
+    """The instructions the device runs one after another: those of
+    the computations no fusion calls (the entry, a loop's body and
+    condition, a conditional's branches), each with its continuation
+    lines (a kernel's metadata spans three)."""
+    found, comp, cur = [], None, None
+    for ln in text.split("\n"):
+        head = _COMPUTATION.match(ln)
+        if head:
+            comp, cur = head.group(1), None
+        elif _INSTRUCTION.match(ln):
+            cur = [comp, ln]
+            found.append(cur)
+        elif ln.rstrip() == "}":
+            comp, cur = None, None
+        elif cur is not None:
+            cur[1] += "\n" + ln
+    fused = set()
+    for _, ins in found:
+        if " fusion(" in ins:
+            fused.update(re.findall(r"calls=%([\w.\-]+)", ins))
+    return [ins for comp, ins in found if comp not in fused]
+
+
+def _scope_of(instruction):
+    """The outermost vocabulary scope in an instruction's `op_name`,
+    or None."""
+    from paddle_tpu.models.scopes import SCOPES
+
+    op = re.search(r'op_name="([^"]*)"', instruction)
+    for part in (op.group(1).split("/") if op else ()):
+        if part in SCOPES:
+            return part
+    return None
+
+
+# the layer parts a family's programs hold; every program also holds
+# the embedding, the head and the engine's tail of the step
+_FAMILY_SCOPES = {
+    "gpt": {"lm_attention", "lm_mlp"},
+    "hybrid": {"lm_attention", "lm_state", "lm_mlp"},
+    "granite": {"lm_attention", "lm_state", "lm_mlp"},
+    "afmoe": {"lm_attention", "lm_mlp", "lm_experts"},
+}
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+@pytest.mark.parametrize("family", sorted(_FAMILY_SCOPES))
+def test_compiled_steps_name_their_parts_from_the_one_vocabulary(
+        programs, family, which):
+    """Every kernel call and at least 95 % of the fusions, products
+    and convolutions a decode step runs carry an `op_name` whose path
+    holds a scope of the vocabulary (the few that do not are the
+    step's prologue: the parked-slot mask, the tables' split, a work
+    list's plan). A chunk program also runs what the compiler made
+    itself and gave no `op_name` at all — the loop a scatter is
+    lowered to, layout copies: 8-13 % of its instructions, which no
+    scope can reach — so there the 95 % is of the instructions that
+    carry a name, and of all of them 85 %. The scopes that appear are
+    the family's own: no recurrent state in the GPT block and the
+    sparse-expert family, no routed experts outside it; a decode step
+    ends in the engine's sampling, traps and retirement, a chunk in
+    sampling and traps."""
+    from paddle_tpu.models.scopes import SCOPES
+
+    kernels, work, seen = [], [], set()
+    for ins in _stepwise_instructions(programs(family, which).text):
+        opcode = re.search(r" ([a-z][a-z\-]*)\(", ins.split(" = ", 1)[1])
+        scope = _scope_of(ins)
+        if scope:
+            seen.add(scope)
+        if opcode is None:
+            continue
+        if opcode.group(1) == "custom-call" and "tpu_custom_call" in ins:
+            kernels.append(scope)
+        elif opcode.group(1) in ("fusion", "dot", "convolution"):
+            work.append((scope, "op_name=" in ins))
+    # (the granite family's chunk is XLA's alone: no kernel in it)
+    assert all(kernels) and (kernels or (family, which) == ("granite",
+                                                            "chunk"))
+    assert len(work) > 50
+    scoped = sum(1 for s, _ in work if s)
+    if which == "decode":
+        assert scoped >= 0.95 * len(work), (scoped, len(work))
     else:
-        text = _afmoe_decode_text(*_afmoe_engine(one_chip))
-    assert _text_digest(text)[0] == _DECODE_TEXT_SHA[family]
+        named = sum(1 for _, has_name in work if has_name)
+        assert scoped >= 0.95 * named, (scoped, named)
+        assert scoped >= 0.85 * len(work), (scoped, len(work))
+    tail = {"step_sample", "step_traps"} | (
+        {"step_retire"} if which == "decode" else set())
+    assert seen == {"lm_embed", "lm_head"} | _FAMILY_SCOPES[family] | tail
+    assert seen <= set(SCOPES)
+
+
+def test_a_scope_outside_the_vocabulary_is_refused():
+    from paddle_tpu.models import scopes
+
+    assert len(set(scopes.SCOPES)) == len(scopes.SCOPES) == 9
+    with scopes.scope("lm_mlp"):
+        pass
+    with pytest.raises(ValueError, match="not a device scope"):
+        scopes.scope("granite_mlp")
