@@ -387,8 +387,7 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: AfmoeConfig,
             kv = dict(zip("kv", paged_kv_write(kv["k"], kv["v"], k, v,
                                                tab, pos)))
             o = paged_decode_attention(
-                q, kv["k"], kv["v"], tab, pos,
-                first=first if w else None, max_context=w or None)
+                q, kv["k"], kv["v"], tab, pos, first=first if w else None)
             o = o.reshape(S, -1)
         else:
             kv = {"k": _scatter_rows(kv["k"], tab, pos, k, cfg, Bt),

@@ -487,8 +487,7 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: SambaYConfig,
         w = cfg.window if lo is not None else 0
         if kernel == "fused":
             return paged_decode_attention(
-                q, kv["k"], kv["v"], tab, pos, first=lo,
-                max_context=w or None, scale=scale)
+                q, kv["k"], kv["v"], tab, pos, first=lo, scale=scale)
         kpos = jnp.arange(maxb * Bt)
         return jax.vmap(
             lambda q1, k1, v1, p1: _attend(q1[None], k1, v1, p1[None],

@@ -14,57 +14,71 @@ table and positions as SCALAR-PREFETCH operands
 (PrefetchScalarGridSpec), so the pipeline DMAs each group's G K/V
 blocks [Bt, H, Dh] straight from the pool buffer into VMEM (G blocks
 per step so the score tile spans G*Bt >= 128 tokens — the reference
-pages_per_compute_block idea; the merged-pool decode call takes more
-where 128 tokens weigh little, see "What sizes a grid step" below) —
+pages_per_compute_block idea; the merged-pool decode call copies its
+blocks itself, see "What the merged-pool call's own copies are for"
+below) —
 the "gather" is the index map, and no
 HBM-resident contiguous view ever exists. Blockwise online softmax
 (running (max, sum, acc), the flash_attention.py discipline) keeps
 VMEM at one group of blocks plus the accumulators, regardless of
 context length.
 
-Two kernel bodies, chosen by the shape of the call and nothing else
-(ISSUE 26):
+Three kernel bodies, chosen by the shape of the call and nothing else
+(ISSUEs 26 and 36):
 
   * R >= 8 window rows (verify windows, prefill chunks) and every
     call on a quantized pool — `_pa_kernel`: a (slots, row-tiles,
     table-groups) grid, heads as a static loop, one `[R, W]` score
     tile a head. With R rows on the MXU's left the per-head product
     is the right shape.
-  * R == 1 (the decode step's one query a slot) — `_pa_decode_kernel`:
-    all heads at once in the pool's native `[Bt, H, Dh]` tile, over a
-    flat work list (`_decode_worklist`) that holds a step only for a
-    table group a live context names, DMAs only the blocks it names
-    and gives a parked slot one empty step. On a v5e at 32 slots x 16
-    heads x 128, bf16, 16-token blocks the head-loop body took 0.76 ms
-    a call at 465-token contexts (0.46 ms at contexts of ONE token:
-    512 grid steps whatever the contexts; a parked slot 58 us against
-    a live one's 24), this body 0.21 ms — 75 % of what the HBM allows
+  * R == 1 (the decode step's one query a slot) on a 4-D pool (the
+    GPT block's) — `_pa_decode_kernel`: all heads at once in the
+    pool's native `[Bt, H, Dh]` tile, over a flat work list
+    (`_decode_worklist`) that holds a step only for a table group a
+    live context names, DMAs only the blocks it names and gives a
+    parked slot one empty step. On a v5e at 32 slots x 16 heads x
+    128, bf16, 16-token blocks the head-loop body took 0.76 ms a call
+    at 465-token contexts (0.46 ms at contexts of ONE token: 512 grid
+    steps whatever the contexts; a parked slot 58 us against a live
+    one's 24), this body 0.21 ms — 75 % of what the HBM allows
     (PERF.md section 5, PR 26).
+  * R == 1 on a merged 3-D pool (the hybrid families' grouped
+    queries) — `_pa_ring_decode_kernel`: the same product and softmax
+    fold, but the call makes its own K/V copies. The pools stay in
+    HBM; a grid step is a SLOT, reads the slot's block ids from the
+    table itself and copies the blocks its context names, a group at
+    a time, into a VMEM ring of three groups, two groups ahead of the
+    one it folds.
 
-What sizes a grid step (ISSUE 32). A step of the decode body costs
-what its DMA costs or what its own work costs, whichever is more, and
-its own work has a part that does not shrink with the step (G copies
-issued and waited for, the (m, l, acc) read-modify-write, the
-`pl.when`s). `_group` sizes a step by tokens: 128 of them, which in
-the GPT pool (8,192 B a token, K + V) is 1 MiB and 1.28 us of DMA at
-the v5e's 819 GB/s — enough to hide the rest (82 % of the HBM
-roofline). A token of the hybrid families' merged pools weighs 5,120
-(SambaY) or 2,048 B (granite), so 128 tokens are 0.80 or 0.32 us of
-DMA, and the call read 76 % and 43 % of its roofline. The merged-pool
-caller therefore sizes a step by BYTES (`_bytes_group`): the fewest
-blocks, `_group`'s doubled, whose K + V reach `_STEP_BYTES` = 1 MiB —
-16 blocks (512 tokens) for granite's 64 KiB block, 8 (256 tokens) for
-SambaY's 160 KiB; a function of the pool's block shape and dtype, no
-option. Measured on the v5e, the kernel alone at the cells' geometry,
-64 slots at contexts of 1.5-4.7 k (`tools/time_decode_attention.py`;
-PERF.md section 6, PR 32), microseconds a call at G = 4, 8, 16, 32:
-granite 1,167, 971, 874, 854 (least 507); SambaY's shared pool 1,661,
-1,425, 1,441, 1,452 (least 1,267); a SambaY window pool 331, 325,
-369, 500 (least 206: past 8 a step's tile work outgrows a 512-token
-window). Cutting the masks, the NEG_INF `where`s and the hi + lo
-split out of the body moved a call by 1-6 %: the step's length beside
-its DMA is the fixed part and the two products, not the tile's
-element-wise work, so the body is as it was.
+What the merged-pool call's own copies are for (ISSUEs 32 and 36). In
+the BlockSpec form a group of G blocks is 2G one-block operands of the
+pipeline — an index map, a changed-index compare, a descriptor and a
+wait for each, on the one instruction stream that also issues the
+products — and a work list `blk [G, N]` gathered through the tables so
+that the pipeline skips a block nobody names (~220 us of every step).
+Measured on the v5e at the cells' geometry, 64 slots (`tools/
+time_decode_attention.py`; PERF.md section 6, PRs 32 and 36): a step
+of that form fits 0.30 us + 0.108 us a block of 64 KiB (K + V) at
+every G against 0.080 us of DMA, and the two products ADD to it
+(Trinity's full call 1,191 us, 931 without them): the copies were
+never what set the step. With the call's own copies a block costs its
+DMA, 0.082 us, once two groups are in flight (with ONE ahead the queue
+of copies runs empty at every group: 0.55 us a group, 1,084 us a
+call), the products and the tile's work run under them, and a group's
+fold is 0.5 us + 0.064 us a block, hidden from ~29 blocks up — so a
+group is sized by BYTES (`_bytes_group`, `_STEP_BYTES`): 32 blocks of
+granite's and Trinity's 64 KiB, 8 of SambaY's 160 KiB. Microseconds a
+call, the BlockSpec form -> this one (least, by the cell's bytes):
+Trinity's full call over contexts of 1.5-7.2 k 1,191 -> 791 (712), its
+2,048-token window call 628 -> 414 (321), granite's call 874 -> 579
+(507), SambaY's shared pool 1,425 -> 1,378 (1,267), its 512-token
+window call 325 -> 255 (206); and no work list. A window's walk starts
+at the block of `first`, not at a group's edge, so its 65 blocks cost
+65 blocks' copies, and a slot's last group, when it holds at most a
+quarter of a group, scores a quarter tile (`_SHORT_GROUP`: Trinity's
+window call 440 -> 414). The 4-D pool's call keeps the BlockSpec form:
+in the GPT pool 128 tokens are 1 MiB a step, which hides its fixed
+part (82 % of the HBM roofline); moving it here is a later PR's.
 
 Masking mirrors the gather primitives exactly: row r of a window based
 at `base` attends positions <= base + r, so unwritten depths — and the
@@ -287,13 +301,45 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
                 o_ref.dtype)
 
 
-def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, *refs,
-                      Bt: int, G: int, span: int, scale: float,
-                      rep: int = 1, windowed: bool = False):
-    """One step of the single-token decode call's flat work list: fold
-    the G blocks of table group `wgrp[i]` of slot `wslot[i]` into that
-    slot's online-softmax state, ALL heads at once, K and V left in
-    the `[Bt, H, Dh]` tile the DMA delivered.
+def _fold_tile(s, v, acc_ref, m_ref, l_ref):
+    """Fold one masked score tile `s` [R, C] (NEG_INF where a column is
+    not the row's) and its values `v` [C, Dh] into the rows' online
+    softmax state. A masked column contributes EXACTLY 0 (the NEG_INF
+    guards, kernel_utils.py). Against a 16-bit V, P goes as two 16-bit
+    halves (hi + lo, stacked on the rows of ONE product, so V is loaded
+    into the MXU once): P keeps ~16 bits of mantissa instead of 8, for
+    1 % of the call."""
+    R = s.shape[0]
+    m_prev = m_ref[...]  # [R, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    p = jnp.where(s <= NEG_INF, 0.0, p)
+    alpha = jnp.exp(m_prev - m_new)
+    alpha = jnp.where(m_prev <= NEG_INF, 0.0, alpha)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    if v.dtype.itemsize == 2:
+        hi = p.astype(v.dtype)
+        lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+        pv = jax.lax.dot_general(
+            jnp.concatenate([hi, lo], axis=0), v,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [2R, Dh]
+        pv = pv[:R] + pv[R:]
+    else:
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [R, Dh]
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    m_ref[...] = m_new
+
+
+def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, q_ref, *refs,
+                      Bt: int, G: int, span: int, scale: float):
+    """One step of the single-token decode call's flat work list (a
+    4-D pool's: the GPT block's): fold the G blocks of table group
+    `wgrp[i]` of slot `wslot[i]` into that slot's online-softmax
+    state, ALL heads at once, K and V left in the `[Bt, H, Dh]` tile
+    the DMA delivered.
 
     With one query row a head, a per-head product puts ONE row on the
     MXU's left and pays for it with a sublane gather of that head's
@@ -309,45 +355,25 @@ def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, *refs,
     sums, per head, only its own rows. H times the model's FLOPs on
     an MXU that is otherwise idle; no head loop, no slice, no
     concatenate of slices, no f32 copy of a block. The state is one
-    `(m, l) [H, 1]` and one `acc [H, Dh]` scratch.
+    `(m, l) [H, 1]` and one `acc [H, Dh]` scratch (`_fold_tile`).
 
     The grid is the work list `_decode_worklist` built: only steps a
     live context names (and one empty step for a parked slot, which
     writes zeros), so `wgrp[i] * W <= pos` holds at every step of a
     live slot and every row has an attended column — the NEG_INF
     guards stay for the decode family's exactness contract (a masked
-    column contributes EXACTLY 0), not because a step can be empty.
-
-    Against a 16-bit V, P goes as two 16-bit halves (hi + lo, stacked
-    on the rows of ONE product, so V is loaded into the MXU once): P
-    keeps ~16 bits of mantissa instead of 8, for 1 % of the call.
-
-    Two things the hybrid family brings (ISSUE 27), both read off the
-    operands: `rep` query rows a K/V head (q is `[H * rep, Dh]`, row r
-    belongs to head r // rep — grouped queries; the pool's blocks may
-    then come as the 2-D `[Bt * H, Dh]` they are stored as, see
-    `paged_decode_attention`), and with `windowed` a fifth prefetch
-    operand `first [S]`: the first position a slot attends. Its walk
-    then starts at group first // W, and depths before `first` are
-    masked like depths past `pos`."""
-    if windowed:
-        first_ref, q_ref, refs = refs[0], refs[1], refs[2:]
-    else:
-        first_ref, q_ref, refs = None, refs[0], refs[1:]
+    column contributes EXACTLY 0), not because a step can be empty."""
     k_refs, v_refs = refs[:G], refs[G:2 * G]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * G:]
-    R, dh = q_ref.shape  # R = H * rep query rows
-    H = R // rep
+    H, dh = q_ref.shape
     W = G * Bt
     i = pl.program_id(0)
     si = wslot_ref[i]
     b = wgrp_ref[i]
     pos = pos_ref[si]
     live = pos < span  # a parked row sits at or past the table's span
-    b_first = 0 if first_ref is None else jnp.where(
-        live, first_ref[si] // W, 0)
 
-    @pl.when(b == b_first)
+    @pl.when(b == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -363,46 +389,209 @@ def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, *refs,
             q_ref[...].astype(k.dtype), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [H, W*H]; decode family: scale after the product
-        col = jax.lax.broadcasted_iota(jnp.int32, (R, W * H), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (R, W * H), 0)
-        if rep > 1:  # rep query rows share a K/V head
-            row = row // rep
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, W * H), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (H, W * H), 0)
         # column c is (token c // H, head c % H): row h keeps its own
         # head's columns at depths <= pos, i.e. c < (pos - b*W + 1)*H;
         # everything else — other heads, unwritten depths, whatever a
         # re-named or clamped block holds — contributes exactly 0
         head = (col & (H - 1)) if H & (H - 1) == 0 else jax.lax.rem(col, H)
         masked = (head != row) | (col >= (pos - b * W + 1) * H)
-        if first_ref is not None:
-            masked = masked | (col < (first_ref[si] - b * W) * H)
         s = jnp.where(masked, NEG_INF, s)
-
-        m_prev = m_ref[...]  # [H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(s <= NEG_INF, 0.0, p)
-        alpha = jnp.exp(m_prev - m_new)
-        alpha = jnp.where(m_prev <= NEG_INF, 0.0, alpha)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        if v.dtype.itemsize == 2:
-            hi = p.astype(v.dtype)
-            lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
-            pv = jax.lax.dot_general(
-                jnp.concatenate([hi, lo], axis=0), v,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [2R, Dh]
-            pv = pv[:R] + pv[R:]
-        else:
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [H, Dh]
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
+        _fold_tile(s, v, acc_ref, m_ref, l_ref)
 
     @pl.when(b == jnp.where(live, pos // W, 0))  # the slot's last step
     def _finalise():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[...] = out[:, None, :].astype(o_ref.dtype)
+
+
+def _masked_scores(q, k, scale, rep: int, at, pos, first):
+    """The merged-pool call's score tile: q [R, Dh] (row r belongs to
+    K/V head r // rep) against a group's K [C, Dh] as it lies in the
+    ring, rows (token, head), the tile's first token at position `at`
+    -> [R, C] float32, scaled after the product (the decode family),
+    NEG_INF wherever column c = (token c // H, head c % H) is not row
+    r's: another head's, or a position outside `first` <= . <= `pos`
+    (unwritten depths, a short group's stale rows)."""
+    R, C = q.shape[0], k.shape[0]
+    H = R // rep
+    s = jax.lax.dot_general(
+        q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, C), 1)
+    # rep query rows share a K/V head
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, C), 0) // rep
+    head = (col & (H - 1)) if H & (H - 1) == 0 else jax.lax.rem(col, H)
+    masked = (head != row) | (col >= (pos - at + 1) * H)
+    if first is not None:
+        masked = masked | (col < (first - at) * H)
+    return jnp.where(masked, NEG_INF, s)
+
+
+def _zero_ring(*bufs):
+    """A ring row that no copy has written may hold anything, and
+    0 x NaN is NaN in P . V: the ring starts as zeros. What stays in a
+    row after that is a pool block's, which a later short group masks
+    to an exact 0 like any depth past `pos`."""
+    for buf in bufs:
+        buf[...] = jnp.zeros_like(buf)
+
+
+# Groups of K/V the merged-pool decode call keeps in VMEM: the one
+# being folded and the ones whose copies are under way
+_RING = 3
+# A group of at most G // `_SHORT_GROUP` blocks (a slot's last) scores
+# that part of a tile and not a whole one
+_SHORT_GROUP = 4
+
+
+def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
+                           span: int, scale: float, rep: int,
+                           windowed: bool):
+    """One SLOT of the merged-pool decode call (ISSUE 36): the pools
+    stay in HBM, the kernel reads the slot's block ids from its table
+    row in scalar memory and copies the blocks its context names — and
+    no other — into a VMEM ring of `_RING` groups of G blocks, K and
+    V. A grid step folds its slot's groups one after another (the
+    same product, masks and `_fold_tile` as `_pa_decode_kernel`,
+    q `[H * rep, Dh]` with row r of K/V head r // rep); around each
+    fold it first STARTS the copies of the group `_RING` - 1 ahead and
+    afterwards WAITS for the next one's, so a group's products run
+    under the later groups' copies and the copies of one group under
+    the next one's: the queue of copies never runs empty. What is
+    ahead of a slot's last groups are the first groups of the slots
+    after it, so the ring does not drain between slots.
+
+    Scalar memory carries the walk across grid steps, `st`: the groups
+    folded so far, the groups started so far (group c sits in ring
+    place c mod `_RING`), and the cursor, the (slot, group) to start
+    next, which skips parked slots; `cnt`: how many blocks were
+    started into each ring place, which is how many to wait for.
+
+    A slot's walk is over BLOCKS, from the block of `first[s]` (a
+    window layer's first attended position; block 0 without `first`)
+    to the block of `pos[s]`: ceil(blocks / G) groups, the last one
+    short. A short group copies its own blocks only and still scores a
+    whole tile: the rows past them hold an earlier group's blocks (or
+    `_zero_ring`'s zeros), masked by position like any depth past
+    `pos`. A parked slot (pos >= span) names no block: nothing is
+    copied or folded for it and it writes zeros.
+
+    All copies into one ring place signal that place's semaphore, each
+    waited for with a descriptor of its own size."""
+    if windowed:
+        first_ref, refs = refs[0], refs[1:]
+    else:
+        first_ref = None
+    (q_ref, k_hbm, v_hbm, o_ref,
+     kbuf, vbuf, sem, acc_ref, m_ref, l_ref, st_ref, cnt_ref) = refs
+    ring = kbuf.shape[0]
+    BH = kbuf.shape[1] // G  # rows a block: (token, head)
+    si = pl.program_id(0)
+    nslots = pl.num_programs(0)
+    FOLDED, STARTED, NEXT_SLOT, NEXT_GROUP = range(4)
+
+    def named(s):
+        """-> (the first block slot s attends, how many it attends)."""
+        pos = pos_ref[s]
+        b0 = 0 if first_ref is None else first_ref[s] // Bt
+        return b0, jnp.where(pos < span, pos // Bt + 1 - b0, 0)
+
+    def live_from(s):
+        """-> the first slot >= s that names a block, or `nslots`."""
+        return jax.lax.while_loop(
+            lambda s: (s < nslots)
+            & (pos_ref[jnp.minimum(s, nslots - 1)] >= span),
+            lambda s: s + 1, s)
+
+    def copies(n, place, wait, s=0, lo=0):
+        """Start the copies of table entries lo .. lo + n of slot s
+        into ring place `place`, or wait for n such copies there."""
+        def one(i, carry):
+            # a wait reads its descriptor's size and semaphore only
+            blk = 0 if wait else jnp.maximum(tbl_ref[s, lo + i], 0)
+            at = pl.ds(pl.multiple_of(i * BH, BH), BH)
+            for pool, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                dma = pltpu.make_async_copy(pool.at[blk], buf.at[place, at],
+                                            sem.at[place])
+                dma.wait() if wait else dma.start()
+            return carry
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    def start_next():
+        """Start the group the cursor stands on and move the cursor."""
+        s, j = st_ref[NEXT_SLOT], st_ref[NEXT_GROUP]
+
+        @pl.when(s < nslots)
+        def _():
+            place = jax.lax.rem(st_ref[STARTED], ring)
+            b0, n = named(s)
+            n_here = jnp.minimum(n - j * G, G)
+            copies(n_here, place, False, s, b0 + j * G)
+            cnt_ref[place] = n_here
+            st_ref[STARTED] = st_ref[STARTED] + 1
+            more = (j + 1) * G < n
+            st_ref[NEXT_SLOT] = jnp.where(more, s, live_from(s + 1))
+            st_ref[NEXT_GROUP] = jnp.where(more, j + 1, 0)
+
+    def wait_for(c):
+        """Group c's copies, if it was started (it is the next to be
+        folded: whatever is left to fold has been started by then)."""
+        @pl.when(c < st_ref[STARTED])
+        def _():
+            place = jax.lax.rem(c, ring)
+            copies(cnt_ref[place], place, True)
+
+    @pl.when(si == 0)
+    def _prime():
+        _zero_ring(kbuf, vbuf)
+        st_ref[FOLDED] = 0
+        st_ref[STARTED] = 0
+        st_ref[NEXT_SLOT] = live_from(0)
+        st_ref[NEXT_GROUP] = 0
+        for _ in range(ring - 1):
+            start_next()
+        wait_for(0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    pos = pos_ref[si]
+    b0, n = named(si)
+    groups = (n + G - 1) // G
+    folded = st_ref[FOLDED]
+
+    def group(j, carry):
+        c = folded + j
+        place = jax.lax.rem(c, ring)
+        start_next()
+
+        def fold(blocks):
+            # the group's first `blocks` blocks; the tile's first
+            # token sits at position (b0 + j * G) * Bt
+            rows = pl.ds(0, blocks * BH)
+            s = _masked_scores(q_ref[...], kbuf[place, rows], scale, rep,
+                               (b0 + j * G) * Bt, pos,
+                               None if first_ref is None else first_ref[si])
+            _fold_tile(s, vbuf[place, rows], acc_ref, m_ref, l_ref)
+
+        few = G // _SHORT_GROUP
+        if few:  # a slot's last group may hold a block or two
+            short = n - j * G <= few
+            pl.when(short)(lambda: fold(few))
+            pl.when(jnp.logical_not(short))(lambda: fold(G))
+        else:
+            fold(G)
+        wait_for(c + 1)
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0)
+    st_ref[FOLDED] = folded + groups
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = out[:, None, :].astype(o_ref.dtype)
 
 
 # what one TPU core gives one program: the scalar memory the v5e's
@@ -424,24 +613,30 @@ def _group(Bt: int, maxb: int) -> int:
     return max(1, min(-(-128 // Bt), maxb))
 
 
-# K + V bytes a grid step of the merged-pool decode call carries at
-# least: what the GPT call's step carries (8 blocks of 16 tokens x
-# 8,192 B). Read on the v5e (module text; PERF.md section 6, PR 32):
-# granite's call, whose 128 tokens are 256 KiB, 1,167 us at 256 KiB a
-# step, 971 at 512 KiB, 874 at 1 MiB, 854 at 2 MiB; SambaY's, whose
-# are 640 KiB, 1,661 at 640 KiB, 1,425 at 1.25 MiB, 1,441 at 2.5 MiB,
-# its window call 331, 325, 369: past 1 MiB nothing is left to gain
-# and a window call loses
-_STEP_BYTES = 1 << 20
+# K + V bytes a group of the merged-pool decode call carries at least.
+# A group's fold has a part that does not shrink with the group (the
+# chain product -> row max -> exp -> product, ~0.5 us on the v5e) beside
+# 0.064 us a block of 64 KiB, whose copies take 0.082: past ~29 such
+# blocks the copies hide the fold. Read on the v5e, the call alone, 64
+# slots, microseconds a call (tools/time_decode_attention.py; PERF.md
+# section 6, PR 36), by K + V bytes a group: Trinity's full call
+# (contexts 1.5-7.2 k; least 712) 1,131 at 512 KiB, 910 at 1 MiB, 791
+# at 2 MiB, 806 at 4 MiB; its 2,048-token window call (least 321) 578,
+# 455, 414, 403; granite's (least 507) 665 at 1 MiB, 579 at 2 MiB;
+# SambaY's shared pool (least 1,267) 1,445 at 640 KiB, 1,378 at
+# 1.25 MiB, 1,379 at 2.5 MiB; its 512-token window call (least 206)
+# 302, 255, 270: the copies bind it from 1.25 MiB on and a short
+# window loses past that
+_STEP_BYTES = 5 << 18
 
 
 def _bytes_group(Bt: int, maxb: int, block_bytes: int) -> int:
-    """Table entries per grid step of the merged-pool decode call, by
+    """Table entries per group of the merged-pool decode call, by
     BYTES: the fewest blocks, `_group`'s doubled, whose K + V
     (`block_bytes` a block: its rows x width x 2 x the dtype's size)
-    reach `_STEP_BYTES`, never more than the table holds. A step then
-    moves 1-2 MiB whatever a token weighs, and a pool whose 128 tokens
-    already weigh that much keeps `_group`'s."""
+    reach `_STEP_BYTES`, never more than the table holds: 32 blocks of
+    granite's and Trinity's 64 KiB (2 MiB), 8 of SambaY's 160 KiB
+    (1.25 MiB)."""
     G = _group(Bt, maxb)
     while G * block_bytes < _STEP_BYTES and 2 * G <= maxb:
         G *= 2
@@ -482,21 +677,23 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
     tiles (a 1-D operand counted as one such row of tiles, which is
     on the safe side), MAXB first padded to a whole number of groups.
     `block_bytes` (K + V of one block) marks a merged 3-D pool, whose
-    only kernel call is the decode call at `_bytes_group`'s group."""
-    merged = block_bytes is not None
-    G = (_bytes_group(block_tokens, maxb, block_bytes) if merged
-         else _group(block_tokens, maxb))
-    mb = -(-maxb // G) * G
-    need = 0 if merged else _smem_padded(slots, mb) + _smem_padded(1, slots)
-    if quant:
-        need += 2 * _smem_padded(slots, mb * heads)
+    only kernel call is the decode call that reads the tables as they
+    are beside `pos` and a window layer's `first`."""
+    if block_bytes is not None:
+        need = _smem_padded(slots, maxb) + 2 * _smem_padded(1, slots)
     else:
-        # the decode call prefetches its work list in the tables'
-        # place: the block of every (operand, step) [G, S*NG] and two
-        # [S*NG] rows — more than the tables only where G < 8
-        steps = slots * mb // G
-        need = max(need, _smem_padded(G, steps) + _smem_padded(1, slots)
-                   + 2 * _smem_padded(1, steps))
+        G = _group(block_tokens, maxb)
+        mb = -(-maxb // G) * G
+        need = _smem_padded(slots, mb) + _smem_padded(1, slots)
+        if quant:
+            need += 2 * _smem_padded(slots, mb * heads)
+        else:
+            # the decode call prefetches its work list in the tables'
+            # place: the block of every (operand, step) [G, S*NG] and
+            # two [S*NG] rows — more than the tables only where G < 8
+            steps = slots * mb // G
+            need = max(need, _smem_padded(G, steps) + _smem_padded(1, slots)
+                       + 2 * _smem_padded(1, steps))
     room = _SMEM_BYTES - _SMEM_RESERVE
     if need > room:
         raise ValueError(
@@ -517,23 +714,19 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
 _LOOKBACK_FROM = 512
 
 
-def _decode_worklist(tables, pos, Bt: int, G: int, span: int,
-                     first=None, max_groups=None):
-    """The decode call's grid, as data: one entry for every table
-    group a live context names, slot after slot, and one empty entry
-    for a parked slot (`pos >= span`; its step computes nothing and
-    writes zeros). -> (blk [G, N], wslot [N], wgrp [N], n): entry
-    i < n works on group `wgrp[i]` of slot `wslot[i]`, and its g-th K/V
-    operand holds pool block `blk[g, i]`. With `first` [S] a slot's
-    walk starts at the group of position first[s] (the hybrid family's
-    window layers) and N = S * `max_groups`, the most groups a slot
-    can then name; otherwise N = S * NG.
+def _decode_worklist(tables, pos, Bt: int, G: int, span: int):
+    """The 4-D pool's decode call's grid, as data: one entry for every
+    table group a live context names, slot after slot, and one empty
+    entry for a parked slot (`pos >= span`; its step computes nothing
+    and writes zeros). -> (blk [G, N], wslot [N], wgrp [N], n), N =
+    S * NG: entry i < n works on group `wgrp[i]` of slot `wslot[i]`,
+    and its g-th K/V operand holds pool block `blk[g, i]`.
 
     `blk` is what keeps the DMA to the blocks the contexts name: where
     entry i's group names no block for operand g (the tail of a
-    context's last group, the head of a window's first, a parked
-    slot), it RE-NAMES the block that operand named last, and the
-    pipeline issues no copy for a block index that did not change.
+    context's last group, a parked slot), it RE-NAMES the block that
+    operand named last, and the pipeline issues no copy for a block
+    index that did not change.
     Nothing is read from a re-named block (the position mask). All of
     it is integer compares and reductions on the tables and positions,
     the widest a fused [G, N, N] masked max, and the same for every
@@ -545,15 +738,18 @@ def _decode_worklist(tables, pos, Bt: int, G: int, span: int,
     takes its place: an operand its
     group does not name keeps what the entry before it held if that
     is the same slot's. Only a slot's first group can then copy a
-    block nobody reads, which long contexts make a rounding error."""
+    block nobody reads, which long contexts make a rounding error.
+    (The merged-pool call needs none of this: its kernel reads the
+    tables themselves, `_pa_ring_decode_kernel`.)"""
     S, mb = tables.shape
     NG = mb // G
-    per = NG if max_groups is None else min(NG, int(max_groups))
-    N = S * per
+    N = S * NG
     W = G * Bt
     live = pos < span
-    g0 = jnp.zeros_like(pos) if first is None else jnp.where(
-        live, first // W, 0)  # [S] the group each slot's walk starts at
+    # a walk starts at group 0 (the zero stays in the arithmetic: the
+    # compiled GPT decode program is held to its text, digit for digit,
+    # by tests/test_tpu_aot_compile.py)
+    g0 = jnp.zeros_like(pos)
     ng = jnp.where(live, pos // W - g0 + 1, 1)  # [S] steps each slot takes
     ends = jnp.cumsum(ng)
     i = jnp.arange(N, dtype=jnp.int32)
@@ -567,8 +763,6 @@ def _decode_worklist(tables, pos, Bt: int, G: int, span: int,
     depth = wgrp[None, :] * G + jnp.arange(G, dtype=jnp.int32)[:, None]
     named = (live[wslot] & (i < ends[-1]))[None, :] \
         & (depth * Bt <= pos[wslot][None, :])
-    if first is not None:
-        named = named & ((depth + 1) * Bt > first[wslot][None, :])
     entry = jnp.maximum(tables[wslot[None, :], depth], 0)  # -1 -> block 0
     if N > _LOOKBACK_FROM:
         prev = jnp.concatenate([entry[:, :1], entry[:, :-1]], axis=1)
@@ -585,44 +779,34 @@ def _decode_worklist(tables, pos, Bt: int, G: int, span: int,
 
 
 def _paged_decode(q, k_pool, v_pool, tables, pos, *, G, span, name, scale,
-                  interpret, rep=1, first=None, max_groups=None):
-    """The R == 1 call: `_pa_decode_kernel` over `_decode_worklist`'s
-    grid, whose LENGTH is data too (a dynamic grid bound: the steps the
-    live contexts name, not slots x table groups). q [S, 1, H, Dh] ->
-    out [S, H, 1, Dh], the shape `_pa_kernel` returns for R == 1 (and
-    the one the benchmark's `paged_attn_roofline` finds the kernel by);
-    the squeezed block dims hand the kernel q and K/V as dense
-    [H, Dh] / [Bt, H, Dh] tiles. A 3-D pool `[NB, Bt * Hk, Dh]` (the
-    hybrid family's) hands its blocks over already merged, with
-    `rep` = H / Hk query rows a K/V head."""
+                  interpret):
+    """The R == 1 call on a 4-D pool: `_pa_decode_kernel` over
+    `_decode_worklist`'s grid, whose LENGTH is data too (a dynamic grid
+    bound: the steps the live contexts name, not slots x table groups).
+    q [S, 1, H, Dh] -> out [S, H, 1, Dh], the shape `_pa_kernel`
+    returns for R == 1 (and the one the benchmark's
+    `paged_attn_roofline` finds the kernel by); the squeezed block dims
+    hand the kernel q and K/V as dense [H, Dh] / [Bt, H, Dh] tiles."""
     S, _, H, dh = q.shape
-    blk_shape = k_pool.shape[1:]
-    Bt = blk_shape[0] if k_pool.ndim == 4 else blk_shape[0] * rep // H
-    blk, wslot, wgrp, n = _decode_worklist(tables, pos, Bt, G, span,
-                                           first=first,
-                                           max_groups=max_groups)
-    zeros = (0,) * len(blk_shape)
+    Bt = k_pool.shape[1]
+    blk, wslot, wgrp, n = _decode_worklist(tables, pos, Bt, G, span)
 
-    def _slot_map(i, blk, pos, wslot, wgrp, *first):
+    def _slot_map(i, blk, pos, wslot, wgrp):
         return (wslot[i], 0, 0, 0)
 
     def _kv_map(g):
-        def _map(i, blk, pos, wslot, wgrp, *first):
-            return (blk[g, i],) + zeros
+        def _map(i, blk, pos, wslot, wgrp):
+            return (blk[g, i], 0, 0, 0)
         return _map
 
     kernel = functools.partial(
-        _pa_decode_kernel, Bt=Bt, G=G, span=span, scale=scale, rep=rep,
-        windowed=first is not None)
-    prefetch = (blk, pos, wslot, wgrp)
-    if first is not None:
-        prefetch += (jnp.asarray(first, jnp.int32),)
+        _pa_decode_kernel, Bt=Bt, G=G, span=span, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
+        num_scalar_prefetch=4,
         grid=(n,),
         in_specs=[pl.BlockSpec((None, None, H, dh), _slot_map)]
-        + [pl.BlockSpec((None,) + blk_shape, _kv_map(g)) for g in range(G)]
-        + [pl.BlockSpec((None,) + blk_shape, _kv_map(g)) for g in range(G)],
+        + [pl.BlockSpec((None, Bt, H, dh), _kv_map(g)) for g in range(G)]
+        + [pl.BlockSpec((None, Bt, H, dh), _kv_map(g)) for g in range(G)],
         out_specs=pl.BlockSpec((None, H, 1, dh), _slot_map),
         scratch_shapes=[pltpu.VMEM((H, dh), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32),
@@ -635,7 +819,62 @@ def _paged_decode(q, k_pool, v_pool, tables, pos, *, G, span, name, scale,
         interpret=resolve_interpret(interpret),
         name=name,
         metadata={"kernel": name},
-    )(*prefetch, q, *([k_pool] * G), *([v_pool] * G))
+    )(blk, pos, wslot, wgrp, q, *([k_pool] * G), *([v_pool] * G))
+
+
+def _merged_decode(q, k_pool, v_pool, tables, pos, first, *, scale,
+                   interpret):
+    """The R == 1 call on a merged 3-D pool `[NB, Bt * Hk, Dh]`:
+    `_pa_ring_decode_kernel` over a grid of slots. q [S, Hk, rep, Dh]
+    -> out [S, Hk * rep, 1, Dh] (the shape the three hybrid roofline
+    metrics find the kernel by). The tables, `pos` and `first` go to
+    scalar memory as they are; the pools are handed over in HBM, one
+    operand each, and the kernel copies what the tables name, a group
+    of `_bytes_group` blocks at a time."""
+    S, Hk, rep, dh = q.shape
+    R = Hk * rep
+    rows = k_pool.shape[1]  # of a block: (token, head)
+    Bt, maxb = rows // Hk, tables.shape[1]
+    G = _bytes_group(Bt, maxb, 2 * rows * dh * k_pool.dtype.itemsize)
+    ring = (_RING, G * rows, dh)
+    prefetch = (jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32))
+    if first is not None:
+        prefetch += (jnp.asarray(first, jnp.int32),)
+
+    def _slot_map(i, *prefetch):
+        return (i, 0, 0, 0)
+
+    kernel = functools.partial(
+        _pa_ring_decode_kernel, Bt=Bt, G=G, span=maxb * Bt, scale=scale,
+        rep=rep, windowed=first is not None)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(S,),
+        in_specs=[pl.BlockSpec((None, None, R, dh), _slot_map),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, R, 1, dh), _slot_map),
+        scratch_shapes=[pltpu.VMEM(ring, k_pool.dtype),
+                        pltpu.VMEM(ring, v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((_RING,)),
+                        pltpu.VMEM((R, dh), jnp.float32),
+                        pltpu.VMEM((R, 1), jnp.float32),
+                        pltpu.VMEM((R, 1), jnp.float32),
+                        pltpu.SMEM((4,), jnp.int32),
+                        pltpu.SMEM((_RING,), jnp.int32)],
+    )
+    name = "hybrid_decode_attention"
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, R, 1, dh), q.dtype),
+        # the ring and the walk's scalars carry over from a slot to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=resolve_interpret(interpret),
+        name=name,
+        metadata={"kernel": name},
+    )(*prefetch, q.reshape(S, 1, R, dh), k_pool, v_pool)
 
 
 def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
@@ -762,7 +1001,7 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
 
 def paged_decode_attention(q, k_pool, v_pool, tables, pos,
                            interpret=None, k_scale=None, v_scale=None,
-                           first=None, max_context=None, scale=None):
+                           first=None, scale=None):
     """Batched single-token paged decode attention: one query per slot.
 
     q [S, H, Dh] at per-slot positions `pos` [S] over block tables
@@ -786,38 +1025,21 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     its key half occupies and is zero in the other, and the value read
     is the pair, D wide. `first` [S] is the first position a slot
     attends (a window layer's pos - window + 1; depths before it are
-    masked and their groups not walked), `max_context` the most
-    positions any slot can then attend, which bounds the work list;
+    masked and the blocks before its block neither copied nor walked);
     `scale` replaces 1 / sqrt(D) where D is not the head's width.
-    This call's grid step is sized by the bytes it moves, not by 128
-    tokens (`_bytes_group`, from the pool's block shape and dtype: 16
-    blocks for granite's pool, 8 for SambaY's; ISSUE 32) — the table
-    pads to a whole number of THOSE groups and a window's work list
-    holds ceil(max_context / (G * Bt)) + 1 entries a slot. The number
-    of columns an online-softmax step folds is all that differs
-    between two group sizes, so logits move within float tolerance."""
+    This call copies its own K/V (`_pa_ring_decode_kernel`, ISSUE 36),
+    a group of blocks at a time, the group sized by the bytes it moves
+    and not by 128 tokens (`_bytes_group`, from the pool's block shape
+    and dtype: 16 blocks for granite's and Trinity's pools, 8 for
+    SambaY's; ISSUE 32). The number of columns an online-softmax step
+    folds is all that differs between two group sizes, so logits move
+    within float tolerance."""
     if k_pool.ndim == 3:
-        S, Hk, rep, D = q.shape
-        Bt = k_pool.shape[1] // Hk
-        maxb = tables.shape[1]
-        G = _bytes_group(Bt, maxb, 2 * k_pool.shape[1] * D
-                         * k_pool.dtype.itemsize)
-        tables = jnp.asarray(tables, jnp.int32)
-        pad = -maxb % G
-        if pad:
-            tables = jnp.concatenate(
-                [tables, jnp.full((S, pad), -1, jnp.int32)], axis=1)
-        max_groups = None
-        if first is not None and max_context is not None:
-            max_groups = -(-int(max_context) // (G * Bt)) + 1
-        out = _paged_decode(
-            q.reshape(S, 1, Hk * rep, D), k_pool, v_pool, tables,
-            jnp.asarray(pos, jnp.int32), G=G, span=maxb * Bt,
-            name="hybrid_decode_attention",
-            scale=1.0 / math.sqrt(D) if scale is None else scale,
-            interpret=interpret, rep=rep, first=first,
-            max_groups=max_groups)
-        return out.reshape(S, Hk, rep, D)
+        out = _merged_decode(
+            q, k_pool, v_pool, tables, pos, first,
+            scale=1.0 / math.sqrt(q.shape[3]) if scale is None else scale,
+            interpret=interpret)
+        return out.reshape(q.shape)
     S, H, dh = q.shape
     out = _paged_attention(
         q[:, None], k_pool, v_pool, tables, pos,

@@ -615,8 +615,9 @@ class ServingEngine(object):
             # (and, on a quantized pool, the named blocks' scales) in
             # scalar memory: a geometry that cannot fit is refused
             # HERE, with the arithmetic, not by the first step's
-            # compile. A merged pool's decode call sizes its grid step
-            # by the block's bytes (ISSUE 32), so the check is told them
+            # compile. A merged pool's decode call keeps the tables as
+            # they are beside `pos` and `first` (ISSUE 36); a block's
+            # bytes tell the check that the pool is one
             from ..parallel.paged_attention import check_paged_smem
 
             check_paged_smem(S, self.blocks_per_slot, Bt, cfg.heads,

@@ -642,22 +642,24 @@ def _merged_case(hk, rep, windowed, pool, seed=0, garbage=False):
             jnp.asarray(first) if windowed else None)
 
 
-def _merged_oracle(q, k, v, tables, pos, first, hk):
-    """Plain softmax attention through the table, in float64."""
+def _merged_oracle(q, k, v, tables, pos, first, hk, bt=_M_BT):
+    """Plain softmax attention through the table, in float64; zeros
+    for a parked slot."""
     q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
     tables, pos = np.asarray(tables), np.asarray(pos)
+    D = q.shape[-1]
     out = np.zeros(q.shape)
     for s in range(len(pos)):
-        if pos[s] >= _M_SPAN:
+        if pos[s] >= tables.shape[1] * bt:
             continue
         ps = np.arange(0 if first is None else int(first[s]), pos[s] + 1)
-        blk = tables[s, ps // _M_BT]
+        blk = tables[s, ps // bt]
         assert (blk >= 0).all()
-        rows = k[blk].reshape(len(ps), _M_BT, hk, _M_D)[
-            np.arange(len(ps)), ps % _M_BT]  # [n, hk, D]
-        vals = v[blk].reshape(len(ps), _M_BT, hk, _M_D)[
-            np.arange(len(ps)), ps % _M_BT]
-        sc = np.einsum("grd,ngd->grn", q[s], rows) / np.sqrt(_M_D)
+        rows = k[blk].reshape(len(ps), bt, hk, D)[
+            np.arange(len(ps)), ps % bt]  # [n, hk, D]
+        vals = v[blk].reshape(len(ps), bt, hk, D)[
+            np.arange(len(ps)), ps % bt]
+        sc = np.einsum("grd,ngd->grn", q[s], rows) / np.sqrt(D)
         pr = np.exp(sc - sc.max(-1, keepdims=True))
         out[s] = np.einsum("grn,ngd->grd", pr / pr.sum(-1, keepdims=True),
                            vals)
@@ -678,8 +680,7 @@ def _merged_call(case, monkeypatch, by):
     assert pa._group(_M_BT, _M_MAXB) == 16
     assert G == {"tokens": 16, "bytes": 32}[by]
     out = pa.paged_decode_attention(
-        q, k, v, tables, pos, interpret=True, first=first,
-        max_context=_M_WIN if first is not None else None)
+        q, k, v, tables, pos, interpret=True, first=first)
     return np.asarray(out), G
 
 
@@ -714,38 +715,36 @@ def test_merged_pool_decode_call_is_plain_softmax_at_either_group(
 
 def test_group_rule_reads_the_pools_block_and_nothing_else():
     """The merged-pool call's group is the fewest blocks, `_group`'s
-    doubled, whose K + V reach `_STEP_BYTES`: granite's 32-token block
-    of 4 pair-rows (64 KiB) -> 16, SambaY's of 10 (160 KiB) -> 8, a
-    block as heavy as the GPT pool's rows (8,192 B a token) ->
+    doubled, whose K + V reach `_STEP_BYTES`: granite's and Trinity's
+    32-token block of 4 rows a token (64 KiB) -> 32, SambaY's of 10
+    (160 KiB) -> 8, a block of 20 (four of them are the target) ->
     `_group`'s own; never past the table; and `check_paged_smem`
-    counts the work list of that same group."""
+    counts the tables, which the call's kernel reads as they are."""
     from paddle_tpu.parallel import paged_attention as pa
 
     bt, maxb = 32, 256
     kv = lambda rows, item=2: 2 * bt * rows * 128 * item  # noqa: E731
     assert pa._group(bt, maxb) == 4
-    assert pa._bytes_group(bt, maxb, kv(4)) == 16
+    assert pa._bytes_group(bt, maxb, kv(4)) == 32
     assert pa._bytes_group(bt, maxb, kv(10)) == 8
-    assert pa._bytes_group(bt, maxb, kv(16)) == 4 == pa._group(bt, maxb)
-    assert pa._bytes_group(bt, maxb, kv(4, item=4)) == 8  # the dtype counts
+    assert pa._bytes_group(bt, maxb, kv(20)) == 4 == pa._group(bt, maxb)
+    assert pa._bytes_group(bt, maxb, kv(4, item=4)) == 16  # the dtype counts
     assert pa._bytes_group(bt, 8, kv(1)) == 8  # capped at the table
     assert pa._bytes_group(bt, 6, kv(1)) == 4  # `_group`'s, doubled 0 times
-    for rows in (4, 10, 16):
+    for rows in (4, 10, 20):
         G = pa._bytes_group(bt, maxb, kv(rows))
         assert kv(rows) * G >= pa._STEP_BYTES > kv(rows) * G // 2 \
             or G == pa._group(bt, maxb)
 
-    # the scalar memory the call is refused by is its own work list's:
-    # [G, S * NG] block names + two [S * NG] rows + positions
-    def need(slots, G):
-        steps = slots * maxb // G
-        return (pa._smem_padded(G, steps) + pa._smem_padded(1, slots)
-                + 2 * pa._smem_padded(1, steps))
+    # the scalar memory the call is refused by is what it prefetches:
+    # the tables as they are, `pos` and a window layer's `first` —
+    # whatever the group
+    def need(slots):
+        return pa._smem_padded(slots, maxb) + 2 * pa._smem_padded(1, slots)
 
     room = pa._SMEM_BYTES - pa._SMEM_RESERVE
+    fits = max(s for s in range(8, 4096, 8) if need(s) <= room)
     for rows in (4, 10):
-        G = pa._bytes_group(bt, maxb, kv(rows))
-        fits = max(s for s in range(8, 4096, 8) if need(s, G) <= room)
         pa.check_paged_smem(fits, maxb, bt, 32, False,
                             block_bytes=kv(rows))
         with pytest.raises(ValueError, match="scalar memory"):
@@ -753,49 +752,223 @@ def test_group_rule_reads_the_pools_block_and_nothing_else():
                                 block_bytes=kv(rows))
 
 
-@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
 @pytest.mark.parametrize("rule", ["every_entry", "look_back"])
 def test_worklist_names_the_tables_blocks_on_both_sides_of_its_switch(
-        rule, windowed, monkeypatch):
-    """`_decode_worklist` at granite's geometry (64 slots, groups of 16
-    blocks of 32 tokens, 16 groups a slot: N = 1,024) with its switch
-    put on either side of the list's length — the re-naming that looks
-    at every entry, and the one that looks one entry back, which is the
-    one N = 1,024 gets (the GPT cells' N = 512 keeps the other): either
-    way the list walks exactly the (slot, group) pairs the live
-    contexts name, slot after slot, and wherever a group names a block
-    its operand holds the table's; a parked slot takes one entry."""
+        rule, monkeypatch):
+    """`_decode_worklist` (the 4-D pool's decode call) at 64 slots,
+    groups of 16 blocks of 32 tokens, 16 groups a slot: N = 1,024, with
+    its switch put on either side of the list's length — the re-naming
+    that looks at every entry, and the one that looks one entry back,
+    which is the one N = 1,024 gets (the GPT cells' N = 512 keeps the
+    other): either way the list walks exactly the (slot, group) pairs
+    the live contexts name, slot after slot, and wherever a group names
+    a block its operand holds the table's; a parked slot takes one
+    entry. (The merged-pool call has no list: its walk is held by the
+    two tests below.)"""
     from paddle_tpu.parallel import paged_attention as pa
 
-    slots, maxb, bt, G, win = 64, 256, 32, 16, 512
+    slots, maxb, bt, G = 64, 256, 32, 16
     assert 32 * (maxb // G) <= pa._LOOKBACK_FROM < slots * (maxb // G)
     rng = np.random.default_rng(7)
     span = maxb * bt
     tables = rng.integers(0, 11264, (slots, maxb)).astype(np.int32)
     pos = rng.integers(0, span, slots).astype(np.int32)
-    pos[:6] = [0, G * bt - 1, G * bt, span - 1, span, win - 1]
-    first = np.maximum(pos - win + 1, 0).astype(np.int32)
-    max_groups = -(-win // (G * bt)) + 1 if windowed else None
-    N = slots * (max_groups or maxb // G)
+    pos[:5] = [0, G * bt - 1, G * bt, span - 1, span]
+    N = slots * (maxb // G)
     monkeypatch.setattr(pa, "_LOOKBACK_FROM",
                         N if rule == "every_entry" else N - 1)
     blk, wslot, wgrp, n = (np.asarray(a) for a in pa._decode_worklist(
-        jnp.asarray(tables), jnp.asarray(pos), bt, G, span,
-        first=jnp.asarray(first) if windowed else None,
-        max_groups=max_groups))
+        jnp.asarray(tables), jnp.asarray(pos), bt, G, span))
     assert blk.shape == (G, N)
     live = pos < span
     want = []
     for s in range(slots):
-        g0 = first[s] // (G * bt) if windowed and live[s] else 0
         last = pos[s] // (G * bt) if live[s] else 0
-        want += [(s, b) for b in range(g0, last + 1)]
+        want += [(s, b) for b in range(last + 1)]
     assert n == len(want) <= blk.shape[1]
     assert list(zip(wslot[:n].tolist(), wgrp[:n].tolist())) == want
     for i, (s, b) in enumerate(want):
         for g in range(G):
             depth = b * G + g
-            named = live[s] and depth * bt <= pos[s] and (
-                not windowed or (depth + 1) * bt > first[s])
-            if named:
+            if live[s] and depth * bt <= pos[s]:
                 assert blk[g, i] == tables[s, depth]
+
+
+# ---------------------------------------------------------------------
+# the merged-pool call's own copies (ISSUE 36): the kernel walks a
+# slot's table row and copies the blocks its context names, no other,
+# into a two-deep ring. Interpreted, a copy descriptor can be watched.
+# ---------------------------------------------------------------------
+
+
+def _watch_copies(monkeypatch):
+    """-> log: every copy the kernel starts as ("start", pool block,
+    ring place, ring row) and every wait as ("wait", place, row), through
+    a wrapper around `pltpu.make_async_copy` (callbacks of an
+    interpreted kernel; their order is not promised)."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    log, real = [], pa.pltpu.make_async_copy
+
+    def watched(src, dst, sem):
+        dma = real(src, dst, sem)
+        blk = src.transforms[0].indices[0]
+        half, at = dst.transforms[0].indices[:2]
+
+        class Watched:
+            def start(self):
+                jax.debug.callback(
+                    lambda b, h, r: log.append(("start", int(b), int(h),
+                                                int(r))),
+                    blk, half, at.start)
+                dma.start()
+
+            def wait(self):
+                jax.debug.callback(
+                    lambda h, r: log.append(("wait", int(h), int(r))),
+                    half, at.start)
+                dma.wait()
+
+        return Watched()
+
+    monkeypatch.setattr(pa.pltpu, "make_async_copy", watched)
+    return log
+
+
+def _walk_call(pos, first, hk, rep, bt, maxb, G, monkeypatch, seed=0,
+               dtype=jnp.float32, D=8):
+    """The merged-pool call over tables that name distinct blocks for
+    exactly what each slot attends (-1 elsewhere), its group set to G
+    blocks -> (out, float64 oracle, live, tables, the copies' log)."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    rng = np.random.RandomState(seed)
+    pos = np.asarray(pos, np.int32)
+    S, span = len(pos), maxb * bt
+    live = pos < span
+    lo = np.zeros(S, np.int64) if first is None else np.asarray(first) // bt
+    hi = np.where(live, pos // bt + 1, lo)
+    NB = int((hi - lo).sum()) + 2
+    order = rng.permutation(NB - 1) + 1  # block 0 is nobody's either
+    tables, at = np.full((S, maxb), -1, np.int32), 0
+    for s in range(S):
+        n = hi[s] - lo[s]
+        tables[s, lo[s]:hi[s]] = order[at:at + n]
+        at += n
+    k = jnp.asarray(rng.randn(NB, bt * hk, D), dtype)
+    v = jnp.asarray(rng.randn(NB, bt * hk, D), dtype)
+    q = jnp.asarray(rng.randn(S, hk, rep, D), dtype).astype(jnp.float32)
+    monkeypatch.setattr(pa, "_bytes_group", lambda *a: G)
+    log = _watch_copies(monkeypatch)
+    got = pa.paged_decode_attention(
+        q, k, v, jnp.asarray(tables), jnp.asarray(pos), interpret=True,
+        first=None if first is None else jnp.asarray(first, jnp.int32))
+    got = np.asarray(jax.block_until_ready(got))
+    jax.effects_barrier()
+    want = _merged_oracle(q, k, v, tables, pos, first, hk, bt)
+    return got, want, live, tables, log
+
+
+def _assert_copies_are_the_named_blocks(log, tables, G, rows):
+    """Every block a table names is copied exactly twice (its K, its
+    V) and nothing else is; every copy lands on a block's row of a
+    ring place and is waited for there, once."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    starts = [e for e in log if e[0] == "start"]
+    waits = [e for e in log if e[0] == "wait"]
+    named = sorted(int(b) for b in tables[tables >= 0])
+    assert sorted(e[1] for e in starts) == sorted(named * 2)
+    assert all(0 <= e[2] < pa._RING and e[3] % rows == 0
+               and e[3] < G * rows for e in starts)
+    assert sorted(e[2:] for e in starts) == sorted(e[1:] for e in waits)
+
+
+_WALK_BT, _WALK_MAXB, _WALK_G = 8, 40, 4  # 32 tokens a group, 10 groups
+_WALK_SPAN = _WALK_BT * _WALK_MAXB
+# name: (pos [S], window or None): the edges the walk has that the
+# work list did not — a walk that starts inside a block, on a block
+# but inside a group, a context of one token and of exactly one
+# block, a last group of one block (Trinity's 65th), parked slots
+# first, last and between live ones, nothing but parked slots
+_WALKS = {
+    "first_inside_a_block": ([150, 201, 77], 61),
+    "first_on_a_block_inside_a_group": ([167, 103, 319], 96),
+    "one_token_and_one_block": ([0, 7, 8, 15], None),
+    "window_longer_than_the_context": ([5, 31, 40], 64),
+    "last_group_of_one_block": ([135, 263, 39], 129),
+    "parked_between_two_live": ([100, _WALK_SPAN, 37, _WALK_SPAN, 250],
+                                None),
+    "parked_first_and_last_window": ([_WALK_SPAN, 180, 66, _WALK_SPAN], 50),
+    "nothing_but_parked": ([_WALK_SPAN, _WALK_SPAN], None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALKS))
+def test_table_walk_copies_what_the_context_names_on_every_edge(
+        case, monkeypatch):
+    """The kernel's walk on its own edges, each against float64 softmax
+    through the table at the pinned tolerance, a parked slot zeros, and
+    the copies counted: the blocks the tables name, K and V, and no
+    other — a window's walk starts at the block of `first` wherever
+    that lies in a group, so 65 blocks cost 65 blocks' copies."""
+    pos, win = _WALKS[case]
+    pos = np.asarray(pos, np.int32)
+    first = None if win is None else np.maximum(pos - win + 1, 0)
+    got, want, live, tables, log = _walk_call(
+        pos, first, 2, 2, _WALK_BT, _WALK_MAXB, _WALK_G, monkeypatch)
+    np.testing.assert_allclose(got[live], want[live], rtol=_RTOL,
+                               atol=_ATOL)
+    np.testing.assert_array_equal(got[~live], 0.0)
+    tables[~live] = -1
+    _assert_copies_are_the_named_blocks(log, tables, _WALK_G, _WALK_BT * 2)
+    if case == "last_group_of_one_block":
+        blocks = pos // _WALK_BT - first // _WALK_BT + 1
+        assert blocks.tolist() == [17, 17, 5]  # 4 groups and one block
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+def test_table_walk_at_the_cells_table_shape(windowed, monkeypatch):
+    """What the work list's windowed cases held, held for the walk that
+    took the list's place: 64 slots, 256 table entries of 32 tokens,
+    groups of 16 blocks (granite's and Trinity's call; a 512-token
+    window as SambaY's), positions on the list's old edges and at
+    random — the call is plain softmax through the tables and copies
+    exactly the blocks they name."""
+    slots, maxb, bt, G, win = 64, 256, 32, 16, 512
+    rng = np.random.default_rng(7)
+    span = maxb * bt
+    pos = rng.integers(0, span, slots).astype(np.int32)
+    pos[:6] = [0, G * bt - 1, G * bt, span - 1, span, win - 1]
+    first = np.maximum(pos - win + 1, 0) if windowed else None
+    got, want, live, tables, log = _walk_call(
+        pos, first, 1, 1, bt, maxb, G, monkeypatch, seed=7)
+    assert (~live).sum() == 1
+    np.testing.assert_allclose(got[live], want[live], rtol=_RTOL,
+                               atol=_ATOL)
+    np.testing.assert_array_equal(got[~live], 0.0)
+    _assert_copies_are_the_named_blocks(log, tables, G, bt)
+
+
+@pytest.mark.parametrize("pool", ["f32", "bf16"])
+def test_ring_rows_no_copy_wrote_cannot_reach_the_result(pool, monkeypatch):
+    """A trap the BlockSpec form did not have: a ring row that no copy
+    has written holds whatever the memory held, and 0 x NaN is NaN in
+    P . V. Interpreted, a kernel's scratch starts as NaN bit patterns:
+    with the ring's zeroing taken out, a call whose first group is
+    short returns NaN — and the kernel as it is returns plain softmax,
+    on contexts whose every group is short."""
+    from jax._src.pallas import primitives
+    from paddle_tpu.parallel import paged_attention as pa
+
+    dt = jnp.float32 if pool == "f32" else jnp.bfloat16
+    assert np.isnan(np.asarray(
+        primitives.uninitialized_value((2,), dt), np.float32)).all()
+    pos = np.asarray([3, 20, 9], np.int32)  # 1, 3 and 2 blocks of a 4-group
+    args = (pos, None, 2, 2, _WALK_BT, _WALK_MAXB, _WALK_G, monkeypatch)
+    got, want, live, _, _ = _walk_call(*args, dtype=dt)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=_RTOL, atol=_ATOL)
+    monkeypatch.setattr(pa, "_zero_ring", lambda *bufs: None)
+    bare, _, _, _, _ = _walk_call(*args, dtype=dt)
+    assert np.isnan(bare).any()

@@ -817,8 +817,7 @@ def test_grouped_decode_kernel_equals_its_oracle(windowed):
     got = pa.paged_decode_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(tables), jnp.asarray(pos), interpret=True,
-        first=None if first is None else jnp.asarray(first),
-        max_context=W if windowed else None, scale=0.25)
+        first=None if first is None else jnp.asarray(first), scale=0.25)
     want = _attention_oracle(q, kp, vp, tables, pos, first, bt, 0.25)
     live = pos < maxb * bt
     assert np.abs(np.asarray(got)[live] - want[live]).max() < 1e-5

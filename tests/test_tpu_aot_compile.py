@@ -707,29 +707,33 @@ def test_granite_prefill_chunk_compiles_at_its_one_bucket(programs):
 
 
 # ---------------------------------------------------------------------
-# the merged-pool decode call alone, at both hybrid cells' geometry,
-# with the grid step the byte rule gives it (ISSUE 32)
+# the merged-pool decode call alone, at the three hybrid cells'
+# geometry, with the group the byte rule gives it (ISSUEs 32 and 36)
 # ---------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("metric,rows,rep,blocks,window,group,steps", [
-    ("gqa_attn_roofline", 4, 8, GR_NB + 1, None, 16, GR_S * 16),
-    ("hybrid_attn_roofline", 10, 4, HY_NB + 1, None, 8, HY_S * 32),
-    ("hybrid_attn_roofline", 10, 4, HY_S * 17 + 1, 512, 8, HY_S * 3),
-], ids=["granite", "sambay_full", "sambay_window"])
-def test_merged_pool_decode_call_compiles_with_its_byte_sized_step(
-        one_chip, metric, rows, rep, blocks, window, group, steps):
+@pytest.mark.parametrize("metric,rows,rep,blocks,window,group", [
+    ("gqa_attn_roofline", 4, 8, GR_NB + 1, None, 32),
+    ("hybrid_attn_roofline", 10, 4, HY_NB + 1, None, 8),
+    ("hybrid_attn_roofline", 10, 4, HY_S * 17 + 1, 512, 8),
+    ("swa_attn_roofline", 4, 8, HY_S * 65 + 1, 2048, 32),
+], ids=["granite", "sambay_full", "sambay_window", "trinity_window"])
+def test_merged_pool_decode_call_compiles_with_its_own_copies(
+        one_chip, metric, rows, rep, blocks, window, group):
     """`hybrid_decode_attention` at the geometry of
     `granite4hmicro_reason_closed` (4 pair-rows a token, 8 queries a
-    row) and of `phi4flash_reason_closed` (10 and 4; the shared pool,
-    and a window pool with `first` and `max_context` 512): 64 slots,
-    8,192 positions in 32-token blocks, bf16. The byte rule gives the
-    one 16 blocks a grid step and the other 8, the work list is
-    [group, slots x groups a slot] (a window walks 512 / 256 + 1 = 3),
-    Mosaic accepts the 2 x group K/V operands and the wider score tile
+    row), of `phi4flash_reason_closed` (10 and 4; the shared pool, and
+    a window pool with `first`) and of `trinitymini_reason_closed` (4
+    K/V heads, 8 queries a head; a 2,048-token window pool): 64 slots,
+    8,192 positions in 32-token blocks, bf16. The byte rule gives
+    granite's and Trinity's pools 32 blocks a group and SambaY's 8;
+    the call takes the tables, `pos` (and `first`), q and the two pools — ONE operand a pool, left
+    in HBM, no work list beside it — Mosaic accepts the kernel's own
+    copies, its loops of data-dependent length and the two-deep ring
     inside the memory a program scopes, and the compiled call is still
     the ONE instruction the cell's roofline metric finds (`op_match`,
-    read from its file), named after the kernel."""
+    read from its file), named after the kernel, its result
+    `[slots, rows x rep, 1, 128]`."""
     sds = _sds(one_chip)
     pool = sds((blocks, HY_BT * rows, 128), jnp.bfloat16)
     assert pa._bytes_group(HY_BT, HY_MAXB,
@@ -741,18 +745,18 @@ def test_merged_pool_decode_call_compiles_with_its_byte_sized_step(
     text = _compile(
         lambda q, k, v, t, p, *first: pa.paged_decode_attention(
             q, k, v, t, p, interpret=False, first=first[0] if first else None,
-            max_context=window, scale=0.125), *args)
+            scale=0.125), *args)
     lines = [ln.strip() for ln in text.split("\n")]
     found = [ln for ln in lines if _metric_pattern(metric).search(ln)]
     assert len(found) == 1 and " custom-call(" in found[0]
+    assert "bf16[%d,%d,1,128]" % (HY_S, rows * rep) in found[0]
     call = found[0][found[0].index(" custom-call("):
                     found[0].index("custom_call_target")]
-    # the grid's length, four or five prefetch operands and q, then K
-    # and V, an operand a block of the group; the work list is
-    # [group, steps]
-    assert call.count("%") == (6 if window else 5) + 1 + 2 * group
-    assert call.count("%k.1") == call.count("%v.1") == group
-    assert re.search(r"s32\[%d,%d\]" % (group, steps), text)
+    # the tables, the positions (and `first`), q, K, V — and nothing
+    # computed from the tables rides beside them
+    assert call.count("%") == (6 if window else 5)
+    assert call.count("%k.1") == call.count("%v.1") == 1
+    assert not re.search(r"s32\[%d,\d+\]" % group, text)
     assert re.search(r'kernel_metadata=\{\s*"kernel":'
                      r'"hybrid_decode_attention"\s*\}', text)
     assert text.count("tpu_custom_call") == 1
@@ -890,22 +894,22 @@ def test_afmoe_prefill_chunk_compiles_at_the_largest_bucket(programs):
 
 
 # the SambaY, the Mamba-2 / grouped-query and the sparse-expert decode
-# programs as PR 34's tree compiles them at their cells' geometry
-# (ISSUE 35 named the parts of every family's compiled steps, and a
-# name is metadata: the programs are the parent's, instruction for
-# instruction): sha256 of the compiled text less locations and less
-# the kernels' embedded bodies, as `_GPT_DECODE_TEXT_SHA` above. A PR
-# that MEANS to change one of them replaces its digest (the failing
-# assertion prints the new one) and says so in CHANGES.md.
+# programs as PR 36's tree compiles them at their cells' geometry
+# (ISSUE 36 changed their attention call — its operands, its scratch,
+# no work list beside it — and meant to): sha256 of the compiled text
+# less locations and less the kernels' embedded bodies, as
+# `_GPT_DECODE_TEXT_SHA` above. A PR that MEANS to change one of them
+# replaces its digest (the failing assertion prints the new one) and
+# says so in CHANGES.md.
 _DECODE_TEXT_SHA = {
-    "hybrid": "fc6585d77a8b3bbdbcb94b371f5d1a8b13848788150cb7203628c2079fca0052",
-    "granite": "efc43696468aec752beebca38adf0c5e26298bde9df189b2fff3bc564db7492a",
-    "afmoe": "a14ad06027fdda8002081f90785b6c02becec9977e46d397c7ef1f5fb6db7298",
+    "hybrid": "4e6f3a74b72a483e7bcdc04dddc5eb0df3201c721ecbc86ac495fae10d58575f",
+    "granite": "c98a1e76fac207ec0e96d2cda950b5e3227cbc2cd2841308015c1e18f1edfbd0",
+    "afmoe": "96868b95999559ab3474ae348110ce27fc0c3b7ab449c59ec67db1b9fb62aaec",
 }
 
 
 @pytest.mark.parametrize("family", sorted(_DECODE_TEXT_SHA))
-def test_other_families_decode_programs_are_the_text_the_parent_compiled(
+def test_other_families_decode_programs_are_the_text_last_meant(
         programs, family):
     assert _text_digest(programs(family).text)[0] == _DECODE_TEXT_SHA[family]
 

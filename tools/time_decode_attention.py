@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
 """Time the merged-pool decode attention call ALONE on the chip
-(ISSUE 32, step 1; no benchmark cell runs this).
+(ISSUEs 32 and 36, step 1 of each; no benchmark cell runs this).
 
     chiprun -- python3 tools/time_decode_attention.py --cell granite \\
-        --groups 4,8,16,32 --variants body,bare
+        --groups 4,8,16,32 --variants body,noproducts
+    chiprun -- python3 tools/time_decode_attention.py --cell trinity --window 2048
     chiprun -- python3 tools/time_decode_attention.py --cell sambay --window 512
 
 One `hybrid_decode_attention` call (`parallel/paged_attention.py`) at a
@@ -11,25 +12,27 @@ benchmark cell's geometry — the head shapes from the configuration's
 `shape` group, slots, block size, pool blocks and positions from its
 `engine` group, each an argument here — over contexts drawn uniformly
 from --context-lo .. --context-hi through tables that name distinct
-blocks, as an allocator would. For every group size G (blocks a grid
-step; `rule` is what the program itself picks) and body variant it
+blocks, as an allocator would. For every group size G (blocks a ring
+half; `rule` is what the program itself picks) and body variant it
 prints one JSON line: device microseconds of the kernel a call (median
-over --calls, read from a profiler trace by the kernel's name), its grid
-steps, microseconds a step, the rest of the call (the work list's
-programs) and the least time of the call from the cell's own cost
-function (`benchmarks/chip/lib/costs_*.py`, the one its roofline metric
-divides by) over the published HBM bandwidth.
+over --calls, read from a profiler trace by the kernel's name), the
+groups it folds (`steps`) and the blocks it copies, microseconds a
+group, the rest of the program (`rest_us`: what is left beside the
+kernel — since ISSUE 36 no work list, the kernel reads the tables) and
+the least time of the call from the cell's own cost function
+(`benchmarks/chip/lib/costs_*.py`, the one its roofline metric divides
+by) over the published HBM bandwidth.
 
-Variants: `body` is the program's kernel. The others answer "what does
-the work on the score tile cost?" and give WRONG ANSWERS, timing only:
-`nomask` drops the head/position masks and the NEG_INF `where`s,
-`nosplit` sends P as one 16-bit product instead of hi + lo halves,
-`bare` drops both; `qkonly`, `pvonly` and `noproducts` keep `body`'s
-tile work and drop one or both of the two matrix products.
+Variants: `body` is the program's kernel. The others keep its copies,
+ring and walk, put their own functions in the place of its score tile
+and softmax fold, and give WRONG ANSWERS, timing only: `nomask` drops
+the head/position masks and the NEG_INF `where`s, `nosplit` sends P as
+one 16-bit product instead of hi + lo halves, `bare` drops both;
+`qkonly`, `pvonly` and `noproducts` keep `body`'s tile work and drop
+one or both of the two matrix products.
 
---worklist also times `_decode_worklist` alone under both re-naming
-rules. --tiny is a rehearsal on the CPU (kernels interpreted, wall
-clock only): its numbers are not device times and say so.
+--tiny is a rehearsal on the CPU (kernels interpreted, wall clock
+only): its numbers are not device times and say so.
 """
 
 from __future__ import annotations
@@ -51,9 +54,9 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks", "chip"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental import pallas as pl  # noqa: E402
 
-from lib import costs_granite_hybrid, costs_sambay, peaks  # noqa: E402
+from lib import (costs_afmoe, costs_granite_hybrid, costs_sambay,  # noqa: E402
+                 peaks)
 from paddle_tpu.parallel import paged_attention as pa  # noqa: E402
 from paddle_tpu.parallel.kernel_utils import NEG_INF  # noqa: E402
 
@@ -70,144 +73,82 @@ CELLS = {
                lambda sh: (sh["kv_heads"] // 2,
                            2 * sh["heads"] // sh["kv_heads"],
                            2 * sh["dim"] // sh["heads"])),
+    # no pairing: a K/V head's rows as they are, its queries beside them
+    "trinity": ("trinity_mini",
+                costs_afmoe.swa_decode_attention_cost,
+                lambda sh: (sh["kv_heads"], sh["heads"] // sh["kv_heads"],
+                            sh["head_dim"])),
 }
 TINY = {"granite": {"heads": 8, "kv_heads": 4, "head_dim": 8},
-        "sambay": {"heads": 8, "kv_heads": 4, "dim": 64}}
+        "sambay": {"heads": 8, "kv_heads": 4, "dim": 64},
+        "trinity": {"heads": 8, "kv_heads": 2, "head_dim": 16}}
 
 
-def _timing_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, *refs, Bt, G, span,
-                   scale, rep=1, windowed=False, masks=True, split=True,
-                   products="qk,pv"):
-    """`_pa_decode_kernel` with the tile work cut out by parts."""
-    if windowed:
-        first_ref, q_ref, refs = refs[0], refs[1], refs[2:]
-    else:
-        first_ref, q_ref, refs = None, refs[0], refs[1:]
-    k_refs, v_refs = refs[:G], refs[G:2 * G]
-    o_ref, acc_ref, m_ref, l_ref = refs[2 * G:]
-    R, dh = q_ref.shape
+def _scores(q, k, scale, rep, at, pos, first, *, masks=True, product=True):
+    """`pa._masked_scores` with parts cut out."""
+    R, C = q.shape[0], k.shape[0]
     H = R // rep
-    W = G * Bt
-    i = pl.program_id(0)
-    si, b = wslot_ref[i], wgrp_ref[i]
-    pos = pos_ref[si]
-    live = pos < span
-    b_first = 0 if first_ref is None else jnp.where(
-        live, first_ref[si] // W, 0)
+    if product:
+        s = jax.lax.dot_general(
+            q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+    else:  # nothing of the K tile is loaded; its copy is still waited for
+        s = jnp.sum(q.astype(jnp.float32), axis=1,
+                    keepdims=True) + jnp.zeros((R, C), jnp.float32)
+    if not masks:
+        return s
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, C), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, C), 0) // rep
+    head = (col & (H - 1)) if H & (H - 1) == 0 else jax.lax.rem(col, H)
+    masked = (head != row) | (col >= (pos - at + 1) * H)
+    if first is not None:
+        masked = masked | (col < (first - at) * H)
+    return jnp.where(masked, NEG_INF, s)
 
-    @pl.when(b == b_first)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _accumulate_by_block():
-        # the body's arithmetic with no [W*H, Dh] copy of K and V: a
-        # product, a mask and an exp a block, the row state across them
-        BH = Bt * H
-        q = q_ref[...].astype(k_refs[0].dtype)
-        col = jax.lax.broadcasted_iota(jnp.int32, (R, BH), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (R, BH), 0) // rep
-        head = ((col & (H - 1)) if H & (H - 1) == 0
-                else jax.lax.rem(col, H))
-        other = head != row
-        ss = []
-        for g, r in enumerate(k_refs):
-            sg = jax.lax.dot_general(
-                q, r[...].reshape(BH, dh), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            at = (b * G + g) * Bt  # this block's first position
-            masked = other | (col >= (pos - at + 1) * H)
-            if first_ref is not None:
-                masked = masked | (col < (first_ref[si] - at) * H)
-            ss.append(jnp.where(masked, NEG_INF, sg))
-        m_prev = m_ref[...]
-        m_new = m_prev
-        for sg in ss:
-            m_new = jnp.maximum(m_new, jnp.max(sg, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
+def _fold(s, v, acc_ref, m_ref, l_ref, *, masks=True, split=True,
+          product=True):
+    """`pa._fold_tile` with parts cut out."""
+    R, dh = acc_ref.shape
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    if masks:
+        p = jnp.where(s <= NEG_INF, 0.0, p)
         alpha = jnp.where(m_prev <= NEG_INF, 0.0, alpha)
-        l_new = l_ref[...] * alpha
-        acc = acc_ref[...] * alpha
-        for sg, r in zip(ss, v_refs):
-            pg = jnp.where(sg <= NEG_INF, 0.0, jnp.exp(sg - m_new))
-            l_new = l_new + jnp.sum(pg, axis=1, keepdims=True)
-            vg = r[...].reshape(BH, dh)
-            hi = pg.astype(vg.dtype)
-            lo = (pg - hi.astype(jnp.float32)).astype(vg.dtype)
-            pv = jax.lax.dot_general(
-                jnp.concatenate([hi, lo], axis=0), vg,
-                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-            acc = acc + pv[:R] + pv[R:]
-        l_ref[...] = l_new
-        acc_ref[...] = acc
-        m_ref[...] = m_new
-
-    def _accumulate():
-        k = jnp.concatenate([r[...].reshape(Bt * H, dh) for r in k_refs], 0)
-        v = jnp.concatenate([r[...].reshape(Bt * H, dh) for r in v_refs], 0)
-        if "qk" in products:
-            s = jax.lax.dot_general(
-                q_ref[...].astype(k.dtype), k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-        else:  # nothing of the K tile is loaded; its copy is still waited for
-            s = jnp.sum(q_ref[...].astype(jnp.float32), axis=1,
-                        keepdims=True) + jnp.zeros((R, W * H), jnp.float32)
-        if masks:
-            col = jax.lax.broadcasted_iota(jnp.int32, (R, W * H), 1)
-            row = jax.lax.broadcasted_iota(jnp.int32, (R, W * H), 0) // rep
-            head = ((col & (H - 1)) if H & (H - 1) == 0
-                    else jax.lax.rem(col, H))
-            masked = (head != row) | (col >= (pos - b * W + 1) * H)
-            if first_ref is not None:
-                masked = masked | (col < (first_ref[si] - b * W) * H)
-            s = jnp.where(masked, NEG_INF, s)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        if masks:
-            p = jnp.where(s <= NEG_INF, 0.0, p)
-            alpha = jnp.where(m_prev <= NEG_INF, 0.0, alpha)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        if "pv" not in products:
-            pv = p[:, :dh] * v[:1, :].astype(jnp.float32)
-        elif split and v.dtype.itemsize == 2:
-            hi = p.astype(v.dtype)
-            lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
-            pv = jax.lax.dot_general(
-                jnp.concatenate([hi, lo], axis=0), v,
-                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-            pv = pv[:R] + pv[R:]
-        else:
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
-
-    pl.when(live)(_accumulate_by_block if products == "perblock"
-                  else _accumulate)
-
-    @pl.when(b == jnp.where(live, pos // W, 0))
-    def _finalise():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = out[:, None, :].astype(o_ref.dtype)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    if not product:
+        pv = p[:, :dh] * v[:1, :].astype(jnp.float32)
+    elif split and v.dtype.itemsize == 2:
+        hi = p.astype(v.dtype)
+        lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+        pv = jax.lax.dot_general(
+            jnp.concatenate([hi, lo], axis=0), v,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        pv = pv[:R] + pv[R:]
+    else:
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    m_ref[...] = m_new
 
 
+# name: what takes the place of (`pa._masked_scores`, `pa._fold_tile`)
+# inside the program's own kernel, whose copies, ring and walk stay
 VARIANTS = {
     "body": None,
-    "nomask": functools.partial(_timing_kernel, masks=False),
-    "nosplit": functools.partial(_timing_kernel, split=False),
-    "bare": functools.partial(_timing_kernel, masks=False, split=False),
-    # the two products by parts (the rest as `body`): what scoring a
-    # row against its own head only could save is inside these
-    "qkonly": functools.partial(_timing_kernel, products="qk"),
-    "pvonly": functools.partial(_timing_kernel, products="pv"),
-    "noproducts": functools.partial(_timing_kernel, products=""),
-    # RIGHT answers: the body's arithmetic a block at a time, with no
-    # concatenated copy of a group's K and V (PERF.md section 6)
-    "perblock": functools.partial(_timing_kernel, products="perblock"),
+    "nomask": (functools.partial(_scores, masks=False),
+               functools.partial(_fold, masks=False)),
+    "nosplit": (_scores, functools.partial(_fold, split=False)),
+    "bare": (functools.partial(_scores, masks=False),
+             functools.partial(_fold, masks=False, split=False)),
+    # the two products by parts (the rest as `body`)
+    "qkonly": (_scores, functools.partial(_fold, product=False)),
+    "pvonly": (functools.partial(_scores, product=False), _fold),
+    "noproducts": (functools.partial(_scores, product=False),
+                   functools.partial(_fold, product=False)),
 }
 
 
@@ -235,6 +176,25 @@ def _device_us(trace_dir, op_prefix):
     return statistics.median(ops), statistics.median(mods)
 
 
+def _max_error(got, q, k_pool, v_pool, tables, pos, first, Bt, scale):
+    """Largest |got - plain softmax attention through the tables|, the
+    reference in float64 on the host, slot by slot."""
+    got, q = np.asarray(got, np.float64), np.asarray(q, np.float64)
+    k_pool, v_pool = np.asarray(k_pool), np.asarray(v_pool)
+    hk, D = q.shape[1], q.shape[3]
+    worst = 0.0
+    for s in range(len(pos)):
+        ps = np.arange(0 if first is None else first[s], pos[s] + 1)
+        rows = tables[s, ps // Bt].astype(np.int64) * Bt + ps % Bt
+        k = k_pool.reshape(-1, hk, D)[rows].astype(np.float64)
+        v = v_pool.reshape(-1, hk, D)[rows].astype(np.float64)
+        sc = np.einsum("grd,ngd->grn", q[s], k) * scale
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        want = np.einsum("grn,ngd->grd", pr / pr.sum(-1, keepdims=True), v)
+        worst = max(worst, float(np.abs(got[s] - want).max()))
+    return worst
+
+
 def _measure(fn, args, calls, on_chip, op_prefix):
     @jax.jit
     def timed(*xs):  # a fresh program a measurement, named for the trace
@@ -260,10 +220,18 @@ def main(argv=None):
     ap.add_argument("--cell", choices=sorted(CELLS), required=True)
     ap.add_argument("--window", type=int, default=0,
                     help="attend only the last N positions (a window "
-                         "layer's call: `first` and `max_context`); 0 = all")
+                         "layer's call: `first`); 0 = all")
     ap.add_argument("--groups", default="rule",
                     help="comma list of blocks a grid step, or 'rule'")
     ap.add_argument("--variants", default="body")
+    ap.add_argument("--shorts", default="rule",
+                    help="comma list of the divisor of a group under which "
+                         "a slot's last group scores part of a tile "
+                         "(`_SHORT_GROUP`), 0 = always a whole tile, or "
+                         "'rule'")
+    ap.add_argument("--rings", default="rule",
+                    help="comma list of groups the VMEM ring holds, or "
+                         "'rule' (the program's `_RING`)")
     ap.add_argument("--slots", type=int)
     ap.add_argument("--block-tokens", type=int)
     ap.add_argument("--pool-blocks", type=int)
@@ -273,7 +241,6 @@ def main(argv=None):
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--calls", type=int, default=20)
-    ap.add_argument("--worklist", action="store_true")
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "time_decode_attention.jsonl"))
@@ -338,60 +305,50 @@ def main(argv=None):
             "least_us": least}
     print(json.dumps(head))
     out = [head]
-    rule_group = pa._bytes_group
 
     def call(q, k, v, t, p, *f):
         return pa.paged_decode_attention(
-            q, k, v, t, p, first=f[0] if f else None,
-            max_context=a.window or None, scale=0.125)
+            q, k, v, t, p, first=f[0] if f else None, scale=0.125)
 
-    for g in a.groups.split(","):
-        G = (rule_group(Bt, maxb, block_bytes) if g == "rule" else int(g))
-        W = G * Bt
-        g0 = 0 if first is None else first // W
-        steps = int((pos // W - g0 + 1).sum())
-        for variant in a.variants.split(","):
-            pa._bytes_group = lambda *_, G=G: G
-            body = pa._pa_decode_kernel
-            if VARIANTS[variant] is not None:
-                pa._pa_decode_kernel = VARIANTS[variant]
-            try:
-                kern, whole = _measure(call, args, a.calls, on_chip,
-                                       "%hybrid_decode_attention")
-            finally:
-                pa._pa_decode_kernel, pa._bytes_group = body, rule_group
-            row = {"G": G, "by": g, "variant": variant, "steps": steps,
-                   "step_kv_bytes": G * block_bytes, "call_us": whole}
-            if kern is not None:
-                row.update(kernel_us=kern, us_per_step=kern / steps,
-                           rest_us=whole - kern,
-                           kernel_share_of_least=least / kern)
-            else:
-                row["note"] = "CPU wall clock, kernel interpreted: no device time"
-            print(json.dumps(row))
-            out.append(row)
-        if a.worklist:
-            span = maxb * Bt
-            mg = (-(-a.window // W) + 1) if a.window else None
-            t = jnp.asarray(np.pad(tables, ((0, 0), (0, -maxb % G)),
-                                   constant_values=-1))
-            for rule, switch in (("every entry", 1 << 30), ("look back", 0)):
-                was, pa._LOOKBACK_FROM = pa._LOOKBACK_FROM, switch
-                try:
-                    fn = lambda t, p, *f: pa._decode_worklist(  # noqa: E731
-                        t, p, Bt, G, span, first=f[0] if f else None,
-                        max_groups=mg)
-                    _, us = _measure(fn, (t,) + args[4:], a.calls, on_chip,
-                                     "%")
-                except Exception as e:  # too large to compile is a reading
-                    us = "refused: %s" % str(e).split("\n")[0][:120]
-                finally:
-                    pa._LOOKBACK_FROM = was
-                row = {"G": G, "worklist": rule,
-                       "N": S * min(maxb // G + (1 if maxb % G else 0),
-                                    mg or 1 << 30), "call_us": us}
-                print(json.dumps(row))
-                out.append(row)
+    rule = (pa._bytes_group, pa._RING, pa._SHORT_GROUP, pa._masked_scores,
+            pa._fold_tile)
+    sweep = [(g, r, sh, v) for g in a.groups.split(",")
+             for r in a.rings.split(",") for sh in a.shorts.split(",")
+             for v in a.variants.split(",")]
+    for g, r, sh, variant in sweep:
+        G = rule[0](Bt, maxb, block_bytes) if g == "rule" else int(g)
+        b0 = 0 if first is None else first // Bt
+        steps = int((-(-(pos // Bt + 1 - b0) // G)).sum())
+        pa._bytes_group = lambda *_, G=G: G
+        pa._RING = rule[1] if r == "rule" else int(r)
+        pa._SHORT_GROUP = rule[2] if sh == "rule" else int(sh) or 1 << 30
+        if VARIANTS[variant] is not None:
+            pa._masked_scores, pa._fold_tile = VARIANTS[variant]
+        row = {"G": G, "by": g, "ring": pa._RING,
+               "short_blocks": G // pa._SHORT_GROUP, "variant": variant,
+               "steps": steps, "blocks": need,
+               "step_kv_bytes": G * block_bytes}
+        try:
+            kern, row["call_us"] = _measure(call, args, a.calls, on_chip,
+                                            "%hybrid_decode_attention")
+            if variant == "body":  # the others answer nothing
+                row["max_error"] = _max_error(
+                    jax.jit(call)(*args), q, k_pool, v_pool, tables, pos,
+                    first, Bt, 0.125)
+                if not row["max_error"] < 0.02:  # bf16: an ulp at |out| < 4
+                    raise SystemExit("the call is off its reference: %s"
+                                     % json.dumps(row))
+        finally:
+            (pa._bytes_group, pa._RING, pa._SHORT_GROUP, pa._masked_scores,
+             pa._fold_tile) = rule
+        if kern is not None:
+            row.update(kernel_us=kern, us_per_step=kern / steps,
+                       rest_us=row["call_us"] - kern,
+                       kernel_share_of_least=least / kern)
+        else:
+            row["note"] = "CPU wall clock, kernel interpreted: no device time"
+        print(json.dumps(row))
+        out.append(row)
     os.makedirs(os.path.dirname(a.out), exist_ok=True)
     with open(a.out, "a") as f:
         for row in out:
