@@ -441,10 +441,12 @@ def paged_decode_step(params, token, pos, tables, cache,
 
 def _chunk_attend(q, k, v, start_pos, cfg):
     """q [C, Hk, rep, dh], row i at position start_pos + i, over the
-    slot's span k, v [K, Hk, dh] (index = position) -> [C, heads * dh],
-    causal. Queries 512 rows at a time, keys 1,024 at a time with the
-    running (max, sum, acc) of an online softmax, and only the key
-    tiles up to a query tile's last position are walked (a trip count
+    slot's span k [K, Hk, dh], v [K, Hk, dv] (index = position; dv is
+    dh but in the latent family, whose values are narrower than its
+    keys) -> [C, heads * dv], causal. Queries 512 rows at a time, keys
+    1,024 at a time with the running (max, sum, acc) of an online
+    softmax, and only the key tiles up to a query tile's last position
+    are walked (a trip count
     read off `start_pos`), so the work follows the context and not
     the table's span. One softmax over the whole span instead ran 130
     times slower on the v5e at 8,192 positions (0.196 s a layer
@@ -452,7 +454,7 @@ def _chunk_attend(q, k, v, start_pos, cfg):
     contraction over dh off the matrix unit."""
     f32 = jnp.float32
     C, Hk, rep, dh = q.shape
-    K = k.shape[0]
+    K, dv = k.shape[0], v.shape[-1]
     tile = min(512, C)
     KT = 1024 if K % 1024 == 0 else K
     kh, vh = k.transpose(1, 0, 2), v.transpose(1, 0, 2)  # [Hk, K, dh]
@@ -483,9 +485,9 @@ def _chunk_attend(q, k, v, start_pos, cfg):
         _, l, acc = jax.lax.fori_loop(
             0, jnp.minimum((start_pos + i + tile + KT - 1) // KT, K // KT),
             body, (m0, jnp.zeros_like(m0),
-                   jnp.zeros((Hk, rep * tile, dh), f32)))
-        o = (acc / l).reshape(Hk, rep, tile, dh).transpose(2, 0, 1, 3)
-        outs.append(o.reshape(tile, Hk * rep * dh).astype(q.dtype))
+                   jnp.zeros((Hk, rep * tile, dv), f32)))
+        o = (acc / l).reshape(Hk, rep, tile, dv).transpose(2, 0, 1, 3)
+        outs.append(o.reshape(tile, Hk * rep * dv).astype(q.dtype))
     return jnp.concatenate(outs)
 
 

@@ -137,7 +137,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .kernel_utils import NEG_INF, resolve_interpret
 
 __all__ = ["paged_decode_attention", "paged_verify_attention",
-           "paged_prefill_attention", "check_paged_smem", "paged_kv_write"]
+           "paged_prefill_attention", "check_paged_smem", "paged_kv_write",
+           "mla_decode_attention"]
 
 
 def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
@@ -421,10 +422,13 @@ def _masked_scores(q, k, scale, rep: int, at, pos, first):
         preferred_element_type=jnp.float32,
     ) * scale
     col = jax.lax.broadcasted_iota(jnp.int32, (R, C), 1)
-    # rep query rows share a K/V head
-    row = jax.lax.broadcasted_iota(jnp.int32, (R, C), 0) // rep
-    head = (col & (H - 1)) if H & (H - 1) == 0 else jax.lax.rem(col, H)
-    masked = (head != row) | (col >= (pos - at + 1) * H)
+    if H > 1:
+        # rep query rows share a K/V head
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, C), 0) // rep
+        head = (col & (H - 1)) if H & (H - 1) == 0 else jax.lax.rem(col, H)
+        masked = (head != row) | (col >= (pos - at + 1) * H)
+    else:  # one latent row a token that every query reads
+        masked = col >= pos - at + 1
     if first is not None:
         masked = masked | (col < (first - at) * H)
     return jnp.where(masked, NEG_INF, s)
@@ -449,7 +453,7 @@ _SHORT_GROUP = 4
 
 def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
                            span: int, scale: float, rep: int,
-                           windowed: bool):
+                           windowed: bool, v_lanes: int = 0):
     """One SLOT of the merged-pool decode call (ISSUE 36): the pools
     stay in HBM, the kernel reads the slot's block ids from its table
     row in scalar memory and copies the blocks its context names — and
@@ -480,13 +484,29 @@ def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
     copied or folded for it and it writes zeros.
 
     All copies into one ring place signal that place's semaphore, each
-    waited for with a descriptor of its own size."""
+    waited for with a descriptor of its own size.
+
+    `v_lanes` > 0 (the latent pool, ISSUE 37): ONE pool, whose row is
+    the key and whose first `v_lanes` lanes are the value — a block is
+    copied once into one ring and read by both products."""
     if windowed:
         first_ref, refs = refs[0], refs[1:]
     else:
         first_ref = None
-    (q_ref, k_hbm, v_hbm, o_ref,
-     kbuf, vbuf, sem, acc_ref, m_ref, l_ref, st_ref, cnt_ref) = refs
+    if v_lanes:
+        (q_ref, k_hbm, o_ref,
+         kbuf, sem, acc_ref, m_ref, l_ref, st_ref, cnt_ref) = refs
+        rings = ((k_hbm, kbuf),)
+
+        def values(place, rows):
+            return kbuf[place, rows, pl.ds(0, v_lanes)]
+    else:
+        (q_ref, k_hbm, v_hbm, o_ref,
+         kbuf, vbuf, sem, acc_ref, m_ref, l_ref, st_ref, cnt_ref) = refs
+        rings = ((k_hbm, kbuf), (v_hbm, vbuf))
+
+        def values(place, rows):
+            return vbuf[place, rows]
     ring = kbuf.shape[0]
     BH = kbuf.shape[1] // G  # rows a block: (token, head)
     si = pl.program_id(0)
@@ -513,7 +533,7 @@ def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
             # a wait reads its descriptor's size and semaphore only
             blk = 0 if wait else jnp.maximum(tbl_ref[s, lo + i], 0)
             at = pl.ds(pl.multiple_of(i * BH, BH), BH)
-            for pool, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
+            for pool, buf in rings:
                 dma = pltpu.make_async_copy(pool.at[blk], buf.at[place, at],
                                             sem.at[place])
                 dma.wait() if wait else dma.start()
@@ -547,7 +567,7 @@ def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
 
     @pl.when(si == 0)
     def _prime():
-        _zero_ring(kbuf, vbuf)
+        _zero_ring(*(buf for _, buf in rings))
         st_ref[FOLDED] = 0
         st_ref[STARTED] = 0
         st_ref[NEXT_SLOT] = live_from(0)
@@ -576,7 +596,7 @@ def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
             s = _masked_scores(q_ref[...], kbuf[place, rows], scale, rep,
                                (b0 + j * G) * Bt, pos,
                                None if first_ref is None else first_ref[si])
-            _fold_tile(s, vbuf[place, rows], acc_ref, m_ref, l_ref)
+            _fold_tile(s, values(place, rows), acc_ref, m_ref, l_ref)
 
         few = G // _SHORT_GROUP
         if few:  # a slot's last group may hold a block or two
@@ -823,19 +843,24 @@ def _paged_decode(q, k_pool, v_pool, tables, pos, *, G, span, name, scale,
 
 
 def _merged_decode(q, k_pool, v_pool, tables, pos, first, *, scale,
-                   interpret):
+                   interpret, v_lanes=0):
     """The R == 1 call on a merged 3-D pool `[NB, Bt * Hk, Dh]`:
     `_pa_ring_decode_kernel` over a grid of slots. q [S, Hk, rep, Dh]
     -> out [S, Hk * rep, 1, Dh] (the shape the three hybrid roofline
     metrics find the kernel by). The tables, `pos` and `first` go to
     scalar memory as they are; the pools are handed over in HBM, one
     operand each, and the kernel copies what the tables name, a group
-    of `_bytes_group` blocks at a time."""
+    of `_bytes_group` blocks at a time. With `v_pool` None the values
+    are the first `v_lanes` lanes of the one pool (the latent call,
+    named `mla_decode_attention`): out [S, Hk * rep, 1, v_lanes]."""
     S, Hk, rep, dh = q.shape
     R = Hk * rep
     rows = k_pool.shape[1]  # of a block: (token, head)
     Bt, maxb = rows // Hk, tables.shape[1]
-    G = _bytes_group(Bt, maxb, 2 * rows * dh * k_pool.dtype.itemsize)
+    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+    dv = v_lanes or dh
+    G = _bytes_group(Bt, maxb,
+                     len(pools) * rows * dh * k_pool.dtype.itemsize)
     ring = (_RING, G * rows, dh)
     prefetch = (jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32))
     if first is not None:
@@ -846,35 +871,34 @@ def _merged_decode(q, k_pool, v_pool, tables, pos, first, *, scale,
 
     kernel = functools.partial(
         _pa_ring_decode_kernel, Bt=Bt, G=G, span=maxb * Bt, scale=scale,
-        rep=rep, windowed=first is not None)
+        rep=rep, windowed=first is not None, v_lanes=v_lanes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(S,),
-        in_specs=[pl.BlockSpec((None, None, R, dh), _slot_map),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((None, R, 1, dh), _slot_map),
-        scratch_shapes=[pltpu.VMEM(ring, k_pool.dtype),
-                        pltpu.VMEM(ring, v_pool.dtype),
-                        pltpu.SemaphoreType.DMA((_RING,)),
-                        pltpu.VMEM((R, dh), jnp.float32),
-                        pltpu.VMEM((R, 1), jnp.float32),
-                        pltpu.VMEM((R, 1), jnp.float32),
-                        pltpu.SMEM((4,), jnp.int32),
-                        pltpu.SMEM((_RING,), jnp.int32)],
+        in_specs=[pl.BlockSpec((None, None, R, dh), _slot_map)]
+        + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+        out_specs=pl.BlockSpec((None, R, 1, dv), _slot_map),
+        scratch_shapes=[pltpu.VMEM(ring, p.dtype) for p in pools]
+        + [pltpu.SemaphoreType.DMA((_RING,)),
+           pltpu.VMEM((R, dv), jnp.float32),
+           pltpu.VMEM((R, 1), jnp.float32),
+           pltpu.VMEM((R, 1), jnp.float32),
+           pltpu.SMEM((4,), jnp.int32),
+           pltpu.SMEM((_RING,), jnp.int32)],
     )
-    name = "hybrid_decode_attention"
+    name = ("hybrid_decode_attention" if v_pool is not None
+            else "mla_decode_attention")
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, R, 1, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, R, 1, dv), q.dtype),
         # the ring and the walk's scalars carry over from a slot to the next
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=resolve_interpret(interpret),
         name=name,
         metadata={"kernel": name},
-    )(*prefetch, q.reshape(S, 1, R, dh), k_pool, v_pool)
+    )(*prefetch, q.reshape(S, 1, R, dh), *pools)
 
 
 def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
@@ -1050,17 +1074,38 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     return out[:, 0]
 
 
-def _kv_write_kernel(blk_ref, off_ref, kn_ref, vn_ref, k_ref, v_ref,
-                     ko_ref, vo_ref, *, Hk: int):
+def mla_decode_attention(q, pool, tables, pos, v_lanes, scale,
+                         interpret=None):
+    """The absorbed latent decode call (ISSUE 37): one query a slot,
+    q [S, H, W] — every head's query folded into the latent's space —
+    over a latent pool [NB, Bt, W] whose row is a token's key, c and
+    the rotated k_r side by side, and whose first `v_lanes` lanes (c)
+    are its value; tables [S, MAXB], positions `pos` [S] -> o_lat
+    [S, H, v_lanes], float32 softmax, bf16 operands where the pool is.
+    Scores are q . row * `scale` at depths <= pos; each live block is
+    copied into VMEM ONCE and serves both products. The ring, the
+    walk over the table in scalar memory and the fold are the
+    merged-pool call's (`_pa_ring_decode_kernel` with `v_lanes`), with
+    one K/V head that all H query rows share."""
+    S, H, W = q.shape
+    out = _merged_decode(q.reshape(S, 1, H, W), pool, None, tables, pos,
+                         None, scale=scale, interpret=interpret,
+                         v_lanes=v_lanes)
+    return out.reshape(S, H, v_lanes)
+
+
+def _kv_write_kernel(blk_ref, off_ref, *refs, Hk: int):
     """One slot's decode write: its token's Hk rows replace rows
-    [off * Hk, (off + 1) * Hk) of the block the slot is filling, K and
-    V at once. The new rows come tiled over the whole block, so the
-    write is a select on a row index, with no unaligned store."""
+    [off * Hk, (off + 1) * Hk) of the block the slot is filling, in
+    every pool at once (K and V, or the one latent pool). The new rows
+    come tiled over the whole block, so the write is a select on a row
+    index, with no unaligned store."""
+    n = len(refs) // 3
     lo = off_ref[pl.program_id(0)] * Hk
-    row = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, refs[n].shape, 0)
     mine = (row >= lo) & (row < lo + Hk)
-    ko_ref[...] = jnp.where(mine, kn_ref[...], k_ref[...])
-    vo_ref[...] = jnp.where(mine, vn_ref[...], v_ref[...])
+    for new, old, out in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
+        out[...] = jnp.where(mine, new[...], old[...])
 
 
 def paged_kv_write(k_pool, v_pool, k_new, v_new, tables, pos,
@@ -1068,7 +1113,8 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, tables, pos,
     """The decode step's K/V write into merged 3-D pools (ISSUE 27):
     slot s's new rows `k_new[s]`, `v_new[s]` [Hk, D] land at position
     pos[s] through tables [S, MAXB] -> (k_pool, v_pool), updated in
-    place. XLA lowers the same scatter to a loop of one small
+    place; with `v_pool` and `v_new` None, the one pool -> (k_pool,).
+    XLA lowers the same scatter to a loop of one small
     dynamic-update-slice a slot (3 us each, 18 scatters a step at 64
     slots: 3.5 ms of a 37 ms step on the v5e; PERF.md section 6, PR
     27); here a grid step copies the slot's block in, selects the new
@@ -1077,6 +1123,8 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, tables, pos,
     beyond what the allocator hands out so that nothing lives there."""
     NB, rows, D = k_pool.shape
     S, Hk = k_new.shape[0], k_new.shape[1]
+    pools = [p for p in (k_pool, v_pool) if p is not None]
+    news = [x for x in (k_new, v_new) if x is not None]
     Bt = rows // Hk
     maxb = tables.shape[1]
     bi = pos // Bt
@@ -1094,27 +1142,24 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, tables, pos,
     def block(i, blk, off):
         return (blk[i], 0, 0)
 
+    n = len(pools)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S,),
-        in_specs=[pl.BlockSpec((None, rows, D), slot),
-                  pl.BlockSpec((None, rows, D), slot),
-                  pl.BlockSpec((None, rows, D), block),
-                  pl.BlockSpec((None, rows, D), block)],
-        out_specs=[pl.BlockSpec((None, rows, D), block),
-                   pl.BlockSpec((None, rows, D), block)],
+        in_specs=[pl.BlockSpec((None, rows, D), slot)] * n
+        + [pl.BlockSpec((None, rows, D), block)] * n,
+        out_specs=[pl.BlockSpec((None, rows, D), block)] * n,
     )
     return pl.pallas_call(
         functools.partial(_kv_write_kernel, Hk=Hk),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
         # operands 0 and 1 are the prefetched block ids and offsets
-        input_output_aliases={4: 0, 5: 1},
+        input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=resolve_interpret(interpret),
         name="paged_kv_write",
         metadata={"kernel": "paged_kv_write"},
-    )(blk, off, tiled(k_new), tiled(v_new), k_pool, v_pool)
+    )(blk, off, *map(tiled, news), *pools)
 
 
 def paged_verify_attention(q, k_pool, v_pool, tables, pos,

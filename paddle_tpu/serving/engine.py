@@ -303,6 +303,9 @@ class ServingEngine(object):
                                       attention layer on the ONE table
       models/afmoe.SERVING            ("paged", "window"): routed
                                       experts, window + full layers
+      models/mla_moe.SERVING          ("paged",): routed experts,
+                                      latent attention, ONE latent
+                                      pool a layer on the one table
 
     "window": window-attention pools whose blocks are freed behind the
     window (`kv_blocks.WindowBlockTables`, inside
@@ -597,7 +600,11 @@ class ServingEngine(object):
         self._state_bytes_per_slot = 0
         self._state_reset_fn = None
         call_block = None  # a merged 3-D pool's block, K + V
-        if self._has_state or "window" in fam.caches:
+        # a family that sizes its own caches (every family but the GPT
+        # block's: merged pools, window pools, state, a latent pool)
+        # reports their bytes by kind
+        self._by_kind = getattr(fam, "cache_bytes", None) is not None
+        if self._by_kind:
             sizes = fam.cache_bytes(cfg, Bt)
             block_bytes = sizes["full"]
             call_block = sizes["call_block"]
@@ -1402,9 +1409,10 @@ class ServingEngine(object):
             )
         if publish_len is not None and publish_len < 0:
             raise ValueError("publish_len must be >= 0 or None")
-        if handoff and (self._has_state or self._win is not None):
-            # imported K/V blocks restore neither a recurrent state nor
-            # a window table: the family's own reason says which
+        if handoff and self._family is not tlm.SERVING:
+            # imported K/V blocks are the GPT block's: they restore
+            # neither a recurrent state nor a window table, nor fill a
+            # latent pool — the family's own reason says which
             raise ValueError(
                 "handoff import is not supported for the %r model family "
                 "(%s)" % (self._family.name, self._family.refusal))
@@ -2101,7 +2109,7 @@ class ServingEngine(object):
         m.decode_steps += 1
         m.occupancy.append(float(alive.sum()) / self.max_slots)
         win = self._win
-        if win is not None or self._has_state:
+        if self._by_kind:
             # by kind of cache, the kinds the family has
             n_live = int(alive.sum())
             used = {"full": self._alloc.blocks_in_use * self.kv_block_bytes}

@@ -893,6 +893,130 @@ def test_afmoe_prefill_chunk_compiles_at_the_largest_bucket(programs):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+# ---------------------------------------------------------------------
+# the latent-attention family (ISSUE 37): one latent pool a layer on
+# the one table, the absorbed decode call, 16 of 128 experts held — at
+# the cell's geometry and depth (kanana2_reason128_closed: 128 slots,
+# 8,192 positions in blocks of 32, a pool of 28,672 blocks, 1 dense + 7
+# expert layers), so that memory_analysis is the chip's whole bill
+# ---------------------------------------------------------------------
+
+ML_S, ML_BT, ML_L, ML_NB, ML_LAYERS = 128, 32, 8192, 28672, 8
+ML_MAXB = ML_L // ML_BT
+
+
+def _mla_config():
+    from paddle_tpu.models import mla_moe as ml
+
+    return ml.MlaMoeConfig(
+        vocab=128256, dim=2048, heads=32, nope_dim=128, rope_dim=64,
+        v_dim=128, kv_rank=512, layers=ML_LAYERS, num_dense_layers=1,
+        dense_width=6144, expert_width=768, n_shared_experts=2,
+        n_experts=128, top_k=6, route_scale=2.448, rope_theta=1e6,
+        experts_held=(0, 16), max_len=ML_L, dtype=jnp.bfloat16)
+
+
+def _mla_engine(one_chip, **kw):
+    from paddle_tpu.models import mla_moe as ml
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = _mla_config()
+    params = jax.eval_shape(
+        lambda: ml.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = ServingEngine(params, cfg, max_slots=ML_S, kv_pool_blocks=4,
+                        kv_block_tokens=ML_BT, prefill_chunk_tokens=4096,
+                        **kw)
+    assert eng.paged_kernel == "fused"
+    cache = jax.eval_shape(
+        lambda: ml.SERVING.init_cache(cfg, ML_NB, ML_BT, ML_S))
+    return eng, _placed(params, one_chip), _placed(cache, one_chip), \
+        _sds(one_chip)
+
+
+def test_latent_decode_call_compiles_reading_one_pool(one_chip):
+    """`mla_decode_attention` at the cell's geometry: 128 slots x 256
+    table entries pass the scalar-memory bound, the ring holds 32
+    blocks of 32 x 640 bf16 (the byte rule's 1.25 MiB, one pool), and
+    the compiled call is the ONE instruction `mla_decode_roofline`'s
+    `op_match` finds, named after the kernel, taking the tables, the
+    positions, q and the ONE latent pool — no second pool, no work
+    list — and returning [slots, heads, 1, kv_rank]."""
+    pa.check_paged_smem(ML_S, ML_MAXB, ML_BT, 32, False,
+                        block_bytes=ML_BT * 640 * 2)
+    assert pa._bytes_group(ML_BT, ML_MAXB, ML_BT * 640 * 2) == 32
+    sds = _sds(one_chip)
+    text = _compile(
+        lambda q, pool, t, p: pa.mla_decode_attention(
+            q, pool, t, p, 512, 192 ** -0.5, interpret=False),
+        sds((ML_S, 32, 640), jnp.bfloat16),
+        sds((ML_NB + 1, ML_BT, 640), jnp.bfloat16),
+        sds((ML_S, ML_MAXB), jnp.int32), sds((ML_S,), jnp.int32))
+    found = [ln.strip() for ln in text.split("\n")
+             if _metric_pattern("mla_decode_roofline").search(ln.strip())]
+    assert len(found) == 1 and " custom-call(" in found[0]
+    assert "bf16[%d,32,1,512]" % ML_S in found[0]
+    call = found[0][found[0].index(" custom-call("):
+                    found[0].index("custom_call_target")]
+    assert call.count("%") == 4 and call.count("%pool") == 1
+    assert re.search(r'kernel_metadata=\{\s*"kernel":'
+                     r'"mla_decode_attention"\s*\}', text)
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_mla_decode_program_is_the_one_the_benchmark_finds(programs):
+    """The fifth family rides the shared loop, one step ahead, with ONE
+    table and no window or state: at the cell's geometry and depth its
+    decode program is the one `decode_step_ms`, `mla_decode_roofline`
+    and `moe_expert_roofline` look for, FLAT, with one latent call and
+    one latent write a layer and two grouped products an expert layer,
+    each named after its kernel, and ONE packed result that carries
+    the router's two counters; its arguments — 2.74 GB of weights and
+    9.40 GB of latent pools — and temporaries fit the chip."""
+    eng, prog = programs("mla").eng, programs("mla")
+    text = prog.text
+    assert eng.async_dispatch and eng._win is None and not eng._has_state
+    assert eng._step_counters == ("moe_experts_hit", "moe_rows_max")
+    module = re.match(r"HloModule (\S+?),", text).group(1)
+    for metric in ("decode_step_ms", "mla_decode_roofline",
+                   "moe_expert_roofline"):
+        program = _metric_spec(metric)["args"]["program_match"]
+        assert re.search(program, module + "(1)"), (metric, module)
+    assert " while(" not in text
+    entry = text[text.index("\nENTRY "):]
+    lines = [ln.strip() for ln in entry.split("\n")]
+    for metric, kernel, calls in (
+            ("moe_expert_roofline", "moe_grouped_matmul",
+             2 * (ML_LAYERS - 1)),
+            ("mla_decode_roofline", "mla_decode_attention", ML_LAYERS)):
+        found = [ln for ln in lines if _metric_pattern(metric).search(ln)]
+        assert len(found) == calls, (metric, len(found))
+        assert all(ln.startswith("%" + kernel) and " custom-call(" in ln
+                   for ln in found)
+    assert len([ln for ln in lines if ln.startswith("%paged_kv_write")
+                and " custom-call(" in ln]) == ML_LAYERS
+    assert text.count("tpu_custom_call") == 4 * ML_LAYERS - 2
+    assert "hybrid_decode_attention" not in text
+    assert re.search(r"s32\[%d\]" % (6 * ML_S + 3), entry)
+    mem = prog.compiled.memory_analysis()
+    assert 12.0e9 < mem.argument_size_in_bytes < 12.3e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+    assert eng.metrics.decode_trace_count() == 1
+
+
+def test_mla_prefill_chunk_compiles_at_the_largest_bucket(programs):
+    """The cell's one-chunk prompt program (4,096 rows: expanded keys and
+    values, key-tiled; 24,576 routed pairs through the grouped product)
+    compiles for the chip under its own name; beside the arguments its
+    temporaries leave room on the 16 GB chip."""
+    compiled = programs("mla", "chunk").compiled
+    text = programs("mla", "chunk").text
+    assert re.match(r"HloModule jit__chunk[,.]", text)
+    assert "moe_grouped_matmul" in text and "/lm_experts/" in text
+    assert "mla_decode_attention" not in text  # the decode step's
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+
+
 # the SambaY, the Mamba-2 / grouped-query and the sparse-expert decode
 # programs as PR 36's tree compiles them at their cells' geometry
 # (ISSUE 36 changed their attention call — its operands, its scratch,
@@ -929,10 +1053,12 @@ _FAMILIES = {  # family -> (engine, its largest chunk, its table rows)
     "hybrid": (_hybrid_engine, 4096, (4, HY_MAXB)),
     "granite": (_granite_engine, GR_CHUNK, (2, GR_MAXB)),
     "afmoe": (_afmoe_engine, 4096, (4, AF_MAXB)),
+    "mla": (_mla_engine, 4096, (ML_MAXB,)),
 }
 # the decode step's block tables: [(kinds of table,) slots, entries]
 _DECODE_TABLES = {"gpt": (S, MAXB), "hybrid": (2, HY_S, HY_MAXB),
-                  "granite": (GR_S, GR_MAXB), "afmoe": (2, AF_S, AF_MAXB)}
+                  "granite": (GR_S, GR_MAXB), "afmoe": (2, AF_S, AF_MAXB),
+                  "mla": (ML_S, ML_MAXB)}
 
 
 class _Program(object):
@@ -1016,6 +1142,7 @@ _FAMILY_SCOPES = {
     "hybrid": {"lm_attention", "lm_state", "lm_mlp"},
     "granite": {"lm_attention", "lm_state", "lm_mlp"},
     "afmoe": {"lm_attention", "lm_mlp", "lm_experts"},
+    "mla": {"lm_attention", "lm_mlp", "lm_experts"},
 }
 
 
