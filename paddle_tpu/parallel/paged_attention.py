@@ -67,17 +67,22 @@ of copies runs empty at every group: 0.55 us a group, 1,084 us a
 call), the products and the tile's work run under them, and a group's
 fold is 0.5 us + 0.064 us a block, hidden from ~29 blocks up — so a
 group is sized by BYTES (`_bytes_group`, `_STEP_BYTES`): 32 blocks of
-granite's and Trinity's 64 KiB, 8 of SambaY's 160 KiB. Microseconds a
-call, the BlockSpec form -> this one (least, by the cell's bytes):
+granite's and Trinity's 64 KiB, 8 of SambaY's 160 KiB; the latent
+call, whose fold and not its copies binds it, 64 of its 40 KiB (1,427
+-> 1,288 us with the rungs below, measured on one v5e chip).
+Microseconds a call, the BlockSpec form -> this one (least, by the
+cell's bytes):
 Trinity's full call over contexts of 1.5-7.2 k 1,191 -> 791 (712), its
 2,048-token window call 628 -> 414 (321), granite's call 874 -> 579
 (507), SambaY's shared pool 1,425 -> 1,378 (1,267), its 512-token
 window call 325 -> 255 (206); and no work list. A window's walk starts
 at the block of `first`, not at a group's edge, so its 65 blocks cost
-65 blocks' copies, and a slot's last group, when it holds at most a
-quarter of a group, scores a quarter tile (`_SHORT_GROUP`: Trinity's
-window call 440 -> 414). The 4-D pool's call keeps the BlockSpec form:
-in the GPT pool 128 tokens are 1 MiB a step, which hides its fixed
+65 blocks' copies, and a slot's last group folds the first rung of
+whole score tiles that covers its blocks (`_rungs`: a quarter group or
+the whole one where the copies bind, which took Trinity's window call
+440 -> 414 us; a ladder of eighths where the fold does). The 4-D
+pool's call keeps the BlockSpec form: in the GPT pool 128 tokens are
+1 MiB a step, which hides its fixed
 part (82 % of the HBM roofline); moving it here is a later PR's.
 
 Masking mirrors the gather primitives exactly: row r of a window based
@@ -446,13 +451,38 @@ def _zero_ring(*bufs):
 # Groups of K/V the merged-pool decode call keeps in VMEM: the one
 # being folded and the ones whose copies are under way
 _RING = 3
-# A group of at most G // `_SHORT_GROUP` blocks (a slot's last) scores
-# that part of a tile and not a whole one
-_SHORT_GROUP = 4
+
+
+def _rungs(G: int, rows: int, fold_bound: bool) -> tuple:
+    """The blocks a slot's last group may fold, the last group folding
+    the first that covers the blocks it names; blocks past them are
+    masked to exact zeros. A rung is the fewest blocks, at least G // 8
+    (G // 4 where the call is bound by its copies), whose rows (`rows`
+    a block) fill whole 128-lane score tiles; with no such rung inside
+    G, the group. A call bound by its fold (the latent call's 32-row
+    block in groups of 64) climbs a ladder of rungs -> (r, 2r, ..., G):
+    rungs of 8 (256 rows), so the padding it folds is under one rung.
+    What a folded padding block costs grows with the group: that call
+    alone on one v5e chip, folding a quarter
+    group or all of it took 1,427 us at 32 blocks and 1,328 at 64, the
+    ladder 1,413 and 1,288. A call whose copies hide its fold folds one
+    rung or the whole group -> (r, G): granite's and Trinity's 128-row
+    block 8 of 32, SambaY's 320-row block 2 of 8 — a rung is a fold
+    body that every process start traces and lowers, and the ladder
+    took Trinity's warm-up from 12.1 to 13.6-14.2 s and its calls
+    nowhere (414.6 -> 412.6 us, 791.9 -> 794.7). One fold a group,
+    whatever its rung — a loop of smaller folds would pay a group's
+    fixed chain (~0.5 us) again each time."""
+    r = max(1, G // (8 if fold_bound else 4))
+    while r < G and r * rows % 128:
+        r += 1
+    if not fold_bound:
+        return (r, G) if r < G else (G,)
+    return tuple(range(r, G, r)) + (G,)
 
 
 def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
-                           span: int, scale: float, rep: int,
+                           rungs: tuple, span: int, scale: float, rep: int,
                            windowed: bool, v_lanes: int = 0):
     """One SLOT of the merged-pool decode call (ISSUE 36): the pools
     stay in HBM, the kernel reads the slot's block ids from its table
@@ -477,11 +507,12 @@ def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
     A slot's walk is over BLOCKS, from the block of `first[s]` (a
     window layer's first attended position; block 0 without `first`)
     to the block of `pos[s]`: ceil(blocks / G) groups, the last one
-    short. A short group copies its own blocks only and still scores a
-    whole tile: the rows past them hold an earlier group's blocks (or
-    `_zero_ring`'s zeros), masked by position like any depth past
-    `pos`. A parked slot (pos >= span) names no block: nothing is
-    copied or folded for it and it writes zeros.
+    short. A short group copies its own blocks only and folds the
+    first of `rungs` (`_rungs`) that covers them, one static shape a
+    rung behind one switch: the rows past its blocks hold an earlier
+    group's blocks (or `_zero_ring`'s zeros), masked by position like
+    any depth past `pos`. A parked slot (pos >= span) names no block:
+    nothing is copied or folded for it and it writes zeros.
 
     All copies into one ring place signal that place's semaphore, each
     waited for with a descriptor of its own size.
@@ -598,13 +629,14 @@ def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
                                None if first_ref is None else first_ref[si])
             _fold_tile(s, values(place, rows), acc_ref, m_ref, l_ref)
 
-        few = G // _SHORT_GROUP
-        if few:  # a slot's last group may hold a block or two
-            short = n - j * G <= few
-            pl.when(short)(lambda: fold(few))
-            pl.when(jnp.logical_not(short))(lambda: fold(G))
-        else:
-            fold(G)
+        # the first rung that covers the blocks this group names (a
+        # whole group names G or more), the whole group as branch 0:
+        # Mosaic lowers a switch to a chain of ifs that tests 0 first,
+        # and most groups are whole
+        rung = sum((n - j * G > b).astype(jnp.int32) for b in rungs[:-1])
+        jax.lax.switch((rung + 1) % len(rungs),
+                       [functools.partial(fold, b) for b in rungs[-1:]
+                        + rungs[:-1]])
         wait_for(c + 1)
         return carry
 
@@ -646,19 +678,29 @@ def _group(Bt: int, maxb: int) -> int:
 # SambaY's shared pool (least 1,267) 1,445 at 640 KiB, 1,378 at
 # 1.25 MiB, 1,379 at 2.5 MiB; its 512-token window call (least 206)
 # 302, 255, 270: the copies bind it from 1.25 MiB on and a short
-# window loses past that
+# window loses past that. The latent call is bound by its fold and not
+# by its copies (its one pool feeds both products): a group's fixed
+# part (~0.47 us) is never hidden, and fewer, larger groups take it
+# off — 128 slots over contexts of 1.5-7.2 k (least 809), groups of 8,
+# 16, 32 and 64 blocks of 40 KiB with `_rungs`: 2,193, 1,684, 1,431
+# and 1,296 (1,413 and 1,288 with the whole group as the switch's
+# first branch), 1,427 at 32 with a quarter group or a whole one
+# (one v5e chip). So it reaches twice the target (2.5 MiB:
+# 64 of its blocks); 128 blocks pass the VMEM a program scopes
 _STEP_BYTES = 5 << 18
 
 
-def _bytes_group(Bt: int, maxb: int, block_bytes: int) -> int:
+def _bytes_group(Bt: int, maxb: int, block_bytes: int,
+                 fold_bound: bool = False) -> int:
     """Table entries per group of the merged-pool decode call, by
     BYTES: the fewest blocks, `_group`'s doubled, whose K + V
     (`block_bytes` a block: its rows x width x 2 x the dtype's size)
-    reach `_STEP_BYTES`, never more than the table holds: 32 blocks of
+    reach `_STEP_BYTES` — twice that where the call is `fold_bound`
+    (the latent call) — never more than the table holds: 32 blocks of
     granite's and Trinity's 64 KiB (2 MiB), 8 of SambaY's 160 KiB
-    (1.25 MiB)."""
-    G = _group(Bt, maxb)
-    while G * block_bytes < _STEP_BYTES and 2 * G <= maxb:
+    (1.25 MiB), 64 of the latent pool's 40 KiB (2.5 MiB)."""
+    G, target = _group(Bt, maxb), _STEP_BYTES * (2 if fold_bound else 1)
+    while G * block_bytes < target and 2 * G <= maxb:
         G *= 2
     return G
 
@@ -853,15 +895,32 @@ def _merged_decode(q, k_pool, v_pool, tables, pos, first, *, scale,
     of `_bytes_group` blocks at a time. With `v_pool` None the values
     are the first `v_lanes` lanes of the one pool (the latent call,
     named `mla_decode_attention`): out [S, Hk * rep, 1, v_lanes]."""
+    rows = k_pool.shape[1]  # of a block: (token, head)
+    fold_bound = v_pool is None  # one pool feeds both products
+    G = _bytes_group(rows // q.shape[1], tables.shape[1],
+                     (1 if fold_bound else 2) * rows * k_pool.shape[2]
+                     * k_pool.dtype.itemsize, fold_bound)
+    return _ring_call(q, k_pool, v_pool, tables, pos, first, G=G,
+                      rungs=_rungs(G, rows, fold_bound), ring=_RING,
+                      scale=scale,
+                      interpret=resolve_interpret(interpret),
+                      v_lanes=v_lanes)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "G", "rungs", "ring", "scale", "interpret", "v_lanes"))
+def _ring_call(q, k_pool, v_pool, tables, pos, first, *, G, rungs, ring,
+               scale, interpret, v_lanes):
+    """`_merged_decode`'s call, its group, rungs and ring given. Jitted,
+    so that a program's layers of one geometry trace and lower ONE
+    kernel, whose body holds a fold a rung: every process lowers its
+    decode program anew, and that is `setup_s`."""
     S, Hk, rep, dh = q.shape
     R = Hk * rep
-    rows = k_pool.shape[1]  # of a block: (token, head)
+    rows = k_pool.shape[1]
     Bt, maxb = rows // Hk, tables.shape[1]
     pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
     dv = v_lanes or dh
-    G = _bytes_group(Bt, maxb,
-                     len(pools) * rows * dh * k_pool.dtype.itemsize)
-    ring = (_RING, G * rows, dh)
     prefetch = (jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32))
     if first is not None:
         prefetch += (jnp.asarray(first, jnp.int32),)
@@ -870,21 +929,22 @@ def _merged_decode(q, k_pool, v_pool, tables, pos, first, *, scale,
         return (i, 0, 0, 0)
 
     kernel = functools.partial(
-        _pa_ring_decode_kernel, Bt=Bt, G=G, span=maxb * Bt, scale=scale,
-        rep=rep, windowed=first is not None, v_lanes=v_lanes)
+        _pa_ring_decode_kernel, Bt=Bt, G=G, rungs=rungs, span=maxb * Bt,
+        scale=scale, rep=rep, windowed=first is not None, v_lanes=v_lanes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(S,),
         in_specs=[pl.BlockSpec((None, None, R, dh), _slot_map)]
         + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
         out_specs=pl.BlockSpec((None, R, 1, dv), _slot_map),
-        scratch_shapes=[pltpu.VMEM(ring, p.dtype) for p in pools]
-        + [pltpu.SemaphoreType.DMA((_RING,)),
+        scratch_shapes=[pltpu.VMEM((ring, G * rows, dh), p.dtype)
+                        for p in pools]
+        + [pltpu.SemaphoreType.DMA((ring,)),
            pltpu.VMEM((R, dv), jnp.float32),
            pltpu.VMEM((R, 1), jnp.float32),
            pltpu.VMEM((R, 1), jnp.float32),
            pltpu.SMEM((4,), jnp.int32),
-           pltpu.SMEM((_RING,), jnp.int32)],
+           pltpu.SMEM((ring,), jnp.int32)],
     )
     name = ("hybrid_decode_attention" if v_pool is not None
             else "mla_decode_attention")
@@ -895,7 +955,7 @@ def _merged_decode(q, k_pool, v_pool, tables, pos, first, *, scale,
         # the ring and the walk's scalars carry over from a slot to the next
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
         name=name,
         metadata={"kernel": name},
     )(*prefetch, q.reshape(S, 1, R, dh), *pools)
@@ -1054,7 +1114,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     This call copies its own K/V (`_pa_ring_decode_kernel`, ISSUE 36),
     a group of blocks at a time, the group sized by the bytes it moves
     and not by 128 tokens (`_bytes_group`, from the pool's block shape
-    and dtype: 16 blocks for granite's and Trinity's pools, 8 for
+    and dtype: 32 blocks for granite's and Trinity's pools, 8 for
     SambaY's; ISSUE 32). The number of columns an online-softmax step
     folds is all that differs between two group sizes, so logits move
     within float tolerance."""
@@ -1086,7 +1146,8 @@ def mla_decode_attention(q, pool, tables, pos, v_lanes, scale,
     copied into VMEM ONCE and serves both products. The ring, the
     walk over the table in scalar memory and the fold are the
     merged-pool call's (`_pa_ring_decode_kernel` with `v_lanes`), with
-    one K/V head that all H query rows share."""
+    one K/V head that all H query rows share; its fold, not its copies,
+    binds it, so its groups are twice the bytes (`_bytes_group`)."""
     S, H, W = q.shape
     out = _merged_decode(q.reshape(S, 1, H, W), pool, None, tables, pos,
                          None, scale=scale, interpret=interpret,

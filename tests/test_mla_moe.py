@@ -320,10 +320,23 @@ def test_parked_slots_latent_blocks_are_untouched(cfg, params, tokens,
         assert np.array_equal(a, np.asarray(pool[mine]))
 
 
-@pytest.mark.parametrize("heads,W,r,Bt", [(4, 128, 32, 4), (8, 256, 160, 8)])
-def test_latent_decode_call_equals_plain_attention(heads, W, r, Bt):
+# the blocks a slot's last group names, by the kind of rung it lands on
+# (`pa._rungs`), at 32 rows a block (the cell's: a rung is 4 blocks,
+# 128 rows) in groups of 32: one block, a rung, a rung and a block, all
+# but one block, all 32. (At 4 or 8 rows a block in one group of 12,
+# no rung short of the group fills a 128-row tile.)
+_LAST = {"one": lambda G, r: 1, "rung": lambda G, r: r,
+         "rung_plus_one": lambda G, r: r + 1,
+         "G_minus_one": lambda G, r: G - 1, "G": lambda G, r: G}
+
+
+@pytest.mark.parametrize("heads,W,r,Bt,last", [
+    (4, 128, 32, 4, None), (8, 256, 160, 8, None)]
+    + [(4, 640, 512, 32, k) for k in _LAST])
+def test_latent_decode_call_equals_plain_attention(heads, W, r, Bt, last):
     """`mla_decode_attention` interpreted, over ragged contexts (one
-    token, a block edge, a short last group, a parked slot) through
+    token, a block edge, a short last group, a parked slot; with
+    `last`, a slot whose last group lands on that kind of rung) through
     tables that name blocks out of order and end in -1, against
     float64 softmax over the rows each table names: scores over the
     whole row, values its first r lanes. A parked slot gives zeros; no
@@ -332,16 +345,24 @@ def test_latent_decode_call_equals_plain_attention(heads, W, r, Bt):
     would be NaN in P . V, `_zero_ring` says)."""
     rng = np.random.default_rng(heads)
     S, maxb, NB = 5, 12, 40
+    pos = [0, Bt - 1, 5 * Bt + 2, maxb * Bt, 11 * Bt + 1]
+    if last is not None:
+        maxb, NB = 64, 128
+        G = pa._bytes_group(Bt, maxb, Bt * W * 4, True)
+        rung = pa._rungs(G, Bt, True)[0]
+        assert (G, rung) == (32, 4)
+        n = G + _LAST[last](G, rung)  # blocks named: a group and the last
+        S, pos = 6, pos[:3] + [maxb * Bt, (n - 1) * Bt + Bt // 2,
+                               (n - G - 1) * Bt + 1]
     pool = rng.normal(size=(NB, Bt, W)).astype(np.float32)
     q = rng.normal(size=(S, heads, W)).astype(np.float32)
-    pos = np.array([0, Bt - 1, 5 * Bt + 2, maxb * Bt, 11 * Bt + 1],
-                   np.int32)
+    pos = np.array(pos, np.int32)
     tables = np.full((S, maxb), -1, np.int32)
-    perm = rng.permutation(NB - 1)
+    perm, at = rng.permutation(NB - 1), 0  # no block is two slots'
     for s in range(S):
         n = min(pos[s] // Bt + 1, maxb) if pos[s] < maxb * Bt else 0
-        tables[s, :n] = perm[s * 8:s * 8 + n] if n <= 8 else \
-            rng.permutation(NB - 1)[:n]
+        tables[s, :n] = perm[at:at + n]
+        at += n
         if n and pos[s] % Bt != Bt - 1:  # what lies past pos is garbage
             pool[tables[s, n - 1], pos[s] % Bt + 1:] = 1e4
     scale = 1 / math.sqrt(W)

@@ -39,6 +39,18 @@ _ATOL = 2e-5
 _RTOL = 2e-5
 
 
+@pytest.fixture(autouse=True)
+def _fresh_ring_call():
+    """The merged-pool call traces once a geometry (`_ring_call` is
+    jitted): a test that patches what its kernel calls must neither
+    meet nor leave behind a body traced without the patch."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    pa._ring_call.clear_cache()
+    yield
+    pa._ring_call.clear_cache()
+
+
 def _cfg(**kw):
     kw.setdefault("vocab", 50)
     kw.setdefault("dim", 32)
@@ -731,6 +743,10 @@ def test_group_rule_reads_the_pools_block_and_nothing_else():
     assert pa._bytes_group(bt, maxb, kv(4, item=4)) == 16  # the dtype counts
     assert pa._bytes_group(bt, 8, kv(1)) == 8  # capped at the table
     assert pa._bytes_group(bt, 6, kv(1)) == 4  # `_group`'s, doubled 0 times
+    # the latent call, bound by its fold: its one 40 KiB block a group of
+    # 64 (2.5 MiB), where K/V calls would stop at 32
+    assert pa._bytes_group(bt, maxb, bt * 640 * 2, True) == 64
+    assert pa._bytes_group(bt, maxb, bt * 640 * 2) == 32
     for rows in (4, 10, 20):
         G = pa._bytes_group(bt, maxb, kv(rows))
         assert kv(rows) * G >= pa._STEP_BYTES > kv(rows) * G // 2 \
@@ -750,6 +766,52 @@ def test_group_rule_reads_the_pools_block_and_nothing_else():
         with pytest.raises(ValueError, match="scalar memory"):
             pa.check_paged_smem(fits + 8, maxb, bt, 32, False,
                                 block_bytes=kv(rows))
+
+
+def test_rung_rule_folds_a_last_group_in_whole_tiles():
+    """`_rungs`, the blocks a slot's last group may fold. A rung is the
+    fewest blocks, at least G // 8 (G // 4 for a call bound by its
+    copies), whose rows fill whole 128-row score tiles, or the group.
+    A call bound by its fold (the latent call: its 32-row block in
+    groups of 64) climbs a ladder of rungs of 8 (256 rows) to G, and a
+    last group that names n <= G blocks folds never fewer than n and
+    less than a rung more. A call bound by its copies folds one rung or
+    the group: granite's and Trinity's 128-row block 8 of 32, SambaY's
+    320-row block 2 of 8 — the two-rung rule it had. On the latent
+    cell's contexts (1,551-7,168 tokens) the blocks folded over those
+    named are ~1.07 with two rungs of a 32-block group, ~1.13 with two
+    rungs of 64, under 1.03 with the ladder of 64."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    assert pa._rungs(64, 32, True) == tuple(range(8, 65, 8))
+    assert pa._rungs(32, 32, True) == tuple(range(4, 33, 4))
+    assert pa._rungs(32, 128, False) == (8, 32)
+    assert pa._rungs(8, 320, False) == (2, 8)
+    for G in (1, 2, 3, 4, 8, 12, 16, 32, 64):
+        for rows in (8, 16, 32, 40, 64, 80, 96, 128, 320, 640):
+            for fold_bound in (True, False):
+                rungs = pa._rungs(G, rows, fold_bound)
+                least = max(1, G // (8 if fold_bound else 4))
+                tiles = [b for b in range(least, G + 1)
+                         if b * rows % 128 == 0]
+                r = tiles[0] if tiles else G
+                assert rungs == ((tuple(range(r, G, r)) if fold_bound
+                                  else (r,) if r < G else ()) + (G,))
+                assert all(b * rows % 128 == 0 for b in rungs[:-1])
+                for n in range(1, G + 1):
+                    fold = next(b for b in rungs if b >= n)
+                    assert fold >= n and (fold < n + r or not fold_bound)
+
+    def share(rungs):
+        ctx = np.arange(1551, 7169)
+        n = -(-ctx // 32)
+        last = (n - 1) % rungs[-1] + 1
+        fold = [next(b for b in rungs if b >= k) for k in last]
+        return (n - last + fold).sum() / n.sum()
+
+    assert 1.06 < share((8, 32)) < 1.09
+    assert 1.11 < share((16, 64)) < 1.15
+    assert share(pa._rungs(64, 32, True)) < 1.03
 
 
 @pytest.mark.parametrize("rule", ["every_entry", "look_back"])
@@ -804,8 +866,10 @@ def test_worklist_names_the_tables_blocks_on_both_sides_of_its_switch(
 def _watch_copies(monkeypatch):
     """-> log: every copy the kernel starts as ("start", pool block,
     ring place, ring row) and every wait as ("wait", place, row), through
-    a wrapper around `pltpu.make_async_copy` (callbacks of an
-    interpreted kernel; their order is not promised)."""
+    a wrapper around `pltpu.make_async_copy`, and every fold that runs
+    as ("fold", columns of its score tile), through one around
+    `_fold_tile` (callbacks of an interpreted kernel; their order is
+    not promised)."""
     from paddle_tpu.parallel import paged_attention as pa
 
     log, real = [], pa.pltpu.make_async_copy
@@ -831,7 +895,15 @@ def _watch_copies(monkeypatch):
 
         return Watched()
 
+    real_fold = pa._fold_tile
+
+    def fold(s, v, *state):  # ("fold", columns) a fold that runs
+        jax.debug.callback(lambda: log.append(("fold", s.shape[1])))
+        real_fold(s, v, *state)
+
     monkeypatch.setattr(pa.pltpu, "make_async_copy", watched)
+    monkeypatch.setattr(pa, "_fold_tile", fold)
+    pa._ring_call.clear_cache()  # a body traced with THIS log
     return log
 
 
@@ -890,7 +962,9 @@ _WALK_SPAN = _WALK_BT * _WALK_MAXB
 # work list did not — a walk that starts inside a block, on a block
 # but inside a group, a context of one token and of exactly one
 # block, a last group of one block (Trinity's 65th), parked slots
-# first, last and between live ones, nothing but parked slots
+# first, last and between live ones, nothing but parked slots. At 2
+# K/V heads a block is 16 rows: no rung short of a group of 4 fills a
+# 128-row score tile, so every group folds all 4 blocks
 _WALKS = {
     "first_inside_a_block": ([150, 201, 77], 61),
     "first_on_a_block_inside_a_group": ([167, 103, 319], 96),
@@ -902,6 +976,23 @@ _WALKS = {
     "parked_first_and_last_window": ([_WALK_SPAN, 180, 66, _WALK_SPAN], 50),
     "nothing_but_parked": ([_WALK_SPAN, _WALK_SPAN], None),
 }
+# the rungs (`_rungs`): at 4 K/V heads a block is 32 rows and a group
+# of 8 folds 4 blocks (one 128-row tile) or 8; the last group of each
+# slot (of one, two and four groups) names one block, a rung, a rung
+# and a block, all but one block, all 8
+_RUNG_HK, _RUNG_G, _RUNGS = 4, 8, (4, 8)
+for _kind, _last in (("one", 1), ("rung", 4), ("rung_plus_one", 5),
+                     ("G_minus_one", _RUNG_G - 1), ("G", _RUNG_G)):
+    _WALKS["last_group_" + _kind] = (
+        [_WALK_BT * _last - 3, _WALK_BT * (_RUNG_G + _last) - _WALK_BT,
+         _WALK_BT * (3 * _RUNG_G + _last) - 1], None, _last)
+
+
+def _folded(n, G, rungs):
+    """Blocks a slot that names n folds: its whole groups, then the
+    first of `rungs` that covers the rest."""
+    whole = (n - 1) // G
+    return whole * G + next(b for b in rungs if b >= n - whole * G)
 
 
 @pytest.mark.parametrize("case", sorted(_WALKS))
@@ -911,19 +1002,28 @@ def test_table_walk_copies_what_the_context_names_on_every_edge(
     through the table at the pinned tolerance, a parked slot zeros, and
     the copies counted: the blocks the tables name, K and V, and no
     other — a window's walk starts at the block of `first` wherever
-    that lies in a group, so 65 blocks cost 65 blocks' copies."""
-    pos, win = _WALKS[case]
+    that lies in a group, so 65 blocks cost 65 blocks' copies. The
+    folds counted too: a slot's last group folds the first rung that
+    covers what it names, never a block less."""
+    pos, win, *last = _WALKS[case]
+    hk, G, rungs = ((_RUNG_HK, _RUNG_G, _RUNGS) if last
+                    else (2, _WALK_G, (_WALK_G,)))
     pos = np.asarray(pos, np.int32)
     first = None if win is None else np.maximum(pos - win + 1, 0)
     got, want, live, tables, log = _walk_call(
-        pos, first, 2, 2, _WALK_BT, _WALK_MAXB, _WALK_G, monkeypatch)
+        pos, first, hk, 2, _WALK_BT, _WALK_MAXB, G, monkeypatch)
     np.testing.assert_allclose(got[live], want[live], rtol=_RTOL,
                                atol=_ATOL)
     np.testing.assert_array_equal(got[~live], 0.0)
     tables[~live] = -1
-    _assert_copies_are_the_named_blocks(log, tables, _WALK_G, _WALK_BT * 2)
+    _assert_copies_are_the_named_blocks(log, tables, G, _WALK_BT * hk)
+    blocks = (pos // _WALK_BT + 1 - (0 if first is None
+                                     else first // _WALK_BT))[live]
+    folded = sum(e[1] for e in log if e[0] == "fold") // (_WALK_BT * hk)
+    assert folded == sum(_folded(n, G, rungs) for n in blocks)
+    if last:
+        assert ((blocks - 1) % G + 1).tolist() == last * 3
     if case == "last_group_of_one_block":
-        blocks = pos // _WALK_BT - first // _WALK_BT + 1
         assert blocks.tolist() == [17, 17, 5]  # 4 groups and one block
 
 
@@ -948,6 +1048,11 @@ def test_table_walk_at_the_cells_table_shape(windowed, monkeypatch):
                                atol=_ATOL)
     np.testing.assert_array_equal(got[~live], 0.0)
     _assert_copies_are_the_named_blocks(log, tables, G, bt)
+    # 32 rows a block: a last group folds a quarter of the group (4
+    # blocks, 128 rows) or all of it
+    blocks = (pos // bt + 1 - (0 if first is None else first // bt))[live]
+    folded = sum(e[1] for e in log if e[0] == "fold") // bt
+    assert folded == sum(_folded(n, G, (4, G)) for n in blocks)
 
 
 @pytest.mark.parametrize("pool", ["f32", "bf16"])

@@ -712,14 +712,45 @@ def test_granite_prefill_chunk_compiles_at_its_one_bucket(programs):
 # ---------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("metric,rows,rep,blocks,window,group", [
-    ("gqa_attn_roofline", 4, 8, GR_NB + 1, None, 32),
-    ("hybrid_attn_roofline", 10, 4, HY_NB + 1, None, 8),
-    ("hybrid_attn_roofline", 10, 4, HY_S * 17 + 1, 512, 8),
-    ("swa_attn_roofline", 4, 8, HY_S * 65 + 1, 2048, 32),
+def _jaxpr_digest(fn, *args):
+    """sha256 of `fn`'s jaxpr at the chip's matmul precision: a kernel
+    call's holds the kernel's body, and no file or line of it."""
+    with jax.default_matmul_precision(None):
+        jaxpr = str(jax.make_jaxpr(fn)(*args))
+    return hashlib.sha256(jaxpr.encode()).hexdigest()
+
+
+# the merged-pool call's jaxpr at the four cell geometries below and the
+# latent call's at its cell's (as the kernel now folds them: a slot's
+# last group folds the first of its rungs, `_rungs`, behind one switch
+# with the whole group first — the latent call's a ladder of 8 blocks
+# in groups of 64, not 32; the others still a quarter group or the
+# whole). A PR that MEANS to change the ring kernel replaces these (the
+# failing assertion prints the new one) and says so in CHANGES.md
+_RING_CALL_JAXPR_SHA = {
+    "granite":
+        "99d75c8100e06aa56bc7ffc4939063a4116c1fcdc782d7e5c10dd301c5e83989",
+    "sambay_full":
+        "6351a9961b29872d100b9a5a91d41fe7175a83d35e1165ab28f38bb64b98f951",
+    "sambay_window":
+        "82fa8eccec8a33ede15cb27da321ca0c585c4437b60763f0ed05f02d681afc76",
+    "trinity_window":
+        "b31ec0049e9f378f0b3477bc33fbbd9ad172b38c073b1ca4df44847011477da4",
+    "latent":
+        "5d50d45c7248a7aca0e0bc50b00bcadbaf78c6fe1e0e6ad364a31018f724ba71",
+}
+
+
+@pytest.mark.parametrize("cell,metric,rows,rep,blocks,window,group,rung", [
+    ("granite", "gqa_attn_roofline", 4, 8, GR_NB + 1, None, 32, 8),
+    ("sambay_full", "hybrid_attn_roofline", 10, 4, HY_NB + 1, None, 8, 2),
+    ("sambay_window", "hybrid_attn_roofline", 10, 4, HY_S * 17 + 1, 512, 8,
+     2),
+    ("trinity_window", "swa_attn_roofline", 4, 8, HY_S * 65 + 1, 2048, 32,
+     8),
 ], ids=["granite", "sambay_full", "sambay_window", "trinity_window"])
 def test_merged_pool_decode_call_compiles_with_its_own_copies(
-        one_chip, metric, rows, rep, blocks, window, group):
+        one_chip, cell, metric, rows, rep, blocks, window, group, rung):
     """`hybrid_decode_attention` at the geometry of
     `granite4hmicro_reason_closed` (4 pair-rows a token, 8 queries a
     row), of `phi4flash_reason_closed` (10 and 4; the shared pool, and
@@ -733,19 +764,26 @@ def test_merged_pool_decode_call_compiles_with_its_own_copies(
     inside the memory a program scopes, and the compiled call is still
     the ONE instruction the cell's roofline metric finds (`op_match`,
     read from its file), named after the kernel, its result
-    `[slots, rows x rep, 1, 128]`."""
+    `[slots, rows x rep, 1, 128]`. Its body is the jaxpr pinned
+    above: a slot's last group folds one `rung` or the whole group
+    (`_rungs`: 8 of granite's and Trinity's 32, 2 of SambaY's 8)."""
     sds = _sds(one_chip)
     pool = sds((blocks, HY_BT * rows, 128), jnp.bfloat16)
     assert pa._bytes_group(HY_BT, HY_MAXB,
                            2 * HY_BT * rows * 128 * 2) == group
+    assert pa._rungs(group, HY_BT * rows, False) == (rung, group)
     args = [sds((HY_S, rows, rep, 128), jnp.bfloat16), pool, pool,
             sds((HY_S, HY_MAXB), jnp.int32), sds((HY_S,), jnp.int32)]
     if window:
         args.append(sds((HY_S,), jnp.int32))
-    text = _compile(
-        lambda q, k, v, t, p, *first: pa.paged_decode_attention(
+
+    def call(q, k, v, t, p, *first):
+        return pa.paged_decode_attention(
             q, k, v, t, p, interpret=False, first=first[0] if first else None,
-            scale=0.125), *args)
+            scale=0.125)
+
+    assert _jaxpr_digest(call, *args) == _RING_CALL_JAXPR_SHA[cell]
+    text = _compile(call, *args)
     lines = [ln.strip() for ln in text.split("\n")]
     found = [ln for ln in lines if _metric_pattern(metric).search(ln)]
     assert len(found) == 1 and " custom-call(" in found[0]
@@ -935,22 +973,29 @@ def _mla_engine(one_chip, **kw):
 
 def test_latent_decode_call_compiles_reading_one_pool(one_chip):
     """`mla_decode_attention` at the cell's geometry: 128 slots x 256
-    table entries pass the scalar-memory bound, the ring holds 32
-    blocks of 32 x 640 bf16 (the byte rule's 1.25 MiB, one pool), and
-    the compiled call is the ONE instruction `mla_decode_roofline`'s
-    `op_match` finds, named after the kernel, taking the tables, the
-    positions, q and the ONE latent pool — no second pool, no work
-    list — and returning [slots, heads, 1, kv_rank]."""
+    table entries pass the scalar-memory bound, the ring holds groups
+    of 64 blocks of 32 x 640 bf16 (the byte rule's 2.5 MiB for a call
+    bound by its fold, one pool), and the compiled call is the ONE
+    instruction `mla_decode_roofline`'s `op_match` finds, named after
+    the kernel, taking the tables, the positions, q and the ONE latent
+    pool — no second pool, no work list — and returning [slots, heads,
+    1, kv_rank]; its body is the jaxpr pinned above, a slot's last
+    group folding rungs of 8 of the 64 blocks (256 latent rows)."""
     pa.check_paged_smem(ML_S, ML_MAXB, ML_BT, 32, False,
                         block_bytes=ML_BT * 640 * 2)
-    assert pa._bytes_group(ML_BT, ML_MAXB, ML_BT * 640 * 2) == 32
+    assert pa._bytes_group(ML_BT, ML_MAXB, ML_BT * 640 * 2, True) == 64
+    assert pa._rungs(64, ML_BT, True) == tuple(range(8, 65, 8))  # 256 rows
     sds = _sds(one_chip)
-    text = _compile(
-        lambda q, pool, t, p: pa.mla_decode_attention(
-            q, pool, t, p, 512, 192 ** -0.5, interpret=False),
-        sds((ML_S, 32, 640), jnp.bfloat16),
-        sds((ML_NB + 1, ML_BT, 640), jnp.bfloat16),
-        sds((ML_S, ML_MAXB), jnp.int32), sds((ML_S,), jnp.int32))
+    args = (sds((ML_S, 32, 640), jnp.bfloat16),
+            sds((ML_NB + 1, ML_BT, 640), jnp.bfloat16),
+            sds((ML_S, ML_MAXB), jnp.int32), sds((ML_S,), jnp.int32))
+
+    def call(q, pool, t, p):
+        return pa.mla_decode_attention(q, pool, t, p, 512, 192 ** -0.5,
+                                       interpret=False)
+
+    assert _jaxpr_digest(call, *args) == _RING_CALL_JAXPR_SHA["latent"]
+    text = _compile(call, *args)
     found = [ln.strip() for ln in text.split("\n")
              if _metric_pattern("mla_decode_roofline").search(ln.strip())]
     assert len(found) == 1 and " custom-call(" in found[0]
@@ -1018,17 +1063,19 @@ def test_mla_prefill_chunk_compiles_at_the_largest_bucket(programs):
 
 
 # the SambaY, the Mamba-2 / grouped-query and the sparse-expert decode
-# programs as PR 36's tree compiles them at their cells' geometry
-# (ISSUE 36 changed their attention call — its operands, its scratch,
-# no work list beside it — and meant to): sha256 of the compiled text
-# less locations and less the kernels' embedded bodies, as
-# `_GPT_DECODE_TEXT_SHA` above. A PR that MEANS to change one of them
-# replaces its digest (the failing assertion prints the new one) and
-# says so in CHANGES.md.
+# programs at their cells' geometry, as the tree compiles them:
+# sha256 of the compiled text less locations and less the kernels'
+# embedded bodies, as `_GPT_DECODE_TEXT_SHA` above. Their attention
+# call takes its own operands and scratch, with no work list beside
+# it, and is lowered through one jitted function a geometry
+# (`_ring_call`), which moved only the numbers XLA gives instructions:
+# with those taken out the texts are those of the form before it,
+# instruction for instruction. A PR that MEANS to change one of them replaces its digest
+# (the failing assertion prints the new one) and says so in CHANGES.md.
 _DECODE_TEXT_SHA = {
-    "hybrid": "4e6f3a74b72a483e7bcdc04dddc5eb0df3201c721ecbc86ac495fae10d58575f",
-    "granite": "c98a1e76fac207ec0e96d2cda950b5e3227cbc2cd2841308015c1e18f1edfbd0",
-    "afmoe": "96868b95999559ab3474ae348110ce27fc0c3b7ab449c59ec67db1b9fb62aaec",
+    "hybrid": "a7fa22c9f38781e54e745f06198235060546b985208b61b7221c3573d80c46d9",
+    "granite": "da13db230832f12fb7294deb98eb585e557be6228d0dfa1fe1d6ecde6963befb",
+    "afmoe": "ff2173aa4c2e87810af7f92cdb0557b1cb44feb9e0647150c0b2843bd91784cc",
 }
 
 
