@@ -64,7 +64,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from .granite_hybrid import _chunk_attend, _view
+from ..parallel.routed_experts import held_range
+from .granite_hybrid import _chunk_attend, _rms32, _view
 from .sambay import _mlp, _scatter_chunk, _scatter_rows
 from .scopes import scope
 from .transformer import _paged_kernel_check
@@ -103,11 +104,7 @@ class AfmoeConfig:
         self.num_dense_layers = int(num_dense_layers)
         self.dense_width, self.expert_width = dense_width, expert_width
         self.n_experts, self.top_k = int(n_experts), int(top_k)
-        lo, hi = experts_held or (0, self.n_experts)
-        if not 0 <= lo < hi <= self.n_experts:
-            raise ValueError("experts_held %r of %d experts"
-                             % ((lo, hi), self.n_experts))
-        self.experts_held = (int(lo), int(hi))
+        self.experts_held = held_range(experts_held, self.n_experts)
         self.shared_expert_held = bool(shared_expert_held)
         self.route_scale = float(route_scale)
         self.route_norm = bool(route_norm)
@@ -174,12 +171,6 @@ def init_params(cfg: AfmoeConfig, key) -> Dict[str, Any]:
 # ---------------------------------------------------------------------
 # pieces every mode shares
 # ---------------------------------------------------------------------
-
-
-def _rms32(x, w, eps):
-    xf = x.astype(jnp.float32)
-    return (xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
-            * w.astype(jnp.float32))
 
 
 def _rope(x, pos, theta):

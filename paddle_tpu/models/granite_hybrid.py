@@ -1,17 +1,32 @@
-"""A Mamba-2 / grouped-query hybrid language model (ISSUE 31).
+"""A Mamba-2 / grouped-query hybrid language model.
 
-The block family of granite-4.0-h-micro (`model_type`
-`granitemoehybrid`, dense: no routed experts): pre-RMSNorm residual
-layers whose mixer is a Mamba-2 (SSD) layer or, at the depths
-`layer_types` names, plain grouped-query attention; a SwiGLU MLP in
-every layer; a tied head; no positional encoding of any kind; and four
-published constants where other families have none:
+The block family of `model_type` `granitemoehybrid`: pre-RMSNorm
+residual layers whose mixer is a Mamba-2 (SSD) layer or, at the depths
+`layer_types` names, plain grouped-query attention; in every layer a
+shared SwiGLU MLP (`mlp_width` wide) and, where `n_experts` > 0,
+routed SwiGLU experts beside it; a tied head; no positional encoding of
+any kind; and four published constants where other families have
+none:
 
     x0     = embedding_multiplier * E[token]
     x     <- x + residual_multiplier * mixer(RMSNorm(x))
-    x     <- x + residual_multiplier * MLP(RMSNorm(x))
+    x     <- x + residual_multiplier * FFN(RMSNorm(x))
     logits = RMSNorm(x) E^T / logits_scaling
     attention scores scaled by attention_multiplier (not 1/sqrt(dh))
+
+    FFN(u) = MLP(u)                                    (granite-4.0-h-micro)
+           = sum_{e in S} w_e Expert_e(u) + MLP(u)     (granite-4.0-h-small)
+             S = the top_k largest router logits u W_r, w = softmax over
+             those k alone (`parallel/routed_experts.route`,
+             scoring "softmax_topk": float32 from the float32 normed row);
+             an expert is a SwiGLU `expert_width` wide
+
+`experts_held = (lo, hi)` names the experts whose weights this chip
+has (the leaves under "experts" carry hi - lo of them): the router
+scores all `n_experts` and the layer computes the part its own experts
+give; the shared MLP is computed on every chip. The default holds all.
+The expert branch (router to combine, and the shared MLP) runs under
+the device scope `lm_experts`, the dense MLP under `lm_mlp`.
 
 Mamba-2 layer (H heads of P channels, di = H P, N state columns, one
 B/C group): [z | xBC | dt] = h W_in (di | di + 2N | H); xBC through a
@@ -34,19 +49,29 @@ The two caches (`init_cache`), served by ServingEngine through
 `SERVING` (`caches = ("paged", "state")`: no window tables):
 
   kv    a paged pool an attention layer, all on the engine's ONE
-        block table: {"k", "v"} [NB, Bt * Hk/2, 2 dh]
+        block table: {"k", "v"} [NB, Bt * groups, row]
   ssm   per-slot state, no position axis: "s" [S, N, di] float32 (2 MB
-        a layer and slot at the published widths, the largest cache of
-        the family) and the last d_conv - 1 rows of the conv's input
-        "conv" [S, d_conv - 1, di + 2N]
+        a layer and slot for h-micro's 64 heads, 4 MB for h-small's
+        128: the largest cache of the family) and the last d_conv - 1
+        rows of the conv's input "conv" [S, d_conv - 1, di + 2N]
 
-A pool row holds two K (or V) heads side by side, 2 dh = 128 wide,
-and a block's rows are (token, pair): the bytes of a `[Bt, Hk, dh]`
-block in that order, 3-D so that the device's 16 x 128 tiles are full
-where 64-wide rows would fill half of each (`sambay.py` says the same
-of its pools). The decode kernel scores a query against a whole row:
-it sits in the half its K head occupies and is zero in the other, and
-of the 2 dh-wide value read its own head's half is kept.
+The pool's row width follows the head width (`cfg.paired`). A block's
+rows are (token, row), the bytes of a `[Bt, Hk, dh]` block in that
+order, 3-D so that the device holds them in 16 x 128 bf16 tiles
+(`sambay.py` says the same of its pools):
+
+  2 dh <= 128 (h-micro, dh 64): a row holds TWO K (or V) heads side by
+        side, groups = Hk / 2, row = 2 dh = 128: the tiles are full
+        where 64-wide rows would fill half of each (twice the bytes
+        and the DMA). The decode kernel scores a query against a whole
+        row: it sits in the half its K head occupies and is zero in
+        the other, and of the 2 dh-wide value read its own head's half
+        is kept.
+  2 dh > 128 (h-small, dh 128): ONE head a row, groups = Hk, row = dh:
+        a 128-wide head fills a tile's lanes alone, and pairing would
+        make 256-lane rows whose every query is zero in half of them:
+        twice the score products and the ring's VMEM for nothing (the
+        layout of `afmoe.py`'s pools).
 """
 
 from __future__ import annotations
@@ -57,13 +82,15 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from ..parallel.routed_experts import held_range
 from .sambay import _conv, _mlp, _pool_view, _scatter_chunk, _scatter_rows
 from .scopes import scope
 from .transformer import _paged_kernel_check
 
-__all__ = ["GraniteHybridConfig", "init_params", "forward", "init_cache",
-           "cache_bytes", "paged_decode_step", "paged_prefill_chunk",
-           "reset_slot_state", "param_count", "SERVING"]
+__all__ = ["GraniteHybridConfig", "init_params", "param_shapes", "forward",
+           "init_cache", "cache_bytes", "paged_decode_step",
+           "paged_prefill_chunk", "reset_slot_state", "param_count",
+           "SERVING", "SERVING_EXPERTS"]
 
 _NEG = -1e30  # finite mask fill (parallel/kernel_utils.NEG_INF)
 
@@ -71,16 +98,33 @@ _NEG = -1e30  # finite mask fill (parallel/kernel_utils.NEG_INF)
 class GraniteHybridConfig:
     def __init__(self, vocab=256, dim=64, heads=4, kv_heads=2, head_dim=16,
                  layer_types=("mamba", "attention"), layers=None, mlp_mult=4,
-                 mamba_heads=8, mamba_head_dim=16, d_state=16, d_conv=4,
-                 chunk=256, embedding_multiplier=12.0,
+                 mlp_width=None, n_experts=0, top_k=0, expert_width=0,
+                 experts_held=None, mamba_heads=8, mamba_head_dim=16,
+                 d_state=16, d_conv=4, chunk=256, embedding_multiplier=12.0,
                  residual_multiplier=0.22, attention_multiplier=0.015625,
                  logits_scaling=8.0, eps=1e-5, max_len=1024,
                  dtype=jnp.float32):
-        if heads % kv_heads or kv_heads % 2:
+        # the pool's row: two K/V heads where two fit 128 lanes, else one
+        self.paired = 2 * head_dim <= 128
+        if heads % kv_heads:
+            raise ValueError("grouped queries share a K/V head: heads %% "
+                             "kv_heads == 0 (got %d, %d)" % (heads, kv_heads))
+        if self.paired and kv_heads % 2:
             raise ValueError(
-                "grouped queries share a K/V head and the pool holds two "
-                "K/V heads a row: heads %% kv_heads == 0, kv_heads even "
-                "(got %d, %d)" % (heads, kv_heads))
+                "a pool row holds two K/V heads of %d (2 x head_dim <= "
+                "128 lanes): kv_heads even (got %d)" % (head_dim, kv_heads))
+        self.n_experts = int(n_experts)
+        self.top_k, self.expert_width = int(top_k), int(expert_width)
+        if self.n_experts:
+            if not 0 < self.top_k <= self.n_experts or self.expert_width < 1:
+                raise ValueError(
+                    "routed experts need 0 < top_k <= n_experts and an "
+                    "expert_width (got top_k %d of %d, width %d)"
+                    % (self.top_k, self.n_experts, self.expert_width))
+            self.experts_held = held_range(experts_held, self.n_experts)
+        elif experts_held is not None or self.top_k or self.expert_width:
+            raise ValueError("top_k, expert_width and experts_held name "
+                             "routed experts: n_experts is 0")
         bad = set(layer_types) - {"mamba", "attention"}
         if bad:
             raise ValueError("layer_types holds %r" % sorted(bad))
@@ -93,6 +137,8 @@ class GraniteHybridConfig:
             raise ValueError("layers %d, layer_types names %d"
                              % (layers, self.layers))
         self.mlp_mult = mlp_mult
+        # the shared MLP's width (h-small's 1,536 is 0.375 x dim)
+        self.mlp_width = int(mlp_width) if mlp_width else mlp_mult * dim
         self.mamba_heads, self.mamba_head_dim = mamba_heads, mamba_head_dim
         self.d_inner = mamba_heads * mamba_head_dim
         self.d_state, self.d_conv, self.chunk = d_state, d_conv, chunk
@@ -102,8 +148,10 @@ class GraniteHybridConfig:
         self.attention_multiplier = float(attention_multiplier)
         self.logits_scaling = float(logits_scaling)
         self.eps, self.max_len, self.dtype = eps, max_len, dtype
-        self.groups = kv_heads // 2  # pool rows a token: two heads each
-        self.serving = SERVING
+        # pool rows a token, and a row's width
+        self.groups = kv_heads // 2 if self.paired else kv_heads
+        self.row = 2 * head_dim if self.paired else head_dim
+        self.serving = SERVING_EXPERTS if self.n_experts else SERVING
 
 
 def _mixer_shapes(cfg, kind):
@@ -118,10 +166,20 @@ def _mixer_shapes(cfg, kind):
 
 
 def param_shapes(cfg: GraniteHybridConfig):
-    d, m = cfg.dim, cfg.mlp_mult * cfg.dim
-    return {"embed": (cfg.vocab, d), "norm_f": (d,), "blocks": [
-        {"norm1": (d,), "mixer": _mixer_shapes(cfg, kind), "norm2": (d,),
-         "w_gu": (d, 2 * m), "w_down": (m, d)} for kind in cfg.kinds]}
+    d, m = cfg.dim, cfg.mlp_width
+
+    def block(kind):
+        blk = {"norm1": (d,), "mixer": _mixer_shapes(cfg, kind),
+               "norm2": (d,), "w_gu": (d, 2 * m), "w_down": (m, d)}
+        if cfg.n_experts:
+            Eh, me = cfg.experts_held[1] - cfg.experts_held[0], \
+                cfg.expert_width
+            blk["router"] = (d, cfg.n_experts)
+            blk["experts"] = {"w_gu": (Eh, d, 2 * me), "w_down": (Eh, me, d)}
+        return blk
+
+    return {"embed": (cfg.vocab, d), "norm_f": (d,),
+            "blocks": [block(kind) for kind in cfg.kinds]}
 
 
 def param_count(cfg: GraniteHybridConfig) -> int:
@@ -131,8 +189,9 @@ def param_count(cfg: GraniteHybridConfig) -> int:
 
 
 def init_params(cfg: GraniteHybridConfig, key) -> Dict[str, Any]:
-    """Seeded random weights in `cfg.dtype`: matrices N(0, 1/rows)
-    (the tied embedding N(0, 1/vocab): a larger one makes every token
+    """Seeded random weights in `cfg.dtype`: matrices N(0, 1/rows), an
+    expert's by its own rows (the tied embedding N(0, 1/vocab): a
+    larger one makes every token
     predict itself through the tie), norm gains near 1, the conv uniform
     +-d_conv^-1/2 with a bias near 0, and the Mamba-2 leaves by the
     published initialisers (A uniform in [1, 16], dt bias the inverse
@@ -159,7 +218,7 @@ def init_params(cfg: GraniteHybridConfig, key) -> Dict[str, Any]:
             return 1.0 + 0.1 * n
         if len(shp) == 1:
             return 0.1 * n
-        return n / math.sqrt(shp[0])
+        return n / math.sqrt(shp[-2])
 
     return jax.tree_util.tree_unflatten(treedef, [
         leaf(i, path, shp).astype(cfg.dtype)
@@ -171,28 +230,60 @@ def init_params(cfg: GraniteHybridConfig, key) -> Dict[str, Any]:
 # ---------------------------------------------------------------------
 
 
-def _rms(x, w, eps):
+def _rms32(x, w, eps):  # float32, before any rounding
     xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
+    return (xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
+            * w.astype(jnp.float32))
+
+
+def _rms(x, w, eps):
+    return _rms32(x, w, eps).astype(x.dtype)
+
+
+def _moe(u32, blk, cfg, valid, kernel):
+    """An expert layer's FFN over the float32 normed rows u32 [N, d]
+    -> (float32 [N, d], stats int32 [2]): the router over all
+    `n_experts`, the experts held here, and the shared MLP. A row's
+    result depends on that row alone; a row that is not `valid`
+    reaches no expert."""
+    from ..parallel.routed_experts import expert_ffn, route
+
+    u = u32.astype(cfg.dtype)
+    idx, w = route(u32, blk["router"], None, cfg.top_k,
+                   scoring="softmax_topk")
+    out, stats = expert_ffn(u, idx, w, blk["experts"], valid,
+                            held=cfg.experts_held, kernel=kernel)
+    return out + _mlp(u, blk).astype(jnp.float32), stats
 
 
 _MIXER_SCOPE = {"mamba": "lm_state", "attention": "lm_attention"}
 
 
-def _stack(params, x, cfg, mixer):
+def _stack(params, x, cfg, mixer, valid=None, kernel="gather"):
     """Every layer in its residual form, each part under its device
     scope (`scopes.py`); `mixer(kind, h, p)` is the mode's (it owns
-    whatever cache the mode has). -> the final norm's output."""
+    whatever cache the mode has); `valid` [rows]: the rows that reach
+    experts. -> (the final norm's output, and for a config with routed
+    experts their stats summed / maxed over the layers, int32 [2]:
+    experts reached, the fullest one's rows; else None)."""
     r = cfg.residual_multiplier
+    stats = None
     for blk, kind in zip(params["blocks"], cfg.kinds):
         with scope(_MIXER_SCOPE[kind]):
             x = x + r * mixer(kind, _rms(x, blk["norm1"], cfg.eps),
                               blk["mixer"])
-        with scope("lm_mlp"):
-            x = x + r * _mlp(_rms(x, blk["norm2"], cfg.eps), blk)
+        if not cfg.n_experts:
+            with scope("lm_mlp"):
+                x = x + r * _mlp(_rms(x, blk["norm2"], cfg.eps), blk)
+            continue
+        with scope("lm_experts"):
+            m, st = _moe(_rms32(x, blk["norm2"], cfg.eps), blk, cfg, valid,
+                         kernel)
+            x = x + (r * m).astype(x.dtype)
+            stats = st if stats is None else jnp.stack(
+                [stats[0] + st[0], jnp.maximum(stats[1], st[1])])
     with scope("lm_head"):
-        return _rms(x, params["norm_f"], cfg.eps)
+        return _rms(x, params["norm_f"], cfg.eps), stats
 
 
 def _embed(params, tokens, cfg):
@@ -247,8 +338,8 @@ def _split_qkv(h, p, cfg):
             qkv[..., nq + nk:].reshape(lead + (cfg.kv_heads, cfg.dh)))
 
 
-def _pairs(kv, cfg):  # [.., Hk, dh] -> [.., Hk/2, 2 dh], the pool's rows
-    return kv.reshape(kv.shape[:-2] + (cfg.groups, 2 * cfg.dh))
+def _pairs(kv, cfg):  # [.., Hk, dh] -> [.., groups, row], the pool's rows
+    return kv.reshape(kv.shape[:-2] + (cfg.groups, cfg.row))
 
 
 def _attend(q, k, v, qpos, kpos, cfg):
@@ -289,8 +380,9 @@ def forward(params, tokens, cfg: GraniteHybridConfig):
         q, k, v = _split_qkv(h, p, cfg)
         return _attend(q, k, v, pos, pos, cfg) @ p["wo"]
 
-    return _head(params, _stack(params, _embed(params, tokens, cfg), cfg,
-                                mixer), cfg)
+    x, _ = _stack(params, _embed(params, tokens, cfg), cfg, mixer,
+                  jnp.ones(tokens.shape, bool))
+    return _head(params, x, cfg)
 
 
 # ---------------------------------------------------------------------
@@ -301,7 +393,7 @@ def forward(params, tokens, cfg: GraniteHybridConfig):
 def init_cache(cfg: GraniteHybridConfig, num_blocks: int, block_tokens: int,
                slots: int):
     dt = cfg.dtype
-    rows, D = int(block_tokens) * cfg.groups, 2 * cfg.dh
+    rows, D = int(block_tokens) * cfg.groups, cfg.row
     # one block more than the allocator hands out: where the fused
     # decode write sends a parked slot's rows (paged_kv_write)
     shape = (int(num_blocks) + 1, rows, D)
@@ -355,7 +447,9 @@ def _place_queries(q, cfg):
     """q [S, Hk, rep, dh] -> [S, Hk/2, 2 rep, 2 dh]: K/V head 2g + j is
     half j of pair g's row, so its `rep` queries sit in that half and
     are zero in the other: one 2 dh-wide product against the row is
-    q . k of the query's own head."""
+    q . k of the query's own head. Unpaired rows take q as it is."""
+    if not cfg.paired:
+        return q
     S = q.shape[0]
     q = q.reshape(S, cfg.groups, 2, cfg.rep, 1, cfg.dh)
     eye = jnp.eye(2, dtype=q.dtype)[None, None, :, None, :, None]
@@ -365,8 +459,10 @@ def _place_queries(q, cfg):
 def _own_halves(o, cfg):
     """o [S, Hk/2, 2 rep, 2 dh] (P V over the pair's row, every query)
     -> [S, heads * dh]: of each read, the half that is the query's own
-    V head."""
+    V head (unpaired: the read as it is)."""
     S = o.shape[0]
+    if not cfg.paired:
+        return o.reshape(S, cfg.heads * cfg.dh)
     o = o.reshape(S, cfg.groups, 2, cfg.rep, 2, cfg.dh)
     o = jnp.stack([o[:, :, 0, :, 0], o[:, :, 1, :, 1]], axis=2)
     return o.reshape(S, cfg.heads * cfg.dh)
@@ -376,13 +472,16 @@ def paged_decode_step(params, token, pos, tables, cache,
                       cfg: GraniteHybridConfig, kernel="gather"):
     """One decode step through the two caches: token [S] at per-row
     positions `pos` [S], `tables` [S, MAXB] -> (float32 logits
-    [S, vocab], updated cache). A parked row (pos >= MAXB * Bt) writes
-    no K/V and leaves its slot's state bit-identical; its logits are
-    garbage nothing reads. With kernel="fused" the attention reads and
-    writes and the state updates are Pallas kernels
-    (parallel/paged_attention.py: the grouped-query decode call with
-    `scale` = attention_multiplier; parallel/ssd_update.py); "gather"
-    is the same arithmetic in XLA."""
+    [S, vocab], updated cache) and, for a config with routed experts,
+    int32 [2]: experts reached summed over the layers, and the fullest
+    expert's rows. A parked row (pos >= MAXB * Bt) writes no K/V,
+    leaves its slot's state bit-identical and reaches no expert; its
+    logits are garbage nothing reads. With kernel="fused" the attention
+    reads and writes, the state updates and the experts' grouped
+    products are Pallas kernels (parallel/paged_attention.py: the
+    grouped-query decode call with `scale` = attention_multiplier;
+    parallel/ssd_update.py; parallel/routed_experts.py); "gather" is
+    the same arithmetic in XLA."""
     from ..parallel.paged_attention import (paged_decode_attention,
                                             paged_kv_write)
     from ..parallel.ssd_update import (ssd_state_update,
@@ -430,8 +529,10 @@ def paged_decode_step(params, token, pos, tables, cache,
         new["kv"].append(kv)
         return o @ p["wo"]
 
-    x = _stack(params, _embed(params, token, cfg), cfg, mixer)
-    return _head(params, x, cfg), new
+    x, stats = _stack(params, _embed(params, token, cfg), cfg, mixer, live,
+                      kernel)
+    out = (_head(params, x, cfg), new)
+    return out if stats is None else out + (stats,)
 
 
 # ---------------------------------------------------------------------
@@ -501,13 +602,15 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
     past `true_len` pad the bucket: they do not advance the state
     (dt = 0: a row that changes nothing), which leaves the chunk as
     the state after row true_len - 1, carried from where the last
-    chunk left it, as the conv's last d_conv - 1 input rows are.
+    chunk left it, as the conv's last d_conv - 1 input rows are; nor do
+    they reach an expert.
 
-    All of it is XLA in either `kernel`: the recurrence in its blocked
-    matrix form (`ssd_chunk_scan`, cfg.chunk rows a block), and the
-    attention over the slot's span gathered through the table after
+    The mixers are XLA in either `kernel`: the recurrence in its
+    blocked matrix form (`ssd_chunk_scan`, cfg.chunk rows a block), and
+    the attention over the slot's span gathered through the table after
     the chunk's own rows are written (`_chunk_attend`), whatever
-    position the chunk starts at."""
+    position the chunk starts at. The experts' grouped products are the
+    decode step's."""
     from ..parallel.ssd_update import ssd_chunk_scan
 
     _paged_kernel_check(kernel)
@@ -549,7 +652,8 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
                           _view(kv["v"], tab, cfg, Bt), start_pos, cfg)
         return o @ p["wo"]
 
-    x = _stack(params, _embed(params, chunk, cfg), cfg, mixer)
+    x, _ = _stack(params, _embed(params, chunk, cfg), cfg, mixer, valid,
+                  kernel)
     with scope("lm_head"):
         xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0,
                                           keepdims=False)
@@ -594,4 +698,12 @@ class _Serving(object):
         return init_cache(cfg, num_blocks, block_tokens, slots)
 
 
+class _ExpertServing(_Serving):
+    """The seam of a config with routed experts: the same caches and
+    refusals, and the decode step hands the engine its `step_counters`
+    beside the logits; they ride the step's one packed result."""
+    step_counters = ("moe_experts_hit", "moe_rows_max")
+
+
 SERVING = _Serving()
+SERVING_EXPERTS = _ExpertServing()

@@ -71,6 +71,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from ..parallel.routed_experts import held_range
 from .afmoe import _rms32, moe_ffn
 from .granite_hybrid import _chunk_attend
 from .sambay import _mlp, _pool_view, _scatter_chunk, _scatter_rows
@@ -103,11 +104,7 @@ class MlaMoeConfig:
         self.dense_width, self.expert_width = dense_width, expert_width
         self.n_shared_experts = int(n_shared_experts)
         self.n_experts, self.top_k = int(n_experts), int(top_k)
-        lo, hi = experts_held or (0, self.n_experts)
-        if not 0 <= lo < hi <= self.n_experts:
-            raise ValueError("experts_held %r of %d experts"
-                             % ((lo, hi), self.n_experts))
-        self.experts_held = (int(lo), int(hi))
+        self.experts_held = held_range(experts_held, self.n_experts)
         self.shared_expert_held = bool(shared_expert_held)
         self.route_scale = float(route_scale)
         self.route_norm = bool(route_norm)

@@ -9,8 +9,9 @@ result alone, in a full batch and beside padded rows. Here
 
   * `route`: the router's product, its scores, the bias that decides
     the choice (and nothing else), top-k, the normalisation and the
-    scale, all in float32 from the float32 row, so that a 16-bit
-    rounding of a score never picks another expert;
+    scale — or, for Granite's router, top-k of the logits and a softmax
+    over the k chosen — all in float32 from the float32 row, so that a
+    16-bit rounding of a score never picks another expert;
   * `plan_rows`: the (row, choice) pairs sorted by expert, each
     expert's rows padded to whole row tiles, so that a tile belongs to
     ONE expert. Rows that do not count (a prefill bucket's padding, a
@@ -45,7 +46,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .kernel_utils import resolve_interpret
 
-__all__ = ["route", "plan_rows", "grouped_matmul", "expert_ffn", "row_tile"]
+__all__ = ["route", "plan_rows", "grouped_matmul", "expert_ffn", "row_tile",
+           "held_range"]
 
 # a weight block a grid step, at most: 4 MiB and 1,024 columns (my chip
 # runs, PR 33: 512 rows over 128 experts 2,201 us at 1,024 columns,
@@ -54,15 +56,39 @@ _RHS_BLOCK_BYTES = 4 << 20
 _RHS_BLOCK_COLS = 1024
 
 
-def route(u32, router_w, bias, top_k, route_scale=1.0, route_norm=True):
+def held_range(experts_held, n_experts):
+    """A configuration's `experts_held` (None: all of them) -> the
+    (lo, hi) range of the `n_experts` whose weights this chip has."""
+    lo, hi = experts_held or (0, n_experts)
+    if not 0 <= lo < hi <= n_experts:
+        raise ValueError("experts_held %r of %d experts"
+                         % ((lo, hi), n_experts))
+    return int(lo), int(hi)
+
+
+def route(u32, router_w, bias, top_k, route_scale=1.0, route_norm=True,
+          scoring="sigmoid"):
     """u32 [N, d] float32 -> (experts [N, k] int32, weights [N, k]
-    float32). Scores are sigmoids; `bias` [E] is added for the CHOICE
-    only; the weights are the chosen experts' own scores, normalised
-    over the k chosen (`route_norm`) and scaled."""
+    float32). `scoring`:
+
+      "sigmoid"        scores are sigmoids of the logits; `bias` [E] is
+                       added for the CHOICE only; the weights are the
+                       chosen experts' own scores, normalised over the
+                       k chosen (`route_norm`) and scaled
+      "softmax_topk"   the k largest logits are chosen and their
+                       weights are a softmax over those k alone
+                       (Granite's `GraniteMoeHybridTopKGating`): no
+                       bias, no normalisation beyond it, no scale"""
     f32 = jnp.float32
-    s = jax.nn.sigmoid(jnp.matmul(
-        u32.astype(f32), router_w.astype(f32),
-        precision=jax.lax.Precision.HIGHEST))
+    logits = jnp.matmul(u32.astype(f32), router_w.astype(f32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax_topk":
+        top, idx = jax.lax.top_k(logits, top_k)
+        return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+    if scoring != "sigmoid":
+        raise ValueError("route scores by 'sigmoid' or 'softmax_topk', "
+                         "not %r" % (scoring,))
+    s = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(s + bias.astype(f32), top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if route_norm:

@@ -707,6 +707,145 @@ def test_granite_prefill_chunk_compiles_at_its_one_bucket(programs):
 
 
 # ---------------------------------------------------------------------
+# the same family with routed experts at the geometry of its
+# cell, `granitehsmall_reason_closed`: granite-4.0-h-small's widths, one
+# 10-layer period, 36 of 72 experts and half the vocabulary held, 64
+# slots, 8,192 positions, 11,264 blocks of 32 tokens
+# ---------------------------------------------------------------------
+
+GS_NB = 11264
+
+
+def _granite_moe_config():
+    import json
+    import pathlib
+
+    from paddle_tpu.models import granite_hybrid as gh
+
+    conf = json.loads((pathlib.Path(__file__).resolve().parent.parent
+                       / "benchmarks" / "chip" / "configs"
+                       / "granite_4_0_h_small.json").read_text())
+    return gh.GraniteHybridConfig(max_len=GR_L, dtype=jnp.bfloat16,
+                                  **conf["shape"]), conf
+
+
+def _granite_moe_engine(one_chip, **kw):
+    from paddle_tpu.models import granite_hybrid as gh
+    from paddle_tpu.serving import ServingEngine
+
+    cfg, conf = _granite_moe_config()
+    eng_kw = dict(conf["engine"], kv_pool_blocks=4, **kw)
+    params = jax.eval_shape(
+        lambda: gh.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = ServingEngine(params, cfg, **eng_kw)
+    assert eng.paged_kernel == "fused" and eng._win is None
+    assert (eng.max_slots, eng.kv_block_tokens) == (GR_S, GR_BT)
+    cache = jax.eval_shape(lambda: gh.SERVING_EXPERTS.init_cache(
+        cfg, conf["engine"]["kv_pool_blocks"], GR_BT, GR_S))
+    return eng, _placed(params, one_chip), _placed(cache, one_chip), \
+        _sds(one_chip)
+
+
+def test_ssd_state_update_at_the_wider_state(one_chip):
+    """h-small's state, 64 slots of [128, 8192] float32 (4 MiB a
+    slot), under the call's VMEM rule as it stands: the batch rule
+    gives two slots a batch (8 MiB), the call holds 16 MiB of batches
+    and 12.1 MiB of rows and scopes twice that, 56.3 of the 128 MiB;
+    Mosaic compiles it in place as ONE custom call under the kernel's
+    name."""
+    from paddle_tpu.parallel import ssd_update
+
+    assert ssd_update._step_slots(GR_S, 128 * 8192 * 4) == 2
+    sds = _sds(one_chip)
+    f32 = jnp.float32
+    text = _compile(
+        lambda *a: ssd_update.ssd_state_update(*a, interpret=False),
+        sds((GR_S, 128, 8192), f32), sds((GR_S, 8192), f32),
+        sds((GR_S, 8192), f32), sds((GR_S, 128), f32),
+        sds((GR_S, 128), f32), sds((GR_S,), jnp.bool_))
+    found = [ln.strip() for ln in text.split("\n")
+             if _metric_pattern("ssd_decode_roofline").search(ln.strip())]
+    assert len(found) == 1 and " custom-call(" in found[0]
+    assert "output_to_operand_aliasing={{0}: (3, {})}" in found[0]
+    call = text[text.index(found[0][:40]):]
+    scoped = int(re.search(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', call).group(1))
+    held = 2 * 2 * 128 * 8192 * 4 + 2 * GR_S * (3 * 8192 + 2 * 128) * 4
+    assert scoped == 2 * held <= ssd_update._VMEM_BYTES
+
+
+def test_granite_moe_decode_program_is_the_one_the_benchmark_finds(
+        programs):
+    """At the cell's geometry the decode program is the one
+    `decode_step_ms`, `ssd_decode_roofline`, `gqa_attn_roofline` and
+    `moe_expert_roofline` look for, FLAT: 9 state updates, 1
+    grouped-query call over one-head rows beside its one K/V write, and
+    two grouped expert products a layer, each named after its kernel;
+    the expert branch under `lm_experts` (no `lm_mlp`); one packed
+    result that carries the router's two counters. Its arguments (9.5
+    GB of weights, 2.45 GB of state, 1.48 GB of pool) and temporaries
+    fit under the bytes the configuration allows."""
+    eng, prog = programs("granite_moe").eng, programs("granite_moe")
+    text = prog.text
+    assert eng.async_dispatch and eng._has_state
+    assert eng._step_counters == ("moe_experts_hit", "moe_rows_max")
+    module = re.match(r"HloModule (\S+?),", text).group(1)
+    for metric in ("decode_step_ms", "ssd_decode_roofline",
+                   "gqa_attn_roofline", "moe_expert_roofline"):
+        program = _metric_spec(metric)["args"]["program_match"]
+        assert re.search(program, module + "(1)"), (metric, module)
+    assert " while(" not in text
+    entry = text[text.index("\nENTRY "):]
+    lines = [ln.strip() for ln in entry.split("\n")]
+    for metric, kernel, calls in (
+            ("ssd_decode_roofline", "ssd_state_update", 9),
+            ("gqa_attn_roofline", "hybrid_decode_attention", 1),
+            ("moe_expert_roofline", "moe_grouped_matmul", 20)):
+        found = [ln for ln in lines if _metric_pattern(metric).search(ln)]
+        assert len(found) == calls, (metric, len(found))
+        assert all(ln.startswith("%" + kernel) and " custom-call(" in ln
+                   for ln in found)
+    # one K/V head of 128 a pool row: a block is 32 tokens x 8 rows,
+    # and the call returns the 32 query heads' 128-wide reads
+    attn = [ln for ln in lines
+            if _metric_pattern("gqa_attn_roofline").search(ln)][0]
+    assert "bf16[%d,256,128]" % (GS_NB + 1) in attn
+    assert "bf16[%d,32,1,128]" % GR_S in attn
+    assert len([ln for ln in lines if ln.startswith("%paged_kv_write")
+                and " custom-call(" in ln]) == 1
+    assert text.count("tpu_custom_call") == 31
+    assert "/lm_experts/" in text and "/lm_mlp/" not in text
+    assert re.search(r"s32\[%d\]" % (6 * GR_S + 3), entry)
+    _, conf = _granite_moe_config()
+    mem = prog.compiled.memory_analysis()
+    assert 13.3e9 < mem.argument_size_in_bytes < 13.6e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < conf["aot_bytes_limit"])
+    assert eng.metrics.decode_trace_count() == 1
+
+
+def test_granite_moe_prefill_chunk_compiles_at_its_one_bucket(programs):
+    """The cell's one chunk program compiles for the chip under its own
+    name: the blocked scan as a loop, the routed rows through the
+    grouped product; beside the arguments its temporaries fit under
+    the bytes the configuration allows."""
+    eng = programs("granite_moe").eng
+    _, conf = _granite_moe_config()
+    rows = conf["engine"]["prefill_chunk_tokens"]
+    assert eng._bucket(1) == eng._bucket(rows - 100) == rows
+    compiled = programs("granite_moe", "chunk").compiled
+    text = programs("granite_moe", "chunk").text
+    assert re.match(r"HloModule jit__chunk[,.]", text)
+    assert "/lm_state/" in text and " while(" in text
+    assert "moe_grouped_matmul" in text and "/lm_experts/" in text
+    assert "ssd_state_update" not in text  # the decode step's kernel
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < conf["aot_bytes_limit"])
+
+
+# ---------------------------------------------------------------------
 # the merged-pool decode call alone, at the three hybrid cells'
 # geometry, with the group the byte rule gives it (ISSUEs 32 and 36)
 # ---------------------------------------------------------------------
@@ -1062,8 +1201,9 @@ def test_mla_prefill_chunk_compiles_at_the_largest_bucket(programs):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
 
 
-# the SambaY, the Mamba-2 / grouped-query and the sparse-expert decode
-# programs at their cells' geometry, as the tree compiles them:
+# the SambaY, the Mamba-2 / grouped-query (with and without routed
+# experts), the sparse-expert and the latent-attention decode programs
+# at their cells' geometry, as the tree compiles them:
 # sha256 of the compiled text less locations and less the kernels'
 # embedded bodies, as `_GPT_DECODE_TEXT_SHA` above. Their attention
 # call takes its own operands and scratch, with no work list beside
@@ -1075,7 +1215,9 @@ def test_mla_prefill_chunk_compiles_at_the_largest_bucket(programs):
 _DECODE_TEXT_SHA = {
     "hybrid": "a7fa22c9f38781e54e745f06198235060546b985208b61b7221c3573d80c46d9",
     "granite": "da13db230832f12fb7294deb98eb585e557be6228d0dfa1fe1d6ecde6963befb",
+    "granite_moe": "8cb9ebd6779443b5c5bc4b823579f7b52b9c6f2a6727406794005f7204208023",
     "afmoe": "ff2173aa4c2e87810af7f92cdb0557b1cb44feb9e0647150c0b2843bd91784cc",
+    "mla": "bb83c404f3d3a686cdc700e67c9fe8658e359070d9b46ac14c413482d826a7b0",
 }
 
 
@@ -1099,12 +1241,15 @@ _FAMILIES = {  # family -> (engine, its largest chunk, its table rows)
     "gpt": (_engine, L, (MAXB,)),
     "hybrid": (_hybrid_engine, 4096, (4, HY_MAXB)),
     "granite": (_granite_engine, GR_CHUNK, (2, GR_MAXB)),
+    "granite_moe": (_granite_moe_engine, GR_CHUNK, (2, GR_MAXB)),
     "afmoe": (_afmoe_engine, 4096, (4, AF_MAXB)),
     "mla": (_mla_engine, 4096, (ML_MAXB,)),
 }
 # the decode step's block tables: [(kinds of table,) slots, entries]
 _DECODE_TABLES = {"gpt": (S, MAXB), "hybrid": (2, HY_S, HY_MAXB),
-                  "granite": (GR_S, GR_MAXB), "afmoe": (2, AF_S, AF_MAXB),
+                  "granite": (GR_S, GR_MAXB),
+                  "granite_moe": (GR_S, GR_MAXB),
+                  "afmoe": (2, AF_S, AF_MAXB),
                   "mla": (ML_S, ML_MAXB)}
 
 
@@ -1188,6 +1333,7 @@ _FAMILY_SCOPES = {
     "gpt": {"lm_attention", "lm_mlp"},
     "hybrid": {"lm_attention", "lm_state", "lm_mlp"},
     "granite": {"lm_attention", "lm_state", "lm_mlp"},
+    "granite_moe": {"lm_attention", "lm_state", "lm_experts"},
     "afmoe": {"lm_attention", "lm_mlp", "lm_experts"},
     "mla": {"lm_attention", "lm_mlp", "lm_experts"},
 }
