@@ -69,7 +69,9 @@ fold is 0.5 us + 0.064 us a block, hidden from ~29 blocks up — so a
 group is sized by BYTES (`_bytes_group`, `_STEP_BYTES`): 32 blocks of
 granite's and Trinity's 64 KiB, 8 of SambaY's 160 KiB; the latent
 call, whose fold and not its copies binds it, 64 of its 40 KiB (1,427
--> 1,288 us with the rungs below, measured on one v5e chip).
+-> 1,288 us with the rungs below, measured on one v5e chip), and it
+cuts each fold in two halves whose chains overlap (`_fold_halves`:
+1,288 -> 1,193 us).
 Microseconds a call, the BlockSpec form -> this one (least, by the
 cell's bytes):
 Trinity's full call over contexts of 1.5-7.2 k 1,191 -> 791 (712), its
@@ -315,7 +317,6 @@ def _fold_tile(s, v, acc_ref, m_ref, l_ref):
     halves (hi + lo, stacked on the rows of ONE product, so V is loaded
     into the MXU once): P keeps ~16 bits of mantissa instead of 8, for
     1 % of the call."""
-    R = s.shape[0]
     m_prev = m_ref[...]  # [R, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -323,6 +324,16 @@ def _fold_tile(s, v, acc_ref, m_ref, l_ref):
     alpha = jnp.exp(m_prev - m_new)
     alpha = jnp.where(m_prev <= NEG_INF, 0.0, alpha)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    pv = _pv(p, v)
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    m_ref[...] = m_new
+
+
+def _pv(p, v):
+    """P [R, C] float32 . V [C, Dh] -> [R, Dh] float32; against a
+    16-bit V, P as its hi + lo halves stacked on the rows of ONE
+    product (`_fold_tile`)."""
+    R = p.shape[0]
     if v.dtype.itemsize == 2:
         hi = p.astype(v.dtype)
         lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
@@ -330,12 +341,41 @@ def _fold_tile(s, v, acc_ref, m_ref, l_ref):
             jnp.concatenate([hi, lo], axis=0), v,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)  # [2R, Dh]
-        pv = pv[:R] + pv[R:]
-    else:
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [R, Dh]
-    acc_ref[...] = acc_ref[...] * alpha + pv
+        return pv[:R] + pv[R:]
+    return jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)  # [R, Dh]
+
+
+def _fold_halves(tiles, acc_ref, m_ref, l_ref):
+    """`_fold_tile` over a tile cut in two, `tiles` = ((s, v), (s, v)):
+    each half's row max, exp, sum and P . V depend on that half alone,
+    and the two merge into the rows' state once. In one basic block the
+    scheduler runs one half's q . K^T under the other's softmax and
+    P . V, where one tile's chain is serial: product, row max, exp,
+    product. The same online softmax — a masked column contributes
+    EXACTLY 0, a half with no attended column weighs 0 — whose float32
+    sums are taken in another order."""
+    parts = []
+    for s, v in tiles:
+        m = jnp.max(s, axis=1, keepdims=True)  # [R, 1]
+        p = jnp.where(s <= NEG_INF, 0.0, jnp.exp(s - m))
+        parts.append((m, jnp.sum(p, axis=1, keepdims=True), _pv(p, v)))
+    m_prev = m_ref[...]
+    m_new = m_prev
+    for m, _, _ in parts:
+        m_new = jnp.maximum(m_new, m)
+
+    def weight(m):
+        return jnp.where(m <= NEG_INF, 0.0, jnp.exp(m - m_new))
+
+    alpha = weight(m_prev)
+    l, acc = l_ref[...] * alpha, acc_ref[...] * alpha
+    for m, l_half, pv in parts:
+        w = weight(m)
+        l, acc = l + w * l_half, acc + w * pv
+    l_ref[...] = l
+    acc_ref[...] = acc
     m_ref[...] = m_new
 
 
@@ -472,7 +512,9 @@ def _rungs(G: int, rows: int, fold_bound: bool) -> tuple:
     took Trinity's warm-up from 12.1 to 13.6-14.2 s and its calls
     nowhere (414.6 -> 412.6 us, 791.9 -> 794.7). One fold a group,
     whatever its rung — a loop of smaller folds would pay a group's
-    fixed chain (~0.5 us) again each time."""
+    fixed chain (~0.5 us) again each time; the fold-bound call cuts a
+    fold in two overlapped halves instead (`_fold_halves`), which a
+    rung of at least two 128-row tiles always allows."""
     r = max(1, G // (8 if fold_bound else 4))
     while r < G and r * rows % 128:
         r += 1
@@ -481,9 +523,19 @@ def _rungs(G: int, rows: int, fold_bound: bool) -> tuple:
     return tuple(range(r, G, r)) + (G,)
 
 
+def _half_cut(blocks: int, rows: int):
+    """Where a fold of `blocks` blocks of `rows` rows is cut in two for
+    `_fold_halves`: the block nearest the middle before which the rows
+    fill whole 128-row score tiles; None where no such block lies
+    inside the fold (it is then one tile, `_fold_tile`)."""
+    cuts = [b for b in range(1, blocks) if b * rows % 128 == 0]
+    return min(cuts, key=lambda b: abs(2 * b - blocks)) if cuts else None
+
+
 def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
                            rungs: tuple, span: int, scale: float, rep: int,
-                           windowed: bool, v_lanes: int = 0):
+                           windowed: bool, v_lanes: int = 0,
+                           overlap: bool = False):
     """One SLOT of the merged-pool decode call (ISSUE 36): the pools
     stay in HBM, the kernel reads the slot's block ids from its table
     row in scalar memory and copies the blocks its context names — and
@@ -519,7 +571,20 @@ def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
 
     `v_lanes` > 0 (the latent pool, ISSUE 37): ONE pool, whose row is
     the key and whose first `v_lanes` lanes are the value — a block is
-    copied once into one ring and read by both products."""
+    copied once into one ring and read by both products.
+
+    `overlap` (the call bound by its fold: the latent call): every
+    fold of two 128-row score tiles or more — at the latent cell's
+    geometry EVERY fold, a whole group of 64 blocks or a rung of the
+    ladder, 8 blocks = 256 rows at the least — is cut in two halves
+    (`_half_cut`) folded by `_fold_halves`: two
+    chains in one basic block, so one half's q . K^T runs under the
+    other's softmax and P . V. On one v5e chip, 128 slots over contexts
+    of 1.5-7.2 k (least 809 us, copies alone 977): 1,288 us a call with
+    one serial chain a fold, 1,193 with the halves; carrying the next
+    GROUP's scores into this group's fold instead (a 256 KiB score tile
+    in VMEM, a ring of 4) took 1,288-1,290. A call bound by its copies
+    folds one tile a fold, as before: its fold is hidden already."""
     if windowed:
         first_ref, refs = refs[0], refs[1:]
     else:
@@ -620,14 +685,25 @@ def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
         place = jax.lax.rem(c, ring)
         start_next()
 
-        def fold(blocks):
-            # the group's first `blocks` blocks; the tile's first
-            # token sits at position (b0 + j * G) * Bt
-            rows = pl.ds(0, blocks * BH)
+        def tile(lo, hi):
+            # blocks lo .. hi of the group; the group's first token
+            # sits at position (b0 + j * G) * Bt (a tile from block 0
+            # adds no 0: the serial fold's body stays as it was traced)
+            rows = pl.ds(lo * BH, (hi - lo) * BH)
             s = _masked_scores(q_ref[...], kbuf[place, rows], scale, rep,
-                               (b0 + j * G) * Bt, pos,
+                               (b0 + j * G + lo) * Bt if lo
+                               else (b0 + j * G) * Bt, pos,
                                None if first_ref is None else first_ref[si])
-            _fold_tile(s, values(place, rows), acc_ref, m_ref, l_ref)
+            return s, values(place, rows)
+
+        def fold(blocks):
+            # the group's first `blocks` blocks
+            cut = _half_cut(blocks, BH) if overlap else None
+            if cut is None:
+                _fold_tile(*tile(0, blocks), acc_ref, m_ref, l_ref)
+            else:
+                _fold_halves((tile(0, cut), tile(cut, blocks)),
+                             acc_ref, m_ref, l_ref)
 
         # the first rung that covers the blocks this group names (a
         # whole group names G or more), the whole group as branch 0:
@@ -686,7 +762,10 @@ def _group(Bt: int, maxb: int) -> int:
 # and 1,296 (1,413 and 1,288 with the whole group as the switch's
 # first branch), 1,427 at 32 with a quarter group or a whole one
 # (one v5e chip). So it reaches twice the target (2.5 MiB:
-# 64 of its blocks); 128 blocks pass the VMEM a program scopes
+# 64 of its blocks); 128 blocks pass the VMEM a program scopes. With
+# each fold cut in two overlapped halves (`_fold_halves`) the call is
+# 1,193 at 64 blocks: what a group adds over its copies (977 alone) is
+# then mostly its q . K^T at 32 query rows (qkonly 1,079, pvonly 1,012)
 _STEP_BYTES = 5 << 18
 
 
@@ -904,13 +983,13 @@ def _merged_decode(q, k_pool, v_pool, tables, pos, first, *, scale,
                       rungs=_rungs(G, rows, fold_bound), ring=_RING,
                       scale=scale,
                       interpret=resolve_interpret(interpret),
-                      v_lanes=v_lanes)
+                      v_lanes=v_lanes, overlap=fold_bound)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "G", "rungs", "ring", "scale", "interpret", "v_lanes"))
+    "G", "rungs", "ring", "scale", "interpret", "v_lanes", "overlap"))
 def _ring_call(q, k_pool, v_pool, tables, pos, first, *, G, rungs, ring,
-               scale, interpret, v_lanes):
+               scale, interpret, v_lanes, overlap=False):
     """`_merged_decode`'s call, its group, rungs and ring given. Jitted,
     so that a program's layers of one geometry trace and lower ONE
     kernel, whose body holds a fold a rung: every process lowers its
@@ -930,7 +1009,8 @@ def _ring_call(q, k_pool, v_pool, tables, pos, first, *, G, rungs, ring,
 
     kernel = functools.partial(
         _pa_ring_decode_kernel, Bt=Bt, G=G, rungs=rungs, span=maxb * Bt,
-        scale=scale, rep=rep, windowed=first is not None, v_lanes=v_lanes)
+        scale=scale, rep=rep, windowed=first is not None, v_lanes=v_lanes,
+        overlap=overlap)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(S,),
@@ -1147,7 +1227,9 @@ def mla_decode_attention(q, pool, tables, pos, v_lanes, scale,
     walk over the table in scalar memory and the fold are the
     merged-pool call's (`_pa_ring_decode_kernel` with `v_lanes`), with
     one K/V head that all H query rows share; its fold, not its copies,
-    binds it, so its groups are twice the bytes (`_bytes_group`)."""
+    binds it, so its groups are twice the bytes (`_bytes_group`) and
+    every fold is cut in two halves whose chains overlap
+    (`_fold_halves`)."""
     S, H, W = q.shape
     out = _merged_decode(q.reshape(S, 1, H, W), pool, None, tables, pos,
                          None, scale=scale, interpret=interpret,

@@ -322,9 +322,12 @@ def test_parked_slots_latent_blocks_are_untouched(cfg, params, tokens,
 
 # the blocks a slot's last group names, by the kind of rung it lands on
 # (`pa._rungs`), at 32 rows a block (the cell's: a rung is 4 blocks,
-# 128 rows) in groups of 32: one block, a rung, a rung and a block, all
-# but one block, all 32. (At 4 or 8 rows a block in one group of 12,
-# no rung short of the group fills a 128-row tile.)
+# 128 rows) in groups of 32: one block, a rung (the first rung: one
+# 128-row tile, folded whole), a rung and a block (a middle rung, 8
+# blocks, folded in two halves), all but one block (the last rung, the
+# group: halves of 16), all 32. (At 4 or 8 rows a block in one group of
+# 12, no rung short of the group fills a 128-row tile, and no fold is
+# cut in two.)
 _LAST = {"one": lambda G, r: 1, "rung": lambda G, r: r,
          "rung_plus_one": lambda G, r: r + 1,
          "G_minus_one": lambda G, r: G - 1, "G": lambda G, r: G}
@@ -336,24 +339,28 @@ _LAST = {"one": lambda G, r: 1, "rung": lambda G, r: r,
 def test_latent_decode_call_equals_plain_attention(heads, W, r, Bt, last):
     """`mla_decode_attention` interpreted, over ragged contexts (one
     token, a block edge, a short last group, a parked slot; with
-    `last`, a slot whose last group lands on that kind of rung) through
-    tables that name blocks out of order and end in -1, against
-    float64 softmax over the rows each table names: scores over the
-    whole row, values its first r lanes. A parked slot gives zeros; no
-    row past `pos` counts, whatever finite values the block holds
-    there (the pools start as zeros and are written finite: 0 x NaN
-    would be NaN in P . V, `_zero_ring` says)."""
+    `last`, slots of one, two and four groups whose last group lands on
+    that kind of rung, every fold of 8 blocks or more cut in two
+    overlapped halves, `_fold_halves`) through tables that name blocks
+    out of order and end in -1, against float64 softmax over the rows
+    each table names: scores over the whole row, values its first r
+    lanes. A parked slot gives zeros; no row past `pos` counts,
+    whatever finite values the block holds there, and no block a table
+    does not name is read: those hold NaN (0 x NaN would be NaN in
+    P . V — a masked column must contribute an exact 0, and the ring
+    starts as zeros, `_zero_ring`)."""
     rng = np.random.default_rng(heads)
     S, maxb, NB = 5, 12, 40
     pos = [0, Bt - 1, 5 * Bt + 2, maxb * Bt, 11 * Bt + 1]
     if last is not None:
-        maxb, NB = 64, 128
+        maxb, NB = 128, 240
         G = pa._bytes_group(Bt, maxb, Bt * W * 4, True)
         rung = pa._rungs(G, Bt, True)[0]
         assert (G, rung) == (32, 4)
         n = G + _LAST[last](G, rung)  # blocks named: a group and the last
-        S, pos = 6, pos[:3] + [maxb * Bt, (n - 1) * Bt + Bt // 2,
-                               (n - G - 1) * Bt + 1]
+        S, pos = 7, pos[:3] + [maxb * Bt, (n - 1) * Bt + Bt // 2,
+                               (n - G - 1) * Bt + 1,
+                               (n + 2 * G - 1) * Bt + Bt - 1]
     pool = rng.normal(size=(NB, Bt, W)).astype(np.float32)
     q = rng.normal(size=(S, heads, W)).astype(np.float32)
     pos = np.array(pos, np.int32)
@@ -365,11 +372,15 @@ def test_latent_decode_call_equals_plain_attention(heads, W, r, Bt, last):
         at += n
         if n and pos[s] % Bt != Bt - 1:  # what lies past pos is garbage
             pool[tables[s, n - 1], pos[s] % Bt + 1:] = 1e4
+    pool[np.setdiff1d(np.arange(NB), tables[tables >= 0])] = np.nan
     scale = 1 / math.sqrt(W)
     got = np.asarray(pa.mla_decode_attention(
         jnp.asarray(q), jnp.asarray(pool), jnp.asarray(tables),
         jnp.asarray(pos), r, scale))
     assert got.shape == (S, heads, r)
+    if last is not None:  # one, two and four groups
+        assert sorted(-(-(pos[pos < maxb * Bt] // Bt + 1) // G)) == \
+            [1, 1, 1, 1, 2, 4]
     for s in range(S):
         if pos[s] >= maxb * Bt:
             assert not got[s].any()

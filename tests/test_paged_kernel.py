@@ -814,6 +814,64 @@ def test_rung_rule_folds_a_last_group_in_whole_tiles():
     assert share(pa._rungs(64, 32, True)) < 1.03
 
 
+def test_only_a_call_bound_by_its_fold_cuts_it_in_halves(monkeypatch):
+    """`_half_cut`: a fold is cut at the block nearest its middle where
+    the rows before it fill whole 128-row score tiles, and a fold of one
+    such tile or less is not cut — the latent cell's group of 64 blocks
+    of 32 rows halves at 32, each rung of 8 at 4. Only the call bound
+    by its fold (one pool feeds both products: `fold_bound`) asks: a
+    call whose copies hide its fold has the body it had, whatever the
+    cut rule says (its rungs of 16 blocks of 32 rows could be cut),
+    while the latent call's body follows the rule — `_half_cut` giving
+    None is its serial fold, one `_fold_tile` a rung."""
+    from paddle_tpu.parallel import paged_attention as pa
+
+    assert pa._half_cut(64, 32) == 32
+    assert pa._half_cut(8, 32) == 4
+    assert pa._half_cut(4, 32) is None  # one tile
+    assert pa._half_cut(12, 32) in (4, 8)
+    assert pa._half_cut(2, 320) is None  # 320 rows: no tile edge
+    assert pa._half_cut(32, 128) == 16
+    assert pa._half_cut(3, 64) == 2  # the rest need not fill a tile
+
+    sds = jax.ShapeDtypeStruct
+    S, maxb = 4, 64
+    tables, pos = sds((S, maxb), jnp.int32), sds((S,), jnp.int32)
+    merged = (sds((S, 4, 8, 128), jnp.bfloat16),
+              sds((200, 32, 128), jnp.bfloat16))
+    latent = (sds((S, 32, 640), jnp.bfloat16),
+              sds((200, 32, 640), jnp.bfloat16))
+
+    def body(which):
+        pa._ring_call.clear_cache()
+        if which == "merged":
+            fn = lambda q, k, t, p: pa.paged_decode_attention(  # noqa: E731
+                q, k, k, t, p, interpret=False, scale=0.125)
+            args = merged + (tables, pos)
+            assert pa._rungs(pa._bytes_group(8, maxb, 2 * 32 * 128 * 2),
+                             32, False) == (16, 64)
+        else:
+            fn = lambda q, k, t, p: pa.mla_decode_attention(  # noqa: E731
+                q, k, t, p, 512, 0.1, interpret=False)
+            args = latent + (tables, pos)
+        with jax.default_matmul_precision(None):
+            return str(jax.make_jaxpr(fn)(*args))
+
+    rule = {w: body(w) for w in ("merged", "latent")}
+    monkeypatch.setattr(pa, "_half_cut", lambda blocks, rows: None)
+    serial = {w: body(w) for w in ("merged", "latent")}
+    # a cut after the first 128-row tile of every fold that has two
+    monkeypatch.setattr(pa, "_half_cut",
+                        lambda blocks, rows: 4 if blocks > 4 else None)
+    every = {w: body(w) for w in ("merged", "latent")}
+    assert rule["merged"] == serial["merged"] == every["merged"]
+    assert len({rule["latent"], serial["latent"], every["latent"]}) == 3
+    # the serial fold has one P . V product a rung, the halves two
+    assert (rule["latent"].count("dot_general")
+            == serial["latent"].count("dot_general")
+            + 2 * len(pa._rungs(64, 32, True)))
+
+
 @pytest.mark.parametrize("rule", ["every_entry", "look_back"])
 def test_worklist_names_the_tables_blocks_on_both_sides_of_its_switch(
         rule, monkeypatch):

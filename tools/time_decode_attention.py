@@ -21,7 +21,9 @@ every group size G (blocks a ring place; `rule` is what the program
 itself picks), ladder of rungs a slot's last group folds (`--shorts`)
 and body variant it prints one JSON line: device microseconds of the
 kernel a call (median over --calls, read from a profiler trace by the
-kernel's name), the groups it folds (`steps`), the blocks it copies
+kernel's name), the groups it folds (`steps`) and how many of those
+folds are cut in two overlapped halves (`overlapped`: the latent call's,
+`_fold_halves`), the blocks it copies
 (`blocks`, those the tables name) beside the blocks it folds
 (`folded`: whole groups, and the first rung that covers a slot's last
 one), microseconds a group, the rest of the program (`rest_us`: what
@@ -30,14 +32,19 @@ reads the tables) and the least time of the call from the cell's own
 cost function (`benchmarks/chip/lib/costs_*.py`, the one its roofline
 metric divides by) over the published HBM bandwidth.
 
-Variants: `body` is the program's kernel. The others keep its copies,
-ring and walk, put their own functions in the place of its score tile
-and softmax fold, and give WRONG ANSWERS, timing only: `nomask` drops
-the head/position masks and the NEG_INF `where`s, `nosplit` sends P as
-one 16-bit product instead of hi + lo halves, `bare` drops both;
-`qkonly`, `pvonly` and `noproducts` keep `body`'s tile work and drop
-one or both of the two matrix products (`noproducts`: what is left is
-the copies, the walk and the tile's element-wise work).
+Variants: `body` is the program's kernel; `serial` is the same kernel
+with every fold one score tile whose chain runs serially (no
+`_fold_halves`: the form before the halves, and what a call bound by its
+copies still runs), checked against the reference like `body`. The
+others keep its copies, ring and walk, put their own functions in the
+place of its score tile and softmax fold, and give WRONG ANSWERS,
+timing only: `nomask` drops the head/position masks and the NEG_INF
+`where`s, `nosplit` sends P as one 16-bit product instead of hi + lo
+halves, `bare` drops both; `qkonly`, `pvonly` and `noproducts` keep
+`body`'s tile work and drop one or both of the two matrix products
+(`noproducts`: what is left is the copies, the walk and the tile's
+element-wise work). `a+b` applies both: `serial+qkonly` is `qkonly`
+with the serial fold.
 
 --tiny is a rehearsal on the CPU (kernels interpreted, wall clock
 only): its numbers are not device times and say so.
@@ -121,10 +128,25 @@ def _scores(q, k, scale, rep, at, pos, first, *, masks=True, product=True):
     return jnp.where(masked, NEG_INF, s)
 
 
-def _fold(s, v, acc_ref, m_ref, l_ref, *, masks=True, split=True,
-          product=True):
+def _pv(p, v, l_cur, *, split=True, product=True):
+    """`pa._pv` with parts cut out."""
+    R = p.shape[0]
+    if not product:  # one row of V read, whatever the tile's width
+        return l_cur * v[:1, :].astype(jnp.float32)
+    if split and v.dtype.itemsize == 2:
+        hi = p.astype(v.dtype)
+        lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+        pv = jax.lax.dot_general(
+            jnp.concatenate([hi, lo], axis=0), v,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return pv[:R] + pv[R:]
+    return jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _fold(s, v, acc_ref, m_ref, l_ref, *, masks=True, **parts):
     """`pa._fold_tile` with parts cut out."""
-    R, dh = acc_ref.shape
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -134,37 +156,60 @@ def _fold(s, v, acc_ref, m_ref, l_ref, *, masks=True, split=True,
         alpha = jnp.where(m_prev <= NEG_INF, 0.0, alpha)
     l_cur = jnp.sum(p, axis=1, keepdims=True)
     l_ref[...] = l_ref[...] * alpha + l_cur
-    if not product:  # one row of V read, whatever the tile's width
-        pv = l_cur * v[:1, :].astype(jnp.float32)
-    elif split and v.dtype.itemsize == 2:
-        hi = p.astype(v.dtype)
-        lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
-        pv = jax.lax.dot_general(
-            jnp.concatenate([hi, lo], axis=0), v,
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        pv = pv[:R] + pv[R:]
-    else:
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha + pv
+    acc_ref[...] = acc_ref[...] * alpha + _pv(p, v, l_cur, **parts)
     m_ref[...] = m_new
 
 
-# name: what takes the place of (`pa._masked_scores`, `pa._fold_tile`)
-# inside the program's own kernel, whose copies, ring and walk stay
+def _halves(tiles, acc_ref, m_ref, l_ref, *, masks=True, **parts):
+    """`pa._fold_halves` with parts cut out."""
+    got = []
+    for s, v in tiles:
+        m = jnp.max(s, axis=1, keepdims=True)
+        p = jnp.exp(s - m)
+        if masks:
+            p = jnp.where(s <= NEG_INF, 0.0, p)
+        l_cur = jnp.sum(p, axis=1, keepdims=True)
+        got.append((m, l_cur, _pv(p, v, l_cur, **parts)))
+    m_new = m_ref[...]
+    for m, _, _ in got:
+        m_new = jnp.maximum(m_new, m)
+
+    def weight(m):
+        w = jnp.exp(m - m_new)
+        return jnp.where(m <= NEG_INF, 0.0, w) if masks else w
+
+    alpha = weight(m_ref[...])
+    l, acc = l_ref[...] * alpha, acc_ref[...] * alpha
+    for m, l_cur, pv in got:
+        w = weight(m)
+        l, acc = l + w * l_cur, acc + w * pv
+    l_ref[...], acc_ref[...], m_ref[...] = l, acc, m_new
+
+
+def _cut_parts(masks=True, split=True, qk=True, pv=True):
+    """-> the replacements of `pa._masked_scores`, `pa._fold_tile` and
+    `pa._fold_halves` that cut out the parts named False."""
+    fold = dict(masks=masks, split=split, product=pv)
+    return {"_masked_scores": functools.partial(_scores, masks=masks,
+                                                product=qk),
+            "_fold_tile": functools.partial(_fold, **fold),
+            "_fold_halves": functools.partial(_halves, **fold)}
+
+
+# name: what takes the place of functions of `pa` inside the program's
+# own kernel, whose copies, ring and walk stay; "a+b" applies both
 VARIANTS = {
-    "body": None,
-    "nomask": (functools.partial(_scores, masks=False),
-               functools.partial(_fold, masks=False)),
-    "nosplit": (_scores, functools.partial(_fold, split=False)),
-    "bare": (functools.partial(_scores, masks=False),
-             functools.partial(_fold, masks=False, split=False)),
+    "body": {},
+    # the fold before the halves: one score tile a fold, its
+    # chain serial (the latent call's copy-bound siblings still fold so)
+    "serial": {"_half_cut": lambda *_: None},
+    "nomask": _cut_parts(masks=False),
+    "nosplit": _cut_parts(split=False),
+    "bare": _cut_parts(masks=False, split=False),
     # the two products by parts (the rest as `body`)
-    "qkonly": (_scores, functools.partial(_fold, product=False)),
-    "pvonly": (functools.partial(_scores, product=False), _fold),
-    "noproducts": (functools.partial(_scores, product=False),
-                   functools.partial(_fold, product=False)),
+    "qkonly": _cut_parts(pv=False),
+    "pvonly": _cut_parts(qk=False),
+    "noproducts": _cut_parts(qk=False, pv=False),
 }
 
 
@@ -345,13 +390,14 @@ def main(argv=None):
         return pa.paged_decode_attention(
             q, k, v, t, p, first=f[0] if f else None, scale=scale)
 
-    rule = (pa._bytes_group, pa._RING, pa._rungs, pa._masked_scores,
-            pa._fold_tile)
+    rule = {n: getattr(pa, n) for n in (
+        "_bytes_group", "_RING", "_rungs", "_masked_scores", "_fold_tile",
+        "_fold_halves", "_half_cut")}
 
     def ladder(form, G):
         """The rungs a slot's last group folds under `form`."""
         if form == "rule":
-            return rule[2](G, Bt * hk, latent)
+            return rule["_rungs"](G, Bt * hk, latent)
         if form in ("two", "whole"):
             return ((max(1, G // 4),) if form == "two" else ()) + (G,)
         return tuple(range(int(form), G, int(form))) + (G,)
@@ -362,37 +408,50 @@ def main(argv=None):
     b0 = 0 if first is None else first // Bt
     blocks = pos // Bt + 1 - b0  # a slot's, from the block of `first`
     for g, r, sh, variant in sweep:
-        G = rule[0](Bt, maxb, block_bytes, latent) if g == "rule" else int(g)
+        G = (rule["_bytes_group"](Bt, maxb, block_bytes, latent)
+             if g == "rule" else int(g))
         rungs = ladder(sh, G)
         last = (blocks - 1) % G + 1
-        folded = int((blocks - last).sum()) + sum(
-            next(b for b in rungs if b >= n) for n in last.tolist())
+        folds = [G] * int(((blocks - last) // G).sum()) + [
+            next(b for b in rungs if b >= n) for n in last.tolist()]
+        patch = {}
+        for part in variant.split("+"):
+            patch.update(VARIANTS[part])
+        # the folds cut in two halves (`pa._fold_halves`): the latent
+        # call's (the program overlaps where a call is bound by its fold)
+        cut = patch.get("_half_cut", rule["_half_cut"])
+        overlapped = sum(cut(b, Bt * hk) is not None
+                         for b in folds) if latent else 0
         pa._bytes_group = lambda *_, G=G: G
-        pa._RING = rule[1] if r == "rule" else int(r)
+        pa._RING = rule["_RING"] if r == "rule" else int(r)
         pa._rungs = lambda *_, rungs=rungs: rungs
-        if VARIANTS[variant] is not None:
-            pa._masked_scores, pa._fold_tile = VARIANTS[variant]
+        for n, fn in patch.items():
+            setattr(pa, n, fn)
         pa._ring_call.clear_cache()  # a body traced with THIS form
         row = {"G": G, "by": g, "ring": pa._RING, "shorts": sh,
                "rungs": list(rungs), "variant": variant,
-               "steps": int((-(-blocks // G)).sum()), "blocks": need,
-               "folded": folded, "folded_per_named": folded / need,
+               "steps": len(folds), "overlapped": overlapped,
+               "blocks": need, "folded": sum(folds),
+               "folded_per_named": sum(folds) / need,
                "step_kv_bytes": G * block_bytes}
         try:
             kern, row["call_us"] = _measure(
                 call, args, a.calls, on_chip,
                 "%mla_decode_attention" if latent
                 else "%hybrid_decode_attention")
-            if variant == "body":  # the others answer nothing
+            if set(variant.split("+")) <= {"body", "serial"}:
+                # the others answer nothing
+                # a function of its own: `jax.jit(call)` would reuse
+                # the first row's trace, and check THAT row's form
                 row["max_error"] = _max_error(
-                    jax.jit(call)(*args), q, k_pool, v_pool, tables, pos,
-                    first, Bt, scale, v_lanes)
+                    jax.jit(lambda *xs: call(*xs))(*args), q, k_pool,
+                    v_pool, tables, pos, first, Bt, scale, v_lanes)
                 if not row["max_error"] < 0.02:  # bf16: an ulp at |out| < 4
                     raise SystemExit("the call is off its reference: %s"
                                      % json.dumps(row))
         finally:
-            (pa._bytes_group, pa._RING, pa._rungs, pa._masked_scores,
-             pa._fold_tile) = rule
+            for n, fn in rule.items():
+                setattr(pa, n, fn)
             pa._ring_call.clear_cache()
         if kern is not None:
             row.update(kernel_us=kern, us_per_step=kern / row["steps"],
