@@ -11,9 +11,9 @@ the digests).
 
   lm_embed      token (and position) lookup, the embedding multiplier
   lm_attention  every kind of attention layer: pre-norm, q/k/v(/gate)
-                products, rotary, the K/V write, the work-list
-                programs, the paged/window/cross kernel call, the
-                output product, the residual add
+                products, rotary, the K/V write, the paged/window/
+                cross kernel call, the output product, the residual
+                add
   lm_state      Mamba-1, Mamba-2 and the gated memory unit: pre-norm,
                 in-projection, conv, the state-update call, gate,
                 out-projection, the residual add

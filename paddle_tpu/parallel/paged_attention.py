@@ -14,48 +14,40 @@ table and positions as SCALAR-PREFETCH operands
 (PrefetchScalarGridSpec), so the pipeline DMAs each group's G K/V
 blocks [Bt, H, Dh] straight from the pool buffer into VMEM (G blocks
 per step so the score tile spans G*Bt >= 128 tokens — the reference
-pages_per_compute_block idea; the merged-pool decode call copies its
-blocks itself, see "What the merged-pool call's own copies are for"
-below) —
+pages_per_compute_block idea; the decode call copies its blocks
+itself, see "What the decode call's own copies are for" below) —
 the "gather" is the index map, and no
 HBM-resident contiguous view ever exists. Blockwise online softmax
 (running (max, sum, acc), the flash_attention.py discipline) keeps
 VMEM at one group of blocks plus the accumulators, regardless of
 context length.
 
-Three kernel bodies, chosen by the shape of the call and nothing else
-(ISSUEs 26 and 36):
+Two kernel bodies, chosen by the shape of the call and nothing else:
 
   * R >= 8 window rows (verify windows, prefill chunks) and every
     call on a quantized pool — `_pa_kernel`: a (slots, row-tiles,
     table-groups) grid, heads as a static loop, one `[R, W]` score
     tile a head. With R rows on the MXU's left the per-head product
     is the right shape.
-  * R == 1 (the decode step's one query a slot) on a 4-D pool (the
-    GPT block's) — `_pa_decode_kernel`: all heads at once in the
-    pool's native `[Bt, H, Dh]` tile, over a flat work list
-    (`_decode_worklist`) that holds a step only for a table group a
-    live context names, DMAs only the blocks it names and gives a
-    parked slot one empty step. On a v5e at 32 slots x 16 heads x
-    128, bf16, 16-token blocks the head-loop body took 0.76 ms a call
-    at 465-token contexts (0.46 ms at contexts of ONE token: 512 grid
-    steps whatever the contexts; a parked slot 58 us against a live
-    one's 24), this body 0.21 ms — 75 % of what the HBM allows
-    (PERF.md section 5, PR 26).
-  * R == 1 on a merged 3-D pool (the hybrid families' grouped
-    queries) — `_pa_ring_decode_kernel`: the same product and softmax
-    fold, but the call makes its own K/V copies. The pools stay in
-    HBM; a grid step is a SLOT, reads the slot's block ids from the
-    table itself and copies the blocks its context names, a group at
-    a time, into a VMEM ring of three groups, two groups ahead of the
-    one it folds.
+  * R == 1, the decode step's one query a slot, on an unquantized
+    pool — `_pa_ring_decode_kernel`: all of a slot's heads at once,
+    ONE product `q [H * rep, Dh] . K^T` against a group's rows as they
+    lie, (token, head), with the head match folded into the position
+    mask. The call makes its own K/V copies. The pools stay in HBM; a
+    grid step is a SLOT, reads the slot's block ids from the table
+    itself and copies the blocks its context names, a group at a time,
+    into a VMEM ring of three groups, two groups ahead of the one it
+    folds. A 4-D pool `[NB, Bt, H, Dh]` (the GPT block's) goes in as
+    the merged view `[NB, Bt * H, Dh]` it already is in memory, with
+    `rep` 1; the hybrid families' pools are merged already.
 
-What the merged-pool call's own copies are for (ISSUEs 32 and 36). In
+What the decode call's own copies are for. In
 the BlockSpec form a group of G blocks is 2G one-block operands of the
 pipeline — an index map, a changed-index compare, a descriptor and a
 wait for each, on the one instruction stream that also issues the
 products — and a work list `blk [G, N]` gathered through the tables so
-that the pipeline skips a block nobody names (~220 us of every step).
+that the pipeline skips a block nobody names (~220 us of every step at
+64 slots), and the pipeline drains at every slot.
 Measured on the v5e at the cells' geometry, 64 slots (`tools/
 time_decode_attention.py`; PERF.md section 6, PRs 32 and 36): a step
 of that form fits 0.30 us + 0.108 us a block of 64 KiB (K + V) at
@@ -67,7 +59,8 @@ of copies runs empty at every group: 0.55 us a group, 1,084 us a
 call), the products and the tile's work run under them, and a group's
 fold is 0.5 us + 0.064 us a block, hidden from ~29 blocks up — so a
 group is sized by BYTES (`_bytes_group`, `_STEP_BYTES`): 32 blocks of
-granite's and Trinity's 64 KiB, 8 of SambaY's 160 KiB; the latent
+granite's and Trinity's 64 KiB, 16 of the GPT pool's 128 KiB, 8 of
+SambaY's 160 KiB; the latent
 call, whose fold and not its copies binds it, 64 of its 40 KiB (1,427
 -> 1,288 us with the rungs below, measured on one v5e chip), and it
 cuts each fold in two halves whose chains overlap (`_fold_halves`:
@@ -77,15 +70,14 @@ cell's bytes):
 Trinity's full call over contexts of 1.5-7.2 k 1,191 -> 791 (712), its
 2,048-token window call 628 -> 414 (321), granite's call 874 -> 579
 (507), SambaY's shared pool 1,425 -> 1,378 (1,267), its 512-token
-window call 325 -> 255 (206); and no work list. A window's walk starts
+window call 325 -> 255 (206), the GPT block's call (32 slots over
+contexts of 300-1,300) 317 -> 295 (267, its copies alone 292); and no
+work list. A window's walk starts
 at the block of `first`, not at a group's edge, so its 65 blocks cost
 65 blocks' copies, and a slot's last group folds the first rung of
 whole score tiles that covers its blocks (`_rungs`: a quarter group or
 the whole one where the copies bind, which took Trinity's window call
-440 -> 414 us; a ladder of eighths where the fold does). The 4-D
-pool's call keeps the BlockSpec form: in the GPT pool 128 tokens are
-1 MiB a step, which hides its fixed
-part (82 % of the HBM roofline); moving it here is a later PR's.
+440 -> 414 us; a ladder of eighths where the fold does).
 
 Masking mirrors the gather primitives exactly: row r of a window based
 at `base` attends positions <= base + r, so unwritten depths — and the
@@ -181,15 +173,12 @@ def _pa_kernel(*args, Bt: int, R: int, G: int, scale: float,
     per-head slices and the f32 products are NOT free: with ONE row
     (the decode shape) a live grid step measured 2.4-2.6 us against
     1.3 us of DMA, and a dead one 0.9 us (PERF.md section 5, PR 26)
-    — why R == 1 on an unquantized pool has `_pa_decode_kernel`; with
-    R >= 8 rows the products amortise and this body stays.
+    — why R == 1 on an unquantized pool has `_pa_ring_decode_kernel`;
+    with R >= 8 rows the products amortise and this body stays.
     Re-probed for the decode body on JAX 0.9.0, compiling for a
-    described v5e: merging the leading dims of a `[Bt, H, Dh]` block
-    lowers for bf16 at H = 16, 12, 8 and f32 at H = 16, 4, Bt from 8
-    to 128; a 16-bit `[H, Dh] -> [H, 1, Dh]` shape cast does NOT
-    lower at Dh = 64 (the f32 one does, so the decode body casts
-    after it); a grid bound that is data lowers beside scalar
-    prefetch.
+    described v5e: a 16-bit `[H, Dh] -> [H, 1, Dh]` shape cast does
+    NOT lower at Dh = 64 (the f32 one does, so the ring body casts
+    after it).
 
     With `quant` (ISSUE 14) the pools hold int8/fp8 codes and two more
     SCALAR-PREFETCH operands carry the absmax scales of the blocks the
@@ -379,79 +368,6 @@ def _fold_halves(tiles, acc_ref, m_ref, l_ref):
     m_ref[...] = m_new
 
 
-def _pa_decode_kernel(blk_ref, pos_ref, wslot_ref, wgrp_ref, q_ref, *refs,
-                      Bt: int, G: int, span: int, scale: float):
-    """One step of the single-token decode call's flat work list (a
-    4-D pool's: the GPT block's): fold the G blocks of table group
-    `wgrp[i]` of slot `wslot[i]` into that slot's online-softmax
-    state, ALL heads at once, K and V left in the `[Bt, H, Dh]` tile
-    the DMA delivered.
-
-    With one query row a head, a per-head product puts ONE row on the
-    MXU's left and pays for it with a sublane gather of that head's
-    rows out of every block (`_pa_kernel`'s shape, right for R >= 8
-    rows). Here a group's K is the 2-D `[W*H, Dh]` it already is in
-    VMEM (merging the leading dims of a `[Bt, H, Dh]` tile moves
-    nothing when H fills whole sublane tiles) and ONE product
-    `q [H, Dh] . K^T -> [H, W*H]` scores every head against every
-    (token, head') row in the pool's own dtype with f32 accumulation;
-    column (t, h') of row h is kept only where h' == h — the head
-    match folded into the position mask, so the other fifteen
-    sixteenths are exact zeros in P and `P [H, W*H] . V [W*H, Dh]`
-    sums, per head, only its own rows. H times the model's FLOPs on
-    an MXU that is otherwise idle; no head loop, no slice, no
-    concatenate of slices, no f32 copy of a block. The state is one
-    `(m, l) [H, 1]` and one `acc [H, Dh]` scratch (`_fold_tile`).
-
-    The grid is the work list `_decode_worklist` built: only steps a
-    live context names (and one empty step for a parked slot, which
-    writes zeros), so `wgrp[i] * W <= pos` holds at every step of a
-    live slot and every row has an attended column — the NEG_INF
-    guards stay for the decode family's exactness contract (a masked
-    column contributes EXACTLY 0), not because a step can be empty."""
-    k_refs, v_refs = refs[:G], refs[G:2 * G]
-    o_ref, acc_ref, m_ref, l_ref = refs[2 * G:]
-    H, dh = q_ref.shape
-    W = G * Bt
-    i = pl.program_id(0)
-    si = wslot_ref[i]
-    b = wgrp_ref[i]
-    pos = pos_ref[si]
-    live = pos < span  # a parked row sits at or past the table's span
-
-    @pl.when(b == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(live)
-    def _accumulate():
-        k = jnp.concatenate([r[...].reshape(Bt * H, dh) for r in k_refs],
-                            axis=0)  # [W*H, Dh], rows (token, head)
-        v = jnp.concatenate([r[...].reshape(Bt * H, dh) for r in v_refs],
-                            axis=0)
-        s = jax.lax.dot_general(
-            q_ref[...].astype(k.dtype), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [H, W*H]; decode family: scale after the product
-        col = jax.lax.broadcasted_iota(jnp.int32, (H, W * H), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (H, W * H), 0)
-        # column c is (token c // H, head c % H): row h keeps its own
-        # head's columns at depths <= pos, i.e. c < (pos - b*W + 1)*H;
-        # everything else — other heads, unwritten depths, whatever a
-        # re-named or clamped block holds — contributes exactly 0
-        head = (col & (H - 1)) if H & (H - 1) == 0 else jax.lax.rem(col, H)
-        masked = (head != row) | (col >= (pos - b * W + 1) * H)
-        s = jnp.where(masked, NEG_INF, s)
-        _fold_tile(s, v, acc_ref, m_ref, l_ref)
-
-    @pl.when(b == jnp.where(live, pos // W, 0))  # the slot's last step
-    def _finalise():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = out[:, None, :].astype(o_ref.dtype)
-
-
 def _masked_scores(q, k, scale, rep: int, at, pos, first):
     """The merged-pool call's score tile: q [R, Dh] (row r belongs to
     K/V head r // rep) against a group's K [C, Dh] as it lies in the
@@ -459,7 +375,11 @@ def _masked_scores(q, k, scale, rep: int, at, pos, first):
     -> [R, C] float32, scaled after the product (the decode family),
     NEG_INF wherever column c = (token c // H, head c % H) is not row
     r's: another head's, or a position outside `first` <= . <= `pos`
-    (unwritten depths, a short group's stale rows)."""
+    (unwritten depths, a short group's stale rows). Scoring every row
+    against every head's rows is H times the model's FLOPs on an MXU
+    the copies leave idle, in place of a head loop that slices each
+    head's rows out of every block; the other heads' columns are exact
+    zeros in P, so `P . V` sums, per row, its own head's rows only."""
     R, C = q.shape[0], k.shape[0]
     H = R // rep
     s = jax.lax.dot_general(
@@ -540,9 +460,10 @@ def _pa_ring_decode_kernel(tbl_ref, pos_ref, *refs, Bt: int, G: int,
     stay in HBM, the kernel reads the slot's block ids from its table
     row in scalar memory and copies the blocks its context names — and
     no other — into a VMEM ring of `_RING` groups of G blocks, K and
-    V. A grid step folds its slot's groups one after another (the
-    same product, masks and `_fold_tile` as `_pa_decode_kernel`,
-    q `[H * rep, Dh]` with row r of K/V head r // rep); around each
+    V. A grid step folds its slot's groups one after another (ONE
+    product `q [H * rep, Dh] . K^T` a group, row r of K/V head
+    r // rep, `_masked_scores` keeping row r's own head's columns at
+    its attended depths, and `_fold_tile`); around each
     fold it first STARTS the copies of the group `_RING` - 1 ahead and
     afterwards WAITS for the next one's, so a group's products run
     under the later groups' copies and the copies of one group under
@@ -735,9 +656,8 @@ def _group(Bt: int, maxb: int) -> int:
     """Table entries per grid step, by TOKENS: enough for the per-head
     score tile [R, G*Bt] to fill the 128-lane dim, capped at the whole
     table for tiny configs (the score tile then equals the array dim,
-    which Mosaic also accepts). What every call on a 4-D pool takes;
-    in the GPT cells' pool 128 tokens are 1 MiB of K + V, which is
-    what lets the decode step's DMA set its length."""
+    which Mosaic also accepts). What `_pa_kernel` takes, and where
+    `_bytes_group` starts doubling."""
     return max(1, min(-(-128 // Bt), maxb))
 
 
@@ -754,7 +674,9 @@ def _group(Bt: int, maxb: int) -> int:
 # SambaY's shared pool (least 1,267) 1,445 at 640 KiB, 1,378 at
 # 1.25 MiB, 1,379 at 2.5 MiB; its 512-token window call (least 206)
 # 302, 255, 270: the copies bind it from 1.25 MiB on and a short
-# window loses past that. The latent call is bound by its fold and not
+# window loses past that; the GPT pool's (32 slots, least 267) 294 at
+# 1 MiB, 295 at 2 MiB, 297 at 4 MiB, its copies alone 292 at each.
+# The latent call is bound by its fold and not
 # by its copies (its one pool feeds both products): a group's fixed
 # part (~0.47 us) is never hidden, and fewer, larger groups take it
 # off — 128 slots over contexts of 1.5-7.2 k (least 809), groups of 8,
@@ -776,8 +698,9 @@ def _bytes_group(Bt: int, maxb: int, block_bytes: int,
     (`block_bytes` a block: its rows x width x 2 x the dtype's size)
     reach `_STEP_BYTES` — twice that where the call is `fold_bound`
     (the latent call) — never more than the table holds: 32 blocks of
-    granite's and Trinity's 64 KiB (2 MiB), 8 of SambaY's 160 KiB
-    (1.25 MiB), 64 of the latent pool's 40 KiB (2.5 MiB)."""
+    granite's and Trinity's 64 KiB (2 MiB), 16 of the GPT pool's
+    128 KiB (2 MiB), 8 of SambaY's 160 KiB (1.25 MiB), 64 of the latent
+    pool's 40 KiB (2.5 MiB)."""
     G, target = _group(Bt, maxb), _STEP_BYTES * (2 if fold_bound else 1)
     while G * block_bytes < target and 2 * G <= maxb:
         G *= 2
@@ -816,10 +739,12 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
     [S, MAXB], the row bases [S] and, on a quantized pool, the two
     flat scale rows [S, MAXB*H] — all padded to SMEM's (8, 128) word
     tiles (a 1-D operand counted as one such row of tiles, which is
-    on the safe side), MAXB first padded to a whole number of groups.
-    `block_bytes` (K + V of one block) marks a merged 3-D pool, whose
-    only kernel call is the decode call that reads the tables as they
-    are beside `pos` and a window layer's `first`."""
+    on the safe side), MAXB first padded to a whole number of
+    `_pa_kernel`'s groups (the decode call on an unquantized pool reads
+    the tables as they are, which is no more). `block_bytes` (K + V of
+    one block) marks a merged 3-D pool, whose only kernel call is the
+    decode call, which reads the tables beside `pos` and a window
+    layer's `first`."""
     if block_bytes is not None:
         need = _smem_padded(slots, maxb) + 2 * _smem_padded(1, slots)
     else:
@@ -828,13 +753,6 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
         need = _smem_padded(slots, mb) + _smem_padded(1, slots)
         if quant:
             need += 2 * _smem_padded(slots, mb * heads)
-        else:
-            # the decode call prefetches its work list in the tables'
-            # place: the block of every (operand, step) [G, S*NG] and
-            # two [S*NG] rows — more than the tables only where G < 8
-            steps = slots * mb // G
-            need = max(need, _smem_padded(G, steps) + _smem_padded(1, slots)
-                       + 2 * _smem_padded(1, steps))
     room = _SMEM_BYTES - _SMEM_RESERVE
     if need > room:
         raise ValueError(
@@ -847,133 +765,17 @@ def check_paged_smem(slots: int, maxb: int, block_tokens: int,
                need, room, _SMEM_BYTES, _SMEM_RESERVE))
 
 
-# the longest work list whose re-naming looks at every entry: the GPT
-# cells' 32 slots x 16 groups. At 64 slots x 16 groups of 16 blocks
-# (N = 1,024) that rule's [G, N, N] reduction took the list from 220
-# to 421 us a step on the v5e, at N = 512 and G = 32 from 220 to 403
-# (PERF.md section 6, PR 32)
-_LOOKBACK_FROM = 512
-
-
-def _decode_worklist(tables, pos, Bt: int, G: int, span: int):
-    """The 4-D pool's decode call's grid, as data: one entry for every
-    table group a live context names, slot after slot, and one empty
-    entry for a parked slot (`pos >= span`; its step computes nothing
-    and writes zeros). -> (blk [G, N], wslot [N], wgrp [N], n), N =
-    S * NG: entry i < n works on group `wgrp[i]` of slot `wslot[i]`,
-    and its g-th K/V operand holds pool block `blk[g, i]`.
-
-    `blk` is what keeps the DMA to the blocks the contexts name: where
-    entry i's group names no block for operand g (the tail of a
-    context's last group, a parked slot), it RE-NAMES the block that
-    operand named last, and the pipeline issues no copy for a block
-    index that did not change.
-    Nothing is read from a re-named block (the position mask). All of
-    it is integer compares and reductions on the tables and positions,
-    the widest a fused [G, N, N] masked max, and the same for every
-    layer of a step that shares a table, so the compiled step keeps
-    ONE copy: ~65 us of an 8 ms decode step at 32 slots x 16 groups on
-    the v5e (PERF.md section 5, PR 26). Past N = `_LOOKBACK_FROM` that
-    reduction is no longer small (64 slots x 16 groups of 16 blocks:
-    16.8 M compares, 200 us), and a rule that looks one entry back
-    takes its place: an operand its
-    group does not name keeps what the entry before it held if that
-    is the same slot's. Only a slot's first group can then copy a
-    block nobody reads, which long contexts make a rounding error.
-    (The merged-pool call needs none of this: its kernel reads the
-    tables themselves, `_pa_ring_decode_kernel`.)"""
-    S, mb = tables.shape
-    NG = mb // G
-    N = S * NG
-    W = G * Bt
-    live = pos < span
-    # a walk starts at group 0 (the zero stays in the arithmetic: the
-    # compiled GPT decode program is held to its text, digit for digit,
-    # by tests/test_tpu_aot_compile.py)
-    g0 = jnp.zeros_like(pos)
-    ng = jnp.where(live, pos // W - g0 + 1, 1)  # [S] steps each slot takes
-    ends = jnp.cumsum(ng)
-    i = jnp.arange(N, dtype=jnp.int32)
-    # plain compares and reductions, nothing materialised: a search
-    # (`searchsorted`) or a scan (`cummax`) is a loop of tiny programs
-    # on the TPU, ~80 us a call where this is a few fusions
-    past = ends[None, :] <= i[:, None]  # [N, S] slots wholly before i
-    wslot = jnp.minimum(jnp.sum(past, axis=1, dtype=jnp.int32), S - 1)
-    local = i - jnp.sum(jnp.where(past, ng[None, :], 0), axis=1)
-    wgrp = jnp.clip(g0[wslot] + local, 0, NG - 1)
-    depth = wgrp[None, :] * G + jnp.arange(G, dtype=jnp.int32)[:, None]
-    named = (live[wslot] & (i < ends[-1]))[None, :] \
-        & (depth * Bt <= pos[wslot][None, :])
-    entry = jnp.maximum(tables[wslot[None, :], depth], 0)  # -1 -> block 0
-    if N > _LOOKBACK_FROM:
-        prev = jnp.concatenate([entry[:, :1], entry[:, :-1]], axis=1)
-        blk = jnp.where(named | (local <= 0)[None, :], entry, prev)
-        return blk, wslot, wgrp, ends[-1]
-    # held[g, i]: the latest entry <= i at which operand g was named
-    held = jnp.max(jnp.where(
-        named[:, None, :] & (i[None, None, :] <= i[None, :, None]),
-        i[None, None, :], -1), axis=2)
-    # an operand not named yet holds whatever entry 0 gives it: one
-    # block copied once and never read
-    blk = jnp.take_along_axis(entry, jnp.maximum(held, 0), axis=1)
-    return blk, wslot, wgrp, ends[-1]
-
-
-def _paged_decode(q, k_pool, v_pool, tables, pos, *, G, span, name, scale,
-                  interpret):
-    """The R == 1 call on a 4-D pool: `_pa_decode_kernel` over
-    `_decode_worklist`'s grid, whose LENGTH is data too (a dynamic grid
-    bound: the steps the live contexts name, not slots x table groups).
-    q [S, 1, H, Dh] -> out [S, H, 1, Dh], the shape `_pa_kernel`
-    returns for R == 1 (and the one the benchmark's
-    `paged_attn_roofline` finds the kernel by); the squeezed block dims
-    hand the kernel q and K/V as dense [H, Dh] / [Bt, H, Dh] tiles."""
-    S, _, H, dh = q.shape
-    Bt = k_pool.shape[1]
-    blk, wslot, wgrp, n = _decode_worklist(tables, pos, Bt, G, span)
-
-    def _slot_map(i, blk, pos, wslot, wgrp):
-        return (wslot[i], 0, 0, 0)
-
-    def _kv_map(g):
-        def _map(i, blk, pos, wslot, wgrp):
-            return (blk[g, i], 0, 0, 0)
-        return _map
-
-    kernel = functools.partial(
-        _pa_decode_kernel, Bt=Bt, G=G, span=span, scale=scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n,),
-        in_specs=[pl.BlockSpec((None, None, H, dh), _slot_map)]
-        + [pl.BlockSpec((None, Bt, H, dh), _kv_map(g)) for g in range(G)]
-        + [pl.BlockSpec((None, Bt, H, dh), _kv_map(g)) for g in range(G)],
-        out_specs=pl.BlockSpec((None, H, 1, dh), _slot_map),
-        scratch_shapes=[pltpu.VMEM((H, dh), jnp.float32),
-                        pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, 1), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, 1, dh), q.dtype),
-        interpret=resolve_interpret(interpret),
-        name=name,
-        metadata={"kernel": name},
-    )(blk, pos, wslot, wgrp, q, *([k_pool] * G), *([v_pool] * G))
-
-
 def _merged_decode(q, k_pool, v_pool, tables, pos, first, *, scale,
-                   interpret, v_lanes=0):
+                   interpret, name, v_lanes=0):
     """The R == 1 call on a merged 3-D pool `[NB, Bt * Hk, Dh]`:
-    `_pa_ring_decode_kernel` over a grid of slots. q [S, Hk, rep, Dh]
-    -> out [S, Hk * rep, 1, Dh] (the shape the three hybrid roofline
-    metrics find the kernel by). The tables, `pos` and `first` go to
-    scalar memory as they are; the pools are handed over in HBM, one
-    operand each, and the kernel copies what the tables name, a group
-    of `_bytes_group` blocks at a time. With `v_pool` None the values
-    are the first `v_lanes` lanes of the one pool (the latent call,
-    named `mla_decode_attention`): out [S, Hk * rep, 1, v_lanes]."""
+    `_pa_ring_decode_kernel` over a grid of slots, its custom call
+    named `name`. q [S, Hk, rep, Dh] -> out [S, Hk * rep, 1, Dh] (the
+    shape the roofline metrics find the kernel by). The tables, `pos`
+    and `first` go to scalar memory as they are; the pools are handed
+    over in HBM, one operand each, and the kernel copies what the
+    tables name, a group of `_bytes_group` blocks at a time. With
+    `v_pool` None the values are the first `v_lanes` lanes of the one
+    pool (the latent call): out [S, Hk * rep, 1, v_lanes]."""
     rows = k_pool.shape[1]  # of a block: (token, head)
     fold_bound = v_pool is None  # one pool feeds both products
     G = _bytes_group(rows // q.shape[1], tables.shape[1],
@@ -982,14 +784,15 @@ def _merged_decode(q, k_pool, v_pool, tables, pos, first, *, scale,
     return _ring_call(q, k_pool, v_pool, tables, pos, first, G=G,
                       rungs=_rungs(G, rows, fold_bound), ring=_RING,
                       scale=scale,
-                      interpret=resolve_interpret(interpret),
+                      interpret=resolve_interpret(interpret), name=name,
                       v_lanes=v_lanes, overlap=fold_bound)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "G", "rungs", "ring", "scale", "interpret", "v_lanes", "overlap"))
+    "G", "rungs", "ring", "scale", "interpret", "name", "v_lanes",
+    "overlap"))
 def _ring_call(q, k_pool, v_pool, tables, pos, first, *, G, rungs, ring,
-               scale, interpret, v_lanes, overlap=False):
+               scale, interpret, name, v_lanes, overlap=False):
     """`_merged_decode`'s call, its group, rungs and ring given. Jitted,
     so that a program's layers of one geometry trace and lower ONE
     kernel, whose body holds a fold a rung: every process lowers its
@@ -1026,8 +829,6 @@ def _ring_call(q, k_pool, v_pool, tables, pos, first, *, G, rungs, ring,
            pltpu.SMEM((4,), jnp.int32),
            pltpu.SMEM((ring,), jnp.int32)],
     )
-    name = ("hybrid_decode_attention" if v_pool is not None
-            else "mla_decode_attention")
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -1072,8 +873,7 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
     scope. Pad rows compute masked garbage nothing reads; every real
     row's online-softmax state is row-independent, so real rows are
     BIT-identical to the unpadded, untiled math. R == 1 (the decode
-    shape) stays unpadded: on an unquantized pool it leaves here for
-    `_paged_decode`, sharing only the operand preparation above it."""
+    shape on a quantized pool) stays unpadded."""
     S, R, H, dh = q.shape
     NB, Bt = k_pool.shape[0], k_pool.shape[1]
     maxb = tables.shape[1]
@@ -1101,13 +901,6 @@ def _paged_attention(q, k_pool, v_pool, tables, base, *, name, scale,
     if pad:
         tables = jnp.concatenate(
             [tables, jnp.full((S, pad), -1, jnp.int32)], axis=1)
-    if R == 1 and not quant:
-        # the single-token decode shape has a body and a grid of its
-        # own; a quantized pool's scales ride `_pa_kernel`'s head loop
-        out = _paged_decode(q, k_pool, v_pool, tables, base, G=G,
-                            span=maxb * Bt, name=name, scale=scale,
-                            interpret=interpret)
-        return out.transpose(0, 2, 1, 3)
 
     # index maps take the scalar-prefetch refs after the grid indices:
     # (tbl, pos) unquantized, (tbl, pos, ksc, vsc) quantized — only
@@ -1172,9 +965,9 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     [S, MAXB] into pools [NB, Bt, H, Dh] -> out [S, H, Dh]. Mirrors
     `_cached_attention` over `_paged_view` (divide-after-matmul
     scaling, depths > pos excluded) without ever materialising the
-    view. A parked row (pos >= MAXB*Bt) costs one empty grid step and
-    returns zeros (on a quantized pool, `_pa_kernel`'s garbage, like
-    the gather path's) — nothing reads it either way.
+    view. A parked row (pos >= MAXB*Bt) returns zeros and costs no
+    copy (on a quantized pool, `_pa_kernel`'s garbage, like the gather
+    path's) — nothing reads it either way.
     `k_scale`/`v_scale` [NB, H] dequantize an int8/fp8 pool inside the
     kernel (ISSUE 14).
 
@@ -1191,24 +984,34 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     attends (a window layer's pos - window + 1; depths before it are
     masked and the blocks before its block neither copied nor walked);
     `scale` replaces 1 / sqrt(D) where D is not the head's width.
+    An unquantized 4-D pool is that call with `rep` 1: the
+    pools go in as their merged view `[NB, Bt * H, Dh]`, which is the
+    memory they already are (a bitcast, no copy), q as
+    `[S, H, 1, Dh]`, and the call keeps its name.
     This call copies its own K/V (`_pa_ring_decode_kernel`, ISSUE 36),
     a group of blocks at a time, the group sized by the bytes it moves
     and not by 128 tokens (`_bytes_group`, from the pool's block shape
-    and dtype: 32 blocks for granite's and Trinity's pools, 8 for
-    SambaY's; ISSUE 32). The number of columns an online-softmax step
-    folds is all that differs between two group sizes, so logits move
-    within float tolerance."""
-    if k_pool.ndim == 3:
+    and dtype: 32 blocks for granite's and Trinity's pools, 16 for the
+    GPT block's, 8 for SambaY's). The number of columns an
+    online-softmax step folds is all that differs between two group
+    sizes, so logits move within float tolerance."""
+    dh = q.shape[-1]
+    scale = 1.0 / math.sqrt(dh) if scale is None else scale
+    if k_pool.ndim == 4 and k_scale is None and v_scale is None:
+        NB, Bt, H = k_pool.shape[:3]
         out = _merged_decode(
-            q, k_pool, v_pool, tables, pos, first,
-            scale=1.0 / math.sqrt(q.shape[3]) if scale is None else scale,
-            interpret=interpret)
+            q.reshape(-1, H, 1, dh), k_pool.reshape(NB, Bt * H, dh),
+            v_pool.reshape(NB, Bt * H, dh), tables, pos, first,
+            scale=scale, interpret=interpret, name="paged_decode_attention")
         return out.reshape(q.shape)
-    S, H, dh = q.shape
+    if k_pool.ndim == 3:
+        out = _merged_decode(q, k_pool, v_pool, tables, pos, first,
+                             scale=scale, interpret=interpret,
+                             name="hybrid_decode_attention")
+        return out.reshape(q.shape)
     out = _paged_attention(
         q[:, None], k_pool, v_pool, tables, pos,
-        name="paged_decode_attention",
-        scale=1.0 / math.sqrt(dh), scale_in_q=False,
+        name="paged_decode_attention", scale=scale, scale_in_q=False,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale,
     )
     return out[:, 0]
@@ -1233,7 +1036,7 @@ def mla_decode_attention(q, pool, tables, pos, v_lanes, scale,
     S, H, W = q.shape
     out = _merged_decode(q.reshape(S, 1, H, W), pool, None, tables, pos,
                          None, scale=scale, interpret=interpret,
-                         v_lanes=v_lanes)
+                         name="mla_decode_attention", v_lanes=v_lanes)
     return out.reshape(S, H, v_lanes)
 
 

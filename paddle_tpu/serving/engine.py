@@ -618,8 +618,8 @@ class ServingEngine(object):
                 cfg.layers, cfg.heads, cfg.dim // cfg.heads, Bt, kv_quant,
                 act_itemsize=jnp.dtype(cfg.dtype).itemsize)
         if pk == "fused":
-            # the kernels keep every slot's block table or work list
-            # (and, on a quantized pool, the named blocks' scales) in
+            # the kernels keep every slot's block table (and, on a
+            # quantized pool, the named blocks' scales) in
             # scalar memory: a geometry that cannot fit is refused
             # HERE, with the arithmetic, not by the first step's
             # compile. A merged pool's decode call keeps the tables as

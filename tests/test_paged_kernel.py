@@ -105,13 +105,15 @@ def _assert_caches_equal(ca, cb, exact=True):
 
 
 # a geometry whose decode call walks SEVERAL table groups: 8-token
-# blocks group 16 to a grid step (W = 128 tokens), 48 table entries
-# make three groups (a 384-token span)
+# blocks group 16 to a grid step of the head-loop body (W = 128
+# tokens) and 32 to a group of the ring body (`_bytes_group`, capped
+# by the table), so 48 table entries make three groups of the one and
+# two of the other (a 384-token span)
 _BT, _MAXB = 8, 48
 _W, _SPAN = 128, _BT * _MAXB
-# positions on every edge the work list has: a context of one token,
-# one token short of a group, exactly one, one more, and the span's
-# last two
+# positions on the edges of the head-loop body's groups: a context of
+# one token, one token short of a group, exactly one, one more, and
+# the span's last two
 _EDGES = [0, _W - 2, _W - 1, _W, _SPAN - 2, _SPAN - 1, 200, 77]
 
 
@@ -160,9 +162,9 @@ def _quant_pool(cfg, NB, Bt, kv_quant, seed=0):
 @pytest.mark.parametrize("S", [3, 1, 8, 32])
 def test_fused_vs_gather_logits_decode(S, kv_quant):
     """S == 3 is the one-group geometry the suite began with. The
-    others run the decode call's own body and work list (an unquantized
-    pool) or the head-loop body (a quantized one) over three table
-    groups, contexts on every edge of a group and of the span, and —
+    others run the ring body (an unquantized pool) or the head-loop
+    body (a quantized one) over several table groups, contexts on
+    every edge of a group and of the span, and —
     from 8 rows up — two parked rows, whose garbage the gather form
     returns too and nothing reads: only LIVE rows are compared."""
     if S == 3:
@@ -257,10 +259,10 @@ def test_garbage_row_invariant_bit_identical_with_adapters(kernel, groups):
     at the same positions, adapters active, on BOTH kernel settings.
     Until now this invariant lived only in `_paged_view`'s docstring;
     the fused kernel must honor it too (its -1 clamp streams block 0's
-    garbage, which the position mask must erase EXACTLY). With three
-    table groups the decode call's work list never visits the groups
-    past a context, whatever their entries name, and re-names a block
-    for the unnamed tail of the last one it does visit."""
+    garbage, which the position mask must erase EXACTLY). With several
+    table groups the decode call copies no block past a context,
+    whatever the table's entries name, and a short last group's stale
+    ring rows are masked like any depth past `pos`."""
     if groups == 1:
         cfg, params = _mk(3)
         NB, Bt = 12, 8
@@ -302,8 +304,8 @@ def test_parked_rows_cost_the_live_rows_nothing(S, pool):
     """The decode call on a batch with parked rows (`pos` at the
     table's span, what the engine hands a dead slot) among the live
     ones: every LIVE row is bit-identical to the same rows called
-    without them, and a parked row's output is zeros — its work-list
-    entry computes nothing, whatever its table row names. The live
+    without them, and a parked row's output is zeros — nothing is
+    copied or folded for it, whatever its table row names. The live
     rows are held to plain softmax attention through the table at the
     pinned tolerance; on a 16-bit pool that bar is what P's hi + lo
     split is for (P rounded once to bf16 misses it by 30x)."""
@@ -595,6 +597,14 @@ def test_fused_engine_refuses_a_geometry_past_scalar_memory():
     msg = str(ei.value)
     assert "64 slots x 128 table entries" in msg
     assert "2 x 16 heads" in msg and "bytes" in msg
+    # the tables alone: 2,048 slots x 128 entries are 1 MiB of words,
+    # past what the core leaves the prefetch operands
+    with pytest.raises(ValueError) as ei:
+        ServingEngine(params, cfg, max_slots=2048, kv_block_tokens=16,
+                      paged_kernel="fused")
+    msg = str(ei.value)
+    assert "2048 slots x 128 table entries need" in msg
+    assert "heads" not in msg
     # the same geometry fits without the scales, and on the XLA form
     for kw in ({"paged_kernel": "fused"},
                {"paged_kernel": "gather", "kv_quant": "int8"}):
@@ -872,46 +882,73 @@ def test_only_a_call_bound_by_its_fold_cuts_it_in_halves(monkeypatch):
             + 2 * len(pa._rungs(64, 32, True)))
 
 
-@pytest.mark.parametrize("rule", ["every_entry", "look_back"])
-def test_worklist_names_the_tables_blocks_on_both_sides_of_its_switch(
-        rule, monkeypatch):
-    """`_decode_worklist` (the 4-D pool's decode call) at 64 slots,
-    groups of 16 blocks of 32 tokens, 16 groups a slot: N = 1,024, with
-    its switch put on either side of the list's length — the re-naming
-    that looks at every entry, and the one that looks one entry back,
-    which is the one N = 1,024 gets (the GPT cells' N = 512 keeps the
-    other): either way the list walks exactly the (slot, group) pairs
-    the live contexts name, slot after slot, and wherever a group names
-    a block its operand holds the table's; a parked slot takes one
-    entry. (The merged-pool call has no list: its walk is held by the
-    two tests below.)"""
+# the GPT block's 4-D pool on the ring body: its geometry
+# (16 heads of 128, 16-token blocks, bf16: 128 KiB of K + V a block, so
+# groups of 16 blocks = 256 tokens and rungs of 4), 48 table entries =
+# three groups
+_GPT_H, _GPT_DH, _GPT_BT, _GPT_MAXB = 16, 128, 16, 48
+_GPT_SPAN, _GPT_W = _GPT_BT * _GPT_MAXB, 16 * _GPT_BT
+# name: positions — a context of one token; contexts one short of, at
+# and one past the edge of a rung and of each group; the span's last
+# position beside a parked slot; contexts drawn at random
+_GPT_CASES = {
+    "pos_zero": [0],
+    "group_edges": [62, 63, 64, _GPT_W - 2, _GPT_W - 1, _GPT_W,
+                    2 * _GPT_W - 2, 2 * _GPT_W - 1, 2 * _GPT_W],
+    "span_end_beside_parked": [_GPT_SPAN - 1, _GPT_SPAN],
+    "random": np.random.default_rng(42).integers(
+        0, _GPT_SPAN, 6).tolist(),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GPT_CASES))
+def test_gpt_pool_decode_call_on_the_ring_is_the_gather_form(
+        case, monkeypatch):
+    """The decode call on a 4-D pool `[NB, Bt, H, Dh]` (the GPT
+    block's) is the ring body over the pool's merged view: against
+    `_cached_attention` over `_paged_view` (the gather form) at the
+    pinned tolerance, a parked slot zeros though its table row names
+    blocks, every block a live context names copied once as K and once
+    as V and no other, and a slot's last group folding the first rung
+    that covers it (4 blocks or the group's 16)."""
     from paddle_tpu.parallel import paged_attention as pa
 
-    slots, maxb, bt, G = 64, 256, 32, 16
-    assert 32 * (maxb // G) <= pa._LOOKBACK_FROM < slots * (maxb // G)
-    rng = np.random.default_rng(7)
-    span = maxb * bt
-    tables = rng.integers(0, 11264, (slots, maxb)).astype(np.int32)
-    pos = rng.integers(0, span, slots).astype(np.int32)
-    pos[:5] = [0, G * bt - 1, G * bt, span - 1, span]
-    N = slots * (maxb // G)
-    monkeypatch.setattr(pa, "_LOOKBACK_FROM",
-                        N if rule == "every_entry" else N - 1)
-    blk, wslot, wgrp, n = (np.asarray(a) for a in pa._decode_worklist(
-        jnp.asarray(tables), jnp.asarray(pos), bt, G, span))
-    assert blk.shape == (G, N)
-    live = pos < span
-    want = []
-    for s in range(slots):
-        last = pos[s] // (G * bt) if live[s] else 0
-        want += [(s, b) for b in range(last + 1)]
-    assert n == len(want) <= blk.shape[1]
-    assert list(zip(wslot[:n].tolist(), wgrp[:n].tolist())) == want
-    for i, (s, b) in enumerate(want):
-        for g in range(G):
-            depth = b * G + g
-            if live[s] and depth * bt <= pos[s]:
-                assert blk[g, i] == tables[s, depth]
+    H, dh, bt, maxb = _GPT_H, _GPT_DH, _GPT_BT, _GPT_MAXB
+    assert pa._bytes_group(bt, maxb, 2 * bt * H * dh * 2) == 16
+    assert pa._rungs(16, bt * H, False) == (4, 16)
+    pos = np.asarray(_GPT_CASES[case], np.int32)
+    S, live = len(pos), pos < _GPT_SPAN
+    rng = np.random.RandomState(S)
+    need = np.where(live, pos // bt + 1, 2)  # a parked row names 2
+    NB = int(need.sum()) + 2
+    order = rng.permutation(NB)
+    tables, at = np.full((S, maxb), -1, np.int32), 0
+    for s in range(S):
+        tables[s, :need[s]] = order[at:at + need[s]]
+        at += need[s]
+    k = jnp.asarray(rng.randn(NB, bt, H, dh), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(NB, bt, H, dh), jnp.bfloat16)
+    # a query the pool's dtype holds exactly: the kernel's cast of q to
+    # it loses nothing the gather form keeps
+    q = jnp.asarray(rng.randn(S, H, dh), jnp.bfloat16).astype(jnp.float32)
+    log = _watch_copies(monkeypatch)
+    got = pa.paged_decode_attention(q, k, v, jnp.asarray(tables),
+                                    jnp.asarray(pos), interpret=True)
+    got = np.asarray(jax.block_until_ready(got))
+    jax.effects_barrier()
+    want = np.asarray(T._cached_attention(
+        q, T._paged_view(k.astype(jnp.float32), jnp.asarray(tables)),
+        T._paged_view(v.astype(jnp.float32), jnp.asarray(tables)),
+        jnp.asarray(pos)))
+    assert got.shape == (S, H, dh)
+    np.testing.assert_allclose(got[live], want[live], rtol=_RTOL,
+                               atol=_ATOL)
+    np.testing.assert_array_equal(got[~live], 0.0)
+    tables[~live] = -1
+    _assert_copies_are_the_named_blocks(log, tables, 16, bt * H)
+    folded = sum(e[1] for e in log if e[0] == "fold") // (bt * H)
+    assert folded == sum(_folded(n, 16, (4, 16))
+                         for n in (pos // bt + 1)[live])
 
 
 # ---------------------------------------------------------------------

@@ -823,32 +823,6 @@ def test_grouped_decode_kernel_equals_its_oracle(windowed):
     assert np.abs(np.asarray(got)[live] - want[live]).max() < 1e-5
 
 
-def test_long_worklists_name_the_same_blocks_as_short_ones():
-    """Past 1,024 entries the work list re-names by looking one entry
-    back instead of at every entry: wherever a group names a block (the
-    only blocks a step reads), both rules give the table's."""
-    rng = np.random.default_rng(9)
-    S, maxb, bt, G = 40, 256, 4, 8  # 40 x 32 groups = 1,280 entries
-    tables = rng.integers(0, 5000, (S, maxb)).astype(np.int32)
-    pos = rng.integers(0, maxb * bt, S).astype(np.int32)
-    pos[3] = maxb * bt  # parked
-    blk, wslot, wgrp, n = pa._decode_worklist(
-        jnp.asarray(tables), jnp.asarray(pos), bt, G, maxb * bt)
-    blk, wslot, wgrp, n = (np.asarray(a) for a in (blk, wslot, wgrp, n))
-    live = pos < maxb * bt
-    assert n == np.where(live, pos // (G * bt) + 1, 1).sum()
-    seen = set()
-    for i in range(n):
-        s, b = wslot[i], wgrp[i]
-        seen.add((s, b))
-        for g in range(G):
-            depth = b * G + g
-            if live[s] and depth * bt <= pos[s]:
-                assert blk[g, i] == tables[s, depth]
-    assert seen == {(s, b) for s in range(S)
-                    for b in range(pos[s] // (G * bt) + 1 if live[s] else 1)}
-
-
 def test_state_update_kernel_equals_its_reference():
     rng = np.random.default_rng(3)
     S, N, di = 5, 16, 256

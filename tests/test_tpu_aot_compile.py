@@ -314,23 +314,25 @@ def test_both_depths_compile_the_same_decode_program(one_chip, as_on_tpu,
                      module + "(1)")
 
 
-# the GPT decode program as PR 31's tree compiles it (ISSUE 32 changed
-# the merged-pool caller of the decode kernel and nothing the GPT block
-# is handed): sha256 of `_decode_text(*_engine(...))` less locations
-# and less the kernels' embedded bodies (serialized with the line
-# numbers of paged_attention.py), and of the decode call's jaxpr at
-# the cells' geometry, which holds the kernel's body itself. A PR
-# that MEANS to change the GPT decode program replaces both (the
-# failing assertion prints the new one) and says so in CHANGES.md.
+# the GPT decode program as the tree compiles it, with the GPT block's
+# decode call on the ring body (its pools handed over as their merged
+# view, no work list beside the call):
+# sha256 of `_decode_text(*_engine(...))` less locations and less the
+# kernels' embedded bodies (serialized with the line numbers of
+# paged_attention.py), and of the decode call's jaxpr at the cells'
+# geometry, which holds the kernel's body itself. A PR that MEANS to
+# change the GPT decode program replaces both (the failing assertion
+# prints the new one) and says so in CHANGES.md.
 _GPT_DECODE_TEXT_SHA = (
-    "207dbcb3618c62203ec77a9cbb40da5535f5bbfde4b48f75749cdb0da5756e3e")
+    "1cece85e628d27469fb53240acbb5de2d2cc3786187c4e5b447d81437295d0aa")
 _GPT_DECODE_CALL_JAXPR_SHA = (
-    "8792e8e088ffe1e215a99c09a7b5fb41b0828c93d1986e4b645b8503d14b1553")
+    "63637e344ace670dfc39d6bb22114361751af2afc5ae2555f52be5ef8a48083b")
 
 
 def test_gpt_decode_program_is_the_text_the_parent_compiled(programs):
     # two kernels: the two layers' decode calls
-    assert _text_digest(programs("gpt").text) == (_GPT_DECODE_TEXT_SHA, 2)
+    text = programs("gpt").text
+    assert _text_digest(text) == (_GPT_DECODE_TEXT_SHA, 2)
     sh = jax.ShapeDtypeStruct
     pool = sh((3500, BT, H, DH), jnp.bfloat16)
     with jax.default_matmul_precision(None):  # the chip's own, as `_compile`
@@ -341,7 +343,21 @@ def test_gpt_decode_program_is_the_text_the_parent_compiled(programs):
             sh((32, MAXB), jnp.int32), sh((32,), jnp.int32)))
     assert hashlib.sha256(jaxpr.encode()).hexdigest() \
         == _GPT_DECODE_CALL_JAXPR_SHA
-
+    # the merged view of a pool is the memory the pool already is: each
+    # call takes its K and V as bitcasts of the cache, no instruction
+    # copies a pool, the temporaries stay under one pool, and the cache
+    # (two layers' K and V) is still aliased whole
+    one_pool = NB * BT * H * DH * 2
+    calls = re.findall(
+        r"%paged_decode_attention\S* = \S+ custom-call\(([^)]*)\)", text)
+    assert len(calls) == 2
+    for operands in calls:
+        k, v = operands.split(", ")[-2:]
+        assert k.startswith("%bitcast") and v.startswith("%bitcast")
+    assert not re.search(r"= bf16\[%d,[^=]* copy\(" % NB, text)
+    mem = programs("gpt").compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < one_pool
+    assert mem.alias_size_in_bytes >= 4 * one_pool
 
 
 def test_default_decode_program_is_what_the_benchmark_reads(programs):

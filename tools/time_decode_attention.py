@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Time the merged-pool decode attention call ALONE on the chip
+"""Time the paged decode attention call ALONE on the chip
 (no benchmark cell runs this). On one TPU chip:
 
     python3 tools/time_decode_attention.py --cell granite \\
@@ -8,11 +8,19 @@
     python3 tools/time_decode_attention.py --cell sambay --window 512
     python3 tools/time_decode_attention.py --cell kanana \\
         --context-lo 1551 --context-hi 7168 --shorts rule,two,whole
+    python3 tools/time_decode_attention.py --cell gpt \\
+        --context-lo 300 --context-hi 1300 --forms call,merged \\
+        --variants body,noproducts,copies
 
 One `hybrid_decode_attention` call (`parallel/paged_attention.py`) at a
 benchmark cell's geometry — or, `--cell kanana`, one
 `mla_decode_attention` call, the same ring body over ONE latent pool
-whose row is the key and whose first kv_rank lanes the value — the
+whose row is the key and whose first kv_rank lanes the value; or,
+`--cell gpt`, the GPT block's `paged_decode_attention` call on its
+4-D pool `[NB, Bt, H, Dh]` (`--forms call`, named
+`paged_decode_attention`) and the same pools handed over as their
+merged view with one query row a head (`--forms merged`, the
+`hybrid_decode_attention` call) — the
 head shapes from the configuration's `shape` group, slots, block size,
 pool blocks and positions from its `engine` group, each an argument
 here — over contexts drawn uniformly from --context-lo .. --context-hi
@@ -43,8 +51,14 @@ timing only: `nomask` drops the head/position masks and the NEG_INF
 halves, `bare` drops both; `qkonly`, `pvonly` and `noproducts` keep
 `body`'s tile work and drop one or both of the two matrix products
 (`noproducts`: what is left is the copies, the walk and the tile's
-element-wise work). `a+b` applies both: `serial+qkonly` is `qkonly`
-with the serial fold.
+element-wise work); `copies` keeps the copies and the walk alone (no
+score tile, no fold). `a+b` applies both: `serial+qkonly` is `qkonly`
+with the serial fold; `halves` cuts every fold in two overlapped halves
+(`_fold_halves`) on a call bound by its copies too, and answers like
+`body`. A variant replaces functions of the ring body:
+it does nothing to a call that another body serves, which is why each
+row names the tree whose `paddle_tpu` it timed (`tree`) — run from a
+checkout of an older tree, `--forms call` times that tree's GPT call.
 
 --tiny is a rehearsal on the CPU (kernels interpreted, wall clock
 only): its numbers are not device times and say so.
@@ -70,10 +84,17 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from lib import (costs_afmoe, costs_granite_hybrid, costs_mla_moe,  # noqa: E402
-                 costs_sambay, peaks)
+from lib import (costs, costs_afmoe, costs_granite_hybrid,  # noqa: E402
+                 costs_mla_moe, costs_sambay, peaks)
 from paddle_tpu.parallel import paged_attention as pa  # noqa: E402
 from paddle_tpu.parallel.kernel_utils import NEG_INF  # noqa: E402
+
+def _gpt_cost(shape, contexts, block_tokens):
+    """`paged_decode_attention_cost` (-> flops, bytes) as the others'
+    rows."""
+    return [(1,) + costs.paged_decode_attention_cost(shape, contexts,
+                                                     block_tokens)]
+
 
 CELLS = {
     # name: (configuration, cost function -> [(calls, flops, bytes)],
@@ -99,8 +120,12 @@ CELLS = {
                costs_mla_moe.mla_decode_attention_cost,
                lambda sh: (1, sh["heads"],
                            -(-(sh["kv_rank"] + sh["rope_dim"]) // 128) * 128)),
+    # the GPT block's 4-D pool: H heads of one query row each
+    "gpt": ("cerebras_gpt_1p3b", _gpt_cost,
+            lambda sh: (sh["heads"], 1, sh["dim"] // sh["heads"])),
 }
-TINY = {"granite": {"heads": 8, "kv_heads": 4, "head_dim": 8},
+TINY = {"gpt": {"heads": 4, "dim": 32},
+        "granite": {"heads": 8, "kv_heads": 4, "head_dim": 8},
         "sambay": {"heads": 8, "kv_heads": 4, "dim": 64},
         "trinity": {"heads": 8, "kv_heads": 2, "head_dim": 16},
         "kanana": {"heads": 8, "kv_rank": 64, "rope_dim": 32}}
@@ -196,6 +221,17 @@ def _cut_parts(masks=True, split=True, qk=True, pv=True):
             "_fold_halves": functools.partial(_halves, **fold)}
 
 
+def _with_halves(ring_call):
+    """`pa._ring_call` that cuts every fold of two 128-row score tiles
+    or more in two overlapped halves (`overlap`), as the call bound by
+    its fold does, whatever bounds this call."""
+    def call(*args, **kw):
+        return ring_call(*args, **dict(kw, overlap=True))
+
+    call.clear_cache = ring_call.clear_cache
+    return call
+
+
 # name: what takes the place of functions of `pa` inside the program's
 # own kernel, whose copies, ring and walk stay; "a+b" applies both
 VARIANTS = {
@@ -210,6 +246,13 @@ VARIANTS = {
     "qkonly": _cut_parts(pv=False),
     "pvonly": _cut_parts(qk=False),
     "noproducts": _cut_parts(qk=False, pv=False),
+    # the folds of a call bound by its fold (`_fold_halves`), on any call
+    "halves": {"_ring_call": _with_halves(pa._ring_call)},
+    # the copies and the walk: the score tile is zeros and no fold runs
+    "copies": {"_masked_scores": lambda q, k, *_: jnp.zeros(
+                   (q.shape[0], k.shape[0]), jnp.float32),
+               "_fold_tile": lambda *_: None,
+               "_fold_halves": lambda *_: None},
 }
 
 
@@ -291,6 +334,10 @@ def main(argv=None):
     ap.add_argument("--groups", default="rule",
                     help="comma list of blocks a grid step, or 'rule'")
     ap.add_argument("--variants", default="body")
+    ap.add_argument("--forms", default="call",
+                    help="--cell gpt: comma list of 'call' (the 4-D pool, "
+                         "as the GPT block calls it) and 'merged' (its "
+                         "merged view, the grouped-query call)")
     ap.add_argument("--shorts", default="rule",
                     help="comma list of the ladders a slot's last group "
                          "folds: 'rule' (the program's `_rungs`), 'two' (a "
@@ -330,7 +377,8 @@ def main(argv=None):
     latent = a.cell == "kanana"
     v_lanes = shape["kv_rank"] if latent else 0
     S = a.slots or (4 if a.tiny else eng["max_slots"])
-    Bt = a.block_tokens or (8 if a.tiny else eng["kv_block_tokens"])
+    # the GPT cell's engine keeps the engine's default 16-token blocks
+    Bt = a.block_tokens or (8 if a.tiny else eng.get("kv_block_tokens", 16))
     L = a.max_len or (512 if a.tiny else conf["max_len"])
     lo, hi = ((40, 400) if a.tiny else (a.context_lo, a.context_hi))
     maxb = L // Bt
@@ -371,7 +419,8 @@ def main(argv=None):
     least = (max(nbytes / pk["hbm_bytes_per_s"],
                  flops / pk["bf16_flops_per_s"]) * 1e6 if pk else None)
     block_bytes = (1 if latent else 2) * Bt * hk * D * dt.itemsize
-    head = {"cell": a.cell, "platform": dev.platform,
+    head = {"cell": a.cell, "tree": os.path.dirname(os.path.dirname(
+                os.path.abspath(pa.__file__))), "platform": dev.platform,
             "device_kind": dev.device_kind, "q": list(q.shape),
             "pool": list(k_pool.shape), "dtype": str(dt), "window": a.window,
             "contexts": [int(ctx.min()), float(ctx.mean()), int(ctx.max())],
@@ -380,19 +429,25 @@ def main(argv=None):
     print(json.dumps(head))
     out = [head]
     # the latent call's scale is the cell's, 1 / sqrt(nope + rope)
+    # (the GPT block's 1 / sqrt(head width), the hybrids' 0.125)
     scale = ((shape["nope_dim"] + shape["rope_dim"]) ** -0.5 if latent
-             else 0.125)
+             else D ** -0.5 if a.cell == "gpt" else 0.125)
 
-    def call(*xs):
+    def call(*xs, form="merged"):
         if latent:
             return pa.mla_decode_attention(*xs, v_lanes, scale)
         q, k, v, t, p, *f = xs
+        if form == "call":  # the GPT block's: q [S, H, Dh], 4-D pools
+            four = (NB, Bt, hk, D)
+            return pa.paged_decode_attention(
+                q.reshape(S, hk, D), k.reshape(four), v.reshape(four), t,
+                p).reshape(q.shape)
         return pa.paged_decode_attention(
             q, k, v, t, p, first=f[0] if f else None, scale=scale)
 
     rule = {n: getattr(pa, n) for n in (
         "_bytes_group", "_RING", "_rungs", "_masked_scores", "_fold_tile",
-        "_fold_halves", "_half_cut")}
+        "_fold_halves", "_half_cut", "_ring_call")}
 
     def ladder(form, G):
         """The rungs a slot's last group folds under `form`."""
@@ -402,12 +457,13 @@ def main(argv=None):
             return ((max(1, G // 4),) if form == "two" else ()) + (G,)
         return tuple(range(int(form), G, int(form))) + (G,)
 
-    sweep = [(g, r, sh, v) for g in a.groups.split(",")
+    forms = a.forms.split(",") if a.cell == "gpt" else ["merged"]
+    sweep = [(g, r, sh, v, fm) for g in a.groups.split(",")
              for r in a.rings.split(",") for sh in a.shorts.split(",")
-             for v in a.variants.split(",")]
+             for v in a.variants.split(",") for fm in forms]
     b0 = 0 if first is None else first // Bt
     blocks = pos // Bt + 1 - b0  # a slot's, from the block of `first`
-    for g, r, sh, variant in sweep:
+    for g, r, sh, variant, form in sweep:
         G = (rule["_bytes_group"](Bt, maxb, block_bytes, latent)
              if g == "rule" else int(g))
         rungs = ladder(sh, G)
@@ -418,10 +474,11 @@ def main(argv=None):
         for part in variant.split("+"):
             patch.update(VARIANTS[part])
         # the folds cut in two halves (`pa._fold_halves`): the latent
-        # call's (the program overlaps where a call is bound by its fold)
+        # call's (the program overlaps where a call is bound by its
+        # fold), or any call's under `halves`
         cut = patch.get("_half_cut", rule["_half_cut"])
-        overlapped = sum(cut(b, Bt * hk) is not None
-                         for b in folds) if latent else 0
+        overlapped = sum(cut(b, Bt * hk) is not None for b in folds) \
+            if latent or "_ring_call" in patch else 0
         pa._bytes_group = lambda *_, G=G: G
         pa._RING = rule["_RING"] if r == "rule" else int(r)
         pa._rungs = lambda *_, rungs=rungs: rungs
@@ -429,22 +486,24 @@ def main(argv=None):
             setattr(pa, n, fn)
         pa._ring_call.clear_cache()  # a body traced with THIS form
         row = {"G": G, "by": g, "ring": pa._RING, "shorts": sh,
-               "rungs": list(rungs), "variant": variant,
+               "rungs": list(rungs), "variant": variant, "form": form,
                "steps": len(folds), "overlapped": overlapped,
                "blocks": need, "folded": sum(folds),
                "folded_per_named": sum(folds) / need,
                "step_kv_bytes": G * block_bytes}
         try:
+            timed = functools.partial(call, form=form)
             kern, row["call_us"] = _measure(
-                call, args, a.calls, on_chip,
+                timed, args, a.calls, on_chip,
                 "%mla_decode_attention" if latent
+                else "%paged_decode_attention" if form == "call"
                 else "%hybrid_decode_attention")
-            if set(variant.split("+")) <= {"body", "serial"}:
+            if set(variant.split("+")) <= {"body", "serial", "halves"}:
                 # the others answer nothing
                 # a function of its own: `jax.jit(call)` would reuse
                 # the first row's trace, and check THAT row's form
                 row["max_error"] = _max_error(
-                    jax.jit(lambda *xs: call(*xs))(*args), q, k_pool,
+                    jax.jit(lambda *xs: timed(*xs))(*args), q, k_pool,
                     v_pool, tables, pos, first, Bt, scale, v_lanes)
                 if not row["max_error"] < 0.02:  # bf16: an ulp at |out| < 4
                     raise SystemExit("the call is off its reference: %s"
