@@ -61,6 +61,10 @@ __all__ = ["lint_file", "lint_paths", "HOT_PATHS"]
 # hot path in its own right.
 HOT_PATHS = [
     "paddle_tpu/models/transformer.py",
+    # the parts every serving model family's compiled steps share
+    # (the paged write rows, the merged pools' scatters and views, the
+    # chunk attention, the expert layer)
+    "paddle_tpu/models/parts.py",
     # the fused paged-attention kernels (ISSUE 13): everything in the
     # module body runs at trace time inside the compiled serving steps
     "paddle_tpu/parallel/paged_attention.py",

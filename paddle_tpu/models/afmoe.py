@@ -26,8 +26,8 @@ head. With d the width and eps 1e-5, no bias on any matrix:
              (`parallel/routed_experts.py`: the router in float32 from
              the float32 normed row)
 
-One stack (`_stack`) runs every mode; a mode is the `attn` it hands
-the stack, as `sambay._stack` and `granite_hybrid._stack` are:
+One stack (`parts.expert_stack`) runs every mode; a mode is the
+`attn` it hands the stack:
 
   forward               whole sequence, no cache (the cached modes'
                         oracle)
@@ -65,16 +65,15 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.routed_experts import held_range
-from .granite_hybrid import _chunk_attend, _rms32, _view
-from .sambay import _mlp, _scatter_chunk, _scatter_rows
+from .parts import (NEG_INF, Seam, chunk_attend, expert_stack, head_view,
+                    init_tree, kv_pool, paged_kernel_check, rms32,
+                    scatter_chunk, scatter_rows, untied_head)
 from .scopes import scope
-from .transformer import _paged_kernel_check
 
-__all__ = ["AfmoeConfig", "init_params", "param_shapes", "param_count",
-           "forward", "moe_ffn", "init_cache", "cache_bytes",
-           "paged_decode_step", "paged_prefill_chunk", "SERVING"]
+__all__ = ["AfmoeConfig", "init_params", "param_shapes", "forward",
+           "init_cache", "cache_bytes", "paged_decode_step",
+           "paged_prefill_chunk", "SERVING"]
 
-_NEG = -1e30  # finite mask fill (parallel/kernel_utils.NEG_INF)
 _KINDS = {"sliding_attention": "window", "full_attention": "full"}
 
 
@@ -109,7 +108,7 @@ class AfmoeConfig:
         self.route_scale = float(route_scale)
         self.route_norm = bool(route_norm)
         self.window, self.rope_theta = int(window), float(rope_theta)
-        # the scores' scale, under the name `granite_hybrid._chunk_attend`
+        # the scores' scale, under the name `parts.chunk_attend`
         # reads it by
         self.attention_multiplier = 1.0 / math.sqrt(head_dim)
         self.eps, self.max_len, self.dtype = eps, max_len, dtype
@@ -138,24 +137,14 @@ def param_shapes(cfg: AfmoeConfig):
                        for l in range(cfg.layers)]}
 
 
-def param_count(cfg: AfmoeConfig) -> int:
-    """Parameters of the tree `init_params` makes, from shapes alone."""
-    return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
-
-
 def init_params(cfg: AfmoeConfig, key) -> Dict[str, Any]:
     """Seeded random weights in `cfg.dtype`: matrices N(0, 1 / the
     contraction's length) (so the embedding's rows come out of the
     sqrt(d) multiplier at unit scale), norm gains 1 + N(0, 0.1), the
     router's bias N(0, 0.05): small against the scores' spread, large
     enough that the choice and the weights differ."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-
-    def leaf(i, path, shp):
-        n = jax.random.normal(jax.random.fold_in(key, i), shp, jnp.float32)
-        name = str(getattr(path[-1], "key", "w"))
+    def leaf(k, name, shp):
+        n = jax.random.normal(k, shp, jnp.float32)
         if name.endswith("norm") or name.startswith("norm"):
             return 1.0 + 0.1 * n
         if name == "router_bias":
@@ -163,9 +152,7 @@ def init_params(cfg: AfmoeConfig, key) -> Dict[str, Any]:
         rows = shp[-1] if name in ("embed", "head") else shp[-2]
         return n / math.sqrt(rows)
 
-    return jax.tree_util.tree_unflatten(treedef, [
-        leaf(i, path, shp).astype(cfg.dtype)
-        for i, (path, shp) in enumerate(flat)])
+    return init_tree(param_shapes(cfg), key, cfg.dtype, leaf)
 
 
 # ---------------------------------------------------------------------
@@ -193,10 +180,10 @@ def _qkvg(h, p, pos, kind, cfg):
     y = h @ p["wqkvg"]
     nq, nk = cfg.heads * cfg.dh, cfg.kv_heads * cfg.dh
     lead = h.shape[:-1]
-    q = _rms32(y[..., :nq].reshape(lead + (cfg.kv_heads, cfg.rep, cfg.dh)),
-               p["q_norm"], cfg.eps)
-    k = _rms32(y[..., nq:nq + nk].reshape(lead + (cfg.kv_heads, cfg.dh)),
-               p["k_norm"], cfg.eps)
+    q = rms32(y[..., :nq].reshape(lead + (cfg.kv_heads, cfg.rep, cfg.dh)),
+              p["q_norm"], cfg.eps)
+    k = rms32(y[..., nq:nq + nk].reshape(lead + (cfg.kv_heads, cfg.dh)),
+              p["k_norm"], cfg.eps)
     if kind == "window":
         q = _rope(q, pos[..., None, None], cfg.rope_theta)
         k = _rope(k, pos[..., None], cfg.rope_theta)
@@ -220,70 +207,16 @@ def _attend(q, k, v, qpos, kpos, window, cfg):
     ok = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
     if window:
         ok = ok & (kpos[None, :] > qpos[:, None] - window)
-    prob = jax.nn.softmax(jnp.where(ok[None, None], s, _NEG), axis=-1)
+    prob = jax.nn.softmax(jnp.where(ok[None, None], s, NEG_INF), axis=-1)
     o = jnp.einsum("hrqk,khd->qhrd", prob.astype(v.dtype), v,
                    preferred_element_type=f32).astype(q.dtype)
     return o.reshape(o.shape[0], -1)
-
-
-def moe_ffn(u32, p, cfg: AfmoeConfig, valid, kernel="gather"):
-    """An expert layer over the float32 normed rows u32 [N, d] ->
-    (float32 [N, d], stats int32 [2]): the router, the experts this
-    chip holds, and the shared expert if it is held here. A row's
-    result depends on that row alone."""
-    from ..parallel.routed_experts import expert_ffn, route
-
-    u = u32.astype(cfg.dtype)
-    idx, w = route(u32, p["router"], p["router_bias"], cfg.top_k,
-                   cfg.route_scale, cfg.route_norm)
-    out, stats = expert_ffn(u, idx, w, p["experts"], valid,
-                            held=cfg.experts_held, kernel=kernel)
-    if cfg.shared_expert_held:
-        out = out + _mlp(u, p["shared"]).astype(jnp.float32)
-    return out, stats
-
-
-def _stack(params, x, cfg, attn, valid, kernel="gather"):
-    """Every layer in its residual form, each part under its device
-    scope (`scopes.py`: a branch's four norms and its residual add
-    with the branch; an expert layer, router to combine and the shared
-    expert, is `lm_experts`, a leading dense layer `lm_mlp`);
-    `attn(kind, h, p)` is the mode's (it owns whatever cache the mode
-    has). `valid` [rows]: the rows that reach experts. -> (the final
-    norm's output, the expert layers' stats summed / maxed: int32
-    [2])."""
-    dt, eps = x.dtype, cfg.eps
-    hit, fullest = jnp.int32(0), jnp.int32(0)
-    for l, (blk, kind) in enumerate(zip(params["blocks"], cfg.kinds)):
-        with scope("lm_attention"):
-            a = attn(kind, _rms32(x, blk["norm1"], eps).astype(dt),
-                     blk["attn"])
-            x = x + _rms32(a, blk["norm2"], eps).astype(dt)
-        dense = l < cfg.num_dense_layers
-        with scope("lm_mlp" if dense else "lm_experts"):
-            u32 = _rms32(x, blk["norm3"], eps)
-            if dense:
-                m = _mlp(u32.astype(dt), blk["ffn"])
-            else:
-                m, stats = moe_ffn(u32, blk["ffn"], cfg, valid, kernel)
-                hit = hit + stats[0]
-                fullest = jnp.maximum(fullest, stats[1])
-            x = x + _rms32(m, blk["norm4"], eps).astype(dt)
-    with scope("lm_head"):
-        x = _rms32(x, params["norm_f"], eps).astype(dt)
-    return x, jnp.stack([hit, fullest])
 
 
 def _embed(params, tokens, cfg):
     with scope("lm_embed"):
         return params["embed"][tokens] * jnp.asarray(math.sqrt(cfg.dim),
                                                      cfg.dtype)
-
-
-def _head(params, x):
-    with scope("lm_head"):
-        return jnp.matmul(x, params["head"].T,
-                          preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------
@@ -296,15 +229,17 @@ def forward(params, tokens, cfg: AfmoeConfig):
     once, no cache, no kernel."""
     pos = jnp.arange(tokens.shape[0])
 
-    def attn(kind, h, p):
+    def attn(l, h, p):
+        kind = cfg.kinds[l]
         q, k, v, gate = _qkvg(h, p, pos, kind, cfg)
         o = _attend(q, k, v, pos, pos,
                     cfg.window if kind == "window" else 0, cfg)
         return _attn_out(o, gate, p)
 
-    x, _ = _stack(params, _embed(params, tokens, cfg), cfg, attn,
-                  jnp.ones(tokens.shape, bool))
-    return _head(params, x)
+    x, _ = expert_stack(params, _embed(params, tokens, cfg), cfg, attn,
+                        jnp.ones(tokens.shape, bool), "gather",
+                        sandwich=True)
+    return untied_head(params, x)
 
 
 # ---------------------------------------------------------------------
@@ -315,17 +250,10 @@ def forward(params, tokens, cfg: AfmoeConfig):
 def init_cache(cfg: AfmoeConfig, num_blocks: int, block_tokens: int,
                window_blocks: int):
     rows = int(block_tokens) * cfg.groups
-
-    def pool(nb):
-        # one block more than the allocator hands out: where the fused
-        # decode write sends a parked slot's rows (paged_kv_write)
-        shape = (int(nb) + 1, rows, cfg.dh)
-        return {"k": jnp.zeros(shape, cfg.dtype),
-                "v": jnp.zeros(shape, cfg.dtype)}
-
-    return {"full": [pool(num_blocks) for k in cfg.kinds if k == "full"],
-            "window": [pool(window_blocks) for k in cfg.kinds
-                       if k == "window"]}
+    return {"full": [kv_pool(num_blocks, rows, cfg.dh, cfg.dtype)
+                     for k in cfg.kinds if k == "full"],
+            "window": [kv_pool(window_blocks, rows, cfg.dh, cfg.dtype)
+                       for k in cfg.kinds if k == "window"]}
 
 
 def cache_bytes(cfg: AfmoeConfig, block_tokens: int) -> Dict[str, int]:
@@ -360,7 +288,7 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: AfmoeConfig,
     from ..parallel.paged_attention import (paged_decode_attention,
                                             paged_kv_write)
 
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     ftab, wtab = tables[0], tables[1]
     S, maxb = ftab.shape
     Bt = cache["window"][0]["k"].shape[1] // cfg.groups
@@ -369,7 +297,8 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: AfmoeConfig,
     new = {"full": [], "window": []}
     it = {"full": iter(cache["full"]), "window": iter(cache["window"])}
 
-    def attn(kind, h, p):
+    def attn(l, h, p):
+        kind = cfg.kinds[l]
         kv = next(it[kind])
         tab = wtab if kind == "window" else ftab
         w = cfg.window if kind == "window" else 0
@@ -381,20 +310,20 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: AfmoeConfig,
                 q, kv["k"], kv["v"], tab, pos, first=first if w else None)
             o = o.reshape(S, -1)
         else:
-            kv = {"k": _scatter_rows(kv["k"], tab, pos, k, cfg, Bt),
-                  "v": _scatter_rows(kv["v"], tab, pos, v, cfg, Bt)}
+            kv = {"k": scatter_rows(kv["k"], tab, pos, k, cfg, Bt),
+                  "v": scatter_rows(kv["v"], tab, pos, v, cfg, Bt)}
             kpos = jnp.arange(maxb * Bt)
             o = jax.vmap(
                 lambda q1, k1, v1, p1: _attend(q1[None], k1, v1, p1[None],
                                                kpos, w, cfg)[0]
-            )(q, _view(kv["k"], tab, cfg, Bt), _view(kv["v"], tab, cfg, Bt),
-              pos)
+            )(q, head_view(kv["k"], tab, cfg, Bt),
+              head_view(kv["v"], tab, cfg, Bt), pos)
         new[kind].append(kv)
         return _attn_out(o, gate, p)
 
-    x, stats = _stack(params, _embed(params, token, cfg), cfg, attn, live,
-                      kernel)
-    return _head(params, x), new, stats
+    x, stats = expert_stack(params, _embed(params, token, cfg), cfg, attn,
+                            live, kernel, sandwich=True)
+    return untied_head(params, x), new, stats
 
 
 # ---------------------------------------------------------------------
@@ -410,7 +339,7 @@ def _band_attend(q, k, v, start_pos, cfg):
     i .. i + tile + W of that array and no others, a key tile at a
     time with the running (max, sum, acc) of an online softmax (one
     softmax over the whole span takes the contraction over dh off the
-    matrix unit: `granite_hybrid._chunk_attend`). Every bound is
+    matrix unit: `parts.chunk_attend`). Every bound is
     static."""
     f32 = jnp.float32
     C, Hk, rep, dh = q.shape
@@ -424,7 +353,7 @@ def _band_attend(q, k, v, start_pos, cfg):
     for i in range(0, C, tile):
         qh = q[i:i + tile].transpose(1, 2, 0, 3).reshape(Hk, rep * tile, dh)
         row = jnp.tile(i + jnp.arange(tile), rep)  # the query's chunk row
-        m = jnp.full((Hk, rep * tile, 1), _NEG, f32)
+        m = jnp.full((Hk, rep * tile, 1), NEG_INF, f32)
         l = jnp.zeros_like(m)
         acc = jnp.zeros((Hk, rep * tile, dh), f32)
         for j in range(i, i + span, tile):
@@ -434,7 +363,7 @@ def _band_attend(q, k, v, start_pos, cfg):
             n = j + jnp.arange(tile)
             ok = ((n[None, :] <= row[:, None] + W) & (n[None, :] > row[:, None])
                   & (n[None, :] + start_pos >= W))
-            s = jnp.where(ok[None], s, _NEG)
+            s = jnp.where(ok[None], s, NEG_INF)
             m_new = jnp.maximum(m, s.max(-1, keepdims=True))
             # a tile with no visible key leaves (m, l, acc) as they were
             p = jnp.where(ok[None], jnp.exp(s - m_new), 0.0)
@@ -463,10 +392,10 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
 
     The attention is XLA in either `kernel`: a full layer attends the
     slot's span gathered through the table after the chunk's own rows
-    are written (`granite_hybrid._chunk_attend`); a window layer its
+    are written (`parts.chunk_attend`); a window layer its
     own rows and the window behind the chunk (`_band_attend`). The
     experts' grouped products are the decode step's."""
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     (C,) = chunk.shape
     ftab, wread, wwrite = table_rows[0], table_rows[1], table_rows[2]
     maxb = ftab.shape[0]
@@ -493,7 +422,8 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
     def nothing(pool):
         return jnp.zeros((W, cfg.groups, cfg.dh), pool.dtype)
 
-    def attn(kind, h, p):
+    def attn(l, h, p):
+        kind = cfg.kinds[l]
         kv = next(it[kind])
         q, k, v, gate = _qkvg(h, p, positions, kind, cfg)
         if kind == "window":
@@ -505,66 +435,41 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
             tab = wwrite
         else:
             tab = ftab
-        kv = {"k": _scatter_chunk(kv["k"], tab, start_pos, wpos, true_len,
-                                  k, cfg, Bt),
-              "v": _scatter_chunk(kv["v"], tab, start_pos, wpos, true_len,
-                                  v, cfg, Bt)}
+        kv = {"k": scatter_chunk(kv["k"], tab, start_pos, wpos, true_len,
+                                 k, cfg, Bt),
+              "v": scatter_chunk(kv["v"], tab, start_pos, wpos, true_len,
+                                 v, cfg, Bt)}
         new[kind].append(kv)
         if kind == "full":
-            o = _chunk_attend(q, _view(kv["k"], tab, cfg, Bt),
-                              _view(kv["v"], tab, cfg, Bt), start_pos, cfg)
+            o = chunk_attend(q, head_view(kv["k"], tab, cfg, Bt),
+                             head_view(kv["v"], tab, cfg, Bt), start_pos, cfg)
         return _attn_out(o, gate, p)
 
-    x, _ = _stack(params, _embed(params, chunk, cfg), cfg, attn, valid,
-                  kernel)
+    x, _ = expert_stack(params, _embed(params, chunk, cfg), cfg, attn,
+                        valid, kernel, sandwich=True)
     with scope("lm_head"):
         xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0,
                                           keepdims=False)
-    return _head(params, xl), new
+    return untied_head(params, xl), new
 
 
-class _Serving(object):
-    """What ServingEngine asks a model family for (the seam
-    `models/transformer.py`, `models/sambay.py` and
-    `models/granite_hybrid.py` fill too). This family's caches are the
-    full layers' pools on the engine's one table and the window
-    layers' pools on a window table whose blocks are freed behind the
-    window: no recurrent state, so no slot is reset at admission. A
-    freed window block cannot be aliased, re-played or handed on, so
-    what re-uses cached blocks is refused by name, and the rest is not
-    built for the family. The decode step hands the engine its
-    `step_counters` beside the logits; they ride the step's one packed
-    result."""
+class _Serving(Seam):
+    """The full layers' pools on the engine's one table, the window
+    layers' on a window table freed behind the window; no state, so no
+    slot is reset at admission."""
     name = "afmoe"
     caches = ("paged", "window")
-    refused = ("prefix_cache_tokens", "kv_store", "spec_draft_len",
-               "kv_quant", "weight_quant", "adapter_registry",
-               "kv_fingerprints")
     refusal = ("its window layers free the blocks behind the window, "
                "which a cached prefix, a stored or handed-on block or a "
                "re-played draft would still name; quantization, adapters "
                "and fingerprints are not built for it")
     step_counters = ("moe_experts_hit", "moe_rows_max")
     cache_bytes = staticmethod(cache_bytes)
-
-    # the engine hands every family the same keywords; the ones this
-    # family refuses at construction arrive here as their defaults
-    @staticmethod
-    def decode_step(params, token, pos, tables, cache, cfg, adapters=None,
-                    adapter_idx=None, kernel="gather", kv_quant="none"):
-        return paged_decode_step(params, token, pos, tables, cache, cfg,
-                                 kernel=kernel)
+    decode = staticmethod(paged_decode_step)
+    prefill = staticmethod(paged_prefill_chunk)
 
     @staticmethod
-    def prefill_chunk(params, cache, chunk, start_pos, table_rows, cfg,
-                      true_len=None, adapters=None, adapter_idx=None,
-                      kernel="gather", kv_quant="none"):
-        return paged_prefill_chunk(params, cache, chunk, start_pos,
-                                   table_rows, cfg, true_len=true_len,
-                                   kernel=kernel)
-
-    @staticmethod
-    def init_cache(cfg, num_blocks, block_tokens, slots, kv_quant="none"):
+    def cache(cfg, num_blocks, block_tokens, slots):
         per_slot = -(-cfg.window // int(block_tokens)) + 1
         return init_cache(cfg, num_blocks, block_tokens, slots * per_slot)
 

@@ -83,16 +83,15 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.routed_experts import held_range
-from .sambay import _conv, _mlp, _pool_view, _scatter_chunk, _scatter_rows
+from .parts import (NEG_INF, Seam, chunk_attend, conv, head_view, init_tree,
+                    kv_pool, mlp, paged_kernel_check, reset_slot_state,
+                    rms32, scatter_chunk, scatter_rows)
 from .scopes import scope
-from .transformer import _paged_kernel_check
 
 __all__ = ["GraniteHybridConfig", "init_params", "param_shapes", "forward",
            "init_cache", "cache_bytes", "paged_decode_step",
-           "paged_prefill_chunk", "reset_slot_state", "param_count",
+           "paged_prefill_chunk", "reset_slot_state",
            "SERVING", "SERVING_EXPERTS"]
-
-_NEG = -1e30  # finite mask fill (parallel/kernel_utils.NEG_INF)
 
 
 class GraniteHybridConfig:
@@ -182,12 +181,6 @@ def param_shapes(cfg: GraniteHybridConfig):
             "blocks": [block(kind) for kind in cfg.kinds]}
 
 
-def param_count(cfg: GraniteHybridConfig) -> int:
-    """Parameters of the tree `init_params` makes, from shapes alone."""
-    return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
-
-
 def init_params(cfg: GraniteHybridConfig, key) -> Dict[str, Any]:
     """Seeded random weights in `cfg.dtype`: matrices N(0, 1/rows), an
     expert's by its own rows (the tied embedding N(0, 1/vocab): a
@@ -196,12 +189,7 @@ def init_params(cfg: GraniteHybridConfig, key) -> Dict[str, Any]:
     +-d_conv^-1/2 with a bias near 0, and the Mamba-2 leaves by the
     published initialisers (A uniform in [1, 16], dt bias the inverse
     softplus of a log-uniform step in [1e-3, 1e-1], D = 1)."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-
-    def leaf(i, path, shp):
-        k = jax.random.fold_in(key, i)
-        name = str(getattr(path[-1], "key", "w"))
+    def leaf(k, name, shp):
         if name == "A_log":
             return jnp.log(jax.random.uniform(k, shp, jnp.float32, 1.0, 16.0))
         if name == "D":
@@ -220,9 +208,7 @@ def init_params(cfg: GraniteHybridConfig, key) -> Dict[str, Any]:
             return 0.1 * n
         return n / math.sqrt(shp[-2])
 
-    return jax.tree_util.tree_unflatten(treedef, [
-        leaf(i, path, shp).astype(cfg.dtype)
-        for i, (path, shp) in enumerate(flat)])
+    return init_tree(param_shapes(cfg), key, cfg.dtype, leaf)
 
 
 # ---------------------------------------------------------------------
@@ -230,14 +216,13 @@ def init_params(cfg: GraniteHybridConfig, key) -> Dict[str, Any]:
 # ---------------------------------------------------------------------
 
 
-def _rms32(x, w, eps):  # float32, before any rounding
-    xf = x.astype(jnp.float32)
-    return (xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
-            * w.astype(jnp.float32))
-
-
 def _rms(x, w, eps):
-    return _rms32(x, w, eps).astype(x.dtype)
+    return rms32(x, w, eps).astype(x.dtype)
+
+
+# the shared MLP, a module global that `_moe` looks up when it is
+# traced: the benchmark's planted fault replaces it
+_mlp = mlp
 
 
 def _moe(u32, blk, cfg, valid, kernel):
@@ -277,7 +262,7 @@ def _stack(params, x, cfg, mixer, valid=None, kernel="gather"):
                 x = x + r * _mlp(_rms(x, blk["norm2"], cfg.eps), blk)
             continue
         with scope("lm_experts"):
-            m, st = _moe(_rms32(x, blk["norm2"], cfg.eps), blk, cfg, valid,
+            m, st = _moe(rms32(x, blk["norm2"], cfg.eps), blk, cfg, valid,
                          kernel)
             x = x + (r * m).astype(x.dtype)
             stats = st if stats is None else jnp.stack(
@@ -350,7 +335,7 @@ def _attend(q, k, v, qpos, kpos, cfg):
     s = jnp.einsum("qhrd,khd->hrqk", q, k,
                    preferred_element_type=f32) * cfg.attention_multiplier
     ok = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
-    prob = jax.nn.softmax(jnp.where(ok[None, None], s, _NEG), axis=-1)
+    prob = jax.nn.softmax(jnp.where(ok[None, None], s, NEG_INF), axis=-1)
     o = jnp.einsum("hrqk,khd->qhrd", prob.astype(v.dtype), v,
                    preferred_element_type=f32).astype(q.dtype)
     return o.reshape(o.shape[0], -1)
@@ -373,7 +358,7 @@ def forward(params, tokens, cfg: GraniteHybridConfig):
             z, xbc, dt = _split_in(h, p, cfg)
             rows = jnp.concatenate(
                 [jnp.zeros((cfg.d_conv - 1, cfg.conv_dim), xbc.dtype), xbc])
-            x, dt, a, B, C = _ssd_inputs(_conv(rows, p), dt, p, cfg)
+            x, dt, a, B, C = _ssd_inputs(conv(rows, p), dt, p, cfg)
             s0 = jnp.zeros((cfg.d_state, cfg.d_inner), jnp.float32)
             _, y = ssd_chunk_scan_reference(s0, dt, x, a, B, C)
             return _mamba_out(y, x, z, p, cfg)
@@ -393,12 +378,9 @@ def forward(params, tokens, cfg: GraniteHybridConfig):
 def init_cache(cfg: GraniteHybridConfig, num_blocks: int, block_tokens: int,
                slots: int):
     dt = cfg.dtype
-    rows, D = int(block_tokens) * cfg.groups, cfg.row
-    # one block more than the allocator hands out: where the fused
-    # decode write sends a parked slot's rows (paged_kv_write)
-    shape = (int(num_blocks) + 1, rows, D)
+    rows = int(block_tokens) * cfg.groups
     return {
-        "kv": [{"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+        "kv": [kv_pool(num_blocks, rows, cfg.row, dt)
                for k in cfg.kinds if k == "attention"],
         "ssm": [{"s": jnp.zeros((slots, cfg.d_state, cfg.d_inner),
                                 jnp.float32),
@@ -421,21 +403,6 @@ def cache_bytes(cfg: GraniteHybridConfig, block_tokens: int) -> Dict[str, int]:
             "state": kinds.count("mamba") * (
                 4 * cfg.d_state * cfg.d_inner
                 + (cfg.d_conv - 1) * cfg.conv_dim * item)}
-
-
-def reset_slot_state(cache, slot):
-    """Zero one slot's recurrent state in every Mamba-2 layer: what a
-    request admitted to the slot must start from."""
-    return dict(cache, ssm=[
-        {"s": st["s"].at[slot].set(0.0), "conv": st["conv"].at[slot].set(0)}
-        for st in cache["ssm"]])
-
-
-def _view(pool, tab, cfg, Bt):
-    """The rows the table names, as positions and K/V heads:
-    [..., MAXB * Bt, Hk, dh]."""
-    v = _pool_view(pool, tab, cfg, Bt)
-    return v.reshape(v.shape[:-2] + (cfg.kv_heads, cfg.dh))
 
 
 # ---------------------------------------------------------------------
@@ -487,7 +454,7 @@ def paged_decode_step(params, token, pos, tables, cache,
     from ..parallel.ssd_update import (ssd_state_update,
                                        ssd_state_update_reference)
 
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     S, maxb = tables.shape
     Bt = cache["kv"][0]["k"].shape[1] // cfg.groups
     live = pos < maxb * Bt
@@ -501,7 +468,7 @@ def paged_decode_step(params, token, pos, tables, cache,
             st = next(it["ssm"])
             z, xbc, dt = _split_in(h, p, cfg)
             rows = jnp.concatenate([st["conv"], xbc[:, None]], axis=1)
-            xbc = jax.vmap(lambda r: _conv(r, p)[0])(rows)
+            xbc = jax.vmap(lambda r: conv(r, p)[0])(rows)
             x, dt, a, B, C = _ssd_inputs(xbc, dt, p, cfg)
             s, y = update(st["s"], _heads(dt * a, cfg), _heads(dt, cfg) * x,
                           B, C, live)
@@ -518,14 +485,14 @@ def paged_decode_step(params, token, pos, tables, cache,
                 _place_queries(q, cfg), kv["k"], kv["v"], tables, pos,
                 scale=cfg.attention_multiplier), cfg)
         else:
-            kv = {"k": _scatter_rows(kv["k"], tables, pos, k, cfg, Bt),
-                  "v": _scatter_rows(kv["v"], tables, pos, v, cfg, Bt)}
+            kv = {"k": scatter_rows(kv["k"], tables, pos, k, cfg, Bt),
+                  "v": scatter_rows(kv["v"], tables, pos, v, cfg, Bt)}
             kpos = jnp.arange(maxb * Bt)
             o = jax.vmap(
                 lambda q1, k1, v1, p1: _attend(q1[None], k1, v1, p1[None],
                                                kpos, cfg)[0]
-            )(q, _view(kv["k"], tables, cfg, Bt),
-              _view(kv["v"], tables, cfg, Bt), pos)
+            )(q, head_view(kv["k"], tables, cfg, Bt),
+              head_view(kv["v"], tables, cfg, Bt), pos)
         new["kv"].append(kv)
         return o @ p["wo"]
 
@@ -538,58 +505,6 @@ def paged_decode_step(params, token, pos, tables, cache,
 # ---------------------------------------------------------------------
 # prefill: a chunk of one slot
 # ---------------------------------------------------------------------
-
-
-def _chunk_attend(q, k, v, start_pos, cfg):
-    """q [C, Hk, rep, dh], row i at position start_pos + i, over the
-    slot's span k [K, Hk, dh], v [K, Hk, dv] (index = position; dv is
-    dh but in the latent family, whose values are narrower than its
-    keys) -> [C, heads * dv], causal. Queries 512 rows at a time, keys
-    1,024 at a time with the running (max, sum, acc) of an online
-    softmax, and only the key tiles up to a query tile's last position
-    are walked (a trip count
-    read off `start_pos`), so the work follows the context and not
-    the table's span. One softmax over the whole span instead ran 130
-    times slower on the v5e at 8,192 positions (0.196 s a layer
-    against 1.5 ms: my chip run, PR 31): its reductions took the
-    contraction over dh off the matrix unit."""
-    f32 = jnp.float32
-    C, Hk, rep, dh = q.shape
-    K, dv = k.shape[0], v.shape[-1]
-    tile = min(512, C)
-    KT = 1024 if K % 1024 == 0 else K
-    kh, vh = k.transpose(1, 0, 2), v.transpose(1, 0, 2)  # [Hk, K, dh]
-    batched = (((2,), (2,)), ((0,), (0,)))
-    outs = []
-    for i in range(0, C, tile):
-        qh = q[i:i + tile].transpose(1, 2, 0, 3).reshape(Hk, rep * tile, dh)
-        qpos = jnp.tile(start_pos + i + jnp.arange(tile), rep)
-
-        def body(j, state, qh=qh, qpos=qpos):
-            m, l, acc = state
-            ks = jax.lax.dynamic_slice_in_dim(kh, j * KT, KT, axis=1)
-            vs = jax.lax.dynamic_slice_in_dim(vh, j * KT, KT, axis=1)
-            s = jax.lax.dot_general(
-                qh, ks, batched,
-                preferred_element_type=f32) * cfg.attention_multiplier
-            kpos = j * KT + jnp.arange(KT)
-            s = jnp.where((kpos[None, :] <= qpos[:, None])[None], s, _NEG)
-            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            a = jnp.exp(m - m_new)
-            pv = jax.lax.dot_general(
-                p.astype(vs.dtype), vs, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=f32)
-            return m_new, l * a + p.sum(-1, keepdims=True), acc * a + pv
-
-        m0 = jnp.full((Hk, rep * tile, 1), _NEG, f32)
-        _, l, acc = jax.lax.fori_loop(
-            0, jnp.minimum((start_pos + i + tile + KT - 1) // KT, K // KT),
-            body, (m0, jnp.zeros_like(m0),
-                   jnp.zeros((Hk, rep * tile, dv), f32)))
-        o = (acc / l).reshape(Hk, rep, tile, dv).transpose(2, 0, 1, 3)
-        outs.append(o.reshape(tile, Hk * rep * dv).astype(q.dtype))
-    return jnp.concatenate(outs)
 
 
 def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
@@ -608,12 +523,12 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
     The mixers are XLA in either `kernel`: the recurrence in its
     blocked matrix form (`ssd_chunk_scan`, cfg.chunk rows a block), and
     the attention over the slot's span gathered through the table after
-    the chunk's own rows are written (`_chunk_attend`), whatever
+    the chunk's own rows are written (`chunk_attend`), whatever
     position the chunk starts at. The experts' grouped products are the
     decode step's."""
     from ..parallel.ssd_update import ssd_chunk_scan
 
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     (C,) = chunk.shape
     tab, slot = table_rows[0], table_rows[1, 0]
     maxb = tab.shape[0]
@@ -631,7 +546,7 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
             st = next(it["ssm"])
             z, xbc, dt = _split_in(h, p, cfg)
             rows = jnp.concatenate([st["conv"][slot], xbc])
-            x, dt, a, B, C_ = _ssd_inputs(_conv(rows, p), dt, p, cfg)
+            x, dt, a, B, C_ = _ssd_inputs(conv(rows, p), dt, p, cfg)
             dt = jnp.where(valid[:, None], dt, 0.0)
             s, y = ssd_chunk_scan(st["s"][slot], dt, x, a, B, C_,
                                   block=cfg.chunk)
@@ -643,13 +558,13 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
             return _mamba_out(y, x, z, p, cfg)
         kv = next(it["kv"])
         q, k, v = _split_qkv(h, p, cfg)
-        kv = {"k": _scatter_chunk(kv["k"], tab, start_pos, wpos, true_len,
-                                  _pairs(k, cfg), cfg, Bt),
-              "v": _scatter_chunk(kv["v"], tab, start_pos, wpos, true_len,
-                                  _pairs(v, cfg), cfg, Bt)}
+        kv = {"k": scatter_chunk(kv["k"], tab, start_pos, wpos, true_len,
+                                 _pairs(k, cfg), cfg, Bt),
+              "v": scatter_chunk(kv["v"], tab, start_pos, wpos, true_len,
+                                 _pairs(v, cfg), cfg, Bt)}
         new["kv"].append(kv)
-        o = _chunk_attend(q, _view(kv["k"], tab, cfg, Bt),
-                          _view(kv["v"], tab, cfg, Bt), start_pos, cfg)
+        o = chunk_attend(q, head_view(kv["k"], tab, cfg, Bt),
+                         head_view(kv["v"], tab, cfg, Bt), start_pos, cfg)
         return o @ p["wo"]
 
     x, _ = _stack(params, _embed(params, chunk, cfg), cfg, mixer, valid,
@@ -660,42 +575,18 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
     return _head(params, xl, cfg), new
 
 
-class _Serving(object):
-    """What ServingEngine asks a model family for (the seam
-    `models/transformer.py` and `models/sambay.py` fill too). This
-    family's caches are the paged pools on the engine's one table and
-    per-slot recurrent state: no window tables. The state cannot be
-    restored by aliasing blocks, so everything that re-uses or
-    re-plays cached blocks is refused by name, as the SambaY family
-    refuses it (ROADMAP B.I.5 keeps the snapshots)."""
+class _Serving(Seam):
+    """The pools on the engine's one table and per-slot recurrent
+    state, no window tables; refused as the SambaY family refuses
+    (ROADMAP B.I.5 keeps the snapshots)."""
     name = "granite_hybrid"
     caches = ("paged", "state")
-    refused = ("prefix_cache_tokens", "kv_store", "spec_draft_len",
-               "kv_quant", "weight_quant", "adapter_registry",
-               "kv_fingerprints")
     refusal = "it keeps recurrent state beside its K/V blocks"
     cache_bytes = staticmethod(cache_bytes)
     reset_slot_state = staticmethod(reset_slot_state)
-
-    # the engine hands every family the same keywords; the ones this
-    # family refuses at construction arrive here as their defaults
-    @staticmethod
-    def decode_step(params, token, pos, tables, cache, cfg, adapters=None,
-                    adapter_idx=None, kernel="gather", kv_quant="none"):
-        return paged_decode_step(params, token, pos, tables, cache, cfg,
-                                 kernel=kernel)
-
-    @staticmethod
-    def prefill_chunk(params, cache, chunk, start_pos, table_rows, cfg,
-                      true_len=None, adapters=None, adapter_idx=None,
-                      kernel="gather", kv_quant="none"):
-        return paged_prefill_chunk(params, cache, chunk, start_pos,
-                                   table_rows, cfg, true_len=true_len,
-                                   kernel=kernel)
-
-    @staticmethod
-    def init_cache(cfg, num_blocks, block_tokens, slots, kv_quant="none"):
-        return init_cache(cfg, num_blocks, block_tokens, slots)
+    decode = staticmethod(paged_decode_step)
+    prefill = staticmethod(paged_prefill_chunk)
+    cache = staticmethod(init_cache)
 
 
 class _ExpertServing(_Serving):

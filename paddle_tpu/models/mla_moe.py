@@ -28,11 +28,11 @@ width and eps 1e-6, no bias on any matrix:
              causal) v; W_o o
     FFN(u):  layer < num_dense_layers: W_down(silu(g) * up)
              else Shared(u) + sum_{e in S} w_e Expert_e(u)
-             (`afmoe.moe_ffn` over `parallel/routed_experts.py`: the
+             (`parts.moe_ffn` over `parallel/routed_experts.py`: the
              router in float32 from the float32 normed row)
 
-One stack (`_stack`) runs every mode; a mode is the `attn` it hands
-the stack, as `afmoe._stack` is:
+One stack (`parts.expert_stack`, afmoe's too) runs every mode; a
+mode is the `attn` it hands the stack:
 
   forward               whole sequence, no cache, every head's keys
                         and values expanded (the cached modes' oracle)
@@ -46,7 +46,7 @@ the stack, as `afmoe._stack` is:
   paged_prefill_chunk   a [C]-token chunk of ONE slot, EXPANDED: the
                         latents through W_kvb to per-head k_n and v,
                         key-tiled causal attention
-                        (`granite_hybrid._chunk_attend`)
+                        (`parts.chunk_attend`)
 
 The one cache (`init_cache`), served by ServingEngine through
 `SERVING` (`caches = ("paged",)`: the engine's one table, no window,
@@ -72,17 +72,14 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.routed_experts import held_range
-from .afmoe import _rms32, moe_ffn
-from .granite_hybrid import _chunk_attend
-from .sambay import _mlp, _pool_view, _scatter_chunk, _scatter_rows
+from .parts import (NEG_INF, Seam, chunk_attend, embed, expert_stack,
+                    init_tree, paged_kernel_check, pool_view, rms32,
+                    scatter_chunk, scatter_rows, untied_head)
 from .scopes import scope
-from .transformer import _paged_kernel_check
 
-__all__ = ["MlaMoeConfig", "init_params", "param_shapes", "param_count",
-           "forward", "init_cache", "cache_bytes", "paged_decode_step",
+__all__ = ["MlaMoeConfig", "init_params", "param_shapes", "forward",
+           "init_cache", "cache_bytes", "paged_decode_step",
            "paged_prefill_chunk", "SERVING"]
-
-_NEG = -1e30  # finite mask fill (parallel/kernel_utils.NEG_INF)
 
 
 class MlaMoeConfig:
@@ -111,8 +108,8 @@ class MlaMoeConfig:
         self.rope_theta = float(rope_theta)
         # a latent row as stored: c and k_r, in whole 128-lane tiles
         self.latent_row = -(-(kv_rank + rope_dim) // 128) * 128
-        self.groups = 1  # pool rows a token (sambay._scatter_rows)
-        # the scores' scale, under the name `granite_hybrid._chunk_attend`
+        self.groups = 1  # pool rows a token (parts.scatter_rows)
+        # the scores' scale, under the name `parts.chunk_attend`
         # reads it by
         self.attention_multiplier = 1.0 / math.sqrt(nope_dim + rope_dim)
         self.eps, self.max_len, self.dtype = eps, max_len, dtype
@@ -141,23 +138,13 @@ def param_shapes(cfg: MlaMoeConfig):
                         "ffn": ffn(l)} for l in range(cfg.layers)]}
 
 
-def param_count(cfg: MlaMoeConfig) -> int:
-    """Parameters of the tree `init_params` makes, from shapes alone."""
-    return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
-
-
 def init_params(cfg: MlaMoeConfig, key) -> Dict[str, Any]:
     """Seeded random weights in `cfg.dtype`: the embedding N(0, 1) (no
     multiplier follows it: its rows enter the residual at unit scale),
     every other matrix N(0, 1 / the contraction's length), norm gains
     1 + N(0, 0.1), the router's bias N(0, 0.05)."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-
-    def leaf(i, path, shp):
-        n = jax.random.normal(jax.random.fold_in(key, i), shp, jnp.float32)
-        name = str(getattr(path[-1], "key", "w"))
+    def leaf(k, name, shp):
+        n = jax.random.normal(k, shp, jnp.float32)
         if "norm" in name:
             return 1.0 + 0.1 * n
         if name == "router_bias":
@@ -166,9 +153,7 @@ def init_params(cfg: MlaMoeConfig, key) -> Dict[str, Any]:
             return n
         return n / math.sqrt(shp[-1] if name == "head" else shp[-2])
 
-    return jax.tree_util.tree_unflatten(treedef, [
-        leaf(i, path, shp).astype(cfg.dtype)
-        for i, (path, shp) in enumerate(flat)])
+    return init_tree(param_shapes(cfg), key, cfg.dtype, leaf)
 
 
 # ---------------------------------------------------------------------
@@ -198,7 +183,7 @@ def _project(h, p, pos, cfg):
     lead = h.shape[:-1]
     q = (h @ p["wq"]).reshape(lead + (cfg.heads, cfg.nope_dim + cfg.rope_dim))
     a = h @ p["wkva"]
-    c = _rms32(a[..., :cfg.kv_rank], p["kv_norm"], cfg.eps)
+    c = rms32(a[..., :cfg.kv_rank], p["kv_norm"], cfg.eps)
     k_r = _rope(a[..., cfg.kv_rank:].astype(f32), pos, cfg.rope_theta)
     q_r = _rope(q[..., cfg.nope_dim:].astype(f32), pos[..., None],
                 cfg.rope_theta)
@@ -228,7 +213,7 @@ def _attend(q, k, v, qpos, kpos, cfg):
     s = jnp.einsum("qhd,khd->hqk", q, k,
                    preferred_element_type=f32) * cfg.attention_multiplier
     ok = kpos[None, :] <= qpos[:, None]
-    prob = jax.nn.softmax(jnp.where(ok[None], s, _NEG), axis=-1)
+    prob = jax.nn.softmax(jnp.where(ok[None], s, NEG_INF), axis=-1)
     o = jnp.einsum("hqk,khd->qhd", prob.astype(v.dtype), v,
                    preferred_element_type=f32).astype(q.dtype)
     return o.reshape(o.shape[0], -1)
@@ -257,46 +242,6 @@ def _absorbed_out(o_lat, p, cfg):
     return o.reshape(o.shape[0], -1).astype(w_uv.dtype)
 
 
-def _stack(params, x, cfg, attn, valid, kernel="gather"):
-    """Every layer in its residual form, each part under its device
-    scope (`scopes.py`: a branch's pre-norm and its residual add with
-    the branch; an expert layer, router to combine and the shared
-    expert, is `lm_experts`, a leading dense layer `lm_mlp`);
-    `attn(h, p)` is the mode's (it owns whatever cache the mode has).
-    `valid` [rows]: the rows that reach experts. -> (the final norm's
-    output, the expert layers' stats summed / maxed: int32 [2])."""
-    dt, eps = x.dtype, cfg.eps
-    hit, fullest = jnp.int32(0), jnp.int32(0)
-    for l, blk in enumerate(params["blocks"]):
-        with scope("lm_attention"):
-            x = x + attn(_rms32(x, blk["norm1"], eps).astype(dt),
-                         blk["attn"]).astype(dt)
-        dense = l < cfg.num_dense_layers
-        with scope("lm_mlp" if dense else "lm_experts"):
-            u32 = _rms32(x, blk["norm2"], eps)
-            if dense:
-                m = _mlp(u32.astype(dt), blk["ffn"])
-            else:
-                m, stats = moe_ffn(u32, blk["ffn"], cfg, valid, kernel)
-                hit = hit + stats[0]
-                fullest = jnp.maximum(fullest, stats[1])
-            x = x + m.astype(dt)
-    with scope("lm_head"):
-        x = _rms32(x, params["norm_f"], eps).astype(dt)
-    return x, jnp.stack([hit, fullest])
-
-
-def _embed(params, tokens):
-    with scope("lm_embed"):
-        return params["embed"][tokens]
-
-
-def _head(params, x):
-    with scope("lm_head"):
-        return jnp.matmul(x, params["head"].T,
-                          preferred_element_type=jnp.float32)
-
-
 # ---------------------------------------------------------------------
 # whole sequence, no cache
 # ---------------------------------------------------------------------
@@ -307,15 +252,16 @@ def forward(params, tokens, cfg: MlaMoeConfig):
     once, every head's keys and values expanded, no cache, no kernel."""
     pos = jnp.arange(tokens.shape[0])
 
-    def attn(h, p):
+    def attn(l, h, p):
         q_n, q_r, lat = _project(h, p, pos, cfg)
         k, v = _expand(lat, p, cfg)
         q = jnp.concatenate([q_n, q_r.astype(q_n.dtype)], -1)
         return _attend(q, k, v, pos, pos, cfg) @ p["wo"]
 
-    x, _ = _stack(params, _embed(params, tokens), cfg, attn,
-                  jnp.ones(tokens.shape, bool))
-    return _head(params, x)
+    x, _ = expert_stack(params, embed(params, tokens), cfg, attn,
+                        jnp.ones(tokens.shape, bool), "gather",
+                        sandwich=False)
+    return untied_head(params, x)
 
 
 # ---------------------------------------------------------------------
@@ -361,7 +307,7 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: MlaMoeConfig,
     from ..parallel.paged_attention import (mla_decode_attention,
                                             paged_kv_write)
 
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     S, maxb = tables.shape
     Bt = cache["latent"][0].shape[1]
     live = pos < maxb * Bt
@@ -369,7 +315,7 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: MlaMoeConfig,
     pools = iter(cache["latent"])
     new = []
 
-    def attn(h, p):
+    def attn(l, h, p):
         pool = next(pools)
         q_n, q_r, lat = _project(h, p, pos, cfg)
         q = _absorbed_query(q_n, q_r, p, cfg)  # [S, heads, row]
@@ -379,21 +325,23 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: MlaMoeConfig,
             o_lat = mla_decode_attention(q, pool, tables, pos, r,
                                          cfg.attention_multiplier)
         else:
-            pool = _scatter_rows(pool, tables, pos, lat[:, None], cfg, Bt)
-            view = _pool_view(pool, tables, cfg, Bt)[:, :, 0]  # [S, K, row]
+            pool = scatter_rows(pool, tables, pos, lat[:, None], cfg, Bt)
+            view = pool_view(pool, tables, cfg, Bt)[:, :, 0]  # [S, K, row]
             s = jnp.einsum("shw,skw->shk", q, view,
                            preferred_element_type=jnp.float32
                            ) * cfg.attention_multiplier
             ok = jnp.arange(maxb * Bt)[None, :] <= pos[:, None]
-            prob = jax.nn.softmax(jnp.where(ok[:, None], s, _NEG), axis=-1)
+            prob = jax.nn.softmax(jnp.where(ok[:, None], s, NEG_INF),
+                                  axis=-1)
             o_lat = jnp.einsum("shk,skr->shr", prob.astype(view.dtype),
                                view[..., :r],
                                preferred_element_type=jnp.float32)
         new.append(pool)
         return _absorbed_out(o_lat, p, cfg) @ p["wo"]
 
-    x, stats = _stack(params, _embed(params, token), cfg, attn, live, kernel)
-    return _head(params, x), {"latent": new}, stats
+    x, stats = expert_stack(params, embed(params, token), cfg, attn, live,
+                            kernel, sandwich=False)
+    return untied_head(params, x), {"latent": new}, stats
 
 
 # ---------------------------------------------------------------------
@@ -416,9 +364,9 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_row,
     span, read through the table after the chunk's rows are written
     and expanded whole — the same keys and values, since the cache
     holds exactly the latents the chunk computed. Key-tiled and causal
-    (`granite_hybrid._chunk_attend`). The experts' grouped products
+    (`parts.chunk_attend`). The experts' grouped products
     are the decode step's."""
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     (C,) = chunk.shape
     maxb = table_row.shape[0]
     Bt = cache["latent"][0].shape[1]
@@ -431,47 +379,35 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_row,
     pools = iter(cache["latent"])
     new = []
 
-    def attn(h, p):
+    def attn(l, h, p):
         pool = next(pools)
         q_n, q_r, lat = _project(h, p, positions, cfg)
         q = jnp.concatenate([q_n, q_r.astype(q_n.dtype)], -1)[:, :, None]
-        pool = _scatter_chunk(pool, table_row, start_pos, wpos, true_len,
-                              lat[:, None], cfg, Bt)
+        pool = scatter_chunk(pool, table_row, start_pos, wpos, true_len,
+                             lat[:, None], cfg, Bt)
         new.append(pool)
 
         def own(pool):
-            return _chunk_attend(q, *_expand(lat, p, cfg), start_pos, cfg)
+            return chunk_attend(q, *_expand(lat, p, cfg), start_pos, cfg)
 
         def span(pool):
-            view = _pool_view(pool, table_row, cfg, Bt)[:, 0]  # [K, row]
-            return _chunk_attend(q, *_expand(view, p, cfg), start_pos, cfg)
+            view = pool_view(pool, table_row, cfg, Bt)[:, 0]  # [K, row]
+            return chunk_attend(q, *_expand(view, p, cfg), start_pos, cfg)
 
         return jax.lax.cond(start_pos == 0, own, span, pool) @ p["wo"]
 
-    x, _ = _stack(params, _embed(params, chunk), cfg, attn, valid, kernel)
+    x, _ = expert_stack(params, embed(params, chunk), cfg, attn, valid,
+                        kernel, sandwich=False)
     with scope("lm_head"):
         xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0,
                                           keepdims=False)
-    return _head(params, xl), {"latent": new}
+    return untied_head(params, xl), {"latent": new}
 
 
-class _Serving(object):
-    """What ServingEngine asks a model family for (the seam
-    `models/transformer.py`, `models/sambay.py`,
-    `models/granite_hybrid.py` and `models/afmoe.py` fill too). This
-    family's one cache is a latent pool a layer on the engine's one
-    table: no window, no state, so nothing is freed behind a window
-    and no slot is reset at admission. A latent block is not the K/V
-    block the prefix cache, the KV store, hand-off and the speculative
-    verify call read and write, so what re-uses or moves cached blocks
-    is refused by name, and the rest is not built for the family. The
-    decode step hands the engine its `step_counters` beside the
-    logits; they ride the step's one packed result."""
+class _Serving(Seam):
+    """One latent pool a layer on the engine's one table: nothing is
+    freed behind a window and no slot is reset at admission."""
     name = "mla_moe"
-    caches = ("paged",)
-    refused = ("prefix_cache_tokens", "kv_store", "spec_draft_len",
-               "kv_quant", "weight_quant", "adapter_registry",
-               "kv_fingerprints")
     refusal = ("its cache holds one latent a token and layer, not the "
                "per-head K/V blocks that a cached prefix, a stored or "
                "handed-on block or a verified draft are built on; "
@@ -479,25 +415,11 @@ class _Serving(object):
                "for it")
     step_counters = ("moe_experts_hit", "moe_rows_max")
     cache_bytes = staticmethod(cache_bytes)
-
-    # the engine hands every family the same keywords; the ones this
-    # family refuses at construction arrive here as their defaults
-    @staticmethod
-    def decode_step(params, token, pos, tables, cache, cfg, adapters=None,
-                    adapter_idx=None, kernel="gather", kv_quant="none"):
-        return paged_decode_step(params, token, pos, tables, cache, cfg,
-                                 kernel=kernel)
+    decode = staticmethod(paged_decode_step)
+    prefill = staticmethod(paged_prefill_chunk)
 
     @staticmethod
-    def prefill_chunk(params, cache, chunk, start_pos, table_row, cfg,
-                      true_len=None, adapters=None, adapter_idx=None,
-                      kernel="gather", kv_quant="none"):
-        return paged_prefill_chunk(params, cache, chunk, start_pos,
-                                   table_row, cfg, true_len=true_len,
-                                   kernel=kernel)
-
-    @staticmethod
-    def init_cache(cfg, num_blocks, block_tokens, slots, kv_quant="none"):
+    def cache(cfg, num_blocks, block_tokens, slots):
         return init_cache(cfg, num_blocks, block_tokens)
 
 

@@ -56,14 +56,14 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from .parts import (NEG_INF, Seam, conv, embed, init_tree, kv_pool, mlp,
+                    paged_kernel_check, pool_view, reset_slot_state,
+                    scatter_chunk, scatter_rows)
 from .scopes import scope
-from .transformer import _paged_kernel_check, _phys_rows
 
 __all__ = ["SambaYConfig", "layer_kinds", "init_params", "forward",
            "init_cache", "paged_decode_step", "paged_prefill_chunk",
            "reset_slot_state", "SERVING"]
-
-_NEG = -1e30  # finite mask fill (parallel/kernel_utils.NEG_INF)
 
 
 def layer_kinds(layers: int):
@@ -134,12 +134,7 @@ def init_params(cfg: SambaYConfig, key) -> Dict[str, Any]:
     shapes = {"embed": (cfg.vocab, d), "ln_f": ln, "blocks": [
         {"ln1": ln, "mixer": _mixer_shapes(cfg, kind), "ln2": ln,
          "w_gu": (d, 2 * m), "w_down": (m, d)} for kind in cfg.kinds]}
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        shapes, is_leaf=lambda x: isinstance(x, tuple))
-
-    def leaf(i, path, shp):
-        k = jax.random.fold_in(key, i)
-        name = str(getattr(path[-1], "key", "w"))
+    def leaf(k, name, shp):
         if name == "A_log":
             return jnp.broadcast_to(
                 jnp.log(jnp.arange(1, shp[1] + 1, dtype=jnp.float32)), shp)
@@ -159,9 +154,7 @@ def init_params(cfg: SambaYConfig, key) -> Dict[str, Any]:
             return 0.1 * n
         return n / math.sqrt(shp[0])
 
-    return jax.tree_util.tree_unflatten(treedef, [
-        leaf(i, path, shp).astype(cfg.dtype)
-        for i, (path, shp) in enumerate(flat)])
+    return init_tree(shapes, key, cfg.dtype, leaf)
 
 
 # ---------------------------------------------------------------------
@@ -178,12 +171,6 @@ def _ln(x, p):
             + p["b"].astype(jnp.float32)).astype(x.dtype)
 
 
-def _mlp(h, blk):
-    gu = h @ blk["w_gu"]
-    m = gu.shape[-1] // 2
-    return (jax.nn.silu(gu[..., :m]) * gu[..., m:]) @ blk["w_down"]
-
-
 def _stack(params, x, cfg, mixer):
     """Every layer in its residual form, each part under its device
     scope (`scopes.py`: the Mamba layers and the gated memory units
@@ -195,14 +182,9 @@ def _stack(params, x, cfg, mixer):
                    else "lm_attention"):
             x = x + mixer(l, kind, _ln(x, blk["ln1"]), blk["mixer"])
         with scope("lm_mlp"):
-            x = x + _mlp(_ln(x, blk["ln2"]), blk)
+            x = x + mlp(_ln(x, blk["ln2"]), blk)
     with scope("lm_head"):
         return _ln(x, params["ln_f"])
-
-
-def _embed(params, tokens):
-    with scope("lm_embed"):
-        return params["embed"][tokens]
 
 
 def _head(params, x):
@@ -249,7 +231,7 @@ def _attend(q, k, v, qpos, kpos, window, cfg):
     ok = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
     if window:
         ok = ok & (kpos[None, :] > qpos[:, None] - window)
-    prob = jax.nn.softmax(jnp.where(ok[None, None], s, _NEG), axis=-1)
+    prob = jax.nn.softmax(jnp.where(ok[None, None], s, NEG_INF), axis=-1)
     return jnp.einsum("grqk,kgd->qgrd", prob.astype(v.dtype), v,
                       preferred_element_type=f32).astype(q.dtype)
 
@@ -293,17 +275,6 @@ def _mamba_inputs(u, p, cfg):
             xp[..., R + N:].astype(f32))
 
 
-def _conv(window_rows, p):
-    """Causal depthwise conv over the rows [T + d_conv - 1, di] (the
-    d_conv - 1 rows before the first one in front) -> silu, [T, di]."""
-    K = p["conv_w"].shape[1]
-    T = window_rows.shape[0] - (K - 1)
-    acc = sum(window_rows[i:i + T].astype(jnp.float32)
-              * p["conv_w"][:, i].astype(jnp.float32) for i in range(K))
-    return jax.nn.silu(acc + p["conv_b"].astype(jnp.float32)).astype(
-        window_rows.dtype)
-
-
 # ---------------------------------------------------------------------
 # whole sequence, no cache
 # ---------------------------------------------------------------------
@@ -324,7 +295,7 @@ def forward(params, tokens, cfg: SambaYConfig):
             u, z = uz[:, :cfg.d_inner], uz[:, cfg.d_inner:]
             rows = jnp.concatenate(
                 [jnp.zeros((cfg.d_conv - 1, cfg.d_inner), u.dtype), u])
-            u, delta, B, C = _mamba_inputs(_conv(rows, p), p, cfg)
+            u, delta, B, C = _mamba_inputs(conv(rows, p), p, cfg)
             a_t = -jnp.exp(p["A_log"].astype(jnp.float32)).T
             _, y = ssm_chunk_scan_reference(jnp.zeros_like(a_t), delta,
                                             delta * u, a_t, B, C)
@@ -342,7 +313,7 @@ def forward(params, tokens, cfg: SambaYConfig):
                     cfg.window if kind == "window" else 0, cfg)
         return _attn_out(o, p, l, cfg, h.dtype)
 
-    x = _stack(params, _embed(params, tokens), cfg, mixer)
+    x = _stack(params, embed(params, tokens), cfg, mixer)
     return _head(params, x)
 
 
@@ -355,16 +326,10 @@ def init_cache(cfg: SambaYConfig, num_blocks: int, block_tokens: int,
                slots: int, window_blocks: int):
     dt = cfg.dtype
     rows, D = int(block_tokens) * cfg.groups, 2 * cfg.dh
-
-    def pool(nb):
-        # one block more than the allocator hands out: where the fused
-        # decode write sends a parked slot's rows (paged_kv_write)
-        return {"k": jnp.zeros((int(nb) + 1, rows, D), dt),
-                "v": jnp.zeros((int(nb) + 1, rows, D), dt)}
-
     return {
-        "full": pool(num_blocks),
-        "window": [pool(window_blocks) for k in cfg.kinds if k == "window"],
+        "full": kv_pool(num_blocks, rows, D, dt),
+        "window": [kv_pool(window_blocks, rows, D, dt)
+                   for k in cfg.kinds if k == "window"],
         "ssm": [{"s": jnp.zeros((slots, cfg.d_state, cfg.d_inner),
                                 jnp.float32),
                  "conv": jnp.zeros((slots, cfg.d_conv - 1, cfg.d_inner), dt)}
@@ -385,68 +350,6 @@ def cache_bytes(cfg: SambaYConfig, block_tokens: int) -> Dict[str, int]:
             "window": blk * sum(k == "window" for k in cfg.kinds),
             "state": n_m * cfg.d_inner * (4 * cfg.d_state
                                           + (cfg.d_conv - 1) * item)}
-
-
-def reset_slot_state(cache, slot):
-    """Zero one slot's recurrent state in every Mamba layer: what a
-    request admitted to the slot must start from."""
-    return dict(cache, ssm=[
-        {"s": st["s"].at[slot].set(0.0), "conv": st["conv"].at[slot].set(0)}
-        for st in cache["ssm"]])
-
-
-def _scatter_rows(pool, tab, wpos, rows, cfg, Bt):
-    """Write rows [n, groups, D] at positions wpos [n] through the
-    table `tab` ([n, MAXB], or [MAXB] for one slot): a position past
-    the table span or on an unallocated (-1) entry is dropped (block
-    NB is out of range), the parking rule of the GPT pool."""
-    phys, off = _phys_rows(tab, wpos, pool.shape[0], Bt)
-    idx = jnp.stack([phys, off * cfg.groups], axis=-1)
-    dn = jax.lax.ScatterDimensionNumbers(
-        update_window_dims=(1, 2), inserted_window_dims=(0,),
-        scatter_dims_to_operand_dims=(0, 1))
-    return jax.lax.scatter(pool, idx, rows.astype(pool.dtype), dn,
-                           mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
-
-
-def _scatter_chunk(pool, tab, start, wpos, true_len, rows, cfg, Bt):
-    """A chunk's rows [C, groups, D] from position `start` through one
-    slot's table `tab` [MAXB]. Where the chunk starts on a block's
-    first row (every chunk of a prompt prefilled from position 0 in
-    chunks of whole blocks) it is written a BLOCK at a time: XLA lowers
-    a scatter to a loop of one small update an index, 3 us each on the
-    v5e, and 4,096 rows a pool, 18 pools a chunk, would be 0.2 s of
-    it. A block's rows past `true_len` are then written too: padding,
-    at positions nothing attends before the decode step that owns them
-    has written them again. Anywhere else, row by row (`wpos` parks
-    the padded rows)."""
-    C = rows.shape[0]
-    if C % Bt:
-        return _scatter_rows(pool, tab, wpos, rows, cfg, Bt)
-    NB, maxb = pool.shape[0], tab.shape[0]
-
-    def by_block(pool):
-        i = jnp.arange(C // Bt)
-        bi = start // Bt + i
-        phys = tab[jnp.clip(bi, 0, maxb - 1)]
-        phys = jnp.where((bi < maxb) & (phys >= 0) & (i * Bt < true_len),
-                         phys, jnp.int32(NB))
-        return pool.at[phys].set(
-            rows.astype(pool.dtype).reshape(C // Bt, Bt * cfg.groups, -1),
-            mode="drop")
-
-    return jax.lax.cond(
-        start % Bt == 0, by_block,
-        lambda pool: _scatter_rows(pool, tab, wpos, rows, cfg, Bt), pool)
-
-
-def _pool_view(pool, tab, cfg, Bt):
-    """The rows the table `tab` [..., MAXB] names, as positions:
-    [..., MAXB * Bt, groups, D] (-1 clamps to block 0: garbage the
-    position mask excludes)."""
-    v = pool[jnp.clip(tab, 0, pool.shape[0] - 1)]
-    return v.reshape(tab.shape[:-1] + (tab.shape[-1] * Bt, cfg.groups,
-                                       pool.shape[-1]))
 
 
 # ---------------------------------------------------------------------
@@ -470,7 +373,7 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: SambaYConfig,
     from ..parallel.ssm_update import (ssm_state_update,
                                        ssm_state_update_reference)
 
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     ftab, wtab = tables[0], tables[1]
     S, maxb = ftab.shape
     Bt = cache["full"]["k"].shape[1] // cfg.groups
@@ -492,8 +395,8 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: SambaYConfig,
         return jax.vmap(
             lambda q1, k1, v1, p1: _attend(q1[None], k1, v1, p1[None],
                                            kpos, w, cfg)[0]
-        )(q, _pool_view(kv["k"], tab, cfg, Bt),
-          _pool_view(kv["v"], tab, cfg, Bt), pos)
+        )(q, pool_view(kv["k"], tab, cfg, Bt),
+          pool_view(kv["v"], tab, cfg, Bt), pos)
 
     def mixer(l, kind, h, p):
         if kind == "mamba":
@@ -501,7 +404,7 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: SambaYConfig,
             uz = h @ p["in_proj"]
             u, z = uz[:, :cfg.d_inner], uz[:, cfg.d_inner:]
             rows = jnp.concatenate([st["conv"], u[:, None]], axis=1)
-            u = jax.vmap(lambda r: _conv(r, p)[0])(rows)
+            u = jax.vmap(lambda r: conv(r, p)[0])(rows)
             u, delta, B, C = _mamba_inputs(u, p, cfg)
             a_t = -jnp.exp(p["A_log"].astype(jnp.float32)).T
             update = (ssm_state_update if kernel == "fused"
@@ -523,8 +426,8 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: SambaYConfig,
                 kv = dict(zip("kv", paged_kv_write(kv["k"], kv["v"], k, v,
                                                    tab, pos)))
             else:
-                kv = {"k": _scatter_rows(kv["k"], tab, pos, k, cfg, Bt),
-                      "v": _scatter_rows(kv["v"], tab, pos, v, cfg, Bt)}
+                kv = {"k": scatter_rows(kv["k"], tab, pos, k, cfg, Bt),
+                      "v": scatter_rows(kv["v"], tab, pos, v, cfg, Bt)}
             if kind == "window":
                 new["window"].append(kv)
                 o = attend(q, kv, tab, first)
@@ -533,7 +436,7 @@ def paged_decode_step(params, token, pos, tables, cache, cfg: SambaYConfig,
                 o = attend(q, kv, tab, None)
         return _attn_out(o, p, l, cfg, h.dtype)
 
-    x = _stack(params, _embed(params, token), cfg, mixer)
+    x = _stack(params, embed(params, token), cfg, mixer)
     return _head(params, x), new
 
 
@@ -578,7 +481,7 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
     from ..parallel.ssm_update import (ssm_chunk_scan,
                                        ssm_chunk_scan_reference)
 
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     scan = ssm_chunk_scan if kernel == "fused" else ssm_chunk_scan_reference
     (C,) = chunk.shape
     ftab, wread, wwrite = table_rows[0], table_rows[1], table_rows[2]
@@ -607,8 +510,8 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
 
         def later(q):
             # one gathered view of the slot's span, through the table
-            k = _pool_view(new["full"]["k"], ftab, cfg, Bt)
-            v = _pool_view(new["full"]["v"], ftab, cfg, Bt)
+            k = pool_view(new["full"]["k"], ftab, cfg, Bt)
+            v = pool_view(new["full"]["v"], ftab, cfg, Bt)
             kpos = jnp.arange(maxb * Bt)
             return _tiled(lambda t: _attend(
                 jax.lax.dynamic_slice_in_dim(q, t, min(256, C)), k, v,
@@ -652,7 +555,7 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
             uz = h @ p["in_proj"]
             u_in, z = uz[:, :cfg.d_inner], uz[:, cfg.d_inner:]
             rows = jnp.concatenate([st["conv"][slot], u_in])
-            u, delta, B, C_ = _mamba_inputs(_conv(rows, p), p, cfg)
+            u, delta, B, C_ = _mamba_inputs(conv(rows, p), p, cfg)
             delta = jnp.where(valid[:, None], delta, 0.0)
             a_t = -jnp.exp(p["A_log"].astype(jnp.float32)).T
             s, y = scan(st["s"][slot], delta, delta * u, a_t, B, C_)
@@ -672,69 +575,44 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_rows,
                 kv = next(it["window"])
                 o = window_attend(q, k, v, kv)
                 new["window"].append({
-                    "k": _scatter_chunk(kv["k"], wwrite, start_pos, wpos,
-                                        true_len, k, cfg, Bt),
-                    "v": _scatter_chunk(kv["v"], wwrite, start_pos, wpos,
-                                        true_len, v, cfg, Bt)})
+                    "k": scatter_chunk(kv["k"], wwrite, start_pos, wpos,
+                                       true_len, k, cfg, Bt),
+                    "v": scatter_chunk(kv["v"], wwrite, start_pos, wpos,
+                                       true_len, v, cfg, Bt)})
             else:
                 kv = cache["full"]
-                kv = {"k": _scatter_chunk(kv["k"], ftab, start_pos, wpos,
-                                          true_len, k, cfg, Bt),
-                      "v": _scatter_chunk(kv["v"], ftab, start_pos, wpos,
-                                          true_len, v, cfg, Bt)}
+                kv = {"k": scatter_chunk(kv["k"], ftab, start_pos, wpos,
+                                         true_len, k, cfg, Bt),
+                      "v": scatter_chunk(kv["v"], ftab, start_pos, wpos,
+                                         true_len, v, cfg, Bt)}
                 new["full"] = kv
                 carry["kv"] = (k, v)  # read by the cross layers above too
                 o = full_attend(q)
         return _attn_out(o, p, l, cfg, h.dtype)
 
-    x = _stack(params, _embed(params, chunk), cfg, mixer)
+    x = _stack(params, embed(params, chunk), cfg, mixer)
     with scope("lm_head"):
         xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0,
                                           keepdims=False)
     return _head(params, xl), new
 
 
-class _Serving(object):
-    """What ServingEngine asks a model family for (the seam
-    `models/transformer.py` fills as `SERVING` for the GPT block): its
-    cache, its two compiled bodies, and the engine options it cannot
-    honour. This family keeps recurrent state, which block aliasing
-    cannot restore, so everything that re-uses or re-plays cached
-    blocks is refused by name (ROADMAP B.I.5 keeps the snapshots).
-    The decode loop's depth is not among them (ISSUE 30): the window's
-    first position is read off the device's own `pos` band in
-    `paged_decode_step`, and the engine advances the window tables
-    for the position the dispatched step writes, so a default engine
-    runs this family one step ahead of the host like the GPT block.
-    Speculation stays refused, so the family never reaches the
-    verify step."""
+class _Serving(Seam):
+    """Recurrent state, which block aliasing cannot restore, beside the
+    pools: what re-uses or re-plays cached blocks is refused (ROADMAP
+    B.I.5 keeps the snapshots). The window's first position is read
+    off the device's `pos` band, so the engine runs this family one
+    step ahead of the host like the GPT block."""
     name = "sambay"
     caches = ("paged", "window", "state")
-    refused = ("prefix_cache_tokens", "kv_store", "spec_draft_len",
-               "kv_quant", "weight_quant", "adapter_registry",
-               "kv_fingerprints")
     refusal = "it keeps recurrent state beside its K/V blocks"
     cache_bytes = staticmethod(cache_bytes)
     reset_slot_state = staticmethod(reset_slot_state)
-
-    # the engine hands every family the same keywords; the ones this
-    # family refuses at construction arrive here as their defaults
-    @staticmethod
-    def decode_step(params, token, pos, tables, cache, cfg, adapters=None,
-                    adapter_idx=None, kernel="gather", kv_quant="none"):
-        return paged_decode_step(params, token, pos, tables, cache, cfg,
-                                 kernel=kernel)
+    decode = staticmethod(paged_decode_step)
+    prefill = staticmethod(paged_prefill_chunk)
 
     @staticmethod
-    def prefill_chunk(params, cache, chunk, start_pos, table_rows, cfg,
-                      true_len=None, adapters=None, adapter_idx=None,
-                      kernel="gather", kv_quant="none"):
-        return paged_prefill_chunk(params, cache, chunk, start_pos,
-                                   table_rows, cfg, true_len=true_len,
-                                   kernel=kernel)
-
-    @staticmethod
-    def init_cache(cfg, num_blocks, block_tokens, slots, kv_quant="none"):
+    def cache(cfg, num_blocks, block_tokens, slots):
         per_slot = -(-cfg.window // int(block_tokens)) + 1
         return init_cache(cfg, num_blocks, block_tokens, slots,
                           slots * per_slot)

@@ -20,15 +20,15 @@ shardings from `param_specs`.
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel.attention import sequence_parallel_attention
+from .parts import NEG_INF, Seam, paged_kernel_check, phys_rows
 from .scopes import scope
 
 __all__ = ["TransformerConfig", "init_params", "param_specs", "forward",
@@ -354,85 +354,6 @@ def prefill(params, tokens, cfg: TransformerConfig, max_len=None):
     return logits, cache
 
 
-def prefill_chunk(params, cache, chunk, start_pos, slot, cfg: TransformerConfig,
-                  true_len=None):
-    """Multi-token incremental prefill: extend slot `slot` of a slotted
-    cache ([S, L, H, Dh] per layer) by a [C]-token `chunk` whose first
-    token sits at position `start_pos` (tokens 0..start_pos-1 must
-    already be cached — written by earlier chunks or device-copied from
-    a prefix pool). Each chunk row attends to cache[0:start_pos] plus
-    the intra-chunk causal prefix, so running a prompt through
-    consecutive chunks is mathematically the monolithic prefill — and
-    BIT-identical to it, because every op mirrors forward()'s numerics
-    exactly: reference_attention's scale-into-q einsum and -1e30 mask
-    (NOT _cached_attention's divide-after-matmul/-inf variant — the two
-    differ in low bits), softmax in the score dtype, the same reshape/
-    matmul order per block, and forward(last_index=...)'s head on the
-    true last row.
-
-    `chunk` may be padded (pow-2 bucketing: compiled shapes stay
-    O(log max_len), the same discipline as the monolithic prefill);
-    `true_len` is the number of real tokens. Padded rows write their
-    K/V OUT OF RANGE (position L — scatter drops them, the same parking
-    trick the batched decode uses for dead slots), so the cache beyond
-    start_pos+true_len is never dirtied, and their attention output is
-    garbage that nothing reads. `start_pos`/`slot`/`true_len` are
-    traced scalars: one compile per chunk bucket, not per position.
-
-    Returns (logits [vocab] of the true last chunk row, new cache).
-    The logits are only meaningful on a prompt's FINAL chunk (where
-    start_pos + true_len == T0); earlier chunks exist for their cache
-    writes. MoE caveat (same as decode_step): reference_moe's capacity
-    cutoff couples rows, so MoE blocks are not bit-stable across
-    chunking — the serving family is dense."""
-    from ..parallel.attention import _NEG_INF
-
-    (C,) = chunk.shape
-    S, L, H, dh = cache[0]["k"].shape
-    if true_len is None:
-        true_len = C
-    scale = 1.0 / math.sqrt(dh)
-    offs = jnp.arange(C)
-    positions = start_pos + offs  # [C] global rows of the chunk
-    # padded rows park out of range: scatter DROPS them
-    wpos = jnp.where(offs < true_len, positions, jnp.int32(L))
-    x = params["embed"][chunk][None] + params["pos"][positions][None]
-    new_cache = []
-    for blk, kv in zip(params["blocks"], cache):
-        h = _ln(x, blk["ln1"])
-        q = (h @ blk["wq"]).reshape(1, C, cfg.heads, dh)
-        k = (h @ blk["wk"]).reshape(1, C, cfg.heads, dh)
-        v = (h @ blk["wv"]).reshape(1, C, cfg.heads, dh)
-        ck = kv["k"].at[slot, wpos].set(k[0].astype(kv["k"].dtype))
-        cv = kv["v"].at[slot, wpos].set(v[0].astype(kv["v"].dtype))
-        new_cache.append({"k": ck, "v": cv})
-        slot_k = jax.lax.dynamic_slice(ck, (slot, 0, 0, 0), (1, L, H, dh))
-        slot_v = jax.lax.dynamic_slice(cv, (slot, 0, 0, 0), (1, L, H, dh))
-        # reference_attention numerics, verbatim: scale folded into q
-        # BEFORE the matmul, -1e30 mask, softmax in the score dtype
-        s = jnp.einsum("bthd,bshd->bhts", q * scale, slot_k)
-        mask = jnp.arange(L)[None, :] <= positions[:, None]  # [C, L]
-        s = jnp.where(mask[None, None], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhts,bshd->bthd", p, slot_v)
-        x = x + o.reshape(1, C, cfg.dim) @ blk["wo"]
-        h = _ln(x, blk["ln2"])
-        if "moe" in blk:
-            from ..parallel.moe import reference_moe
-
-            mp = blk["moe"]
-            flat = h.reshape(C, cfg.dim)
-            y = reference_moe(flat, mp["gate_w"], mp["w1"], mp["b1"],
-                              mp["w2"], mp["b2"])
-            x = x + y.reshape(1, C, cfg.dim)
-        else:
-            x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
-    xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=1,
-                                      keepdims=False)  # [1, dim]
-    xl = _ln(xl, params["ln_f"])
-    return (xl @ params["embed"].T)[0], new_cache
-
-
 # ---------------------------------------------------------------------
 # paged KV cache (serving, ISSUE 7): the cache is a pool of fixed-size
 # token BLOCKS ([NB, Bt, H, Dh] per layer) and each slot owns a block
@@ -462,13 +383,6 @@ def prefill_chunk(params, cache, chunk, start_pos, slot, cfg: TransformerConfig,
 # reads dequantize in-kernel (fused) or on the gather view. "none" is
 # byte-identical to the pre-quant code path.
 # ---------------------------------------------------------------------
-
-
-def _paged_kernel_check(kernel: str):
-    if kernel not in ("gather", "fused"):
-        raise ValueError(
-            "paged kernel must be 'gather' or 'fused' (got %r)"
-            % (kernel,))
 
 
 # ---------------------------------------------------------------------
@@ -648,25 +562,6 @@ def _paged_view(buf, tables):
     return v.reshape(lead)
 
 
-def _phys_rows(tables, wpos, NB, Bt):
-    """Map global write positions to (physical block, in-block offset).
-    A position past the table span (the engine parks dead/padded rows
-    at MAXB*Bt, the paged analogue of the slab's position-L trick) or
-    landing on an unallocated (-1) entry resolves to block NB — out of
-    range, so the scatter DROPS the write."""
-    maxb = tables.shape[-1]
-    bi = wpos // Bt
-    safe = jnp.clip(bi, 0, maxb - 1)
-    if tables.ndim == 1:
-        phys = tables[safe]
-    elif safe.ndim == tables.ndim:
-        phys = jnp.take_along_axis(tables, safe, axis=-1)
-    else:  # one position per table row (the decode step's [S] case)
-        phys = jnp.take_along_axis(tables, safe[..., None], axis=-1)[..., 0]
-    phys = jnp.where((bi < maxb) & (phys >= 0), phys, jnp.int32(NB))
-    return phys, wpos % Bt
-
-
 def _adapter_delta(h, a, b, scale):
     """LoRA-style low-rank delta for one projection: h @ A @ B * scale
     with PER-SLOT adapter gathers (ISSUE 12 — Punica/S-LoRA batching:
@@ -714,108 +609,149 @@ def _adapter_qv(h, blk, li, adapters, idx):
     return q, v
 
 
+def _paged_lm(params, cache, tokens, positions, tables, wpos, attend, cfg,
+              adapters, adapter_idx, kv_quant, true_len=None):
+    """The layer loop of the three paged steps -> (logits, updated
+    cache): the embedding at `positions`; per layer the K/V write at
+    `wpos` through `tables` and the step's own `attend(q, k_pool,
+    v_pool, k_scale, v_scale, dtype)`; the head. Rows are [S] (decode)
+    or [S, K] (verify); with `true_len` (the chunk) one slot's [1, C],
+    whose head reads row true_len - 1."""
+    one = true_len is not None
+    quant = kv_quant != "none"
+    qmax = _KV_QMAX.get(kv_quant)
+    NB, Bt = cache[0]["k"].shape[0], cache[0]["k"].shape[1]
+    with scope("lm_embed"):
+        if one:
+            x = params["embed"][tokens][None] + params["pos"][positions][None]
+        else:
+            x = params["embed"][tokens] + params["pos"][positions]
+    lead = x.shape[:-1]
+    heads = lead + (cfg.heads, cfg.dim // cfg.heads)
+    new_cache = []
+    for li, (blk, kv) in enumerate(zip(params["blocks"], cache)):
+        with scope("lm_attention"):
+            h = _ln(x, blk["ln1"])
+            q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
+            q = q.reshape(heads)
+            k = (h @ blk["wk"]).reshape(heads)
+            v = v.reshape(heads)
+            pk, off = phys_rows(tables, wpos, NB, Bt)
+            if quant:  # a chunk commits from all its rows (never drafts)
+                ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
+                                         k[0] if one else k, qmax,
+                                         commit_from_call=one)
+                cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
+                                         v[0] if one else v, qmax,
+                                         commit_from_call=one)
+                new_cache.append({"k": ck, "v": cv,
+                                  "k_scale": ksc, "v_scale": vsc})
+            else:
+                ksc = vsc = None
+                ck = kv["k"].at[pk, off].set(
+                    (k[0] if one else k).astype(kv["k"].dtype))
+                cv = kv["v"].at[pk, off].set(
+                    (v[0] if one else v).astype(kv["v"].dtype))
+                new_cache.append({"k": ck, "v": cv})
+            o = attend(q, ck, cv, ksc, vsc, x.dtype)
+            x = x + o.reshape(lead + (cfg.dim,)) @ blk["wo"]
+        with scope("lm_mlp"):
+            h = _ln(x, blk["ln2"])
+            x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
+    with scope("lm_head"):
+        if one:
+            xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=1,
+                                              keepdims=False)  # [1, dim]
+            return (_ln(xl, params["ln_f"]) @ params["embed"].T)[0], \
+                new_cache
+        return _ln(x, params["ln_f"]) @ params["embed"].T, new_cache
+
+
+def _kv_view(buf, scale, tables):
+    """The gather form's view of one pool through `tables` [S, MAXB],
+    or one slot's row [MAXB] as [1, MAXB]: `_paged_view`, dequantized
+    (`_paged_deq_view`) where the pool carries scales."""
+    if tables.ndim == 1:
+        tables = tables[None]
+    if scale is None:
+        return _paged_view(buf, tables)
+    return _paged_deq_view(buf, scale, tables)
+
+
+def _masked_attention(q, ck, cv, ksc, vsc, tables, positions, dtype):
+    """The chunk's and verify's attention on the gathered views:
+    reference_attention's numerics (scale folded into q BEFORE the
+    matmul, the -1e30 mask, softmax in the score dtype). q [B, T, H,
+    dh]; `positions` [T] (one slot's chunk, `tables` its row) or
+    [B, T]."""
+    kview = _kv_view(ck, ksc, tables)
+    vview = _kv_view(cv, vsc, tables)
+    s = jnp.einsum("bthd,bshd->bhts", q * (1.0 / math.sqrt(q.shape[-1])),
+                   kview)
+    lv = jnp.arange(kview.shape[1])
+    if positions.ndim == 1:
+        mask = (lv[None, :] <= positions[:, None])[None, None]
+    else:
+        mask = (lv[None, None, :] <= positions[:, :, None])[:, None]
+    s = jnp.where(mask, s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", p, vview).astype(dtype)
+
+
 def paged_decode_step(params, token, pos, tables, cache,
                       cfg: TransformerConfig, adapters=None,
                       adapter_idx=None, kernel="gather",
                       kv_quant="none"):
     """One decode step over the paged pool: token [S] at per-row
     positions `pos` [S], block tables [S, MAXB] -> (logits [S, vocab],
-    updated cache). Mirrors decode_step's numerics verbatim
-    (_cached_attention's divide-after-matmul/-inf mask) on the gathered
-    per-slot view — or, with kernel="fused", attends through the block
-    table inside the Pallas kernel (parallel/paged_attention.py: same
-    scaling family, online softmax, no materialised view) — so a paged
-    engine row decodes to the same tokens the slab engine (and
-    sequential generate()) produces. A parked row (pos >= MAXB*Bt)
+    updated cache), with decode_step's numerics (_cached_attention) on
+    the gathered view, or through the block table inside the Pallas
+    kernel (kernel="fused", parallel/paged_attention.py), so a paged
+    row decodes to generate()'s tokens. A parked row (pos >= MAXB*Bt)
     writes nothing; its logits are garbage nothing reads. With
     `adapters`/`adapter_idx` [S], each slot's q/v projections gain its
-    tenant's LoRA delta gathered from the stacked adapter pool (ISSUE
-    12 — index 0 is the zero adapter, exact no-op); the adapter gather
-    is INSIDE this one compiled step, so N tenants retrace nothing.
+    tenant's LoRA delta gathered from the stacked adapter pool (index
+    0 is the zero adapter, an exact no-op); the adapter gather is
+    INSIDE this one compiled step, so N tenants retrace nothing.
     With `kv_quant` ('int8' | 'fp8'), writes quantize at the scatter
     (`_quant_scatter`: a block-opening row commits the block's scale,
     appends re-use it) and reads dequantize inside the fused kernel
     (scales ride as scalar-prefetch operands) or on the gather view —
     'none' is byte-identical to the pre-quant step."""
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     _kv_quant_check(kv_quant)
-    quant = kv_quant != "none"
-    qmax = _KV_QMAX.get(kv_quant)
-    B = token.shape[0]
-    dh = cfg.dim // cfg.heads
-    NB, Bt = cache[0]["k"].shape[0], cache[0]["k"].shape[1]
-    with scope("lm_embed"):
-        x = params["embed"][token] + params["pos"][pos]
-    new_cache = []
-    for li, (blk, kv) in enumerate(zip(params["blocks"], cache)):
-        with scope("lm_attention"):
-            h = _ln(x, blk["ln1"])
-            q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
-            q = q.reshape(B, cfg.heads, dh)
-            k = (h @ blk["wk"]).reshape(B, cfg.heads, dh)
-            v = v.reshape(B, cfg.heads, dh)
-            pk, off = _phys_rows(tables, pos, NB, Bt)
-            if quant:
-                ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
-                                         k, qmax)
-                cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
-                                         v, qmax)
-                new_cache.append({"k": ck, "v": cv,
-                                  "k_scale": ksc, "v_scale": vsc})
-            else:
-                ksc = vsc = None
-                ck = kv["k"].at[pk, off].set(k.astype(kv["k"].dtype))
-                cv = kv["v"].at[pk, off].set(v.astype(kv["v"].dtype))
-                new_cache.append({"k": ck, "v": cv})
-            if kernel == "fused":
-                from ..parallel.paged_attention import paged_decode_attention
 
-                o = paged_decode_attention(
-                    q, ck, cv, tables, pos, k_scale=ksc, v_scale=vsc
-                ).reshape(B, cfg.dim)
-            elif quant:
-                # f32 dequantized view: cast the attention output back to
-                # the activation dtype so quantization never silently
-                # promotes a bf16 model's residual stream (the fused
-                # kernel's out dtype is q's already)
-                o = _cached_attention(
-                    q, _paged_deq_view(ck, ksc, tables),
-                    _paged_deq_view(cv, vsc, tables), pos
-                ).astype(x.dtype).reshape(B, cfg.dim)
-            else:
-                o = _cached_attention(
-                    q, _paged_view(ck, tables), _paged_view(cv, tables), pos
-                ).reshape(B, cfg.dim)
-            x = x + o @ blk["wo"]
-        with scope("lm_experts" if "moe" in blk else "lm_mlp"):
-            h = _ln(x, blk["ln2"])
-            if "moe" in blk:
-                from ..parallel.moe import reference_moe
+    def attend(q, ck, cv, ksc, vsc, dtype):
+        if kernel == "fused":
+            from ..parallel.paged_attention import paged_decode_attention
 
-                mp = blk["moe"]
-                x = x + reference_moe(
-                    h, mp["gate_w"], mp["w1"], mp["b1"], mp["w2"], mp["b2"]
-                )
-            else:
-                x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
-    with scope("lm_head"):
-        logits = _ln(x, params["ln_f"]) @ params["embed"].T
-    return logits, new_cache
+            return paged_decode_attention(q, ck, cv, tables, pos,
+                                          k_scale=ksc, v_scale=vsc)
+        o = _cached_attention(q, _kv_view(ck, ksc, tables),
+                              _kv_view(cv, vsc, tables), pos)
+        # a dequantized view is f32: cast the output back so that
+        # quantization never promotes a bf16 model's residual stream
+        # (the fused kernel's out dtype is q's already)
+        return o if ksc is None else o.astype(dtype)
+
+    return _paged_lm(params, cache, token, pos, tables, pos, attend, cfg,
+                     adapters, adapter_idx, kv_quant)
 
 
 def paged_prefill_chunk(params, cache, chunk, start_pos, table_row,
                         cfg: TransformerConfig, true_len=None,
                         adapters=None, adapter_idx=None,
                         kernel="gather", kv_quant="none"):
-    """prefill_chunk over the paged pool: extend the slot whose block
-    table is `table_row` [MAXB] by a [C]-token chunk starting at
-    `start_pos`. Identical math to prefill_chunk (reference_attention's
-    scale-into-q einsum and -1e30 mask — see its docstring for why),
-    with the slot's contiguous cache replaced by the gathered block
-    view (kernel="gather") or by the in-kernel table walk
-    (kernel="fused" — parallel/paged_attention.py, same scale-into-q
-    family); padded rows (offs >= true_len) park their writes past the
-    table span, where the scatter drops them. `adapters`/`adapter_idx`
+    """Extend the slot whose block table is `table_row` [MAXB] by a
+    [C]-token chunk starting at `start_pos` (the positions before it
+    cached) -> (logits [vocab] of row true_len - 1, meaningful on a
+    prompt's last chunk; updated cache). A row attends the cache and
+    the chunk's causal prefix with forward()'s numerics
+    (reference_attention's scale-into-q einsum and -1e30 mask, not
+    _cached_attention's: they differ in low bits), gathered or through
+    the in-kernel table walk (kernel="fused"), so consecutive chunks
+    are the monolithic prefill. Padded rows (past `true_len`) park
+    their writes past the table span. `adapters`/`adapter_idx`
     (a SCALAR here — one slot prefills per chunk call) fold the slot's
     tenant LoRA delta into q/v exactly like paged_decode_step, so the
     cached K/V a chunk writes are the adapted model's. `kv_quant`
@@ -823,84 +759,29 @@ def paged_prefill_chunk(params, cache, chunk, start_pos, table_row,
     block it opens (absmax over the chunk's rows in that block) and
     clips into blocks earlier chunks committed — and dequantizes on
     the read, fused or gathered, like paged_decode_step."""
-    from ..parallel.attention import _NEG_INF
-
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     _kv_quant_check(kv_quant)
-    quant = kv_quant != "none"
-    qmax = _KV_QMAX.get(kv_quant)
     (C,) = chunk.shape
-    NB, Bt, H, dh = cache[0]["k"].shape
-    Lv = table_row.shape[0] * Bt
     if true_len is None:
         true_len = C
-    scale = 1.0 / math.sqrt(dh)
     offs = jnp.arange(C)
     positions = start_pos + offs  # [C] global rows of the chunk
-    wpos = jnp.where(offs < true_len, positions, jnp.int32(Lv))
-    with scope("lm_embed"):
-        x = params["embed"][chunk][None] + params["pos"][positions][None]
-    new_cache = []
-    for li, (blk, kv) in enumerate(zip(params["blocks"], cache)):
-        with scope("lm_attention"):
-            h = _ln(x, blk["ln1"])
-            q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
-            q = q.reshape(1, C, cfg.heads, dh)
-            k = (h @ blk["wk"]).reshape(1, C, cfg.heads, dh)
-            v = v.reshape(1, C, cfg.heads, dh)
-            pk, off = _phys_rows(table_row, wpos, NB, Bt)
-            if quant:
-                # call-commit: the chunk's whole fill of each opened block
-                # is real prompt content (never speculative), so the
-                # block scale sees every row — the best absmax available
-                ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
-                                         k[0], qmax, commit_from_call=True)
-                cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
-                                         v[0], qmax, commit_from_call=True)
-                new_cache.append({"k": ck, "v": cv,
-                                  "k_scale": ksc, "v_scale": vsc})
-            else:
-                ksc = vsc = None
-                ck = kv["k"].at[pk, off].set(k[0].astype(kv["k"].dtype))
-                cv = kv["v"].at[pk, off].set(v[0].astype(kv["v"].dtype))
-                new_cache.append({"k": ck, "v": cv})
-            if kernel == "fused":
-                from ..parallel.paged_attention import (
-                    paged_prefill_attention)
+    wpos = jnp.where(offs < true_len, positions,
+                     jnp.int32(table_row.shape[0] * cache[0]["k"].shape[1]))
 
-                o = paged_prefill_attention(
-                    q[0], ck, cv, table_row, start_pos,
-                    k_scale=ksc, v_scale=vsc)[None]
-            else:
-                if quant:
-                    slot_k = _paged_deq_view(ck, ksc, table_row[None])
-                    slot_v = _paged_deq_view(cv, vsc, table_row[None])
-                else:
-                    slot_k = _paged_view(ck, table_row[None])  # [1, Lv, H, dh]
-                    slot_v = _paged_view(cv, table_row[None])
-                s = jnp.einsum("bthd,bshd->bhts", q * scale, slot_k)
-                mask = jnp.arange(Lv)[None, :] <= positions[:, None]
-                s = jnp.where(mask[None, None], s, _NEG_INF)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhts,bshd->bthd", p, slot_v).astype(x.dtype)
-            x = x + o.reshape(1, C, cfg.dim) @ blk["wo"]
-        with scope("lm_experts" if "moe" in blk else "lm_mlp"):
-            h = _ln(x, blk["ln2"])
-            if "moe" in blk:
-                from ..parallel.moe import reference_moe
+    def attend(q, ck, cv, ksc, vsc, dtype):
+        if kernel == "fused":
+            from ..parallel.paged_attention import paged_prefill_attention
 
-                mp = blk["moe"]
-                flat = h.reshape(C, cfg.dim)
-                y = reference_moe(flat, mp["gate_w"], mp["w1"], mp["b1"],
-                                  mp["w2"], mp["b2"])
-                x = x + y.reshape(1, C, cfg.dim)
-            else:
-                x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
-    with scope("lm_head"):
-        xl = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=1,
-                                          keepdims=False)  # [1, dim]
-        logits = (_ln(xl, params["ln_f"]) @ params["embed"].T)[0]
-    return logits, new_cache
+            return paged_prefill_attention(
+                q[0], ck, cv, table_row, start_pos,
+                k_scale=ksc, v_scale=vsc)[None]
+        return _masked_attention(q, ck, cv, ksc, vsc, table_row, positions,
+                                 dtype)
+
+    return _paged_lm(params, cache, chunk, positions, table_row, wpos,
+                     attend, cfg, adapters, adapter_idx, kv_quant,
+                     true_len=true_len)
 
 
 def paged_verify_step(params, cache, window, pos, wpos, tables,
@@ -920,82 +801,27 @@ def paged_verify_step(params, cache, window, pos, wpos, tables,
     always pos[s] + i. logits[s, i] is "the next token after
     window[s, :i+1]" — exactly decode_step's answer when drafts
     0..i match what the model would have produced, which is what the
-    engine's acceptance rule checks. Chunk-family numerics
-    (scale-into-q, -1e30 mask), the same low-bit-vs-decode_step class
-    prefill_chunk documents; kernel="fused" runs the same family
-    through the in-kernel table walk (parallel/paged_attention.py).
-    `kv_quant` quantizes the window's writes at the scatter (a window
+    engine's acceptance rule checks. The chunk's numerics, gathered or
+    fused (`paged_prefill_chunk`). `kv_quant` quantizes the window's
+    writes at the scatter (a window
     row opening a fresh block commits its scale; re-writes of rejected
     draft positions clip to the committed scale until the block is
     re-opened) and dequantizes the reads, fused or gathered."""
-    from ..parallel.attention import _NEG_INF
-
-    _paged_kernel_check(kernel)
+    paged_kernel_check(kernel)
     _kv_quant_check(kv_quant)
-    quant = kv_quant != "none"
-    qmax = _KV_QMAX.get(kv_quant)
-    S, K = window.shape
-    NB, Bt, H, dh = cache[0]["k"].shape
-    Lv = tables.shape[1] * Bt
-    scale = 1.0 / math.sqrt(dh)
-    positions = pos[:, None] + jnp.arange(K)[None, :]  # [S, K]
-    with scope("lm_embed"):
-        x = params["embed"][window] + params["pos"][positions]
-    new_cache = []
-    for li, (blk, kv) in enumerate(zip(params["blocks"], cache)):
-        with scope("lm_attention"):
-            h = _ln(x, blk["ln1"])
-            q, v = _adapter_qv(h, blk, li, adapters, adapter_idx)
-            q = q.reshape(S, K, cfg.heads, dh)
-            k = (h @ blk["wk"]).reshape(S, K, cfg.heads, dh)
-            v = v.reshape(S, K, cfg.heads, dh)
-            pk, off = _phys_rows(tables, wpos, NB, Bt)  # [S, K]
-            if quant:
-                ck, ksc = _quant_scatter(kv["k"], kv["k_scale"], pk, off,
-                                         k, qmax)
-                cv, vsc = _quant_scatter(kv["v"], kv["v_scale"], pk, off,
-                                         v, qmax)
-                new_cache.append({"k": ck, "v": cv,
-                                  "k_scale": ksc, "v_scale": vsc})
-            else:
-                ksc = vsc = None
-                ck = kv["k"].at[pk, off].set(k.astype(kv["k"].dtype))
-                cv = kv["v"].at[pk, off].set(v.astype(kv["v"].dtype))
-                new_cache.append({"k": ck, "v": cv})
-            if kernel == "fused":
-                from ..parallel.paged_attention import (
-                    paged_verify_attention)
+    positions = pos[:, None] + jnp.arange(window.shape[1])[None, :]
 
-                o = paged_verify_attention(q, ck, cv, tables, pos,
-                                           k_scale=ksc, v_scale=vsc)
-            else:
-                if quant:
-                    kview = _paged_deq_view(ck, ksc, tables)
-                    vview = _paged_deq_view(cv, vsc, tables)
-                else:
-                    kview = _paged_view(ck, tables)  # [S, Lv, H, dh]
-                    vview = _paged_view(cv, tables)
-                s = jnp.einsum("bthd,bshd->bhts", q * scale, kview)
-                mask = jnp.arange(Lv)[None, None, :] <= positions[:, :, None]
-                s = jnp.where(mask[:, None], s, _NEG_INF)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhts,bshd->bthd", p, vview).astype(x.dtype)
-            x = x + o.reshape(S, K, cfg.dim) @ blk["wo"]
-        with scope("lm_experts" if "moe" in blk else "lm_mlp"):
-            h = _ln(x, blk["ln2"])
-            if "moe" in blk:
-                from ..parallel.moe import reference_moe
+    def attend(q, ck, cv, ksc, vsc, dtype):
+        if kernel == "fused":
+            from ..parallel.paged_attention import paged_verify_attention
 
-                mp = blk["moe"]
-                flat = h.reshape(S * K, cfg.dim)
-                y = reference_moe(flat, mp["gate_w"], mp["w1"], mp["b1"],
-                                  mp["w2"], mp["b2"])
-                x = x + y.reshape(S, K, cfg.dim)
-            else:
-                x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
-    with scope("lm_head"):
-        logits = _ln(x, params["ln_f"]) @ params["embed"].T
-    return logits, new_cache
+            return paged_verify_attention(q, ck, cv, tables, pos,
+                                          k_scale=ksc, v_scale=vsc)
+        return _masked_attention(q, ck, cv, ksc, vsc, tables, positions,
+                                 dtype)
+
+    return _paged_lm(params, cache, window, positions, tables, wpos, attend,
+                     cfg, adapters, adapter_idx, kv_quant)
 
 
 def logits_trap(logits):
@@ -1184,8 +1010,7 @@ def generate(params, prompt, cfg: TransformerConfig, max_new_tokens,
     return jnp.concatenate([prompt, buf], axis=1)
 
 
-__all__ += ["init_kv_cache", "decode_step", "prefill", "prefill_chunk",
-            "generate"]
+__all__ += ["init_kv_cache", "decode_step", "prefill", "generate"]
 
 
 # diagnostics of the last eager beam_search_generate call: executed vs
@@ -1316,17 +1141,9 @@ def beam_search_generate(params, prompt, cfg: TransformerConfig,
 __all__ += ["beam_search_generate"]
 
 
-class _Serving(object):
-    """What ServingEngine asks a model family for (ISSUE 27): its
-    cache, the bodies of its two compiled steps, and the engine options
-    it cannot honour, and the caches it keeps (`caches`: "paged" is
-    the block pool on the engine's one table; "window" adds window
-    tables whose blocks are freed behind the window; "state" adds
-    per-slot recurrent state, zeroed at admission). The GPT block keeps
-    the pool alone and honours every option; `models/sambay.py` and
-    `models/granite_hybrid.py` fill the same seam for theirs."""
+class _Serving(Seam):
+    """The GPT block keeps the pool alone and honours every option."""
     name = "gpt"
-    caches = ("paged",)
     refused = ()
     decode_step = staticmethod(paged_decode_step)
     prefill_chunk = staticmethod(paged_prefill_chunk)

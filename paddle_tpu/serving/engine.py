@@ -263,8 +263,7 @@ class ServingEngine(object):
     table inside the kernel (parallel/paged_attention.py — no
     per-layer gathered view; the default on accelerator backends),
     "gather" = the XLA `_paged_view` form (the CPU-backend default,
-    where fused would run interpreted); `PADDLE_TPU_PAGED_KERNEL`
-    overrides when the arg is None. Greedy outputs are token-identical
+    where fused would run interpreted). Greedy outputs are token-identical
     either way (tests/test_paged_kernel.py pins it per primitive and
     end-to-end).
 
@@ -289,11 +288,13 @@ class ServingEngine(object):
     folded into the compiled steps — the decode HBM roofline's weight
     term drops ~4x independently of the KV side.
 
-    Model families (ISSUE 27, 31): the engine asks `cfg.serving` for
-    the cache and for the bodies of its two compiled steps — same
-    scheduler, allocator, side-bands, sampler, spans and program names
-    whatever the family — and for the caches it keeps
-    (`cfg.serving.caches`), and builds those and no others:
+    Model families: the engine asks `cfg.serving`, the
+    family's seam (a `models/parts.Seam`), for the cache and for the
+    bodies of its two compiled steps — same scheduler, allocator,
+    side-bands, sampler, spans and program names whatever the family —
+    and for the caches it keeps (`cfg.serving.caches`), and builds
+    those and no others. A family is its own mixers, caches and steps
+    over the shared parts of `models/parts.py`:
 
       models/transformer.SERVING      ("paged",): the GPT block, one
                                       block pool on one table
@@ -318,17 +319,18 @@ class ServingEngine(object):
     independent: a family may keep window tables without state
     (`models/afmoe.SERVING`, `("paged", "window")`: nothing is reset at
     admission). The options a family cannot honour
-    (`cfg.serving.refused`: every family with state or window tables
-    refuses the prefix cache, the KV store, speculation, KV and weight
-    quantization, adapters and fingerprints; hand-off import at
-    `submit`) raise a ValueError that names the option and gives the
-    family's own reason (`cfg.serving.refusal`: a recurrent state that
-    block aliasing cannot restore, blocks freed behind the window);
-    nothing is silently ignored. A family whose decode step computes
-    counters on the device (`cfg.serving.step_counters`: the router's,
-    for a family with routed experts) returns them beside the logits;
-    they ride the step's one packed result into the metrics of the
-    same names.
+    (`cfg.serving.refused`: by the seam's default, every family but
+    the GPT block refuses the prefix cache, the KV store, speculation,
+    KV and weight quantization, adapters and fingerprints, which need
+    its per-head K/V blocks; hand-off import at `submit`) raise a
+    ValueError that names the option and gives the family's own reason
+    (`cfg.serving.refusal`: a recurrent state that block aliasing
+    cannot restore, blocks freed behind the window); nothing is
+    silently ignored. A family whose decode step computes counters on
+    the device (`cfg.serving.step_counters`, empty by default: the
+    router's, for a family with routed experts) returns them beside
+    the logits; they ride the step's one packed result into the
+    metrics of the same names.
 
     One decode program, one decode loop, two depths (ISSUE 29): the
     plain one-token decode is ONE compiled program (`_make_decode`)
@@ -393,7 +395,6 @@ class ServingEngine(object):
         fam = self._family = getattr(cfg, "serving", None) or tlm.SERVING
         asked = {"prefix_cache_tokens": prefix_cache_tokens,
                  "kv_store": kv_store, "spec_draft_len": spec_draft_len,
-                 "async_dispatch": async_dispatch,
                  "kv_quant": kv_quant != "none",
                  "weight_quant": weight_quant,
                  "adapter_registry": adapter_registry,
@@ -416,10 +417,9 @@ class ServingEngine(object):
             # the GPT block's Switch layer (parallel/moe.py) has a
             # capacity cutoff that couples rows: padded chunk rows
             # would compete with real rows for expert slots and
-            # silently change real outputs (prefill_chunk docstring) —
-            # refuse loudly instead. Routed experts are served by a
-            # family whose layer has no capacity
-            # (parallel/routed_experts.py, ISSUE 33)
+            # silently change real outputs — refuse loudly instead.
+            # Routed experts are served by a family whose layer has no
+            # capacity (parallel/routed_experts.py)
             raise ValueError(
                 "the Switch layer of moe_experts > 0 has a capacity "
                 "cutoff that couples rows: not bit-stable under "
@@ -475,14 +475,12 @@ class ServingEngine(object):
         # N run under N+1's device time; emission then runs one step
         # behind. None (the default) = ahead wherever the engine can:
         # not under speculation (its acceptance is a host decision
-        # after every verify), nor for a family whose seam refuses it
-        # (none does: the hybrid family's window tables advance for
-        # the position the dispatched step writes, ISSUE 30). False =
-        # lock-step: a token leaves the engine in the step() that
-        # computed it.
+        # after every verify); every family runs ahead (the hybrid
+        # family's window tables advance for the position the
+        # dispatched step writes). False = lock-step: a
+        # token leaves the engine in the step() that computed it.
         if async_dispatch is None:
-            async_dispatch = (self.spec_draft_len is None
-                              and "async_dispatch" not in fam.refused)
+            async_dispatch = self.spec_draft_len is None
         self.async_dispatch = bool(async_dispatch)
         if self.spec_draft_len is not None and self.async_dispatch:
             # speculative acceptance is a HOST decision after every
@@ -499,17 +497,17 @@ class ServingEngine(object):
         # (parallel/paged_attention.py — no per-layer gathered view);
         # "gather" keeps the XLA `_paged_view` form. Fixed for the
         # engine's lifetime (it is baked into the compiled steps);
-        # resolution: explicit arg > PADDLE_TPU_PAGED_KERNEL > backend
-        # default. The oracle suite (tests/test_paged_kernel.py) is
-        # green, so the default IS flipped to "fused" — on accelerator
+        # resolution: explicit arg > backend default. The oracle suite
+        # (tests/test_paged_kernel.py) is green, so the default IS
+        # flipped to "fused" — on accelerator
         # backends, where the kernel compiles to Mosaic. The CPU
         # backend keeps "gather": there the fused path runs the
         # identical kernel INTERPRETED (resolve_interpret), ~4x slower
         # per step and ~1.5x per compile — correct but the wrong
         # default for a CI backend; the paged-kernel suite and the
         # serving_paged_kernel bench force "fused" explicitly on CPU.
-        pk = paged_kernel or os.environ.get("PADDLE_TPU_PAGED_KERNEL") \
-            or ("gather" if jax.default_backend() == "cpu" else "fused")
+        pk = paged_kernel or (
+            "gather" if jax.default_backend() == "cpu" else "fused")
         if pk not in ("fused", "gather"):
             raise ValueError(
                 "paged_kernel must be 'fused' or 'gather' (got %r)"
@@ -596,14 +594,14 @@ class ServingEngine(object):
         # counters a family's decode step computes on the device (the
         # router's, for a family with experts): running stats of the
         # metrics under the same names, read off the packed result
-        self._step_counters = tuple(getattr(fam, "step_counters", ()))
+        self._step_counters = fam.step_counters
         self._state_bytes_per_slot = 0
         self._state_reset_fn = None
         call_block = None  # a merged 3-D pool's block, K + V
         # a family that sizes its own caches (every family but the GPT
         # block's: merged pools, window pools, state, a latent pool)
         # reports their bytes by kind
-        self._by_kind = getattr(fam, "cache_bytes", None) is not None
+        self._by_kind = fam.cache_bytes is not None
         if self._by_kind:
             sizes = fam.cache_bytes(cfg, Bt)
             block_bytes = sizes["full"]

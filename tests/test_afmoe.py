@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.models import afmoe as af
+from paddle_tpu.models import parts
 from paddle_tpu.parallel import routed_experts as rx
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.kv_blocks import WindowBlockTables
@@ -104,13 +105,14 @@ def test_parameter_counts_of_the_published_shape_and_of_the_cut(ref):
     expert_layer = attn + 4 * d + E * 3 * d * m + 3 * d * m + d * E + E
     dense_layer = attn + 4 * d + 3 * d * 6144
     for shape, dense, expert in ((whole, 2, 30), (cut, 1, 4)):
-        n = af.param_count(af.AfmoeConfig(**shape))
+        n = parts.param_count(af.param_shapes(af.AfmoeConfig(**shape)))
         assert n == ref.param_count(shape)
         assert n == (dense * dense_layer + expert * expert_layer
                      + 2 * V * d + d)
-    assert 26.0e9 < af.param_count(af.AfmoeConfig(**whole)) < 26.2e9
-    assert af.param_count(af.AfmoeConfig(**cut)) == conf["parameters"] \
-        == 4_241_534_720
+    assert 26.0e9 < parts.param_count(
+        af.param_shapes(af.AfmoeConfig(**whole))) < 26.2e9
+    assert parts.param_count(af.param_shapes(af.AfmoeConfig(**cut))) \
+        == conf["parameters"] == 4_241_534_720
     # every width, all experts, top-8 and the vocabulary are the
     # published ones; only the depth and the position cap are cut
     assert (cut["dim"], cut["heads"], cut["kv_heads"], cut["head_dim"]) == (
@@ -234,16 +236,16 @@ def test_a_rows_experts_do_not_depend_on_the_rows_beside_it(cfg, params):
     batch and beside rows that do not count — to the last bit of the
     products' summation order (1e-6 on values of size ~1)."""
     p, u32 = _layer_inputs(params, 8)
-    full, _ = af.moe_ffn(u32, p, cfg, jnp.ones(8, bool))
-    alone, _ = af.moe_ffn(u32[3:4], p, cfg, jnp.ones(1, bool))
-    beside, stats = af.moe_ffn(
+    full, _ = parts.moe_ffn(u32, p, cfg, jnp.ones(8, bool))
+    alone, _ = parts.moe_ffn(u32[3:4], p, cfg, jnp.ones(1, bool))
+    beside, stats = parts.moe_ffn(
         u32, p, cfg, jnp.asarray([False] * 3 + [True] + [False] * 4))
     assert np.abs(np.asarray(full[3] - alone[0])).max() < 1e-6
     assert np.abs(np.asarray(full[3] - beside[3])).max() < 1e-6
     # ... and the rows that do not count reached nobody: the shared
     # expert is all they get
     assert int(stats[0]) == SHAPE["top_k"] and int(stats[1]) == 1
-    shared = af._mlp(u32, p["shared"])
+    shared = parts.mlp(u32, p["shared"])
     assert np.abs(np.asarray(beside[0] - shared[0])).max() < 1e-6
 
 
@@ -265,11 +267,11 @@ def test_the_shares_of_eight_chips_add_up_to_the_whole_layer(ref, cfg,
                                       shared_expert_held=(i == 3)))
         mine = dict(p, experts=jax.tree_util.tree_map(
             lambda a: a[lo:hi], p["experts"]))
-        part, stats = af.moe_ffn(u32, mine, share, jnp.ones(10, bool))
+        part, stats = parts.moe_ffn(u32, mine, share, jnp.ones(10, bool))
         assert int(stats[0]) <= 2
         total += np.asarray(part)
     assert np.abs(total - want).max() < 1e-5
-    whole, _ = af.moe_ffn(u32, p, cfg, jnp.ones(10, bool))
+    whole, _ = parts.moe_ffn(u32, p, cfg, jnp.ones(10, bool))
     assert np.abs(np.asarray(whole) - want).max() < 1e-5
     with pytest.raises(ValueError, match="experts_held"):
         af.AfmoeConfig(**dict(SHAPE, experts_held=(8, 20)))

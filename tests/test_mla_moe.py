@@ -34,6 +34,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.models import mla_moe as ml
+from paddle_tpu.models import parts
 from paddle_tpu.parallel import paged_attention as pa
 from paddle_tpu.serving import ServingEngine
 
@@ -109,14 +110,14 @@ def test_parameter_counts_of_the_published_shape_and_of_the_cut(ref):
 
     dense_layer = attn + norms + 3 * d * 6144
     for shape, held, expert in ((whole, 128, 47), (cut, 16, 7)):
-        n = ml.param_count(ml.MlaMoeConfig(**shape))
+        n = parts.param_count(ml.param_shapes(ml.MlaMoeConfig(**shape)))
         assert n == ref.param_count(shape)
         assert n == (dense_layer + expert * expert_layer(held)
                      + 2 * V * d + d)
-    assert ml.param_count(ml.MlaMoeConfig(**whole)) == \
+    assert parts.param_count(ml.param_shapes(ml.MlaMoeConfig(**whole))) == \
         conf["parameters_published"] == 30_670_815_104
-    assert ml.param_count(ml.MlaMoeConfig(**cut)) == conf["parameters"] \
-        == 1_370_266_496
+    assert parts.param_count(ml.param_shapes(ml.MlaMoeConfig(**cut))) \
+        == conf["parameters"] == 1_370_266_496
     # every width, the router's 128 outputs, top-6 and the vocabulary
     # are the published ones; the depth, the experts held and the
     # position cap are the cut
@@ -219,7 +220,7 @@ def test_the_shares_of_eight_chips_add_up_to_the_whole_layer(ref, cfg,
                                        shared_expert_held=(i == 5)))
         mine = dict(p, experts=jax.tree_util.tree_map(
             lambda a: a[i:i + 1], p["experts"]))
-        part, stats = ml.moe_ffn(u32, mine, share, jnp.ones(10, bool))
+        part, stats = parts.moe_ffn(u32, mine, share, jnp.ones(10, bool))
         assert int(stats[0]) <= 1
         total += np.asarray(part)
     assert np.abs(total - want).max() < 1e-5

@@ -490,19 +490,15 @@ def test_fused_compile_counts_and_zero_paged_view_gathers(monkeypatch):
     assert eng.metrics.trace_counts.get("decode_step", 0) == 0
 
 
-def test_paged_kernel_knob_resolution_and_validation(monkeypatch):
+def test_paged_kernel_knob_resolution_and_validation():
     cfg, params = _mk(8)
-    # env override wins over the backend default…
-    monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "fused")
-    eng = ServingEngine(params, cfg, max_slots=1)
-    assert eng.paged_kernel == "fused"
-    # …and the explicit arg wins over the env
-    eng = ServingEngine(params, cfg, max_slots=1, paged_kernel="gather")
-    assert eng.paged_kernel == "gather"
-    monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "mosaic")
+    # the explicit arg wins over the backend default, either way…
+    for kernel in ("fused", "gather"):
+        eng = ServingEngine(params, cfg, max_slots=1, paged_kernel=kernel)
+        assert eng.paged_kernel == kernel
+    # …and a kernel that does not exist is refused
     with pytest.raises(ValueError):
-        ServingEngine(params, cfg, max_slots=1)
-    monkeypatch.delenv("PADDLE_TPU_PAGED_KERNEL")
+        ServingEngine(params, cfg, max_slots=1, paged_kernel="mosaic")
     # the backend default on this CI host (CPU) is the gather form —
     # fused would run interpreted; accelerator backends default fused
     eng = ServingEngine(params, cfg, max_slots=1)

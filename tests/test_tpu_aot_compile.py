@@ -1250,6 +1250,34 @@ def test_other_families_decode_programs_are_the_text_last_meant(
     assert _text_digest(programs(family).text)[0] == _DECODE_TEXT_SHA[family]
 
 
+# every family's prefill chunk program at the largest chunk `_FAMILIES`
+# names, as the tree compiles it: sha256 of the compiled text less
+# locations and less the kernels' embedded bodies, as
+# `_DECODE_TEXT_SHA` above. A PR that MEANS to change one of them
+# replaces its digest (the failing assertion prints the new one) and
+# says so in CHANGES.md.
+_CHUNK_TEXT_SHA = {
+    "gpt": (
+        "550909a765d977b897293158f210a8331359cdbb45c30505c53a04920926fea2"),
+    "hybrid": (
+        "43abb7817f254d8c75b2712b69c764b7bea886cc2d99e5796013d4ab68ea194d"),
+    "granite": (
+        "2fcdd9b2ff8b2557ce995d81538f4c3679fa12cb02694acfe4474ec05a020f7f"),
+    "granite_moe": (
+        "31af3046efc46c209f7aef340bcd0d29862c988484838587d35505a0e7d92c85"),
+    "afmoe": (
+        "f5ee9ac814d074bd6c3e106882706f74104c2215a1d796da358c9880a81ab3f5"),
+    "mla": (
+        "886d49057700cd329fe5acf57695f07255c506ac12507800d1eab794bbc56371"),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_CHUNK_TEXT_SHA))
+def test_chunk_programs_are_the_text_last_meant(programs, family):
+    assert (_text_digest(programs(family, "chunk").text)[0]
+            == _CHUNK_TEXT_SHA[family])
+
+
 # ---------------------------------------------------------------------
 # the device scopes (ISSUE 35): every family's compiled steps name
 # their parts from the one vocabulary of `models/scopes.py`, which a
